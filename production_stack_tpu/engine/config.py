@@ -60,8 +60,8 @@ class ModelConfig:
     attention_impl: str = "auto"
     # Per-shape overrides resolved by the model runner's compile probe:
     # decode and prefill kernels degrade to XLA *independently* (a
-    # Mosaic failure in one must not discard the other — round-2
-    # lesson, VERDICT §weak 3). None = follow attention_impl.
+    # Mosaic failure in one must not discard the other). None =
+    # follow attention_impl.
     attention_impl_decode: Optional[str] = None
     attention_impl_prefill: Optional[str] = None
     # Unified-step ([R, W] mixed batch) kernel, resolved separately: the
@@ -234,8 +234,9 @@ class SchedulerConfig:
     # Deferred KV writes inside a decode burst: append each step's K/V
     # to a dense [B, S, kv, d] tail (one-hot select, no scatter) and
     # flush the tail to the pages ONCE per burst per layer. Motivated
-    # by the round-5 on-chip ablation (results/round5_notes.md): the
-    # per-step paged scatters cost ~5.1 of 11.1 ms for ~1 MB written.
+    # by a decode ablation (builder-captured 2026-07-31, not measured
+    # by the driver): the per-step paged scatters cost ~5.1 of 11.1 ms
+    # for ~1 MB written.
     # Llama-family single-runner path only (guarded in model_runner);
     # requires decode_steps > 1.
     deferred_kv_writes: bool = False
@@ -708,8 +709,8 @@ EXCLUSIVITY_RULES = (
 
 def bench_1b_model_config() -> ModelConfig:
     """The 1B-class llama geometry the TPU bench serves (bench.py) and
-    benchmarks/chip_sweep.sh's ``--model bench-1b`` server runs — one
-    definition so the sweep drives exactly the benched config."""
+    the ``--model bench-1b`` server builds (chip_smoke.py) — one
+    definition so the server runs exactly the benched config."""
     return ModelConfig(
         name="llama-1b-class",
         architecture="llama",

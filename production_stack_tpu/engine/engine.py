@@ -734,6 +734,30 @@ class LLMEngine:
                 if self._tracer is not None:
                     self._trace_finish(seq)
 
+    def abort_after_step_failure(self) -> List[StepOutput]:
+        """A step raised: abort every sequence that holds device state.
+
+        The rows a failed step scheduled are the running sequences and
+        the waiting ones that already own pages (chunked prefill keeps
+        a sequence in ``waiting`` until its last chunk commits). Their
+        KV cannot be trusted — the step's donated cache buffers may be
+        consumed and the scheduler's bookkeeping may be ahead of what
+        reached the pages — so retrying them repeats the failure
+        forever (a Mosaic refusal or an HBM OOM at first dispatch used
+        to show up as a request that never ends). Waiting sequences
+        the device never touched stay queued."""
+        outputs: List[StepOutput] = []
+        with self._lock:
+            self._in_flight = None
+            self.metrics.set_inflight_depth(0)
+            touched = list(self.scheduler.running) + [
+                s for s in self.scheduler.waiting if s.pages]
+            for seq in touched:
+                self.scheduler.abort_sequence(seq)
+                outputs.append(self._delta(seq, None))
+        self._pop_finished(outputs)
+        return outputs
+
     def _trace_finish(self, seq: Sequence) -> None:
         """Finalize ``seq``'s engine span (caller checked the tracer)."""
         self._tracer.finish(

@@ -37,10 +37,11 @@ import statistics
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-# Peak bf16 matmul FLOP/s per chip for the MFU estimate (same table
-# as bench.py's _PEAK_FLOPS). Prefix-matched against
+# Peak bf16 matmul FLOP/s per chip, the ONE table (the engine's MFU
+# gauge and bench.py both read it). Prefix-matched against
 # ``device.device_kind``; an unknown device (including CPU) resolves
-# to 0.0 so the MFU gauge reads 0 instead of lying.
+# to 0.0 so the gauge reads 0 instead of lying, and bench.py treats
+# 0.0 as an error rather than assuming a peak.
 PEAK_FLOPS_BY_DEVICE_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -239,15 +240,20 @@ class PerfObservatory:
             "page_size": self.config.cache.page_size,
             "param_count": self.param_count,
         }
-        try:  # backend-dependent; absent on CPU
-            import jax
-            stats = jax.devices()[0].memory_stats()
-            if stats:
-                report["device"] = {
-                    k: int(v) for k, v in stats.items()
-                    if isinstance(v, (int, float))}
-        except Exception:
-            pass
+        import jax
+        devices = jax.local_devices()
+        stats = devices[0].memory_stats()  # backend-dependent: None on CPU
+        if stats:
+            report["device"] = {
+                k: int(v) for k, v in stats.items()
+                if isinstance(v, (int, float))}
+            # Every local device, so a sharded engine shows its
+            # weights and pages spread out rather than on device 0.
+            report["devices"] = [
+                {"id": d.id, **{k: int(d.memory_stats().get(k, 0))
+                                for k in ("bytes_in_use",
+                                          "peak_bytes_in_use")}}
+                for d in devices]
         return report
 
     # ---- step-time / MFU ledger ------------------------------------------

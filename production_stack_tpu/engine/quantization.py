@@ -94,7 +94,8 @@ def init_random_quantized(init_fn, config: ModelConfig,
     bf16 model plus f32 quantization copies on device — a 16 GB HBM
     chip cannot hold that for an 8B model even though the final int8
     footprint (~8 GB) fits comfortably (observed: RESOURCE_EXHAUSTED
-    on the round-5 8B bench, results/round5_notes.md). Random weights
+    at the 8B bench config, builder-captured 2026-07-31). Random
+    weights
     carry no information worth quantizing, so the projection targets
     are sampled directly as int8 (uniform) with a flat per-channel
     scale matching the init distribution's magnitude; only the

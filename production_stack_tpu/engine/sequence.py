@@ -149,8 +149,7 @@ class Sequence:
     first_token_time: Optional[float] = None
     # When the scheduler first planned this sequence's prefill: splits
     # client TTFT into queueing (arrival -> here) vs prefill compute
-    # (here -> first_token_time) — VERDICT r2 asked for the honest
-    # decomposition.
+    # (here -> first_token_time).
     first_scheduled_time: Optional[float] = None
     # Wall time of the latest decode-step emission for this sequence:
     # inter-token latency is observed per token as steps complete

@@ -54,8 +54,7 @@ class BenchTokenizer(ByteTokenizer):
     0-255) — greedy tokens under random weights are almost surely
     >= 256, which ByteTokenizer.decode silently drops, so a streaming
     client sees only empty content deltas: no TTFT signal and
-    gen_tokens == 0 (observed in the round-5 engine QPS sweep,
-    benchmarks/results/round5_notes.md). Here every id >= 258 decodes
+    gen_tokens == 0. Here every id >= 258 decodes
     to one printable ASCII char, so each generated token yields
     exactly one non-empty delta — what a latency benchmark needs —
     while encode stays byte-level (realistic prompt token counts).

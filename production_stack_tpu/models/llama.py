@@ -124,8 +124,7 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
       per_layer: tuples of L [kv, pages, d, page_size] buffers; this
                  layer's buffer is updated and the tuple rebuilt, so
                  each scatter/kernel operand is ONE layer's buffer and
-                 jit donation aliases the L buffers 1:1 (the round-3
-                 decode-roofline experiment, round3_onchip_notes §0.6).
+                 jit donation aliases the L buffers 1:1.
 
     Returns ``(attn, k_cache, v_cache)``; callers must thread the
     returned caches so the buffer chain stays linear (see

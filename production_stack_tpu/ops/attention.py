@@ -132,9 +132,9 @@ def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
                   slot: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
     """One decode token into its burst-tail slot (deferred KV write).
 
-    The round-5 decode ablation (benchmarks/results/round5_notes.md)
-    measured the per-step paged scatters at ~5.1 of 11.1 ms — for
-    ~1 MB of writes. Deferred mode appends each step's K/V to a small
+    A decode ablation (builder-captured 2026-07-31, not measured by
+    the driver) put the per-step paged scatters at ~5.1 of 11.1 ms —
+    for ~1 MB of writes. Deferred mode appends each step's K/V to a small
     dense [B, S, kv, d] tail instead (a one-hot select over S<=32
     slots — no scatter), and flushes the whole tail to the pages with
     ONE write_to_pages call per layer at burst end.

@@ -27,12 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from production_stack_tpu.ops.quant_kv import QuantKV
 
-try:  # jax >= 0.5 spelling
-    _HBM = pltpu.MemorySpace.HBM
-except AttributeError:  # jax 0.4.x: ANY keeps the operand un-blocked in HBM
-    _HBM = pltpu.TPUMemorySpace.ANY
-
-HBM = _HBM
 NEG_INF = -1e30
 
 # Mosaic's VMEM tile for 32-bit (and the floor for narrower) types:
@@ -54,7 +48,7 @@ def tile_pad(n: int, tile: int) -> int:
 def hbm_block_spec():
     """A BlockSpec that keeps the operand un-blocked in HBM (the
     kernel DMAs pages itself)."""
-    return pl.BlockSpec(memory_space=_HBM)
+    return pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
 
 
 # ---- wrapper-level operand helpers -------------------------------------

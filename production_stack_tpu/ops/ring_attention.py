@@ -23,8 +23,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.utils.compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -111,7 +109,7 @@ def ring_attention_sharded(q: jnp.ndarray, k: jnp.ndarray,
     """
     from jax.sharding import PartitionSpec as P
     spec = P(None, sp_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=sp_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,

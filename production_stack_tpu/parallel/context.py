@@ -19,8 +19,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-
-from production_stack_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from production_stack_tpu.engine.config import ModelConfig
@@ -94,7 +92,7 @@ def context_parallel_forward(params: Params, config: ModelConfig,
     tok_spec = P(batch_axis, sp_axis)
     out_spec = P(batch_axis, sp_axis, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_local_forward, config=config, sp_axis=sp_axis),
         mesh=mesh,
         in_specs=(P(), tok_spec),

@@ -44,8 +44,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from production_stack_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from production_stack_tpu.engine.config import ModelConfig
@@ -306,7 +304,7 @@ def sp_prefill_forward(params: Params, config: ModelConfig,
     else:
         from production_stack_tpu.engine.lora import lora_stack_specs
         lora_ab_spec = lora_stack_specs(lora_ab, None, on_mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=({k: lp_spec(k) for k in layer_params},
                   {k: on_mesh(specs.get(k, repl)) for k in shared},

@@ -21,8 +21,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-
-from production_stack_tpu.utils.compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -167,7 +165,7 @@ def pipeline_forward(params: Params, config: ModelConfig,
             head = shared_p["embed"].T
         return (x @ head).astype(jnp.float32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(layer_specs, {k: none_spec for k in shared},
                   none_spec),

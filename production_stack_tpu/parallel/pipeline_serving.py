@@ -56,8 +56,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from production_stack_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from production_stack_tpu.engine.config import ModelConfig
@@ -388,7 +386,7 @@ def pp_paged_forward(params: Params, config: ModelConfig,
     else:
         from production_stack_tpu.engine.lora import lora_stack_specs
         lora_ab_spec = lora_stack_specs(lora_ab, "pp", on_mesh)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(lp_specs, shared_specs, cache_spec, cache_spec,
                   repl, repl, repl, repl, repl,

@@ -390,10 +390,12 @@ def _waiver_findings(project: Project,
 
 def run_rules(project: Project,
               rules: Optional[Iterable[str]] = None,
-              jobs: int = 1) -> List[Finding]:
+              jobs: int = 1,
+              today: Optional[datetime.date] = None) -> List[Finding]:
     """Run analyzers (all registered by default) plus the waiver
     spelling/expiry checks; waived findings are dropped, everything
-    else is returned sorted.
+    else is returned sorted. ``today`` is the clock dated waivers
+    expire against (default: the real date).
 
     ``jobs > 1`` runs the analyzers in a thread pool after warming
     the shared parse cache (and the call-graph/summary memos, which
@@ -417,7 +419,7 @@ def run_rules(project: Project,
     else:
         for name in names:
             findings.extend(REGISTRY[name].run(project))
-    findings.extend(_waiver_findings(project))
+    findings.extend(_waiver_findings(project, today))
     # Files any analyzer failed to parse fail the run explicitly —
     # an unparseable file is unanalyzed, not clean.
     for sf in project.files("**/*.py"):
@@ -425,7 +427,8 @@ def run_rules(project: Project,
             findings.append(Finding(
                 rule="parse-error", path=sf.relpath, line=0,
                 message=f"file does not parse: {sf.parse_error}"))
-    findings = [f for f in findings if not _waived(project, f)]
+    findings = [f for f in findings
+                if not _waived(project, f, today)]
     return sorted(findings,
                   key=lambda f: (f.path, f.line, f.rule, f.message))
 

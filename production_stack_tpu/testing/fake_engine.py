@@ -1267,9 +1267,10 @@ async def debug_compiles(request: web.Request) -> web.Response:
 
 
 async def version(request: web.Request) -> web.Response:
-    """GET /version: same shape as the real server (the package
-    version — the fake IS this package), plus the deployed build id
-    for rollout membership checks."""
+    """GET /version: the identity fields of the real server's reply
+    (the package version — the fake IS this package — and the
+    deployed build id for rollout membership checks). The real server
+    also names its device and attention impls; the fake has none."""
     state: FakeEngineState = request.app["state"]
     return web.json_response({"version": __version__,
                               "build_id": state.build_id})

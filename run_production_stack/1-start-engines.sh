@@ -1,6 +1,12 @@
 #!/bin/bash
 # Launch N tpu-engine processes from a config file (fork cluster-on
 # analogue). Usage: ./1-start-engines.sh [config/llama3-1chip.env]
+#
+# One process per chip: an engine claims every chip of its host and
+# nothing pins a process to a device yet, so NUM_ENGINES > 1 on one
+# host makes the second engine exit at start-up ("cannot claim the
+# accelerator"). Use one engine per host (README "One process per
+# chip").
 set -euo pipefail
 cd "$(dirname "$0")"
 CONFIG="${1:-config/llama3-1chip.env}"

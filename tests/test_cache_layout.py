@@ -1,8 +1,6 @@
 """Per-layer vs stacked KV cache layout parity.
 
-CacheConfig.cache_layout='per_layer' is the round-3 decode-roofline
-experiment (benchmarks/results/round3_onchip_notes.md §0.6): a tuple of
-L per-layer buffers instead of one stacked [L, ...] array. Numerics
+CacheConfig.cache_layout='per_layer' is a tuple of L per-layer buffers instead of one stacked [L, ...] array. Numerics
 must be identical — the layout changes buffer granularity (scatter
 operands, donation aliasing), not math.
 """

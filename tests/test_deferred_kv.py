@@ -2,8 +2,8 @@
 the tail-buffer burst must generate exactly what the per-step-write
 burst and single-step decoding generate.
 
-Motivation (benchmarks/results/round5_notes.md, round-5 on-chip
-ablation): per-step paged scatters cost ~5.1 of 11.1 ms/token-step
+Motivation (a decode ablation, builder-captured 2026-07-31, not
+measured by the driver): per-step paged scatters cost ~5.1 of 11.1 ms/token-step
 for ~1 MB of writes; deferring them to one batched write per layer
 per burst removes that cost. Correctness risks covered here: tail
 attention masking (positional), mid-burst row freeze (stop/budget),

@@ -49,7 +49,15 @@ def test_models_health_version():
         data = await resp.json()
         assert data["data"][0]["id"] == "tiny-llama"
         assert (await client.get("/health")).status == 200
-        assert (await client.get("/version")).status == 200
+        resp = await client.get("/version")
+        assert resp.status == 200
+        version = await resp.json()
+        # The process that holds the device names it, and says which
+        # attention impl each phase resolved to.
+        assert version["platform"] == "cpu"
+        assert version["device_kind"] and version["num_devices"] >= 1
+        assert version["attention_impl"]["decode"] == "xla"
+        assert version["attention_impl"]["prefill"] == "xla"
     asyncio.run(_with_client(run))
 
 

@@ -110,8 +110,8 @@ def test_quantized_params_reject_embedder():
 def test_direct_int8_random_init_shapes(family):
     """Random int8 init (quantization.init_random_quantized) produces
     the same pytree structure as quantize(init) without ever
-    materializing the full-precision model (the 8B-on-16GB OOM fix,
-    results/round5_notes.md). gpt2 exercises the bias/norm-bias
+    materializing the full-precision model (the 8B-on-16GB OOM
+    fix). gpt2 exercises the bias/norm-bias
     leaves (semantics derived from the family init, not names)."""
     from production_stack_tpu.engine.quantization import (
         init_random_quantized,

@@ -628,9 +628,10 @@ def test_fake_engine_serves_version_like_the_real_server():
         try:
             resp = await client.get("/version")
             assert resp.status == 200
-            # Same shape as EngineServer.version: the build identity
-            # rides along so rollouts can verify a canary's revision
-            # (docs/fleet.md); empty when no --build-id was given.
+            # The identity fields of EngineServer.version: the build
+            # identity rides along so rollouts can verify a canary's
+            # revision (docs/fleet.md); empty when no --build-id was
+            # given. (The real server also names its device.)
             assert await resp.json() == {"version": __version__,
                                          "build_id": ""}
         finally:

@@ -597,17 +597,25 @@ def test_deep_chain_is_capped_in_finding_json():
 
 
 def test_dated_waiver_suppresses_until_expiry():
-    future = (datetime.date(2026, 8, 6)
-              + datetime.timedelta(days=30)).isoformat()
-    findings = _run({
+    # The clock is injected: a date built from the day the test was
+    # written stops being the future.
+    today = datetime.date(2026, 8, 6)
+    until = (today + datetime.timedelta(days=30)).isoformat()
+    project = _project({
         "production_stack_tpu/router/app.py": f"""\
             import time
 
             async def handler(request):
-                time.sleep(1)  # lint: allow-async-blocking until={future}
+                time.sleep(1)  # lint: allow-async-blocking until={until}
         """,
-    }, "async-blocking")
-    assert findings == []
+    })
+    assert run_rules(project, rules=["async-blocking"],
+                     today=today) == []
+    # The day after it lapses, the finding and the lapse both surface.
+    late = today + datetime.timedelta(days=31)
+    assert {f.rule for f in run_rules(
+        project, rules=["async-blocking"], today=late)} == {
+            "async-blocking", "expired-waiver"}
 
 
 def test_expired_waiver_stops_suppressing_and_is_reported():
