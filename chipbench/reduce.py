@@ -1,0 +1,187 @@
+"""From a profiler trace (``.xplane.pb``) to device busy time, time per
+program, the heaviest device operations and the longest idle gaps.
+
+    JAX_PLATFORMS=cpu python3 chipbench/reduce.py <profile dir> <out.json> <platform>
+
+A TPU's plane is ``/device:TPU:<n>``.  Its line ``XLA Ops`` holds one
+event per operation that ran on the device (their union is the busy
+time); its line ``XLA Modules`` holds one event per execution of a
+jitted program, named after the jitted function
+(``jit__decode_burst_impl(...)``); an execution cut by the slice's edge
+is recorded short, which ``whole_execution_s`` allows for.  The window
+is the span from the
+first operation's start to the last one's end over all device planes;
+busy time is averaged over the planes.  An idle gap is labelled by the
+program that ran next: the gap is the time the device waited for that
+program to be dispatched.
+
+``<platform>`` is what the server said it runs on.  For ``tpu`` a trace
+without a device plane that has an ``XLA Ops`` line is an error: the
+reduction never reads host threads under a TPU's name.  Only for
+``cpu`` (the rehearsal) do the XLA client's threads of ``/host:CPU``
+stand in, so that the pipeline runs end to end; the summary then says
+``stand_in`` and ``device_idle`` is not read from it.
+"""
+
+from __future__ import annotations
+
+import glob
+import json
+import os
+import re
+import sys
+
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+
+
+def union_s(intervals: list) -> float:
+    """Seconds covered by the union of (start_ns, end_ns) intervals."""
+    total, end = 0, None
+    for s, e in sorted(intervals):
+        if end is None or s > end:
+            total += e - s
+            end = e
+        elif e > end:
+            total += e - end
+            end = e
+    return total / 1e9
+
+
+def gaps(intervals: list) -> list:
+    """(start_ns, end_ns) of the holes in the union of ``intervals``."""
+    out, end = [], None
+    for s, e in sorted(intervals):
+        if end is not None and s > end:
+            out.append((end, s))
+        end = e if end is None else max(end, e)
+    return out
+
+
+def program_name(event_name: str) -> str:
+    """``jit__decode_burst_impl(1234)`` -> ``_decode_burst_impl``."""
+    name = re.sub(r"\(.*$", "", event_name)
+    return name[4:] if name.startswith("jit_") else name
+
+
+def op_name(event_name: str) -> str:
+    """An op event is named by its whole HLO line (``%fusion.12 =
+    bf16[...] fusion(...)``): keep the instruction's name."""
+    return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def whole_execution_s(durations: list) -> float:
+    """Device time of one whole execution of a program.  An execution
+    that the slice's edge cut short is recorded with what was seen of
+    it, so executions shorter than half the longest are left out and
+    the median of the rest is taken.  Right for a program of one fixed
+    shape, whose executions take nearly the same time."""
+    whole = sorted(d for d in durations if d >= max(durations) / 2)
+    mid = len(whole) // 2
+    return whole[mid] if len(whole) % 2 else (whole[mid - 1] + whole[mid]) / 2
+
+
+def summarize(planes: dict) -> dict:
+    """``planes``: {plane name: {"ops": [(name, start_ns, dur_ns)],
+    "modules": [(name, start_ns, dur_ns)]}}."""
+    starts = [s for p in planes.values() for _, s, _ in p["ops"]]
+    ends = [s + d for p in planes.values() for _, s, d in p["ops"]]
+    if not starts:
+        return {"window_s": 0.0, "busy_s": 0.0, "planes": sorted(planes)}
+    busy, op_time, programs, gap_list = [], {}, {}, []
+    for plane in planes.values():
+        intervals = [(s, s + d) for _, s, d in plane["ops"]]
+        busy.append(union_s(intervals))
+        for name, _, d in plane["ops"]:
+            # A loop's own event spans its body's: count the body.
+            if not name.startswith(("while", "conditional")):
+                op_time[name] = op_time.get(name, 0) + d
+        modules = sorted((s, d, program_name(n))
+                         for n, s, d in plane["modules"])
+        for _, d, name in modules:
+            entry = programs.setdefault(
+                name, {"count": 0, "seconds": 0.0, "durations": []})
+            entry["count"] += 1
+            entry["seconds"] += d / 1e9
+            entry["durations"].append(d / 1e9)
+        for s, e in gaps(intervals):
+            after = next((n for ms, _, n in modules if ms >= e - 1000),
+                         "end of trace")
+            gap_list.append((f"before {after}", (e - s) / 1e9))
+    n = len(planes)
+    by_label = {}
+    for label, seconds in gap_list:
+        by_label[label] = by_label.get(label, 0.0) + seconds
+    return {
+        "planes": sorted(planes),
+        "window_s": (max(ends) - min(starts)) / 1e9,
+        "busy_s": sum(busy) / n,
+        "programs": {k: {"count": v["count"] / n,
+                         "seconds": v["seconds"] / n,
+                         "whole_s": whole_execution_s(v["durations"])}
+                     for k, v in programs.items()},
+        "device_ops": sorted(([k, v / 1e9 / n] for k, v in op_time.items()),
+                             key=lambda kv: -kv[1])[:10],
+        "idle_gaps": sorted(([k, v / n] for k, v in by_label.items()),
+                            key=lambda kv: -kv[1])[:10],
+        "longest_gap_s": max((g for _, g in gap_list), default=0.0),
+    }
+
+
+class NoDevicePlane(Exception):
+    pass
+
+
+def read_planes(path: str, platform: str) -> dict:
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(path)
+    planes = {}
+    if platform == "cpu":  # the rehearsal: host threads stand in
+        for plane in data.planes:
+            if plane.name == "/host:CPU":
+                ops = [(e.name, int(e.start_ns), int(e.duration_ns))
+                       for line in plane.lines
+                       if line.name.startswith("tf_XLA")
+                       for e in line.events]
+                planes[plane.name] = {"ops": ops, "modules": []}
+        return planes
+    if platform != "tpu":
+        raise NoDevicePlane(f"no reduction for platform {platform!r}")
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU"):
+            lines = {line.name: line for line in plane.lines}
+            if OPS_LINE not in lines:
+                continue
+            planes[plane.name] = {
+                key: [(op_name(e.name), int(e.start_ns), int(e.duration_ns))
+                      for e in lines[name].events] if name in lines else []
+                for key, name in (("ops", OPS_LINE),
+                                  ("modules", MODULES_LINE))}
+    if not planes:
+        raise NoDevicePlane(
+            f"no /device:TPU plane with an {OPS_LINE!r} line among "
+            f"{[p.name for p in data.planes]}")
+    return planes
+
+
+def main(argv) -> int:
+    profile_dir, out, platform = argv
+    paths = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        print(f"no .xplane.pb under {profile_dir}", file=sys.stderr)
+        return 1
+    try:
+        planes = read_planes(paths[-1], platform)
+    except NoDevicePlane as e:
+        print(f"{paths[-1]}: {e}", file=sys.stderr)
+        return 1
+    summary = summarize(planes)
+    summary["stand_in"] = platform != "tpu"
+    summary["trace_bytes"] = os.path.getsize(paths[-1])
+    with open(out, "w") as f:
+        json.dump(summary, f)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
