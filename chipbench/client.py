@@ -3,13 +3,20 @@
 recorded on the host's clock.
 
 Times in a record are seconds relative to the start of the measured
-window (negative during the ramp).
+window (negative during the ramp).  Beside the records the load keeps
+``arrivals``: ``[t, n]`` for every millisecond ``t`` (its start, in
+seconds on the same clock) in which output tokens reached a client, and
+how many, over all requests of all phases, in time order.  Tokens per
+second are counted from that list (``e2e.output_tok_s``), so a run can
+be counted again afterwards with the window laid elsewhere
+(``phase.py``).
 """
 
 from __future__ import annotations
 
 import asyncio
 import json
+import math
 import re
 import time
 
@@ -36,7 +43,7 @@ class Load:
         self.t0 = time.perf_counter() + start_in_s
         self.t0_unix = time.time() + start_in_s
         self.records = []
-        self.window_tokens = 0
+        self.arrivals = []
         self._session = None
         self._in_flight = set()
 
@@ -103,8 +110,11 @@ class Load:
                 record["first"] = now
             record["last"] = now
             record["tokens"] += n
-            if 0 <= now < self.seconds:
-                self.window_tokens += n
+            at = math.floor(now * 1e3) / 1e3
+            if self.arrivals and self.arrivals[-1][0] == at:
+                self.arrivals[-1][1] += n
+            else:
+                self.arrivals.append([at, n])
         elif line.startswith(b"data: [DONE]"):
             record["done"] = True
         elif b'"usage": {' in line:

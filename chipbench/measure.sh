@@ -1,12 +1,15 @@
 #!/bin/bash
 # The sets of runs a bound is set from: for one cell, <sets> sets of
-# three runs with the same three seeds in each, all in one call, then
-# (trace = 1) one traced run of the first seed.  Result lines go to
+# runs with the same seeds in each, all in one call, then (trace = 1)
+# one traced run of the first seed.  Result lines go to
 # <out>/set<n>.jsonl and <out>/traced.jsonl, the findings lines beside
-# them, and chipbench/spread.py reads the sets.
+# them, and chipbench/spread.py reads the sets.  Each run's arrivals,
+# records, cell, compile ledger and engine log are kept under
+# <out>/<set>_<seed>/, which chipbench/phase.py reads.
 #
-#   bash chipbench/measure.sh <cell> <seconds> <out dir> "<sets>" <trace>
+#   bash chipbench/measure.sh <cell> <seconds> <out dir> "<sets>" <trace> ["<seeds>"]
 cell=$1; secs=$2; out=$3; sets=$4; trace=$5
+seeds=${6:-"2147484001 1234567 42 2147483999 987654321 31337"}
 mkdir -p "$out"
 one_run() {  # <seed> <trace> <name of the .jsonl>
   local t0=$(date +%s)
@@ -18,14 +21,19 @@ one_run() {  # <seed> <trace> <name of the .jsonl>
   if [ $rc -ne 0 ]; then tail -40 "$out/run.err"; return; fi
   tail -n 1 "$out/run.out" >> "$out/$3.jsonl"
   head -n -1 "$out/run.out" | tail -n 1 >> "$out/findings_$3.jsonl"
+  mkdir -p "$out/$3_$1"
+  cp ".chipbench/runs/$cell"/{arrivals,records,cell,compiles}.json "$out/$3_$1/"
+  gzip -c ".chipbench/runs/$cell/engine.log" > "$out/$3_$1/engine.log.gz"
 }
 for set in $sets; do
-  for seed in 2147484001 1234567 42; do one_run $seed 0 "set$set"; done
+  for seed in $seeds; do one_run $seed 0 "set$set"; done
 done
 if [ "$trace" = "1" ]; then
-  one_run 2147484001 1 traced
+  one_run ${seeds%% *} 1 traced
   cp ".chipbench/runs/$cell/trace_summary.json" "$out/" 2>/dev/null
 fi
 if [ -n "$sets" ]; then
   python3 chipbench/spread.py "$out"/set*.jsonl | tee "$out/spread.txt"
+  python3 chipbench/phase.py "$out"/set*_*/ > "$out/phase.txt"
+  grep -v '^{' "$out/phase.txt"
 fi

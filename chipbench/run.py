@@ -400,6 +400,10 @@ def run(args) -> int:
 
         health = json.loads(http(engine_url + "/health", 30))
         memory = json.loads(http(engine_url + "/debug/memory", 30))
+        # After the drain: what compiled or was loaded behind the
+        # window shows as a stall in the arrivals (PERF.md, PR 25).
+        side["compiles_end"] = json.loads(
+            http(engine_url + "/debug/compiles?limit=64", 30))
         with open(engine_log, errors="replace") as f:
             text = f.read()
         bad = [line for line in BAD_LOG_LINES if line in text]
@@ -416,9 +420,11 @@ def run(args) -> int:
     if not procs.stop_all():
         return 1
 
-    files = {"records.json": load.records, "memory.json": memory,
+    files = {"records.json": load.records, "arrivals.json": load.arrivals,
+             "memory.json": memory,
              "compiles.json": {"before": side["compiles_before"],
-                               "after": side["compiles_after"]},
+                               "after": side["compiles_after"],
+                               "end": side["compiles_end"]},
              "steps.json": side.get("steps"),
              "cache_usage.json": side.get("cache_usage"),
              "cell.json": {**cell, "config_as_run": config,
@@ -434,7 +440,7 @@ def run(args) -> int:
         reduce_trace(run_dir, procs, version["platform"])
         procs.stop_all()
 
-    summary = e2e.summarize(load.records, load.window_tokens, args.seconds)
+    summary = e2e.summarize(load.records, load.arrivals, args.seconds)
     summary["setup_s"] = setup_s
     lags = [r["sent"] - r["due"] for r in load.records
             if r["phase"] == "window" and r["sent"] is not None]
@@ -486,6 +492,9 @@ def run(args) -> int:
               "window_compiles": (
                   sum(side["compiles_after"]["events"].values())
                   - sum(side["compiles_before"]["events"].values())),
+              "drain_compiles": (
+                  sum(side["compiles_end"]["events"].values())
+                  - sum(side["compiles_after"]["events"].values())),
               "attention_impl": version.get("attention_impl"),
               "result": result}
     with open(os.path.join(run_dir, "report.json"), "w") as f:

@@ -36,6 +36,13 @@ class RunFiles:
         return self._json("records.json") or []
 
     @functools.cached_property
+    def arrivals(self) -> list:
+        """``[t, n]``: ``n`` output tokens reached the clients in the
+        millisecond from ``t`` seconds after the window's start
+        (``client.Load.arrivals``)."""
+        return self._json("arrivals.json") or []
+
+    @functools.cached_property
     def spans(self) -> dict:
         """Engine spans (``--request-span-log``) by ``x-request-id``."""
         path = os.path.join(self.dir, "spans.jsonl")
@@ -57,7 +64,8 @@ class RunFiles:
 
     @functools.cached_property
     def compiles(self):
-        """``/debug/compiles`` at the window's start and end."""
+        """``/debug/compiles`` at the window's start (``before``) and
+        end (``after``), and after the drain (``end``)."""
         return self._json("compiles.json")
 
     @functools.cached_property

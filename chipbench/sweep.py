@@ -68,8 +68,7 @@ def main() -> int:
             asyncio.run(bench_run.window(
                 load, requests, traffic, engine_url, run_dir,
                 rate == args.trace_rate, side))
-            summary = e2e.summarize(load.records, load.window_tokens,
-                                    args.seconds)
+            summary = e2e.summarize(load.records, load.arrivals, args.seconds)
             lasts = [r["last"] for r in load.records if r["last"]]
             row = {"rate_per_s": rate, **summary,
                    "in_flight_mid": e2e.in_flight(load.records,

@@ -10,7 +10,8 @@ import sys
 
 import pytest
 
-from chipbench import run as bench_run
+from chipbench import e2e, run as bench_run
+from chipbench.runfiles import RunFiles
 
 
 @pytest.mark.parametrize("cell,trace", [("rehearsal-open", 0),
@@ -31,6 +32,16 @@ def test_rehearsal_run(cell, trace):
     assert (result["failed"], result["unfinished"]) == (0, 0)
     assert result["attempted"] > 10
     assert result["device"]["platform"] == "cpu"
+    # The arrivals the run kept hold every token of every request, in
+    # time order, and give the line's tokens per second again.
+    files = RunFiles(os.path.join(bench_run.STATE, "runs", cell))
+    times = [t for t, _ in files.arrivals]
+    assert times == sorted(set(times)) and times[0] < 0 < times[-1]
+    assert sum(n for _, n in files.arrivals) == sum(
+        r["tokens"] for r in files.records)
+    if not trace:
+        assert e2e.output_tok_s(files.arrivals, 6.0) == result["metrics"][
+            "output_tok_s"]["value"]
     wanted = bench_run.find_cell(cell)[
         "per_layer" if trace else "end_to_end"]
     assert set(result["metrics"]) <= set(wanted)
