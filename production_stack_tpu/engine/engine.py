@@ -135,9 +135,12 @@ class LLMEngine:
     @tracer.setter
     def tracer(self, tracer) -> None:
         # Mirrored onto the scheduler so chunk/preempt/first-token
-        # events emit without a back-reference to the engine.
+        # events emit without a back-reference to the engine, and onto
+        # the runner so it names its turn phases (build, rng, dispatch,
+        # wait, parse).
         self._tracer = tracer
         self.scheduler.tracer = tracer
+        self.runner.tracer = tracer
 
     def _init_offload(self) -> None:
         import numpy as np
@@ -798,6 +801,8 @@ class LLMEngine:
         return self._step_sync()
 
     def _plan_locked(self, outputs: List[StepOutput]):
+        if self._tracer is not None:
+            self._tracer.phase("plan")
         with self._lock:
             plan = self.scheduler.plan_step()
             for seq in self.scheduler.newly_aborted:
@@ -855,6 +860,8 @@ class LLMEngine:
         sampled, lp_rows = self.runner.run_prefill(plan.prefill)
         tr = time.perf_counter()
         self._idle_mark = tr
+        if self._tracer is not None:
+            self._tracer.phase("commit")
         with self._lock:
             for i, (chunk, token) in enumerate(
                     zip(plan.prefill.chunks, sampled)):
@@ -888,6 +895,8 @@ class LLMEngine:
         token_lists, lp_lists = self.runner.run_decode(plan.decode)
         tr = time.perf_counter()
         self._idle_mark = tr
+        if self._tracer is not None:
+            self._tracer.phase("commit")
         now = time.time()
         spec_drafts = plan.decode.drafts
         with self._lock:
@@ -944,6 +953,8 @@ class LLMEngine:
          prefill_lps) = self.runner.run_unified(plan)
         tr = time.perf_counter()
         self._idle_mark = tr
+        if self._tracer is not None:
+            self._tracer.phase("commit")
         now = time.time()
         seqs = plan.decode.seqs[: self.runner.decode_width]
         chunks = plan.prefill.chunks[: self.runner.prefill_width]
@@ -1016,6 +1027,8 @@ class LLMEngine:
         if handle is not None:
             t0 = time.perf_counter()
             rows = None
+            if self._tracer is not None:
+                self._tracer.phase("plan")
             if handle.expected_lens is None:
                 with self._lock:
                     rows = self.scheduler.plan_ahead(handle.rows)
@@ -1087,6 +1100,8 @@ class LLMEngine:
             # (docs/unified_step.md §spec-under-async).
             self._note_dispatch(time.perf_counter())
             self._in_flight = self.runner.dispatch_spec(plan.decode)
+            if self._tracer is not None:
+                self._tracer.phase("commit")
             self.metrics.set_inflight_depth(1)
             self._account_step(
                 host_s=time.perf_counter() - t0, wait_s=0.0,
@@ -1109,6 +1124,8 @@ class LLMEngine:
         self._note_dispatch(time.perf_counter())
         self._in_flight = self.runner.dispatch_decode(
             plan.decode.seqs[: self.runner.decode_width])
+        if self._tracer is not None:
+            self._tracer.phase("commit")
         self.metrics.set_inflight_depth(1)
         self._account_step(
             host_s=time.perf_counter() - t0, wait_s=0.0,
@@ -1130,6 +1147,8 @@ class LLMEngine:
         tw = time.perf_counter()
         token_lists, lp_lists = handle.result()
         wait_s = time.perf_counter() - tw
+        if self._tracer is not None:
+            self._tracer.phase("commit")
         now = time.time()
         outputs: List[StepOutput] = []
         expected = handle.expected_lens

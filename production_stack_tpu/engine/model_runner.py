@@ -252,7 +252,7 @@ class DecodeStepHandle:
 
     def result(self) -> Tuple[List[List[int]], Optional[list]]:
         """Block on the step's one fused device_get and parse."""
-        host = jax.device_get(self.sampled)
+        host = self.runner.read_back(self.sampled)
         n = len(self.rows)
         if not self.want_lp:
             return [[int(host[i])] for i in range(n)], None
@@ -303,7 +303,7 @@ class SpecStepHandle:
         return out[:, 0]
 
     def result(self) -> Tuple[List[List[int]], Optional[list]]:
-        host = jax.device_get(self.sampled)
+        host = self.runner.read_back(self.sampled)
         n = len(self.rows)
         if not self.want_lp:
             return [[int(t) for t in host[i] if t >= 0]
@@ -609,6 +609,9 @@ class ModelRunner:
         # Multihost step broadcast (parallel/distributed.py); host 0's
         # engine sets this so every dispatch is mirrored to workers.
         self.bridge = None
+        # LLMEngine.tracer, mirrored by its setter: names this thread's
+        # turn phases (engine/tracing.py TURN_PHASES). None = untraced.
+        self.tracer = None
         # Embedder for /v1/embeddings|score|rerank; in multihost mode
         # every host builds one at startup so KIND_EMBED payloads can
         # be executed slice-wide (server.py main, --distributed).
@@ -1549,8 +1552,36 @@ class ModelRunner:
         return out, k_cache, v_cache
 
     def _next_rng(self) -> jax.Array:
+        # The split is an eager program of its own: its own turn phase.
+        tracer = self.tracer
+        back = tracer.phase("rng") if tracer is not None else None
         self._rng, sub = jax.random.split(self._rng)
+        if back is not None:
+            tracer.phase(back)
         return sub
+
+    def _host_rng(self) -> np.ndarray:
+        """The next key as a numpy payload entry. The read-back waits
+        for the split program, so it is inside the one rng phase (and
+        kept out of _next_rng, which the async dispatch path calls)."""
+        tracer = self.tracer
+        back = tracer.phase("rng") if tracer is not None else None
+        self._rng, sub = jax.random.split(self._rng)
+        key = np.asarray(sub)
+        if back is not None:
+            tracer.phase(back)
+        return key
+
+    def read_back(self, sampled):
+        """A step's one blocking device_get; to the turn's phases the
+        loop thread waits, then parses."""
+        tracer = self.tracer
+        if tracer is not None:
+            tracer.phase("wait")
+        host = jax.device_get(sampled)
+        if tracer is not None:
+            tracer.phase("parse")
+        return host
 
     def _bucket_for(self, n: int) -> int:
         for b in self._buckets:
@@ -1879,6 +1910,8 @@ class ModelRunner:
         return penalties, seeding, bias, suppress, fsm
 
     def _dispatch(self, kind: int, t: int, payload: dict) -> jax.Array:
+        if self.tracer is not None:
+            self.tracer.phase("dispatch")
         if self.bridge is not None:
             # Atomic publish+execute: see MultihostStepBridge.lock.
             with self.bridge.lock:
@@ -1897,6 +1930,8 @@ class ModelRunner:
             raise NotImplementedError(
                 "context-parallel prefill over the multihost step "
                 "bridge")
+        if self.tracer is not None:
+            self.tracer.phase("build")
         chunk = plan.chunks[0]
         seq = chunk.seq
         n = len(chunk.chunk_tokens)
@@ -1925,6 +1960,8 @@ class ModelRunner:
         lora_ids = (None if self.lora_registry is None
                     else jnp.asarray(
                         np.asarray([seq.lora_id], np.int32)))
+        if self.tracer is not None:
+            self.tracer.phase("dispatch")
         sampled, self.k_cache, self.v_cache = self._sp_prefill_jit(
             self.params, self.k_cache, self.v_cache,
             jnp.asarray(tokens),
@@ -1939,7 +1976,7 @@ class ModelRunner:
             penalties, seeding, bias, suppress, fsm,
             want_logprobs=want_lp,
         )
-        host = jax.device_get(sampled)
+        host = self.read_back(sampled)
         if want_lp:
             toks, slp, tids, tlps = host
             return ([int(toks[0])],
@@ -1956,6 +1993,8 @@ class ModelRunner:
         parallel list of per-row logprob entries (else None)."""
         if plan.sp:
             return self.run_sp_prefill(plan)
+        if self.tracer is not None:
+            self.tracer.phase("build")
         chunks = plan.chunks
         b = self.prefill_width
         t = self._bucket_for(max(len(c.chunk_tokens) for c in chunks))
@@ -1995,7 +2034,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": np.asarray(self._next_rng()),
+            "rng": self._host_rng(),
         }
         if self.lora_registry is not None:
             ids = np.zeros((b,), np.int32)
@@ -2025,7 +2064,7 @@ class ModelRunner:
         for i, chunk in enumerate(chunks):
             if chunk.is_last_chunk:
                 if host is None:
-                    host = jax.device_get(sampled)
+                    host = self.read_back(sampled)
                 if want_lp:
                     out.append(int(host[0][i]))
                     lps.append(
@@ -2105,6 +2144,8 @@ class ModelRunner:
                 "step broadcast ships host-resident numpy payloads)")
         b = self.decode_width
         rows = list(rows)[:b]
+        if self.tracer is not None:
+            self.tracer.phase("build")
         st = self._staging_set()
         off = 1 if ahead else 0
         page_table = st["page_table"]
@@ -2218,6 +2259,8 @@ class ModelRunner:
                                     time.perf_counter() - t0)
             return out
         stop_w = STOP_SET_WIDTH
+        if self.tracer is not None:
+            self.tracer.phase("build")
 
         tokens = np.zeros((b, 1), np.int32)
         positions = np.zeros((b, 1), np.int32)
@@ -2258,7 +2301,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": np.asarray(self._next_rng()),
+            "rng": self._host_rng(),
         }
         if window > 1:
             payload["active"] = valid[:, 0].copy()
@@ -2280,7 +2323,7 @@ class ModelRunner:
 
         t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(2, window, payload)
-        host = jax.device_get(sampled)
+        host = self.read_back(sampled)
         if _TIMING:
             self._record_timing("decode", window,
                                 time.perf_counter() - t0)
@@ -2328,6 +2371,8 @@ class ModelRunner:
         total_len + draft_len.
         """
         from production_stack_tpu.parallel.distributed import KIND_SPEC
+        if self.tracer is not None:
+            self.tracer.phase("build")
         seqs = plan.seqs[: self.decode_width]
         b = self.decode_width
         s = self.spec_width
@@ -2371,7 +2416,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": np.asarray(self._next_rng()),
+            "rng": self._host_rng(),
             "drafts": drafts,
             "draft_lens": draft_lens,
         }
@@ -2422,6 +2467,8 @@ class ModelRunner:
         from production_stack_tpu.parallel.distributed import (
             KIND_UNIFIED,
         )
+        if self.tracer is not None:
+            self.tracer.phase("build")
         seqs = plan.decode.seqs[: self.decode_width]
         chunks = plan.prefill.chunks[: self.prefill_width]
         spec_drafts = plan.decode.drafts
@@ -2496,7 +2543,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": np.asarray(self._next_rng()),
+            "rng": self._host_rng(),
         }
         if lora_ids is not None:
             payload["lora_ids"] = lora_ids
@@ -2508,7 +2555,7 @@ class ModelRunner:
 
         t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(KIND_UNIFIED, w, payload)
-        host = jax.device_get(sampled)
+        host = self.read_back(sampled)
         if _TIMING:
             self._record_timing("unified", w,
                                 time.perf_counter() - t0)

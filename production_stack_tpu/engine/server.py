@@ -73,6 +73,11 @@ logger = init_logger(__name__)
 STEP_FAILURE_LIMIT = 3
 
 
+def _put_annotated(annotate, put, item) -> None:
+    with annotate("server.stream_token"):
+        put(item)
+
+
 class AsyncEngine:
     """Background-thread engine loop with asyncio streaming outputs."""
 
@@ -104,6 +109,11 @@ class AsyncEngine:
         # turns 503 at STEP_FAILURE_LIMIT so the router's prober
         # rotates out a replica whose device programs keep failing.
         self.consecutive_step_failures = 0
+        # The profiler endpoints set this to the tracer's annotation
+        # factory while a slice runs: each delivery of a token to its
+        # stream is then a ``server.stream_token`` event on the event
+        # loop's thread. None outside a slice, so it costs nothing.
+        self.stream_annotation = None
 
     def current_step_s(self) -> float:
         """Seconds the in-flight engine step has been running
@@ -121,9 +131,20 @@ class AsyncEngine:
     def _run(self) -> None:
         from production_stack_tpu.engine.engine import StepOutput
         self._started.wait()
+        # Turn phases (engine/tracing.py TURN_PHASES): with a tracer
+        # this thread is always in one named phase, and each pass that
+        # accounts a step closes one turn record after its outputs
+        # have been handed over.
+        tracer = self.engine.tracer
+        obs = getattr(self.engine.runner, "observatory", None)
+        if tracer is not None:
+            tracer.start_turns(
+                compiles=obs.compile_events_total() if obs else 0)
         while True:
             # Drain submissions (non-blocking when engine has work).
             block = not self.engine.has_work()
+            if tracer is not None:
+                tracer.phase("idle" if block else "admit")
             try:
                 item = self._submit_q.get(
                     block=block, timeout=1.0 if block else None
@@ -131,6 +152,8 @@ class AsyncEngine:
             except queue.Empty:
                 item = None
             if item is not None:
+                if tracer is not None:
+                    tracer.phase("admit")
                 seq_id = item["seq_id"]
                 try:
                     if item.get("kind") == "handoff":
@@ -170,6 +193,8 @@ class AsyncEngine:
                         finish_reason="abort",
                     ))
                 continue  # admit as many as possible before stepping
+            if tracer is not None:
+                tracer.phase("other")
             if self.autotuner is not None:
                 try:
                     self.autotuner.maybe_tick()
@@ -183,6 +208,8 @@ class AsyncEngine:
             except Exception as e:
                 logger.exception("Engine step failed: %s", e)
                 self.consecutive_step_failures += 1
+                if tracer is not None:
+                    tracer.phase("other")
                 # The sequences that step touched end here with a
                 # terminal 'abort' instead of being retried forever.
                 for out in self.engine.abort_after_step_failure():
@@ -201,16 +228,38 @@ class AsyncEngine:
                 # KV-cache starvation, or an async dispatch that owes
                 # nothing yet): don't busy-spin, but let new arrivals
                 # cut the wait short.
+                if tracer is not None:
+                    tracer.phase("other")
                 self._wakeup.wait(0.002)
                 self._wakeup.clear()
+            if tracer is not None:
+                tracer.phase("emit")
+                emit_start = time.perf_counter()
             for out in outputs:
                 self._emit(out.seq_id, out)
+            if tracer is None:
+                continue
+            record = tracer.end_turn(
+                emitted=len(outputs),
+                compiles=obs.compile_events_total() if obs else 0)
+            if record is not None and outputs and self._loop is not None:
+                # Queued behind this turn's outputs: the event loop
+                # stamps handoff_ms when it has taken the last of them.
+                self._loop.call_soon_threadsafe(
+                    tracer.on_handoff, record, emit_start)
 
     def _emit(self, seq_id: str, item) -> None:
         stream = self._streams.get(seq_id)
         if stream is None or self._loop is None:
             return
-        self._loop.call_soon_threadsafe(stream.put_nowait, item)
+        annotate = self.stream_annotation
+        if annotate is None:
+            self._loop.call_soon_threadsafe(stream.put_nowait, item)
+        else:
+            # Bound now: the slice may have ended by the time the
+            # event loop gets to the callback.
+            self._loop.call_soon_threadsafe(
+                _put_annotated, annotate, stream.put_nowait, item)
 
     async def submit(self, prompt: List[int], sampling: SamplingParams,
                      lora_name: Optional[str] = None,
@@ -2029,6 +2078,7 @@ class EngineServer:
         self._profiling = True
         tracer = self.engine.tracer
         if tracer is not None:
+            self.async_engine.stream_annotation = tracer.annotate
             sid = f"prof-{uuid.uuid4().hex[:12]}"
             self._profiler_span_id = sid
             tracer.start(
@@ -2048,6 +2098,7 @@ class EngineServer:
             )
         jax.profiler.stop_trace()
         self._profiling = False
+        self.async_engine.stream_annotation = None
         tracer = self.engine.tracer
         sid, self._profiler_span_id = self._profiler_span_id, None
         if tracer is not None and sid is not None:
@@ -2626,11 +2677,16 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
         # Server default: flight recorder on (ring > 0), span log off.
         # Library/tests constructing LLMEngine directly keep
         # engine.tracer None — zero tracing cost there.
+        import jax
+
         from production_stack_tpu.engine.tracing import EngineTracer
         engine.tracer = EngineTracer(
             span_log_path=args.request_span_log,
             ring_size=max(1, args.trace_ring_size),
             role=args.engine_role,
+            # The loop's turn phases as profiler events; a no-op
+            # while no /debug/profiler slice runs.
+            annotate=jax.profiler.TraceAnnotation,
         )
     return engine, served_name
 

@@ -18,6 +18,17 @@ restore, handoff ship, finish reason. Two sinks:
   rings of recent request timelines and per-step records, served at
   ``/debug/trace/{request_id}`` and ``/debug/steps``.
 
+Under the server loop a step record is one *turn* of that loop: the
+loop thread is always in exactly one of ``TURN_PHASES``, the record
+carries the milliseconds spent in each between ``t_start`` and
+``t_end``, and turns are contiguous, so nothing the loop thread does
+falls between two records. With an
+annotation factory (the server hands over
+``jax.profiler.TraceAnnotation``) each phase and the turn around it
+are also profiler events on the loop thread, so a profiler slice shows
+what the host did while the device idled. A turn far slower than its
+kind's recent median logs one ``slow turn`` WARNING.
+
 Concurrency: the engine's device loop, the asyncio handlers, and the
 drain path all touch the tracer. Every mutation is a GIL-atomic dict
 or ``deque(maxlen=...)`` operation — no lock is taken on the step or
@@ -31,12 +42,12 @@ check, so the disabled hot path allocates no span objects at all.
 
 from __future__ import annotations
 
-import itertools
 import json
+import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from production_stack_tpu.utils.log import init_logger
 
@@ -67,6 +78,36 @@ SPAN_EVENTS = (
     "autotune_decision",
     "finish",
 )
+
+# The closed vocabulary of what the server loop's thread can be doing.
+# ``EngineTracer.phase`` takes one of these; the profiler annotation of
+# a phase is ``engine.<name>`` inside ``engine.turn``. tests/
+# test_turn_phases.py holds this tuple, every literal passed to
+# ``*.phase(...)`` across the package and the table in
+# docs/observability.md in agreement.
+TURN_PHASES = (
+    "idle",      # parked: no request, nothing in flight
+    "admit",     # draining the submit queue into the scheduler
+    "plan",      # scheduler.plan_step / plan_ahead, lock wait included
+    "build",     # host arrays for the program
+    "rng",       # splitting the sampling key: a small program of its own
+    "dispatch",  # until the jitted call returns
+    "wait",      # blocked on the device's results
+    "parse",     # device arrays to Python lists
+    "commit",    # scheduler and sequence updates under the lock
+    "emit",      # handing the outputs to the event loop
+    "other",     # autotuner tick, back-off waits, the rest
+)
+
+# A turn is slow when its wall (less ``idle``) is more than
+# SLOW_TURN_FACTOR times the median of the last SLOW_TURN_HISTORY turns
+# of its kind and at least SLOW_TURN_MIN_S longer; a kind says nothing
+# before it has SLOW_TURN_MIN_HISTORY turns. ``handoff_ms`` is judged
+# the same way.
+SLOW_TURN_HISTORY = 32
+SLOW_TURN_MIN_HISTORY = 8
+SLOW_TURN_FACTOR = 2.0
+SLOW_TURN_MIN_S = 1.0
 
 
 def _ms(a: Optional[float], b: Optional[float]) -> Optional[float]:
@@ -141,14 +182,32 @@ class EngineTracer:
 
     def __init__(self, span_log_path: Optional[str] = None,
                  ring_size: int = 256, step_ring_size: int = 512,
-                 role: str = "both"):
+                 role: str = "both",
+                 annotate: Optional[Callable[..., Any]] = None):
         self.role = role
         self._live: Dict[str, EngineSpan] = {}
         self._ring: deque = deque(maxlen=max(1, int(ring_size)))
         self._steps: deque = deque(maxlen=max(1, int(step_ring_size)))
-        self._step_ids = itertools.count()
+        self._next_step = 0
         self._sink = (_SpanSink(span_log_path)
                       if span_log_path else None)
+        # Turn state, touched by the server loop's thread only.
+        # ``_phase`` is None until start_turns(): an engine stepped
+        # without the server loop records steps as before.
+        self.annotate = annotate
+        self._phase: Optional[str] = None
+        self._phase_t = 0.0
+        self._turn_t = 0.0
+        self._acc: Dict[str, float] = {}
+        self._turn_step = 0
+        self._open: Optional[Dict[str, Any]] = None
+        self._marks: List[Any] = []
+        self._compiles_seen = 0
+        # perf_counter() + _unix0 is the records' clock: t_end - t_start
+        # is then exactly the sum of the phases.
+        self._unix0 = time.time() - time.perf_counter()
+        self._walls: Dict[str, deque] = {}
+        self._handoffs: Dict[str, deque] = {}
 
     # -- request timeline ---------------------------------------------------
 
@@ -196,10 +255,134 @@ class EngineTracer:
     # -- step flight recorder -----------------------------------------------
 
     def on_step(self, **fields: Any) -> None:
-        record: Dict[str, Any] = {"step": next(self._step_ids),
+        record: Dict[str, Any] = {"step": self._next_step,
                                   "ts": round(time.time(), 6)}
+        self._next_step += 1
         record.update(fields)
+        if self._phase is None:
+            self._steps.append(record)
+            return
+        # Under the server loop the record is the turn's: end_turn()
+        # completes it and puts it into the ring. One left open (the
+        # step raised after its accounting) goes in as it is.
+        if self._open is not None:
+            self._steps.append(self._open)
+        self._open = record
+
+    # -- turns of the server loop -------------------------------------------
+
+    def _mark(self, name: str) -> None:
+        # ``step`` joins the event to the record this turn writes.
+        mark = self.annotate("engine." + name, step=self._turn_step)
+        mark.__enter__()
+        self._marks.append(mark)
+
+    def _open_turn(self) -> None:
+        """A turn starts where the last ended, in ``other``."""
+        self._turn_step = self._next_step
+        if self.annotate is not None:
+            self._mark("turn")
+            self._mark("other")
+
+    def start_turns(self, compiles: int = 0) -> None:
+        """The server loop's first act: from here on its thread is
+        always in one phase of one turn. ``compiles`` is the compile
+        ledger's total so far, start-up's, which is no turn's."""
+        self._phase = "other"
+        self._phase_t = self._turn_t = time.perf_counter()
+        self._compiles_seen = compiles
+        self._open_turn()
+
+    def phase(self, name: str) -> Optional[str]:
+        """The loop thread goes over to ``name`` (one of TURN_PHASES);
+        the time since the last switch is the old phase's. Returns the
+        old phase, or None where no server loop keeps turns."""
+        old = self._phase
+        if old is None or old == name:
+            return old
+        self._close_phase(name)
+        if self._marks:
+            self._marks.pop().__exit__(None, None, None)
+            self._mark(name)
+        return old
+
+    def _close_phase(self, name: str) -> None:
+        now = time.perf_counter()
+        self._acc[self._phase] = (self._acc.get(self._phase, 0.0)
+                                  + now - self._phase_t)
+        self._phase_t = now
+        self._phase = name
+
+    def end_turn(self, emitted: int,
+                 compiles: int = 0) -> Optional[Dict[str, Any]]:
+        """The turn ends here if it accounted a step: its record gets
+        ``t_start``, ``t_end``, ``phases`` (ms, summing to the wall),
+        ``emitted`` and, where the compile ledger's total ``compiles``
+        grew during it, ``compiles``; it goes into the ring and is
+        returned. A turn without a step (nothing planned) goes on."""
+        record = self._open
+        if record is None:
+            return None
+        self._open = None
+        self._close_phase("other")
+        while self._marks:
+            self._marks.pop().__exit__(None, None, None)
+        phases, self._acc = self._acc, {}
+        t_start, self._turn_t = self._turn_t, self._phase_t
+        record["t_start"] = round(self._unix0 + t_start, 6)
+        record["t_end"] = round(self._unix0 + self._turn_t, 6)
+        record["phases"] = {k: round(v * 1e3, 3)
+                            for k, v in phases.items()}
+        record["emitted"] = emitted
+        if compiles > self._compiles_seen:
+            record["compiles"] = compiles - self._compiles_seen
+        self._compiles_seen = compiles
         self._steps.append(record)
+        # Parked without work is no part of a stall, nor its name.
+        idle = phases.pop("idle", 0.0)
+        wall = self._turn_t - t_start - idle
+        median = self._slow(self._walls, record, wall)
+        if median is not None:
+            name = max(phases, key=phases.get)
+            logger.warning(
+                "slow turn: %s %.1f ms against a median of %.1f ms, "
+                "most of it in %s (%.1f ms): %s", record.get("kind"),
+                wall * 1e3, median * 1e3, name, phases[name] * 1e3,
+                json.dumps(record))
+        self._open_turn()
+        return record
+
+    def on_handoff(self, record: Dict[str, Any],
+                   emit_start: float) -> None:
+        """Runs on the event loop, queued behind the turn's outputs:
+        ``handoff_ms`` is how long after the loop thread began to emit
+        them the event loop had taken the last. The readers of the ring
+        are on the event loop too, so the store races with none."""
+        taken = time.perf_counter() - emit_start
+        record["handoff_ms"] = round(taken * 1e3, 3)
+        median = self._slow(self._handoffs, record, taken)
+        if median is not None:
+            logger.warning(
+                "slow turn: %s handoff_ms %.1f against a median of "
+                "%.1f ms, the event loop was late: %s",
+                record.get("kind"), taken * 1e3, median * 1e3,
+                json.dumps(record))
+
+    @staticmethod
+    def _slow(history: Dict[str, deque], record: Dict[str, Any],
+              seconds: float) -> Optional[float]:
+        """The median ``seconds`` is slow against, else None; either
+        way ``seconds`` joins its kind's history."""
+        past = history.setdefault(
+            str(record.get("kind")), deque(maxlen=SLOW_TURN_HISTORY))
+        median = None
+        if len(past) >= SLOW_TURN_MIN_HISTORY:
+            median = statistics.median(past)
+            if not (seconds > SLOW_TURN_FACTOR * median
+                    and seconds >= median + SLOW_TURN_MIN_S):
+                median = None
+        past.append(seconds)
+        return median
 
     def recent_steps(self, limit: int = 100) -> List[Dict[str, Any]]:
         steps = list(self._steps)
