@@ -934,6 +934,7 @@ class LLMEngine:
                     "decode_rows": len(plan.decode.seqs),
                     "row_bucket": self.runner.decode_width,
                     "window": plan.decode.window,
+                    "attn_pages": self.runner.last_attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
                 }
@@ -1195,6 +1196,7 @@ class LLMEngine:
                     "decode_rows": sum(
                         1 for seq in handle.rows if seq is not None),
                     "row_bucket": self.runner.decode_width,
+                    "attn_pages": handle.attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
                 }

@@ -27,7 +27,11 @@ from production_stack_tpu.engine.perf_observatory import (
 from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
 from production_stack_tpu.engine.sequence import Sequence, decode_budget
 from production_stack_tpu.models.registry import get_model
-from production_stack_tpu.ops.attention import write_to_pages
+from production_stack_tpu.ops.attention import (
+    block_pages,
+    gathered_blocks,
+    write_to_pages,
+)
 from production_stack_tpu.ops.quant_kv import (
     QuantKV,
     quant_cache_struct,
@@ -237,6 +241,7 @@ class DecodeStepHandle:
         self.rows = rows
         self.sampled = sampled
         self.want_lp = want_lp
+        self.attn_pages = runner.last_attn_pages
         # Set by the engine when this step was dispatched ahead of an
         # unread speculative verify step: expected_lens[i] is the
         # committed length row i must reach at completion for this
@@ -295,6 +300,7 @@ class SpecStepHandle:
         self.drafts = drafts  # per-row draft lists (parallel to rows)
         self.sampled = sampled
         self.want_lp = want_lp
+        self.attn_pages = runner.last_attn_pages
 
     @property
     def token_source(self) -> jax.Array:
@@ -584,6 +590,12 @@ class ModelRunner:
         self.max_pages_per_seq = config.scheduler.max_pages_per_seq(
             config.cache.page_size
         )
+        # The decode record's ``attn_pages``: how many pages of each
+        # row's table the XLA attention gathers for the dispatch's
+        # longest row, by the rule the program applies on the device
+        # (ops/attention.py). None while a Pallas kernel serves
+        # decode (it gathers none).
+        self.last_attn_pages: Optional[int] = None
         self.decode_width = config.scheduler.max_num_seqs
         self.prefill_width = config.scheduler.prefill_batch_size
         self._buckets = prefill_buckets(
@@ -2231,6 +2243,7 @@ class ModelRunner:
                       for s in rows)
         if want_lp:
             payload["want_logprobs"] = True
+        self._note_attn_pages(st["kv_lens"])
         sampled = self._dispatch(2, 1, payload)
         return DecodeStepHandle(self, rows, sampled, want_lp)
 
@@ -2321,6 +2334,11 @@ class ModelRunner:
         if want_lp:
             payload["want_logprobs"] = True
 
+        # A deferred burst's pages hold the tokens before its first
+        # (positions), frozen through the burst; an eager one starts
+        # at kv_lens and may take more blocks as its rows grow.
+        self._note_attn_pages(positions if self._deferred and window > 1
+                              else kv_lens)
         t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(2, window, payload)
         host = self.read_back(sampled)
@@ -2429,6 +2447,7 @@ class ModelRunner:
         if want_lp:
             payload["want_logprobs"] = True
 
+        self._note_attn_pages(kv_lens, "prefill")
         sampled = self._dispatch(KIND_SPEC, s, payload)
         return SpecStepHandle(
             self, list(seqs),
@@ -2706,6 +2725,21 @@ class ModelRunner:
         self.v_cache = self._write_page_q_jit(
             self.v_cache, jnp.asarray(v_page), jnp.asarray(v_scale),
             page_id)
+
+    def _note_attn_pages(self, kv_lens: np.ndarray,
+                         phase: str = "decode") -> None:
+        """``last_attn_pages`` for a decode dispatch whose pages hold
+        ``kv_lens`` tokens a row; ``phase`` names the attention that
+        serves its shape (a verify step's T > 1 is prefill's)."""
+        m = self.config.model
+        if (getattr(m, f"attention_impl_{phase}")
+                or m.attention_impl) != "xla":
+            self.last_attn_pages = None
+            return
+        page, most = self.config.cache.page_size, self.max_pages_per_seq
+        self.last_attn_pages = min(most, block_pages(most, page)
+                                   * gathered_blocks(int(kv_lens.max()),
+                                                     most, page))
 
     def _page_table_rows(self, seqs: List[Sequence],
                          pad_to: Optional[int] = None) -> np.ndarray:

@@ -73,6 +73,19 @@ logger = init_logger(__name__)
 STEP_FAILURE_LIMIT = 3
 
 
+def slice_options():
+    """What a /debug/profiler slice records: the device's planes and
+    the host's TraceAnnotations (``engine.*``, ``server.stream_token``)
+    but no Python frames. The Python tracer writes an event per call
+    on every thread: it doubled the hand-over it was there to measure,
+    made a slice of 8 s 100 MB, and stopping it held the interpreter
+    for tens of seconds (PERF.md, PR 26 and PR 28)."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return options
+
+
 def _put_annotated(annotate, put, item) -> None:
     with annotate("server.stream_token"):
         put(item)
@@ -566,7 +579,9 @@ class EngineServer:
         self._embedder = None
         self._embed_lock = asyncio.Lock()
         self.profile_dir = profile_dir
-        self._profiling = False
+        # The one /debug/profiler slice: None, "running", or
+        # "stopping" while its trace is being written.
+        self._profiling: Optional[str] = None
         # Synthetic span id for the active profiler capture window, so
         # the capture shows up in traceview next to the requests it
         # overlapped (docs/observability.md).
@@ -2074,8 +2089,9 @@ class EngineServer:
                 {"error": {"message": "profiler already running"}},
                 status=409,
             )
-        jax.profiler.start_trace(trace_dir)
-        self._profiling = True
+        jax.profiler.start_trace(trace_dir,
+                                 profiler_options=slice_options())
+        self._profiling = "running"
         tracer = self.engine.tracer
         if tracer is not None:
             self.async_engine.stream_annotation = tracer.annotate
@@ -2090,15 +2106,23 @@ class EngineServer:
                                   "dir": trace_dir})
 
     async def profiler_stop(self, request: web.Request):
+        """Stop the slice; answers once the trace is on disk. Writing
+        it takes seconds, so it runs off the event loop: streams keep
+        flowing and requests keep being admitted meanwhile, and a
+        start or a second stop that arrives then gets its 409."""
         import jax
-        if not self._profiling:
+        if self._profiling != "running":
             return web.json_response(
                 {"error": {"message": "profiler not running"}},
                 status=409,
             )
-        jax.profiler.stop_trace()
-        self._profiling = False
+        self._profiling = "stopping"
         self.async_engine.stream_annotation = None
+        try:
+            await asyncio.get_running_loop().run_in_executor(
+                None, jax.profiler.stop_trace)
+        finally:
+            self._profiling = None
         tracer = self.engine.tracer
         sid, self._profiler_span_id = self._profiler_span_id, None
         if tracer is not None and sid is not None:

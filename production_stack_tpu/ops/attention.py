@@ -11,6 +11,15 @@ Replaces: vLLM's PagedAttention CUDA kernels (external to the reference
 repo; provisioned via helm/templates/deployment-vllm-multi.yaml engine
 image) — re-designed for TPU: gather whole pages (contiguous HBM reads),
 mask in-register, let XLA tile the batched matmuls onto the MXU.
+
+What is gathered: the first pages of every row's table, a block of
+BLOCK_TOKENS at a time, as many blocks as the call's longest row
+holds. The program counts them from ``kv_lens`` on the device (one
+loop with that trip count per call, an online softmax across the
+blocks), so a batch of short rows under a large ``--max-model-len``
+does not read, write and contract max_pages pages a row only to mask
+them; the table itself stays [B, max_pages] and the host never
+chooses a shape.
 """
 
 from __future__ import annotations
@@ -21,6 +30,12 @@ import jax.numpy as jnp
 from production_stack_tpu.ops.quant_kv import QuantKV, quantize_kv
 
 NEG_INF = -1e30
+
+# Pages are gathered this many tokens at a time (8 pages of 128). On
+# a v5e a block of 64 rows is 33 MB a KV plane, which the re-layout
+# for the contraction keeps in the fast memory; a finer block would
+# follow the lengths closer at more loop turns a layer.
+BLOCK_TOKENS = 1024
 
 # Every attention implementation with a paged-KV read path. The
 # quantized-coverage lint (tests/test_kv_parity_coverage_lint.py)
@@ -37,9 +52,31 @@ ATTENTION_IMPLS = {
 }
 
 
+def block_pages(max_pages: int, page_size: int) -> int:
+    """Pages ``paged_attention`` gathers at a time: BLOCK_TOKENS'
+    worth, or the whole table where it is narrower."""
+    return min(max_pages, -(-BLOCK_TOKENS // page_size))
+
+
+def gathered_blocks(max_len, max_pages: int, page_size: int):
+    """How many blocks a call whose longest row holds ``max_len``
+    tokens gathers: those that hold it, at least one, at most the
+    table's. One rule for the program, where ``max_len`` is traced,
+    and for the host's step record (``attn_pages``), where it is an
+    int."""
+    block = block_pages(max_pages, page_size)
+    need = (max_len + block * page_size - 1) // (block * page_size)
+    most = -(-max_pages // block)
+    if isinstance(need, int):
+        return min(max(need, 1), most)
+    return jnp.clip(need, 1, most)
+
+
 def gather_pages(cache_layer: jnp.ndarray,
                  page_table: jnp.ndarray) -> jnp.ndarray:
-    """[kv, num_pages, d, page] gathered to [kv, B, max_pages, d, page].
+    """[kv, num_pages, d, page] gathered to [kv, B, P, d, page], P the
+    table's width as passed: ``paged_attention`` hands over one block
+    of the [B, max_pages] table at a time.
 
     Cache layout (shared with the Pallas kernels): kv-head axis major
     so TP shards a leading axis, and each page stored *token-minor*
@@ -162,6 +199,17 @@ def paged_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                     v_tail: "jnp.ndarray | None" = None) -> jnp.ndarray:
     """Causal attention of q against a sequence's cached pages.
 
+    Gathers and contracts the table a block of ``block_pages`` at a
+    time, ``gathered_blocks(max(kv_lens))`` of them: a loop whose trip
+    count the device reads from ``kv_lens``, with the softmax carried
+    across blocks as a running maximum, sum and weighted values (the
+    tail, where given, starts it). A block past every row's length
+    changes nothing (its positions are masked to NEG_INF and weigh
+    exactly 0), so the result does not depend on how many blocks the
+    rest of the batch asks for. Pad rows (``kv_lens`` 0) ask for
+    nothing. ``kv_lens`` may be traced (a burst's carry): the count
+    follows it step by step.
+
     Args:
       q:           [B, T, num_q_heads, head_dim]
       k/v_cache_layer: [num_kv_heads, num_pages, head_dim, page_size],
@@ -192,54 +240,76 @@ def paged_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
     b, t, num_q_heads, head_dim = q.shape
     num_kv_heads = k_cache_layer.shape[0]
     group = num_q_heads // num_kv_heads
+    page = k_cache_layer.shape[-1]
+    max_pages = page_table.shape[1]
+    block = block_pages(max_pages, page)
     scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, dtype=jnp.float32))
-
-    k = gather_pages(k_cache_layer, page_table)  # [kv, B, P, d, page]
-    v = gather_pages(v_cache_layer, page_table)
-    quantized = isinstance(k, QuantKV)
-    if quantized:
-        # int8 pages: keep the matmul operands int8 (dequant BEFORE
-        # the gather would materialize the whole cache in f32, the
-        # same hazard as the convert-hoist note below) and fold the
-        # per-slot scales in afterwards — exact, because each scale
-        # varies only over non-contracted score axes. Broadcast shape
-        # [B, kv, 1(group), 1(T), P, page].
-        k_scale_b = k.scale.transpose(1, 0, 2, 3)[:, :, None, None]
-        v_scale_b = v.scale.transpose(1, 0, 2, 3)[:, :, None, None]
-        k, v = k.data, v.data
-    p_cnt, page = k.shape[2], k.shape[4]
-
     qg = q.reshape(b, t, num_kv_heads, group, head_dim)
-    # scores: [B, kv, group, T, P, page], contracted in the cache's
-    # NATIVE axis order. Two deliberate choices, both HBM-traffic
-    # driven (this runs once per layer per step):
-    # - operands stay in the cache dtype with an f32 accumulator (the
-    #   MXU's native bf16xbf16->f32 form): upcasting k/v first makes
-    #   XLA hoist the convert above the page gather and materialize
-    #   the ENTIRE cache in f32,
-    # - no reshape/transpose of the gathered pages: an explicit
-    #   transpose gets hoisted onto the gather operand as a
-    #   whole-cache transposed copy (see gather_pages).
-    scores = jnp.einsum(
-        "btkgd,kbpdc->bkgtpc", qg, k,
-        preferred_element_type=jnp.float32,
-    ) * scale
-    if quantized:
-        scores = scores * k_scale_b  # fold k dequant into the logits
+    quantized = isinstance(k_cache_layer, QuantKV)
+    # A table that is no whole number of blocks ends in the trash
+    # page, at positions past every length.
+    page_table = jnp.pad(page_table, ((0, 0), (0, -max_pages % block)))
 
-    token_pos = (jnp.arange(p_cnt)[:, None] * page
-                 + jnp.arange(page)[None, :])  # [P, page]
-    causal = (token_pos[None, None]
-              <= q_positions[:, :, None, None])  # [B, T, P, page]
-    in_len = token_pos[None] < kv_lens[:, None, None]  # [B, P, page]
-    mask = causal & in_len[:, None]  # [B, T, P, page]
-    scores = jnp.where(mask[:, None, None], scores, NEG_INF)
+    def add_block(i, carry):
+        """Fold block ``i`` of the table into the running softmax."""
+        m, denom, acc = carry
+        table = jax.lax.dynamic_slice_in_dim(page_table, i * block,
+                                             block, axis=1)
+        k = gather_pages(k_cache_layer, table)  # [kv, B, P, d, page]
+        v = gather_pages(v_cache_layer, table)
+        if quantized:
+            # int8 pages: keep the matmul operands int8 (dequant
+            # BEFORE the gather would materialize the whole cache in
+            # f32, the same hazard as the convert-hoist note below)
+            # and fold the per-slot scales in afterwards — exact,
+            # because each scale varies only over non-contracted
+            # score axes. Broadcast shape [B, kv, 1(group), 1(T), P,
+            # page].
+            k_scale_b = k.scale.transpose(1, 0, 2, 3)[:, :, None, None]
+            v_scale_b = v.scale.transpose(1, 0, 2, 3)[:, :, None, None]
+            k, v = k.data, v.data
+        # scores: [B, kv, group, T, P, page], contracted in the
+        # cache's NATIVE axis order. Two deliberate choices, both
+        # HBM-traffic driven (this runs once per layer per step):
+        # - operands stay in the cache dtype with an f32 accumulator
+        #   (the MXU's native bf16xbf16->f32 form): upcasting k/v
+        #   first makes XLA hoist the convert above the page gather
+        #   and materialize the ENTIRE cache in f32,
+        # - no reshape/transpose of the gathered pages: an explicit
+        #   transpose gets hoisted onto the gather operand as a
+        #   whole-cache transposed copy (see gather_pages).
+        scores = jnp.einsum(
+            "btkgd,kbpdc->bkgtpc", qg, k,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        if quantized:
+            scores = scores * k_scale_b  # fold k dequant into the logits
+        token_pos = ((i * block + jnp.arange(block))[:, None] * page
+                     + jnp.arange(page)[None, :])  # [P, page]
+        causal = (token_pos[None, None]
+                  <= q_positions[:, :, None, None])  # [B, T, P, page]
+        in_len = token_pos[None] < kv_lens[:, None, None]  # [B, P, page]
+        mask = causal & in_len[:, None]  # [B, T, P, page]
+        scores = jnp.where(mask[:, None, None], scores, NEG_INF)
+        m_new = jnp.maximum(m, scores.max(axis=(-2, -1)))
+        keep = jnp.exp(m - m_new)
+        probs = jnp.exp(scores - m_new[..., None, None])  # f32
+        denom = denom * keep + probs.sum(axis=(-2, -1))
+        if quantized:
+            # v dequant folds into the weights (f32 — casting to the
+            # cache dtype would truncate to int8).
+            probs = probs * v_scale_b
+        else:
+            probs = probs.astype(v.dtype)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "bkgtpc,kbpdc->bkgtd", probs, v,
+            preferred_element_type=jnp.float32)
+        return m_new, denom, acc
 
-    shape = scores.shape
-    flat = scores.reshape(*shape[:-2], p_cnt * page)
-
+    stat = (b, num_kv_heads, group, t)
     if k_tail is not None:
-        # Burst tail: S un-flushed tokens at positions kv_lens + s.
+        # Burst tail: S un-flushed tokens at positions kv_lens + s;
+        # the tail itself stays full precision.
         s_len = k_tail.shape[1]
         t_scores = jnp.einsum(
             "btkgd,bskd->bkgts", qg, k_tail,
@@ -250,35 +320,22 @@ def paged_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
         t_mask = (tail_pos[:, None, :]
                   <= q_positions[:, :, None])  # [B, T, S]
         t_scores = jnp.where(t_mask[:, None, None], t_scores, NEG_INF)
-        # One softmax over the joint pages+tail token axis.
-        joint = jnp.concatenate([flat, t_scores], axis=-1)
-        probs = jax.nn.softmax(joint, axis=-1)
-        p_pages = probs[..., :p_cnt * page].reshape(shape)
-        p_tail = probs[..., p_cnt * page:]
-        if quantized:
-            # v dequant folds into the probabilities (f32 — casting to
-            # the cache dtype would truncate to int8); the burst tail
-            # itself stays full precision.
-            p_pages = p_pages * v_scale_b
-        else:
-            p_pages = p_pages.astype(v.dtype)
-        out = jnp.einsum(
-            "bkgtpc,kbpdc->btkgd", p_pages, v,
-            preferred_element_type=jnp.float32,
-        ) + jnp.einsum(
-            "bkgts,bskd->btkgd", p_tail.astype(v_tail.dtype), v_tail,
-            preferred_element_type=jnp.float32,
-        )
-        return out.reshape(b, t, num_q_heads, head_dim).astype(q.dtype)
-
-    # Softmax over the joint (P, page) token axis.
-    probs = jax.nn.softmax(flat, axis=-1).reshape(shape)  # f32
-    if quantized:
-        probs = probs * v_scale_b  # fold v dequant; keep f32
+        m = t_scores.max(axis=-1)
+        t_probs = jnp.exp(t_scores - m[..., None])
+        carry = (m, t_probs.sum(axis=-1), jnp.einsum(
+            "bkgts,bskd->bkgtd", t_probs.astype(v_tail.dtype), v_tail,
+            preferred_element_type=jnp.float32))
     else:
-        probs = probs.astype(v.dtype)
-    out = jnp.einsum(
-        "bkgtpc,kbpdc->btkgd", probs, v,
-        preferred_element_type=jnp.float32,
-    )
+        carry = (jnp.full(stat, NEG_INF, jnp.float32),
+                 jnp.zeros(stat, jnp.float32),
+                 jnp.zeros((*stat, head_dim), jnp.float32))
+
+    if max_pages <= block:
+        carry = add_block(0, carry)
+    else:
+        carry = jax.lax.fori_loop(
+            0, gathered_blocks(jnp.max(kv_lens), max_pages, page),
+            add_block, carry)
+    _, denom, acc = carry
+    out = (acc / denom[..., None]).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, t, num_q_heads, head_dim).astype(q.dtype)

@@ -25,17 +25,18 @@ from production_stack_tpu.engine.sequence import SamplingParams
 
 
 def _engine(decode_steps, deferred=False, max_num_seqs=4, arch="llama",
-            quantization=None, cache_layout="auto"):
+            quantization=None, cache_layout="auto", max_model_len=256,
+            num_pages=128, prefill_chunk_size=32):
     model = tiny_model_config(arch)
     if quantization:
         model.quantization = quantization
     config = EngineConfig(
         model=model,
-        cache=CacheConfig(page_size=16, num_pages=128,
+        cache=CacheConfig(page_size=16, num_pages=num_pages,
                           cache_layout=cache_layout),
         scheduler=SchedulerConfig(max_num_seqs=max_num_seqs,
-                                  max_model_len=256,
-                                  prefill_chunk_size=32,
+                                  max_model_len=max_model_len,
+                                  prefill_chunk_size=prefill_chunk_size,
                                   decode_steps=decode_steps,
                                   deferred_kv_writes=deferred),
     )
@@ -157,3 +158,48 @@ def test_deferred_guards():
         _engine(decode_steps=1, deferred=True)
     with pytest.raises(NotImplementedError, match="llama family"):
         _engine(decode_steps=4, deferred=True, arch="gpt2")
+
+
+# ---- the attention's width follows the longest row (ops/attention.py) -----
+
+
+def _wide_engine(decode_steps, deferred):
+    """A table of 128 pages of 16: two of the attention's blocks of
+    64 pages, their edge at 1024 tokens."""
+    return _engine(decode_steps, deferred, max_model_len=2048,
+                   num_pages=320, prefill_chunk_size=256)
+
+
+def test_deferred_row_crossing_a_width_edge_matches_single_step():
+    """1019 prompt tokens + 24 greedy ones at K=8: the first burst's
+    pages hold 1018 tokens (one block of 64 pages holds them, the
+    tail carries the row past 1024), the next burst starts past the
+    edge and gathers two; the flush in between writes page 64 through
+    the whole table."""
+    prompts = _prompts(sizes=(1019, 40), seed=5)
+    expected = _gen(_wide_engine(1, False), prompts, max_tokens=24)
+    engine = _wide_engine(8, True)
+    seen = []
+    real = engine.runner._note_attn_pages
+
+    def note(kv_lens):
+        real(kv_lens)
+        seen.append(engine.runner.last_attn_pages)
+
+    engine.runner._note_attn_pages = note
+    got = _gen(engine, prompts, max_tokens=24)
+    assert got == expected
+    # The short row bursts alone while the long prompt prefills.
+    assert seen == sorted(seen) and set(seen) == {64, 128}
+
+
+def test_deferred_longer_row_joining_compiles_no_new_burst():
+    engine = _wide_engine(8, True)
+    obs = engine.runner.observatory
+    short = _prompts(sizes=(40, 200), seed=5)
+    _gen(engine, short, max_tokens=16)
+    warm = obs.compile_events_total("decode_burst")
+    assert warm == 1
+    _gen(engine, short + _prompts(sizes=(1100,), seed=6), max_tokens=16)
+    assert obs.compile_events_total("decode_burst") == warm
+    assert engine.runner.last_attn_pages == 128

@@ -14,13 +14,14 @@ from production_stack_tpu.engine.engine import LLMEngine
 from production_stack_tpu.engine.sequence import SamplingParams
 
 
-def _engine(decode_steps, max_num_seqs=4):
+def _engine(decode_steps, max_num_seqs=4, max_model_len=256,
+            num_pages=128, prefill_chunk_size=32):
     config = EngineConfig(
         model=tiny_model_config("llama"),
-        cache=CacheConfig(page_size=16, num_pages=128),
+        cache=CacheConfig(page_size=16, num_pages=num_pages),
         scheduler=SchedulerConfig(max_num_seqs=max_num_seqs,
-                                  max_model_len=256,
-                                  prefill_chunk_size=32,
+                                  max_model_len=max_model_len,
+                                  prefill_chunk_size=prefill_chunk_size,
                                   decode_steps=decode_steps),
     )
     return LLMEngine(config)
@@ -138,3 +139,46 @@ def test_seeded_requests_reproduce():
     d = gen(6, 999)
     assert a == b == c
     assert d != a
+
+
+# ---- the attention's width follows the longest row (ops/attention.py) -----
+
+
+def _wide_engine(decode_steps):
+    """A table of 128 pages of 16: two of the attention's blocks of
+    64 pages, their edge at 1024 tokens."""
+    return _engine(decode_steps, max_model_len=2048, num_pages=320,
+                   prefill_chunk_size=256)
+
+
+def _long_prompts(sizes, seed=5):
+    rs = np.random.RandomState(seed)
+    return [[int(x) for x in rs.randint(1, 500, size=n)] for n in sizes]
+
+
+def test_row_crossing_a_width_edge_mid_burst_matches_single_step():
+    """1019 prompt tokens + 24 greedy ones at K=8: the row passes 1024
+    tokens inside the first burst, so the eager burst gathers one
+    block of 64 pages, then two, between two of its steps."""
+    from production_stack_tpu.ops.attention import block_pages
+    assert block_pages(128, 16) == 64
+    prompts = _long_prompts((1019, 40))
+    expected = _gen(_wide_engine(1), prompts, max_tokens=24)
+    got = _gen(_wide_engine(8), prompts, max_tokens=24)
+    assert got == expected
+    assert all(len(t) == 24 for t in got)
+
+
+def test_a_longer_row_joining_the_batch_compiles_no_new_burst():
+    """One burst program serves every length: the compile ledger's
+    decode_burst count stands still when a row past the first block's
+    edge joins rows under it."""
+    engine = _wide_engine(8)
+    obs = engine.runner.observatory
+    short = _long_prompts((40, 200))
+    _gen(engine, short, max_tokens=16)
+    assert engine.runner.last_attn_pages == 64
+    warm = obs.compile_events_total("decode_burst")
+    assert warm == 1
+    _gen(engine, short + _long_prompts((1100,), seed=6), max_tokens=16)
+    assert obs.compile_events_total("decode_burst") == warm

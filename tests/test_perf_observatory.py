@@ -248,7 +248,7 @@ def test_debug_endpoints_404_without_observatory():
 
 def test_profiler_start_stop_guard_and_spans(monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda trace_dir: None)
+                        lambda trace_dir, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     engine = _engine()
     engine.tracer = EngineTracer(ring_size=8)

@@ -22,7 +22,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 FIXTURES = ROOT / "chipbench" / "tests"
 
 
-def _engine(**scheduler):
+def _engine(page_size=16, num_pages=64, max_model_len=128,
+            prefill_chunk_size=32, **scheduler):
     from production_stack_tpu.engine.config import (
         CacheConfig, EngineConfig, SchedulerConfig, tiny_model_config,
     )
@@ -30,9 +31,11 @@ def _engine(**scheduler):
 
     return LLMEngine(EngineConfig(
         model=tiny_model_config("llama"),
-        cache=CacheConfig(page_size=16, num_pages=64),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_model_len=128,
-                                  prefill_chunk_size=32, **scheduler),
+        cache=CacheConfig(page_size=page_size, num_pages=num_pages),
+        scheduler=SchedulerConfig(max_num_seqs=4,
+                                  max_model_len=max_model_len,
+                                  prefill_chunk_size=prefill_chunk_size,
+                                  **scheduler),
     ))
 
 
@@ -140,6 +143,35 @@ async def test_without_a_tracer_no_record_and_no_annotation(monkeypatch):
     assert engine.tracer.annotate is not None
 
 
+@pytest.mark.parametrize("scheduler", [
+    {"decode_steps": 4}, {"decode_steps": 4, "deferred_kv_writes": True},
+    {}, {"async_scheduling": True}],
+    ids=["burst", "deferred-burst", "single-step", "async"])
+def test_a_decode_record_says_how_many_pages_the_attention_gathered(
+        scheduler):
+    """``attn_pages``: rows of 300 and 1300 tokens at page 128 under a
+    table of 32 pages (blocks of 8) take 16, and 8 once the long row
+    has left."""
+    from production_stack_tpu.engine.sequence import SamplingParams
+
+    engine = _engine(page_size=128, num_pages=32, max_model_len=4096,
+                     prefill_chunk_size=256, **scheduler)
+    engine.tracer = EngineTracer(ring_size=256)
+    for prompt, max_tokens in ((1300, 5), (300, 24)):
+        engine.add_request(
+            [7 + i % 400 for i in range(prompt)], SamplingParams(
+                temperature=0.0, max_tokens=max_tokens, ignore_eos=True))
+    while engine.has_work():
+        engine.step()
+    decodes = [s for s in engine.tracer.recent_steps(limit=0)
+               if s.get("kind") == "decode"]
+    both = [s["attn_pages"] for s in decodes if s["decode_rows"] == 2]
+    alone = [s["attn_pages"] for s in decodes if s["decode_rows"] == 1]
+    assert both and set(both) == {16}
+    assert alone and alone[-1] == 8
+    assert {s["attn_pages"] for s in decodes} == {8, 16}
+
+
 def test_a_tracer_outside_the_server_loop_records_steps_as_before():
     tracer = EngineTracer()
     assert tracer.phase("build") is None  # no loop keeps turns
@@ -223,15 +255,24 @@ def test_tracing_imports_the_standard_library_only():
     assert modules <= set(sys.stdlib_module_names), modules
 
 
-async def test_a_profiler_slice_holds_the_turns_of_the_records(tmp_path):
+@pytest.mark.parametrize("server_options", [True, False],
+                         ids=["as-the-server-starts-it", "jax-defaults"])
+async def test_a_profiler_slice_holds_the_turns_of_the_records(
+        tmp_path, server_options):
+    """With the options /debug/profiler/start passes (no Python
+    tracer) as with jax's defaults: the annotations are what the
+    reduction reads, and they are in both."""
     import jax
     from jax.profiler import ProfileData
 
     from chipbench import host_phases, reduce
+    from production_stack_tpu.engine.server import slice_options
 
     engine = _engine(decode_steps=4)
     engine.tracer = EngineTracer(annotate=jax.profiler.TraceAnnotation)
-    jax.profiler.start_trace(str(tmp_path))
+    options = slice_options() if server_options else None
+    assert options is None or options.python_tracer_level == 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         served = await _serve(engine, requests=1, max_tokens=4)
         served.stream_annotation = engine.tracer.annotate
@@ -259,6 +300,9 @@ async def test_a_profiler_slice_holds_the_turns_of_the_records(tmp_path):
     assert {f"engine.{p}" for p in ("plan", "build", "dispatch", "wait",
                                     "commit", "emit")} <= names
     assert sum(e.name == "server.stream_token" for e in events) == 8
+    # Python frames ("$" + file:line function) only from the tracer
+    # that the server's slices leave off.
+    assert any(e.name.startswith("$") for e in events) != server_options
     # The reduction joins each turn event to its record by step,
     # whatever the clocks say: its start and its end are two pairs.
     summary = host_phases.summarize(
@@ -267,6 +311,79 @@ async def test_a_profiler_slice_holds_the_turns_of_the_records(tmp_path):
     assert summary["clock_pairs"] == 2 * len(turns)
     assert abs(summary["clock_offset_ns"]) < 5e6
     assert summary["idle_by_phase_s"]["unattributed"] < summary["idle_s"]
+
+
+async def test_stopping_a_slice_does_not_hold_the_streams(monkeypatch):
+    """Writing a trace takes seconds (here: a stop_trace that sleeps
+    2 s): POST /debug/profiler/stop waits for it off the event loop,
+    so a stream's tokens keep arriving, a second stop or a start
+    meanwhile gets 409, and the slice's span closes after it."""
+    import jax
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.server import EngineServer
+
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda trace_dir, **kw: started.append(kw))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: time.sleep(2.0))
+    engine = _engine(max_model_len=1024, num_pages=80)
+    engine.tracer = EngineTracer(ring_size=8)
+    step = engine.step
+    # A token every 10 ms or slower: the stream outlasts the stop.
+    engine.step = lambda: (time.sleep(0.01), step())[1]
+    client = TestClient(TestServer(
+        EngineServer(engine, "tiny-llama").build_app()))
+    await client.start_server()
+    arrivals, ticks = [], []
+
+    async def read(resp):
+        async for _ in resp.content.iter_any():
+            arrivals.append(time.perf_counter())
+
+    async def tick():
+        while True:
+            ticks.append(time.perf_counter())
+            await asyncio.sleep(0.005)
+
+    ticker = asyncio.ensure_future(tick())
+    try:
+        assert (await client.post("/debug/profiler/start")).status == 200
+        assert started[0]["profiler_options"].python_tracer_level == 0
+        resp = await client.post("/v1/completions", json={
+            "model": "tiny-llama", "prompt": "a b c", "stream": True,
+            "max_tokens": 900, "temperature": 0.0, "ignore_eos": True})
+        assert resp.status == 200
+        reader = asyncio.ensure_future(read(resp))
+        while len(arrivals) < 5:
+            await asyncio.sleep(0.01)
+        t0 = time.perf_counter()
+        stop = asyncio.ensure_future(client.post("/debug/profiler/stop"))
+        await asyncio.sleep(0.5)
+        assert not stop.done()
+        assert (await client.post("/debug/profiler/stop")).status == 409
+        assert (await client.post("/debug/profiler/start")).status == 409
+        assert (await stop).status == 200
+        t1 = time.perf_counter()
+        assert t1 - t0 >= 2.0
+        assert not reader.done()  # the stream outlasted the stop
+        during = [t for t in arrivals if t0 + 0.1 < t < t1 - 0.1]
+        assert len(during) >= 20
+        assert max(b - a for a, b in zip(during, during[1:])) < 0.5
+        inside = [t for t in ticks if t0 <= t <= t1]
+        assert max(b - a for a, b in zip(inside, inside[1:])) < 0.5
+        span = list(engine.tracer._ring)[-1]
+        assert span.seq_id.startswith("prof-")
+        assert "profiler_stop" in [e["event"] for e in span.events]
+        # The slice is over: a stop has nothing to stop, a start starts.
+        assert (await client.post("/debug/profiler/stop")).status == 409
+        assert (await client.post("/debug/profiler/start")).status == 200
+        reader.cancel()
+        resp.close()
+    finally:
+        ticker.cancel()
+        await client.close()
 
 
 def test_a_token_emitted_inside_a_slice_is_delivered_after_it():
