@@ -1,5 +1,6 @@
 """From a profiler trace (``.xplane.pb``) to device busy time, time per
-program, the heaviest device operations and the longest idle gaps.
+program, the heaviest device operations, the longest idle gaps, and
+device time by name: by JAX name stack and by source file.
 
     JAX_PLATFORMS=cpu python3 chipbench/reduce.py <profile dir> <out.json> <platform>
 
@@ -14,6 +15,20 @@ first operation's start to the last one's end over all device planes;
 busy time is averaged over the planes.  An idle gap is labelled by the
 program that ran next: the gap is the time the device waited for that
 program to be dispatched.
+
+Each operation's event points at metadata of the plane (``xplane.py``)
+that says where it came from.  ``scopes`` sums device seconds and
+events by the name stack the operation was traced under, the traced
+primitive last (``jit(_step_impl)/attn/named_kernel/pallas_call``: a
+``jax.named_scope`` and a ``pallas_call``'s ``name`` are components of
+it), so that a reader finds a kernel or a scope by the name the
+program gave it (``roofline.scope_time``).  ``sources`` sums device
+seconds by jitted program and by the file of the program's source the
+operation came from, relative to the checkout, which needs no name in
+the program.  Neither is cut at ten; an operation without the one or
+the other is counted under ``NO_NAME`` / ``NO_SOURCE``, and a loop or
+a conditional by its body's operations, like ``device_ops``.  A trace
+that says neither (the rehearsal's stand-in) leaves both maps empty.
 
 ``<platform>`` is what the server said it runs on.  For ``tpu`` a trace
 without a device plane that has an ``XLA Ops`` line is an error: the
@@ -31,7 +46,14 @@ import os
 import re
 import sys
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if __name__ == "__main__":
+    sys.path.insert(0, ROOT)
+
+from chipbench import xplane  # noqa: E402
+
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+NO_NAME, NO_SOURCE, NO_PROGRAM = "(no name)", "(no source)", "(no program)"
 
 
 def union_s(intervals: list) -> float:
@@ -69,6 +91,23 @@ def op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].lstrip("%")
 
 
+def program_id(event_name: str):
+    """``jit__decode_burst_impl(1234)`` -> 1234; None without one."""
+    found = re.search(r"\((\d+)\)$", event_name)
+    return int(found.group(1)) if found else None
+
+
+def source_file(source: str, root: str = ROOT) -> str:
+    """``/checkout/pkg/ops/x.py:57`` -> ``pkg/ops/x.py``: the line
+    dropped, the path relative to ``root`` where it lies under it."""
+    path = re.sub(r":\d+$", "", source)
+    if not path:
+        return NO_SOURCE
+    if path.startswith(root + os.sep):
+        return path[len(root) + 1:]
+    return path
+
+
 def whole_execution_s(durations: list) -> float:
     """Device time of one whole execution of a program.  An execution
     that the slice's edge cut short is recorded with what was seen of
@@ -82,19 +121,31 @@ def whole_execution_s(durations: list) -> float:
 
 def summarize(planes: dict) -> dict:
     """``planes``: {plane name: {"ops": [(name, start_ns, dur_ns)],
-    "modules": [(name, start_ns, dur_ns)]}}."""
+    "modules": [(name, start_ns, dur_ns)], and where the trace says
+    it, in the order of "ops", "op_meta": [(name stack, source file,
+    program)]}}."""
     starts = [s for p in planes.values() for _, s, _ in p["ops"]]
     ends = [s + d for p in planes.values() for _, s, d in p["ops"]]
     if not starts:
         return {"window_s": 0.0, "busy_s": 0.0, "planes": sorted(planes)}
     busy, op_time, programs, gap_list = [], {}, {}, []
+    scopes, sources = {}, {}
     for plane in planes.values():
         intervals = [(s, s + d) for _, s, d in plane["ops"]]
         busy.append(union_s(intervals))
-        for name, _, d in plane["ops"]:
+        meta = plane.get("op_meta")
+        for index, (name, _, d) in enumerate(plane["ops"]):
             # A loop's own event spans its body's: count the body.
-            if not name.startswith(("while", "conditional")):
-                op_time[name] = op_time.get(name, 0) + d
+            if name.startswith(("while", "conditional")):
+                continue
+            op_time[name] = op_time.get(name, 0) + d
+            if meta:
+                scope, source, program = meta[index]
+                entry = scopes.setdefault(scope, [0, 0])
+                entry[0] += d
+                entry[1] += 1
+                by_file = sources.setdefault(program, {})
+                by_file[source] = by_file.get(source, 0) + d
         modules = sorted((s, d, program_name(n))
                          for n, s, d in plane["modules"])
         for _, d, name in modules:
@@ -124,6 +175,11 @@ def summarize(planes: dict) -> dict:
         "idle_gaps": sorted(([k, v / n] for k, v in by_label.items()),
                             key=lambda kv: -kv[1])[:10],
         "longest_gap_s": max((g for _, g in gap_list), default=0.0),
+        "scopes": {k: {"seconds": ns / 1e9 / n, "count": count / n}
+                   for k, (ns, count) in sorted(scopes.items())},
+        "sources": {program: {k: ns / 1e9 / n
+                              for k, ns in sorted(by_file.items())}
+                    for program, by_file in sorted(sources.items())},
     }
 
 
@@ -132,11 +188,10 @@ class NoDevicePlane(Exception):
 
 
 def read_planes(path: str, platform: str) -> dict:
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(path)
     planes = {}
     if platform == "cpu":  # the rehearsal: host threads stand in
-        for plane in data.planes:
+        from jax.profiler import ProfileData
+        for plane in ProfileData.from_file(path).planes:
             if plane.name == "/host:CPU":
                 ops = [(e.name, int(e.start_ns), int(e.duration_ns))
                        for line in plane.lines
@@ -146,20 +201,38 @@ def read_planes(path: str, platform: str) -> dict:
         return planes
     if platform != "tpu":
         raise NoDevicePlane(f"no reduction for platform {platform!r}")
-    for plane in data.planes:
+    space = xplane.read_space(path)
+    for plane in space.planes:
         if plane.name.startswith("/device:TPU"):
             lines = {line.name: line for line in plane.lines}
             if OPS_LINE not in lines:
                 continue
+            metadata = xplane.event_metadata(plane)
+            modules = ([(metadata[i]["name"], s, d) for i, s, d in
+                        xplane.line_events(lines[MODULES_LINE])]
+                       if MODULES_LINE in lines else [])
+            programs = {program_id(n): program_name(n)
+                        for n, _, _ in modules}
+            # What an operation is, worked out once for each and not
+            # for each of its events.
+            described = {
+                i: (op_name(m["name"]),
+                    (m.get("tf_op", "").rstrip(":") or NO_NAME,
+                     source_file(m.get("source", "")),
+                     programs.get(m.get("program_id"), NO_PROGRAM)))
+                for i, m in metadata.items()}
+            ops, op_meta = [], []
+            for i, start, duration in xplane.line_events(lines[OPS_LINE]):
+                name, meta = described[i]
+                ops.append((name, start, duration))
+                op_meta.append(meta)
             planes[plane.name] = {
-                key: [(op_name(e.name), int(e.start_ns), int(e.duration_ns))
-                      for e in lines[name].events] if name in lines else []
-                for key, name in (("ops", OPS_LINE),
-                                  ("modules", MODULES_LINE))}
+                "ops": ops, "op_meta": op_meta,
+                "modules": [(op_name(n), s, d) for n, s, d in modules]}
     if not planes:
         raise NoDevicePlane(
             f"no /device:TPU plane with an {OPS_LINE!r} line among "
-            f"{[p.name for p in data.planes]}")
+            f"{[p.name for p in space.planes]}")
     return planes
 
 

@@ -13,8 +13,13 @@ batching: one sequence, one full forward, every matrix product under
 compute it in float32.
 
 Weights come as ``Weights``: the caller fills it from whatever holds the
-values (for the benchmark, the program's own random init); linear
-weights are [in, out].
+values; linear weights are [in, out].  For the benchmark
+``program_model`` fills it from the program's own random init, which
+is data here: nothing else of the program is used.
+
+``reference/check.py`` finds this module by the family a configuration
+names and uses ``program_model`` and ``log_probs`` of it, as of any
+family's.
 """
 
 from __future__ import annotations
@@ -73,9 +78,56 @@ def attention(q, k, v):
     return jnp.einsum("hts,shd->thd", jax.nn.softmax(scores, -1), v)
 
 
-def log_probs(weights: Weights, shape: Shape, tokens, positions):
+def program_model(hf_config: dict, bench: dict):
+    """The server's random weights for this configuration (``bench``:
+    its ``chipbench`` group), by the server's own init from
+    ``weights_seed``, as the ``(Weights, Shape)`` that ``log_probs``
+    takes; int8 leaves dequantised as value x scale."""
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.engine.quantization import (
+        init_random_quantized,
+    )
+    from production_stack_tpu.models.registry import get_model
+
+    config = ModelConfig.from_hf_config(hf_config)
+    config.quantization = bench["quantization"]
+    seed = bench["weights_seed"]
+    init_fn, _ = get_model(config)
+    if config.quantization == "int8":
+        params = init_random_quantized(init_fn, config, seed)
+    else:
+        params = init_fn(config, jax.random.PRNGKey(seed))
+    per_layer = [k for k, v in params.items()
+                 if k not in ("embed", "final_norm", "lm_head")]
+
+    def layer(i: int) -> dict:
+        out = {}
+        for name in per_layer:
+            leaf = params[name]
+            if isinstance(leaf, tuple):  # (int8 values, scale per column)
+                q, scale = leaf
+                out[name] = (q[i].astype(jnp.float32)
+                             * scale[i].astype(jnp.float32)[None, :])
+            else:
+                out[name] = leaf[i].astype(jnp.float32)
+        return out
+
+    shape = Shape(
+        num_layers=config.num_hidden_layers,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim, rms_eps=config.rms_norm_eps,
+        rope_theta=config.rope_theta)
+    return Weights(
+        embed=params["embed"], final_norm=params["final_norm"],
+        lm_head=params.get("lm_head"), layer=layer), shape
+
+
+def log_probs(model, tokens, positions):
     """Log-softmax over the vocabulary of the next token after each of
-    ``positions`` (indices into ``tokens``): [len(positions), vocab]."""
+    ``positions`` (indices into ``tokens``): [len(positions), vocab].
+    ``model`` is ``(Weights, Shape)``."""
+    weights, shape = model
     with jax.default_matmul_precision("highest"):
         tokens = jnp.asarray(tokens, jnp.int32)
         x = weights.embed[tokens].astype(jnp.float32)

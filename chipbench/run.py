@@ -37,7 +37,7 @@ sys.path.insert(0, ROOT)
 
 import aiohttp  # noqa: E402
 
-from chipbench import e2e, wordtok  # noqa: E402
+from chipbench import e2e, family, wordtok  # noqa: E402
 from chipbench.client import Load  # noqa: E402
 from chipbench.procs import (  # noqa: E402
     Procs, RunFailure, free_port, http, wait_http_ok)
@@ -358,12 +358,24 @@ def reduce_trace(run_dir: str, procs: Procs, platform: str) -> None:
         raise RunFailure("the trace reduction failed")
 
 
+def device_time_by_source(summary: dict) -> list:
+    """[["<program>: <source file>", device seconds]], the most first:
+    what the ledger's reader can place in the program, where an
+    operation's own name (``fusion.6205``) says nothing.  Empty where
+    the trace names no source (the rehearsal's stand-in)."""
+    by_source = [[f"{program}: {file}", seconds]
+                 for program, files in summary.get("sources", {}).items()
+                 for file, seconds in files.items()]
+    return sorted(by_source, key=lambda kv: -kv[1])
+
+
 def run(args) -> int:
     if not os.path.isdir(os.path.join(ROOT, "production_stack_tpu")):
         raise RunFailure("the system under test is not in this checkout")
     cell = find_cell(args.workload)
     validate(cell)
     config = load_json(cell["config_file"])
+    family.name_of(config)  # or the run ends here, saying what to add
     bench = config["chipbench"]
     trace = bool(args.trace)
     run_dir = os.path.join(STATE, "runs", cell["name"])
@@ -470,6 +482,7 @@ def run(args) -> int:
         if summary_trace.get("device_ops"):
             result["breakdown"] = {
                 "device_ops": summary_trace["device_ops"][:10],
+                "device_sources": device_time_by_source(summary_trace)[:10],
                 "idle_gaps": summary_trace["idle_gaps"][:10]}
     else:
         for name in cell["end_to_end"]:
@@ -516,7 +529,7 @@ def main(argv=None) -> int:
             os.path.join(ROOT, "BENCHMARK.json"))["run_seconds"]
     try:
         return run(args)
-    except RunFailure as e:
+    except (RunFailure, family.UnknownFamily) as e:
         print(f"[chipbench] FAILED: {e}", file=sys.stderr)
         return 1
 

@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from chipbench import run as bench_run
+from chipbench import family, run as bench_run
 
 ROOT = bench_run.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -56,9 +56,21 @@ def test_config_file_matches_its_entry(entry):
     bench = config["chipbench"]
     assert (bench["name"], bench["source"], bench["reduced"]) == (
         entry["name"], entry["source"], entry["reduced"])
-    assert bench["server_flags"]["page-size"] == 128
+    family.name_of(config)
+    if "page-size" in bench["server_flags"]:
+        # The Pallas kernels serve no smaller page (PERF.md section 4); a
+        # model whose state is not paged has no reason to give the flag.
+        assert bench["server_flags"]["page-size"] == 128
     assert {"max_abs_logprob_diff", "mean_abs_logprob_diff", "why"} <= set(
         bench["reference_tolerance"])
+
+
+def test_every_per_layer_metric_lists_its_cells():
+    """Without the list a metric belongs to every cell that reports
+    what it moves, those of later PRs too; with it a new cell lists
+    itself where it has something to read."""
+    for entry in MANIFEST["per_layer"]:
+        assert entry["workloads"] and set(entry["workloads"]) <= set(CELLS)
 
 
 def test_end_to_end_units_are_the_harness_own():

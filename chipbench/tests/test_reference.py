@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.reference import check, llama_family
+from chipbench.reference import llama_family
 
 TINY = {"hidden_size": 64, "intermediate_size": 160,
         "num_hidden_layers": 3, "num_attention_heads": 8,
@@ -48,7 +48,7 @@ def test_reference_agrees_with_the_program_in_float32(arch, tied):
         layer=lambda i: {k: params[k][i] for k in per_layer})
     shape = llama_family.Shape(3, 8, 2, 8, 1e-6, 1e6)
     positions = [0, 7, 38, 39]
-    got = np.asarray(llama_family.log_probs(weights, shape, tokens,
+    got = np.asarray(llama_family.log_probs((weights, shape), tokens,
                                             positions))
     # float32 on both sides: rounding only.
     np.testing.assert_allclose(got, want[positions], atol=2e-5)
@@ -65,7 +65,8 @@ def test_the_adapter_dequantises_int8_leaves_as_value_times_scale():
 
     hf = dict(TINY, architectures=["MistralForCausalLM"],
               tie_word_embeddings=False)
-    weights, shape = check.program_weights(hf, "int8", seed=3)
+    weights, shape = llama_family.program_model(
+        hf, {"quantization": "int8", "weights_seed": 3})
     config = ModelConfig.from_hf_config(hf)
     raw = init_random_quantized(llama.init_params, config, 3)
     q, scale = raw["w_gate"]
@@ -78,7 +79,7 @@ def test_the_adapter_dequantises_int8_leaves_as_value_times_scale():
     assert (shape.num_layers, shape.num_heads, shape.num_kv_heads,
             shape.head_dim) == (3, 8, 2, 8)
     # The dequantised model runs and gives a distribution.
-    got = llama_family.log_probs(weights, shape, [1, 2, 3, 4], [3])
+    got = llama_family.log_probs((weights, shape), [1, 2, 3, 4], [3])
     assert np.exp(np.asarray(got)).sum() == pytest.approx(1.0, abs=1e-5)
 
 

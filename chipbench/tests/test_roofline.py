@@ -1,4 +1,6 @@
-"""The byte and FLOP functions against sums made by hand."""
+"""The byte and FLOP functions against sums made by hand: the Llama
+family's counts, and ``roofline.py`` handing on to them by the family a
+configuration names."""
 
 import json
 import os
@@ -6,6 +8,7 @@ import os
 import pytest
 
 from chipbench import roofline
+from chipbench.counts import llama_family as counts
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "configs")
@@ -21,7 +24,8 @@ def cfg(name):
 MISTRAL_INT8 = {"hidden_size": 4096, "intermediate_size": 14336,
                 "num_hidden_layers": 32, "num_attention_heads": 32,
                 "num_key_value_heads": 8, "vocab_size": 32768,
-                "chipbench": {"quantization": "int8"}}
+                "chipbench": {"family": "llama_family",
+                              "quantization": "int8"}}
 
 
 def test_qwen_sums():
@@ -29,10 +33,10 @@ def test_qwen_sums():
     # q 2048x2048, k and v 2048x256, o 2048x2048, three of 2048x11008.
     per_layer = 2 * 2048 * 2048 + 2 * 2048 * 256 + 3 * 2048 * 11008
     assert per_layer == 77_070_336
-    assert roofline.layer_matmul_params(c) == per_layer
-    assert roofline.head_params(c) == 2048 * 151936
+    assert counts.layer_matmul_params(c) == per_layer
+    assert counts.head_params(c) == 2048 * 151936
     # 36 layers x 2 (K, V) x 2 heads x 128 x 2 bytes = 36 KiB a token.
-    assert roofline.kv_bytes_per_token(c) == 36_864
+    assert counts.kv_bytes_per_token(c) == 36_864
     weights = 36 * per_layer * 2 + 2048 * 151936 * 2
     assert weights == 6_171_394_048
     assert roofline.decode_step_bytes(c, 0) == weights
@@ -43,8 +47,8 @@ def test_mistral_int8_sums():
     c = MISTRAL_INT8
     per_layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     assert per_layer == 218_103_808
-    assert roofline.layer_matmul_params(c) == per_layer
-    assert roofline.kv_bytes_per_token(c) == 131_072
+    assert counts.layer_matmul_params(c) == per_layer
+    assert counts.kv_bytes_per_token(c) == 131_072
     # int8 projections are one byte each; the head stays bfloat16.
     weights = 32 * per_layer + 4096 * 32768 * 2
     assert weights == 7_247_757_312
