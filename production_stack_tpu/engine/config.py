@@ -28,7 +28,8 @@ class ModelConfig:
     """Architecture hyperparameters (HF-config compatible field names)."""
 
     name: str = "tiny-llama"
-    architecture: str = "llama"  # llama | opt | gpt2 | mistral | qwen2
+    # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next
+    architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
     intermediate_size: int = 5632
@@ -49,6 +50,33 @@ class ModelConfig:
     # Mixtral-style sparse MoE (architecture == "mixtral").
     num_local_experts: int = 0
     num_experts_per_tok: int = 2
+    # Qwen3-Next hybrid decoders (architecture == "qwen3_next",
+    # models/qwen3_next.py). Layer i is gated full attention when
+    # (i + 1) % full_attention_interval == 0 and a Gated DeltaNet
+    # (linear attention) layer otherwise; 0 = every layer is full
+    # attention (every other architecture). The linear layers keep a
+    # recurrent state per sequence instead of pages
+    # (engine/kv_cache.py state slots).
+    full_attention_interval: int = 0
+    # Share of each head's dimensions the rotary embedding turns.
+    partial_rotary_factor: float = 1.0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # Sparse block: ``num_experts`` routed experts are HELD by this
+    # engine, block ``expert_parallel_rank`` of ``expert_parallel_size``
+    # equal blocks; the router is as wide as all of them
+    # (num_experts * expert_parallel_size, the published count) and a
+    # token keeps its num_experts_per_tok choices, of which this
+    # engine computes the ones it holds (ops/moe.py).
+    num_experts: int = 0
+    expert_parallel_size: int = 1
+    expert_parallel_rank: int = 0
+    moe_intermediate_size: int = 0
+    shared_expert_intermediate_size: int = 0
+    norm_topk_prob: bool = True
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -78,6 +106,51 @@ class ModelConfig:
     def jax_dtype(self):
         return _DTYPE_MAP[self.dtype]
 
+    @property
+    def layer_is_linear(self) -> tuple:
+        """Per layer: True where the layer keeps a recurrent state
+        (Gated DeltaNet) and no pages, False where it attends over
+        the paged cache. The pattern is static."""
+        n = self.full_attention_interval
+        return tuple(bool(n) and (i + 1) % n != 0
+                     for i in range(self.num_hidden_layers))
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers whose K and V live in pages."""
+        return self.layer_is_linear.count(False)
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        return any(self.layer_is_linear)
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router chooses among (the published count)."""
+        return self.num_experts * self.expert_parallel_size
+
+    def recurrent_state_shapes(self):
+        """One sequence's state in one linear layer: the delta rule's
+        ``S`` (float32, as the published recurrence keeps it) and the
+        causal convolution's tail of inputs (model dtype)."""
+        conv_channels = (2 * self.linear_num_key_heads
+                         * self.linear_key_head_dim
+                         + self.linear_num_value_heads
+                         * self.linear_value_head_dim)
+        return ((self.linear_num_value_heads, self.linear_key_head_dim,
+                 self.linear_value_head_dim),
+                (self.linear_conv_kernel_dim - 1, conv_channels))
+
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of one sequence's recurrent state over all linear
+        layers (0 for a model with none)."""
+        if not self.has_recurrent_state:
+            return 0
+        s, tail = self.recurrent_state_shapes()
+        per_layer = (math.prod(s) * 4
+                     + math.prod(tail) * jnp.dtype(self.jax_dtype).itemsize)
+        return per_layer * self.layer_is_linear.count(True)
+
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "") -> "ModelConfig":
         """Build from a HuggingFace config.json dict."""
@@ -95,6 +168,65 @@ class ModelConfig:
                 max_position_embeddings=hf["n_positions"],
                 tie_word_embeddings=True,
                 activation="gelu",
+                dtype="bfloat16",
+            )
+        if "qwen3next" in arch:
+            ep = int(hf.get("expert_parallel_size", 1))
+            rank = int(hf.get("expert_parallel_rank", 0))
+            if not 0 <= rank < ep:
+                raise ValueError(
+                    f"expert_parallel_rank {rank} is not one of "
+                    f"expert_parallel_size {ep} blocks")
+            unsupported = [k for k, bad in (
+                ("mlp_only_layers", bool(hf.get("mlp_only_layers"))),
+                ("decoder_sparse_step",
+                 hf.get("decoder_sparse_step", 1) != 1),
+                ("rope_scaling", hf.get("rope_scaling") is not None),
+                ("use_sliding_window",
+                 bool(hf.get("use_sliding_window", False))),
+                ("attention_bias", bool(hf.get("attention_bias", False))),
+            ) if bad]
+            if unsupported:
+                raise ValueError(
+                    "Qwen3-Next config keys this engine does not "
+                    f"serve at a non-default value: {unsupported}")
+            return cls(
+                name=name or hf.get("_name_or_path", "qwen3-next"),
+                architecture="qwen3_next",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf.get("intermediate_size", 0),
+                num_hidden_layers=hf["num_hidden_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                num_key_value_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim"),
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 262144),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+                rope_theta=hf.get("rope_theta", 1e7),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           False),
+                full_attention_interval=hf.get(
+                    "full_attention_interval", 4),
+                partial_rotary_factor=hf.get(
+                    "partial_rotary_factor", 0.25),
+                linear_num_key_heads=hf["linear_num_key_heads"],
+                linear_num_value_heads=hf["linear_num_value_heads"],
+                linear_key_head_dim=hf["linear_key_head_dim"],
+                linear_value_head_dim=hf["linear_value_head_dim"],
+                linear_conv_kernel_dim=hf.get(
+                    "linear_conv_kernel_dim", 4),
+                # The count this engine holds; the router's width is
+                # this times expert_parallel_size.
+                num_experts=hf["num_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                shared_expert_intermediate_size=hf[
+                    "shared_expert_intermediate_size"],
+                norm_topk_prob=hf.get("norm_topk_prob", True),
+                activation="silu",
                 dtype="bfloat16",
             )
         if "mixtral" in arch:
@@ -194,6 +326,13 @@ class CacheConfig:
     #                  in-kernel; the page budget is expanded to spend
     #                  the SAME HBM bytes (~2x pages at bf16 widths).
     kv_cache_dtype: str = "auto"
+    # Recurrent-state slots for a model with linear-attention layers
+    # (engine/kv_cache.py), beside the trash slot 0. Derived by
+    # EngineConfig, not set by hand: 0 for a model whose state is all
+    # pages, else max_num_seqs + prefill_batch_size (a sequence holds
+    # its slot from its first prefill chunk, before it counts as
+    # running).
+    num_state_slots: int = 0
 
     def max_tokens(self) -> int:
         return self.page_size * self.num_pages
@@ -213,7 +352,7 @@ class CacheConfig:
     def kv_bytes_per_token(self, model: "ModelConfig") -> int:
         """Total KV bytes appended per committed token (k and v,
         all layers, all kv heads)."""
-        return (2 * model.num_hidden_layers
+        return (2 * model.num_kv_layers
                 * model.num_key_value_heads
                 * self.kv_slot_bytes(model))
 
@@ -561,6 +700,20 @@ class EngineConfig:
             raise ValueError(
                 "cache.kv_cache_dtype must be 'auto', 'bf16' or "
                 f"'int8' (got {self.cache.kv_cache_dtype!r})")
+        if self.model.has_recurrent_state:
+            refused = _recurrent_state_refusals(self)
+            if refused:
+                raise ValueError(
+                    f"{self.model.architecture} keeps a recurrent "
+                    "state beside its pages; refused: " + "; ".join(
+                        f"{feature} ({why})" for feature, why in refused))
+            if self.cache.cache_layout == "auto":
+                self.cache = dataclasses.replace(
+                    self.cache, cache_layout="per_layer")
+            self.cache = dataclasses.replace(
+                self.cache,
+                num_state_slots=(self.scheduler.max_num_seqs
+                                 + self.scheduler.prefill_batch_size))
         if self.cache.resolved_kv_dtype() == "int8":
             # int8 now composes with pipeline/context parallelism:
             # the pp/sp shard_map boundaries carry QuantKV pytree
@@ -620,6 +773,45 @@ class EngineConfig:
             )
 
 
+def _recurrent_state_refusals(config: "EngineConfig"):
+    """(feature, why) for every configured feature that moves, skips
+    or rolls back K/V pages without the recurrent state of a model
+    with linear-attention layers, or has no path for that state."""
+    s, p = config.scheduler, config.parallel
+    checks = (
+        (config.offload.enable, "KV offload",
+         "it moves pages to another tier and back without the state"),
+        (config.engine_role != "both", "disaggregated prefill/decode",
+         "the handoff ships pages without the state"),
+        (config.checkpoint_interval_tokens > 0,
+         "mid-stream checkpoint descriptors",
+         "a resume restores pages without the state"),
+        (s.speculative_k > 0, "speculative decoding",
+         "a rejected draft rolls the pages back and cannot roll the "
+         "state back"),
+        (p.pipeline_parallel_size > 1, "pipeline-parallel serving",
+         "the staged forward has no state pools"),
+        (p.context_parallel_size > 1, "context-parallel prefill",
+         "the ring prefill has no state pools"),
+        (p.tensor_parallel_size > 1, "tensor parallelism",
+         "the state pools and the expert layer have no sharding rules"),
+        (s.unified_step, "the unified ragged step",
+         "its rows mix decode tokens and prompt chunks in one block, "
+         "which the recurrent layers do not take"),
+        (s.deferred_kv_writes, "deferred KV writes",
+         "the tail path is the llama family's"),
+        (config.lora.enable, "LoRA", "the model has no LoRA targets"),
+        (config.cache.resolved_kv_dtype() == "int8", "int8 KV pages",
+         "the hybrid cache is not quantized"),
+        (config.model.quantization != "none", "weight quantization",
+         "the fused projections and experts have no quantized form"),
+        (config.cache.cache_layout == "stacked",
+         "cache_layout='stacked'",
+         "pages and state pools are per-layer buffers"),
+    )
+    return [(feature, why) for on, feature, why in checks if on]
+
+
 # ---- staticcheck config-contract markers -------------------------------
 # Read statically by staticcheck/analyzers/config_contract.py (keep
 # them literals). Every field reachable from EngineConfig must map to
@@ -676,6 +868,19 @@ INTERNAL_FIELDS = {
     "model.attention_bias",
     "model.num_local_experts",
     "model.num_experts_per_tok",
+    "model.full_attention_interval",
+    "model.partial_rotary_factor",
+    "model.linear_num_key_heads",
+    "model.linear_num_value_heads",
+    "model.linear_key_head_dim",
+    "model.linear_value_head_dim",
+    "model.linear_conv_kernel_dim",
+    "model.num_experts",
+    "model.expert_parallel_size",
+    "model.expert_parallel_rank",
+    "model.moe_intermediate_size",
+    "model.shared_expert_intermediate_size",
+    "model.norm_topk_prob",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -684,6 +889,8 @@ INTERNAL_FIELDS = {
     # Data parallelism is derived mesh residue (devices not consumed
     # by tp/pp/sp), never requested directly.
     "parallel.data_parallel_size",
+    # Derived from the model and the scheduler's widths.
+    "cache.num_state_slots",
 }
 
 # Mutually-exclusive feature combos: (field_a, field_b, token). The
@@ -740,5 +947,41 @@ def tiny_model_config(architecture: str = "llama") -> ModelConfig:
         max_position_embeddings=512,
         activation={"llama": "silu", "opt": "relu",
                     "gpt2": "gelu"}[architecture],
+        dtype="float32",
+    )
+
+
+def tiny_qwen3_next_config(expert_parallel_size: int = 1,
+                           expert_parallel_rank: int = 0) -> ModelConfig:
+    """A tiny hybrid model (one period and a half of the layer
+    pattern, both layer kinds, held experts of a wider router) for
+    tests that run anywhere."""
+    return ModelConfig(
+        name="tiny-qwen3-next",
+        architecture="qwen3_next",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=0,
+        num_hidden_layers=6,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=32,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-6,
+        rope_theta=1e7,
+        full_attention_interval=3,
+        partial_rotary_factor=0.25,
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        num_experts=16 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=4,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=32,
+        norm_topk_prob=True,
         dtype="float32",
     )

@@ -83,6 +83,7 @@ class LLMEngine:
         self._lock = threading.Lock()
         from production_stack_tpu.engine.metrics import EngineMetrics
         self.metrics = EngineMetrics()
+        self.metrics.moe_held_experts = config.model.num_experts
         # Overlapped async pipeline state (docs/async_pipeline.md):
         # at most ONE dispatched-but-unread decode step. ``_idle_mark``
         # timestamps the moment the device drained its queue so the
@@ -849,6 +850,13 @@ class LLMEngine:
             note = self._step_note or {}
             self._step_note = None
             note.update(extra)
+            if self.cache_manager.num_state_slots:
+                note["state_slots_used"] = (
+                    self.cache_manager.num_used_state_slots)
+                note["state_slots_total"] = (
+                    self.cache_manager.num_state_slots)
+                note["prefix_declined_tokens"] = (
+                    self.cache_manager.prefix_declined_tokens)
             self._tracer.on_step(
                 host_ms=round(host_s * 1e3, 3),
                 device_wait_ms=round(wait_s * 1e3, 3),
@@ -897,6 +905,10 @@ class LLMEngine:
         self._idle_mark = tr
         if self._tracer is not None:
             self._tracer.phase("commit")
+        # The dispatch's result is on the host, so the expert layer's
+        # counters can be read without waiting for the device.
+        moe = self.runner.read_moe_stats()
+        moe_note = self.metrics.on_moe_stats(moe) if moe else {}
         now = time.time()
         spec_drafts = plan.decode.drafts
         with self._lock:
@@ -937,6 +949,7 @@ class LLMEngine:
                     "attn_pages": self.runner.last_attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
+                    **{k: round(v, 3) for k, v in moe_note.items()},
                 }
         self._obs_note = ("spec" if spec_drafts is not None
                           else "decode", step_tokens)
@@ -1306,6 +1319,21 @@ class LLMEngine:
             "checkpoint_ships_total": self.checkpoint_ships,
             "checkpoint_kv_bytes_total": self.checkpoint_kv_bytes,
             "stream_resumes_total": self.stream_resumes,
+            # Recurrent-state slots and the sparse expert layer
+            # (docs/observability.md §hybrid models); zeros for a
+            # model with neither.
+            "engine_state_slots_used":
+                self.cache_manager.num_used_state_slots,
+            "engine_state_slots_total":
+                self.cache_manager.num_state_slots,
+            "engine_prefix_declined_tokens_total":
+                self.cache_manager.prefix_declined_tokens,
+            "engine_moe_tokens_per_expert_max":
+                self.metrics.moe_last["moe_tokens_per_expert_max"],
+            "engine_moe_tokens_per_expert_mean":
+                self.metrics.moe_last["moe_tokens_per_expert_mean"],
+            "engine_moe_held_choice_share":
+                self.metrics.moe_last["moe_held_choice_share"],
         }
         if self.offload is not None:
             out.update({

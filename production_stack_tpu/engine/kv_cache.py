@@ -11,6 +11,17 @@ Capacity metrics feed the engine's ``/metrics``:
 ``vllm:gpu_cache_usage_perc`` and ``vllm:gpu_prefix_cache_hit_rate``
 (scraped by the router, reference engine_stats.py:46-55).
 
+State slots: a model with recurrent layers (``CacheConfig.
+num_state_slots`` > 0, engine/config.py) keeps per sequence a state of
+fixed size beside its pages, in a pool the model runner holds. This
+manager hands a slot out with a sequence's first pages and takes it
+back with them (``allocate_state_slot`` / ``free_sequence``); slot 0
+is the trash slot of padded rows, as page 0 is the trash page. A slot
+is not cleared: the model starts a row whose block begins at position
+0 from zero. Cached pages alone do not let such a model skip a prefix
+(nobody kept the state after it), so ``match_prefix`` declines every
+hit and counts the tokens it declined.
+
 Page accounting is storage-dtype agnostic: with ``--kv-cache-dtype
 int8`` the EngineConfig expands ``num_pages`` ~2x at the same HBM byte
 budget (engine/config.py) before this manager is built, and content
@@ -66,9 +77,17 @@ class PagedCacheManager:
         # Fired with (page_id, page_hash) just before a hashed page's
         # HBM slot is reused — the offload tier's capture point.
         self.evict_listener = None
+        # Recurrent-state slots (slot 0 is the trash slot); empty for
+        # a model whose state is all pages.
+        self.num_state_slots = config.num_state_slots
+        self._free_state: List[int] = list(
+            range(config.num_state_slots, 0, -1))
         # Stats
         self.prefix_hit_tokens = 0
         self.prefix_query_tokens = 0
+        # Tokens of prefix hits not taken because the model keeps a
+        # recurrent state that no page holds.
+        self.prefix_declined_tokens = 0
 
     # ---- capacity ---------------------------------------------------------
 
@@ -88,6 +107,21 @@ class PagedCacheManager:
         if self.prefix_query_tokens == 0:
             return 0.0
         return self.prefix_hit_tokens / self.prefix_query_tokens
+
+    @property
+    def num_used_state_slots(self) -> int:
+        return self.num_state_slots - len(self._free_state)
+
+    def allocate_state_slot(self) -> Optional[int]:
+        """A recurrent-state slot for a sequence that has just been
+        given its first pages; None for a model that keeps no such
+        state. Raises OutOfPagesError when every slot is taken, so
+        that the caller waits as it does for pages."""
+        if not self.num_state_slots:
+            return None
+        if not self._free_state:
+            raise OutOfPagesError("out of recurrent-state slots")
+        return self._free_state.pop()
 
     # ---- low-level page ops ----------------------------------------------
 
@@ -172,6 +206,11 @@ class PagedCacheManager:
             page_id = self._hash_to_page.get(page_hash)
             if page_id is None:
                 break
+            if self.num_state_slots:
+                # The pages are there, the state after them is not:
+                # the hit is not taken (snapshots are a later PR).
+                self.prefix_declined_tokens += self.page_size
+                continue
             self._revive_page(page_id)
             matched.append(page_id)
         self.prefix_hit_tokens += len(matched) * self.page_size
@@ -222,6 +261,11 @@ class PagedCacheManager:
             info.page_hash = page_hash
             self._hash_to_page[page_hash] = page_id
 
-    def free_sequence(self, pages: List[int]) -> None:
+    def free_sequence(self, pages: List[int],
+                      state_slot: Optional[int] = None) -> None:
+        """Take back a sequence's pages and, if it held one, its
+        recurrent-state slot."""
         for page_id in pages:
             self._release_page(page_id)
+        if state_slot:
+            self._free_state.append(state_slot)

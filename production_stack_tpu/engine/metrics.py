@@ -59,6 +59,16 @@ class EngineMetrics:
 
     def __init__(self):
         self._lock = threading.Lock()
+        # Sparse expert layer (ops/moe.py): experts this engine holds
+        # (set by the engine) and the last decode dispatch's load
+        # figures.
+        self.moe_held_experts = 0
+        self.moe_last = {
+            "moe_tokens_per_expert_max": 0.0,
+            "moe_tokens_per_expert_mean": 0.0,
+            "moe_held_choice_share": 0.0,
+            "moe_experts_hit": 0.0,
+        }
         self.ttft = Histogram(_TTFT_BUCKETS)
         self.itl = Histogram(_ITL_BUCKETS)
         self.e2e = Histogram(_E2E_BUCKETS)
@@ -120,6 +130,24 @@ class EngineMetrics:
         # rendered (empty without an offload tier) for a stable
         # scrape surface.
         self.preempt_restore_latency = Histogram(_TTFT_BUCKETS)
+
+    def on_moe_stats(self, stats: dict) -> dict:
+        """The held experts' load over the decode steps one dispatch
+        ran (models/qwen3_next.MOE_STATS, sums over layer-steps), as
+        the dispatch's figures: returned for the step record and kept
+        as the gauges' values until the next one."""
+        steps = stats["layer_steps"]
+        held = max(1, self.moe_held_experts)
+        last = {
+            "moe_tokens_per_expert_max": stats["max_load"] / steps,
+            "moe_tokens_per_expert_mean":
+                stats["held_choices"] / steps / held,
+            "moe_held_choice_share":
+                stats["held_choices"] / max(stats["choices"], 1.0),
+            "moe_experts_hit": stats["experts_hit"] / steps,
+        }
+        self.moe_last = last
+        return last
 
     def on_spec_step(self, drafted: int, accepted: int) -> None:
         """One speculative verify step's draft/accept counts."""

@@ -562,7 +562,17 @@ class ModelRunner:
             return shard_cache(jnp.zeros(shape, dtype), mesh)
 
         self.cache_layout = config.cache.cache_layout
-        if self.cache_layout == "per_layer":
+        # A model with recurrent layers: its per-layer cache tuples
+        # hold state pools where a linear layer has no pages
+        # (models/qwen3_next.py), and every step hands the forward
+        # each row's state slot beside its page table.
+        self._hybrid = model_config.has_recurrent_state
+        if self._hybrid:
+            from production_stack_tpu.models.qwen3_next import init_cache
+            self.k_cache, self.v_cache = init_cache(
+                model_config, config.cache.num_pages,
+                config.cache.page_size, config.cache.num_state_slots)
+        elif self.cache_layout == "per_layer":
             # A tuple of L per-layer buffers instead of one stacked
             # array: scatters/kernels touch one layer's buffer and
             # donation aliases 1:1 (the round-3 decode-roofline
@@ -1183,7 +1193,7 @@ class ModelRunner:
                    top_p, top_k, rng, lora, lora_ids, penalties,
                    seeding, bias, suppress, fsm,
                    sample_index_mode: str,
-                   want_logprobs: bool = False):
+                   want_logprobs: bool = False, state_slots=None):
         # Deliberate two-shape specialization ([B] decode feed-forward
         # vs [B, T] prefill/burst): exactly two traces, cached for the
         # process lifetime — not a per-step retrace.
@@ -1199,6 +1209,7 @@ class ModelRunner:
             params, self.config.model, tokens, positions, page_table,
             kv_lens, valid, k_cache, v_cache,
             lora=lora, lora_ids=lora_ids,
+            **self._state_kwargs(state_slots),
         )
         if sample_index_mode == "last":
             # Prefill: sample only from the final prompt position.
@@ -1239,13 +1250,43 @@ class ModelRunner:
             return (sampled,) + lp, k_cache, v_cache
         return sampled, k_cache, v_cache
 
+    def _state_kwargs(self, state_slots) -> dict:
+        """The forward's extra argument for a model with recurrent
+        layers (each row's state slot); none for any other."""
+        return {"state_slots": state_slots} if self._hybrid else {}
+
+    def _state_slot_rows(self, seqs, pad_to: int) -> np.ndarray:
+        """[pad_to] int32: each row's recurrent-state slot; pad rows
+        take the trash slot 0."""
+        slots = np.zeros((pad_to,), np.int32)
+        for i, seq in enumerate(seqs):
+            slots[i] = seq.state_slot or 0
+        return slots
+
+    def read_moe_stats(self) -> Optional[dict]:
+        """The expert layer's counters over the decode steps since the
+        last call (models/qwen3_next.MOE_STATS), read from the device
+        and zeroed; None for a model without them or with no decode
+        step to report. Blocks on the last dispatched program, so call
+        it where that program's result has been read already."""
+        if not self._hybrid:
+            return None
+        from production_stack_tpu.models.qwen3_next import MOE_STATS
+        values = np.asarray(jax.device_get(self.k_cache[-1]))
+        if values[0] == 0:
+            return None
+        self.k_cache = self.k_cache[:-1] + (
+            jnp.zeros_like(self.k_cache[-1]),)
+        return dict(zip(MOE_STATS, (float(v) for v in values)))
+
     def _decode_burst_impl(self, params, k_cache, v_cache, tokens,
                            positions, page_table, kv_lens, active,
                            budgets, stop_tokens, temperature, top_p,
                            top_k, rng, lora, lora_ids, penalties,
                            seeding, bias, suppress, fsm,
                            num_steps: int,
-                           want_logprobs: bool = False):
+                           want_logprobs: bool = False,
+                           state_slots=None):
         """K chained decode iterations in one program, with per-row
         lifecycle on device.
 
@@ -1290,6 +1331,7 @@ class ModelRunner:
                 params, self.config.model, tok, pos, page_table,
                 kv, act[:, None], kc, vc, lora=lora,
                 lora_ids=lora_ids,
+                **self._state_kwargs(state_slots),
             )
             out, sampled, emitted, counts, act_next, fs = \
                 sample_step(logits, step_rng, act, emitted, counts,
@@ -1634,6 +1676,8 @@ class ModelRunner:
         penalties, seeding, bias, suppress, fsm = \
             self._optional_device_inputs(payload)
         want_lp = bool(payload.get("want_logprobs", False))
+        state = ({"state_slots": _as_device(payload["state_slots"])}
+                 if "state_slots" in payload else {})
         if kind == KIND_SPEC:
             # Speculative verify: the scheduler only plans eligible
             # rows (no penalties/seeds/bias/min_tokens/guided), so
@@ -1695,7 +1739,7 @@ class ModelRunner:
                     _as_device(payload["rng"]),
                     self._lora_stack, lora_ids, penalties, seeding,
                     bias, suppress, fsm,
-                    num_steps=t, want_logprobs=want_lp,
+                    num_steps=t, want_logprobs=want_lp, **state,
                 )
             return sampled  # [K, B] (+ logprob arrays when requested)
         sampled, self.k_cache, self.v_cache = self._step_jit(
@@ -1713,7 +1757,7 @@ class ModelRunner:
             self._lora_stack, lora_ids, penalties, seeding, bias,
             suppress, fsm,
             sample_index_mode=("last" if kind == 1 else "first"),
-            want_logprobs=want_lp,
+            want_logprobs=want_lp, **state,
         )
         return sampled
 
@@ -2048,6 +2092,9 @@ class ModelRunner:
             "top_k": top_k,
             "rng": self._host_rng(),
         }
+        if self._hybrid:
+            payload["state_slots"] = self._state_slot_rows(
+                [c.seq for c in chunks], b)
         if self.lora_registry is not None:
             ids = np.zeros((b,), np.int32)
             for i, chunk in enumerate(chunks):
@@ -2121,6 +2168,8 @@ class ModelRunner:
                 }
                 if self.lora_registry is not None:
                     buf["lora_ids"] = np.zeros((b,), np.int32)
+                if self._hybrid:
+                    buf["state_slots"] = np.zeros((b,), np.int32)
                 return buf
 
             self._decode_staging = (one(), one())
@@ -2196,6 +2245,8 @@ class ModelRunner:
             page_table[i, :n] = seq.pages[:n]
             if self.lora_registry is not None:
                 st["lora_ids"][i] = seq.lora_id
+            if self._hybrid:
+                st["state_slots"][i] = seq.state_slot or 0
         # ONE fused host->device transfer for the (changed part of
         # the) input set — replaces the per-array jnp.asarray shower.
         # An ahead dispatch additionally excludes the tokens buffer:
@@ -2316,6 +2367,8 @@ class ModelRunner:
             "top_k": top_k,
             "rng": self._host_rng(),
         }
+        if self._hybrid:
+            payload["state_slots"] = self._state_slot_rows(seqs, b)
         if window > 1:
             payload["active"] = valid[:, 0].copy()
             payload["budgets"] = budgets

@@ -208,7 +208,7 @@ class PerfObservatory:
         model = self.config.model
         cache = self.config.cache
         sched = self.config.scheduler
-        slots = 2 * model.num_hidden_layers * model.num_key_value_heads
+        slots = 2 * model.num_kv_layers * model.num_key_value_heads
         tokens = cache.num_pages * cache.page_size
         if cache.resolved_kv_dtype() == "int8":
             kv_pages = slots * tokens * model.head_dim  # int8 data
@@ -223,12 +223,19 @@ class PerfObservatory:
         # Step-buffer estimate: one f32 logits plane plus the i32
         # token/descriptor blocks for the widest mixed batch.
         step_buffers = rows * model.vocab_size * 4 + rows * width * 4
-        return {
+        out = {
             "weights": int(self.params_bytes),
             "kv_pages": int(kv_pages),
             "kv_scales": int(kv_scales),
             "step_buffers": int(step_buffers),
         }
+        if cache.num_state_slots:
+            # Recurrent-state pools of the linear-attention layers
+            # (the trash slot included).
+            out["recurrent_state"] = int(
+                (cache.num_state_slots + 1)
+                * model.recurrent_state_bytes())
+        return out
 
     def memory_report(self) -> Dict[str, Any]:
         analytic = self.hbm_bytes()

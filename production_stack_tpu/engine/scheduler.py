@@ -565,6 +565,7 @@ class Scheduler:
                 needed = self._pages_needed(seq, seq.num_prompt_tokens)
                 try:
                     seq.pages.extend(self.cache.allocate_pages(needed))
+                    seq.state_slot = self.cache.allocate_state_slot()
                 except OutOfPagesError:
                     self.cache.free_sequence(seq.pages)
                     seq.pages = []
@@ -678,8 +679,9 @@ class Scheduler:
         if evicted and self.tracer is not None:
             self.tracer.event(seq.seq_id, "preempt_offload",
                               pages=evicted)
-        self.cache.free_sequence(seq.pages)
+        self.cache.free_sequence(seq.pages, seq.state_slot)
         seq.pages = []
+        seq.state_slot = None
         seq.num_hashed_pages = 0
         # Recompute everything including generated tokens as "prompt".
         # num_prior_output_tokens keeps every generated-so-far budget
@@ -819,5 +821,6 @@ class Scheduler:
         if self.proposer is not None:
             self.proposer.drop(seq.seq_id)
         if seq.pages:
-            self.cache.free_sequence(seq.pages)
+            self.cache.free_sequence(seq.pages, seq.state_slot)
             seq.pages = []
+            seq.state_slot = None

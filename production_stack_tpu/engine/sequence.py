@@ -145,6 +145,9 @@ class Sequence:
     num_computed_tokens: int = 0
     pages: List[int] = field(default_factory=list)
     num_hashed_pages: int = 0
+    # Slot of the recurrent-state pool (engine/kv_cache.py), held with
+    # the pages; None for a model that keeps no such state.
+    state_slot: Optional[int] = None
     finish_reason: Optional[FinishReason] = None
     first_token_time: Optional[float] = None
     # When the scheduler first planned this sequence's prefill: splits

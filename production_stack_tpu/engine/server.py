@@ -2243,6 +2243,19 @@ class EngineServer:
         lines.append("# TYPE vllm:num_preemptions_total counter")
         lines.append("vllm:num_preemptions_total "
                      f"{float(stats['num_preemptions_total'])}")
+        # Hybrid models (docs/observability.md): the recurrent-state
+        # pool beside the pages, prefix hits declined for want of a
+        # state, and the held experts' load over the last decode
+        # dispatch. Zeros for a model with neither.
+        for name, kind in (
+                ("vllm:engine_state_slots_used", "gauge"),
+                ("vllm:engine_state_slots_total", "gauge"),
+                ("vllm:engine_prefix_declined_tokens_total", "counter"),
+                ("vllm:engine_moe_tokens_per_expert_max", "gauge"),
+                ("vllm:engine_moe_tokens_per_expert_mean", "gauge"),
+                ("vllm:engine_moe_held_choice_share", "gauge")):
+            lines.append(f"# TYPE {name} {kind}")
+            lines.append(f"{name} {float(stats[name[5:]])}")
         # KV quantization telemetry: page budget after any int8
         # expansion, worst-case KV bytes written per decode step, and
         # the storage dtype as a labeled one-hot gauge so dashboards
@@ -2534,7 +2547,7 @@ def _resolve_async_scheduling(args) -> bool:
         distributed=args.distributed)
 
 
-def _resolve_unified_step(args) -> bool:
+def _resolve_unified_step(args, model_config=None) -> bool:
     """--unified-step auto|on|off -> bool.
 
     'auto' enables the unified ragged step (docs/unified_step.md) —
@@ -2546,6 +2559,10 @@ def _resolve_unified_step(args) -> bool:
     if args.unified_step == "on":
         return True
     if args.unified_step == "off":
+        return False
+    if model_config is not None and model_config.has_recurrent_state:
+        # The ragged rows have no path for a recurrent state
+        # (engine/config.py refuses an explicit 'on').
         return False
     from production_stack_tpu.engine.model_runner import (
         unified_step_eligible,
@@ -2633,7 +2650,7 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
             speculative_k=args.speculative_k,
             speculative_min_match=args.speculative_min_match,
             async_scheduling=_resolve_async_scheduling(args),
-            unified_step=_resolve_unified_step(args),
+            unified_step=_resolve_unified_step(args, model_config),
             max_queue_len=args.max_queue_len,
         ),
         parallel=ParallelConfig(

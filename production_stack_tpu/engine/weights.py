@@ -247,4 +247,9 @@ def load_weights(model_dir: str, config: ModelConfig,
         return load_gpt2_weights(model_dir, config, dtype)
     if config.architecture == "mixtral":
         return load_mixtral_weights(model_dir, config, dtype)
+    if config.architecture == "qwen3_next":
+        raise NotImplementedError(
+            "reading a Qwen3-Next checkpoint into this engine's fused "
+            "layout is not written yet: serve the architecture with "
+            "--random-weights")
     return load_llama_weights(model_dir, config, dtype)

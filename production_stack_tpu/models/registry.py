@@ -20,8 +20,12 @@ def get_model(config: ModelConfig) -> Tuple[Callable, Callable]:
     if arch == "mixtral":
         from production_stack_tpu.models import mixtral
         return mixtral.init_params, mixtral.forward
+    if arch == "qwen3_next":
+        from production_stack_tpu.models import qwen3_next
+        return qwen3_next.init_params, qwen3_next.forward
     raise ValueError(f"Unknown architecture: {arch}")
 
 
 def list_architectures():
-    return ["llama", "mistral", "qwen2", "opt", "gpt2", "mixtral"]
+    return ["llama", "mistral", "qwen2", "opt", "gpt2", "mixtral",
+            "qwen3_next"]

@@ -1,0 +1,124 @@
+"""A routed expert layer that is told which experts it holds.
+
+The router scores every expert of the model (``router_w`` is as wide
+as the published count) and a token keeps its ``top_k`` choices. This
+engine holds one contiguous block of the experts,
+``[first_expert, first_expert + E)``, and computes for each token the
+part of the weighted sum that its held choices give; what experts held
+elsewhere would add is left out, and the partial sum is what goes on
+(on one chip of an expert-parallel group the exchange that would
+complete it is simply absent). With every expert held the sum is
+whole.
+
+Work follows the choices, not the experts: the (token, choice) pairs
+that fall on held experts are sorted by expert and each expert's rows
+go through its three matrices as one group of a grouped matrix
+product, so FLOPs are tokens x held choices x one expert, and an
+expert nobody chose is never read. On a TPU the grouped product is the
+Pallas ``megablox`` kernel that ships with JAX (group sizes reach it
+through scalar prefetch; it visits only tiles that hold rows);
+elsewhere it is ``jax.lax.ragged_dot``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+# m, k, n tiles of the grouped product. 128 rows a tile: a decode step
+# of 128 rows x 10 choices leaves ~2.5 rows an expert, so a tile is
+# mostly one expert's few rows and the product is bound by reading the
+# expert (6.3 MB at the published widths), not by the matrix unit.
+_TILING = (128, 1024, 512)
+
+
+def route(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
+          norm_topk: bool):
+    """Softmax over ALL experts in float32, the ``top_k`` largest, and
+    (``norm_topk``) their weights divided by their sum.
+
+    x [N, H], router_w [H, E_all] -> (weights [N, k] f32, ids [N, k]).
+    """
+    probs = jax.nn.softmax(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32),
+        axis=-1)
+    weights, ids = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, ids
+
+
+def _grouped_dot(lhs, rhs, group_sizes, impl: str):
+    """lhs [M, K] rows sorted by group, rhs [E, K, N] -> [M, N] f32;
+    rows past the groups' total come back zero."""
+    m, k = lhs.shape
+    # Neither product defines the rows that belong to no group.
+    grouped = (jnp.arange(m) < jnp.sum(group_sizes))[:, None]
+    if impl == "xla":
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                 preferred_element_type=jnp.float32)
+        return jnp.where(grouped, out, 0.0)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    n = rhs.shape[-1]
+    tm, tk, tn = _TILING
+    pad = (-m) % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(lhs, rhs, group_sizes,
+              preferred_element_type=jnp.float32,
+              tiling=(tm, min(tk, k), min(tn, n)),
+              interpret=impl == "pallas-interpret")
+    return jnp.where(grouped, out[:m], 0.0)
+
+
+def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
+                 w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
+                 first_expert: int, valid: jnp.ndarray = None,
+                 impl: str = "xla"):
+    """The held experts' part of the routed sum.
+
+    Args:
+      x:         [N, H] normalised hidden states
+      weights:   [N, k] routing weights (float32), ids [N, k] expert ids
+      w_gate_up: [E, H, 2F] held experts' gate and up matrices, side by
+                 side; w_down [E, F, H]
+      first_expert: id of the first held expert
+      valid:     [N] bool; a token that is not real chooses nothing
+      impl:      "xla" (``ragged_dot``), "pallas" (the ``megablox``
+                 kernel, on a TPU) or "pallas-interpret"
+
+    Returns (y [N, H] in x's dtype, load [E] int32: real tokens that
+    chose each held expert).
+    """
+    with jax.named_scope("moe_experts"):
+        n, top_k = ids.shape
+        e, _, f2 = w_gate_up.shape
+        f = f2 // 2
+        local = ids - first_expert
+        held = (local >= 0) & (local < e)
+        if valid is not None:
+            held = held & valid[:, None]
+        # Choices that fall elsewhere sort behind every held expert
+        # and belong to no group, so the product never touches them.
+        key = jnp.where(held, local, e).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        load = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        token = order // top_k
+        rows = x[token]                                   # [N*k, H]
+        hidden = _grouped_dot(rows, w_gate_up, load, impl)
+        act = (jax.nn.silu(hidden[:, :f]) * hidden[:, f:]).astype(x.dtype)
+        out = _grouped_dot(act, w_down, load, impl)       # [N*k, H] f32
+        out = (out * weights.reshape(-1)[order][:, None]).astype(x.dtype)
+        # Back to (token, choice) order, then the sum over choices.
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = jnp.sum(out[inverse].reshape(n, top_k, -1), axis=1,
+                    dtype=jnp.float32)
+        return y.astype(x.dtype), load
+
+
+def swiglu(x, w_gate_up, w_down):
+    """A dense SwiGLU expert: gate and up side by side."""
+    f = w_gate_up.shape[-1] // 2
+    hidden = x @ w_gate_up
+    return (jax.nn.silu(hidden[..., :f]) * hidden[..., f:]) @ w_down

@@ -9,14 +9,21 @@ import jax.numpy as jnp
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float = 10000.0) -> jnp.ndarray:
+               theta: float = 10000.0,
+               rotary_dim: "int | None" = None) -> jnp.ndarray:
     """Rotate q or k.
 
     Args:
       x: [..., seq, heads, head_dim]
       positions: [..., seq] absolute token positions
       theta: rope base frequency
+      rotary_dim: how many leading dimensions of each head turn
+        (partial rotary: the frequencies are those of a head of that
+        size, the rest of the head passes unchanged); None = all
     """
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, theta)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     head_dim = x.shape[-1]
     half = head_dim // 2
     freq_exponents = jnp.arange(half, dtype=jnp.float32) / half

@@ -137,6 +137,14 @@ _ROUTER_UNSCRAPED = frozenset({
     # Autotune decision counts are an operator/dashboard rate, not a
     # routing signal — cluster Prometheus reads them directly.
     "vllm:autotune_decisions_total",
+    # Hybrid-model state pool and expert load: capacity planning and
+    # the benchmark's readers, not routing signals.
+    "vllm:engine_state_slots_used",
+    "vllm:engine_state_slots_total",
+    "vllm:engine_prefix_declined_tokens_total",
+    "vllm:engine_moe_tokens_per_expert_max",
+    "vllm:engine_moe_tokens_per_expert_mean",
+    "vllm:engine_moe_held_choice_share",
 })
 
 
