@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import dataclasses
 import json
 import queue
@@ -86,11 +87,6 @@ def slice_options():
     return options
 
 
-def _put_annotated(annotate, put, item) -> None:
-    with annotate("server.stream_token"):
-        put(item)
-
-
 class AsyncEngine:
     """Background-thread engine loop with asyncio streaming outputs."""
 
@@ -123,9 +119,9 @@ class AsyncEngine:
         # rotates out a replica whose device programs keep failing.
         self.consecutive_step_failures = 0
         # The profiler endpoints set this to the tracer's annotation
-        # factory while a slice runs: each delivery of a token to its
-        # stream is then a ``server.stream_token`` event on the event
-        # loop's thread. None outside a slice, so it costs nothing.
+        # factory while a slice runs: each delivery of a turn's
+        # outputs to their streams is then one ``server.stream_token``
+        # event on the event loop's thread. None outside a slice.
         self.stream_annotation = None
 
     def current_step_s(self) -> float:
@@ -201,10 +197,10 @@ class AsyncEngine:
                     # Queue full / invalid request: fail THIS request,
                     # never the engine loop.
                     logger.warning("Rejecting %s: %s", seq_id, e)
-                    self._emit(seq_id, StepOutput(
+                    self._hand_over([StepOutput(
                         seq_id=seq_id, new_token=None, finished=True,
                         finish_reason="abort",
-                    ))
+                    )])
                 continue  # admit as many as possible before stepping
             if tracer is not None:
                 tracer.phase("other")
@@ -225,8 +221,7 @@ class AsyncEngine:
                     tracer.phase("other")
                 # The sequences that step touched end here with a
                 # terminal 'abort' instead of being retried forever.
-                for out in self.engine.abort_after_step_failure():
-                    self._emit(out.seq_id, out)
+                self._hand_over(self.engine.abort_after_step_failure())
                 # Interruptible backoff: a new submission or abort
                 # wakes the loop immediately instead of serving out
                 # the full 50 ms.
@@ -245,34 +240,38 @@ class AsyncEngine:
                     tracer.phase("other")
                 self._wakeup.wait(0.002)
                 self._wakeup.clear()
-            if tracer is not None:
-                tracer.phase("emit")
-                emit_start = time.perf_counter()
-            for out in outputs:
-                self._emit(out.seq_id, out)
             if tracer is None:
+                self._hand_over(outputs)
                 continue
-            record = tracer.end_turn(
+            tracer.phase("emit")
+            self._hand_over(outputs, tracer.handoff_stamp())
+            tracer.end_turn(
                 emitted=len(outputs),
                 compiles=obs.compile_events_total() if obs else 0)
-            if record is not None and outputs and self._loop is not None:
-                # Queued behind this turn's outputs: the event loop
-                # stamps handoff_ms when it has taken the last of them.
-                self._loop.call_soon_threadsafe(
-                    tracer.on_handoff, record, emit_start)
 
-    def _emit(self, seq_id: str, item) -> None:
-        stream = self._streams.get(seq_id)
-        if stream is None or self._loop is None:
-            return
-        annotate = self.stream_annotation
-        if annotate is None:
-            self._loop.call_soon_threadsafe(stream.put_nowait, item)
-        else:
-            # Bound now: the slice may have ended by the time the
-            # event loop gets to the callback.
+    def _hand_over(self, outputs, stamp=None) -> None:
+        """The loop thread's side: one cross-thread call for all of
+        ``outputs``, whatever their number, and back to the chip. The
+        streams are fed on the event loop while the next program runs."""
+        if outputs:
+            # The annotation is bound now: the slice may have ended by
+            # the time the event loop gets to the callback.
             self._loop.call_soon_threadsafe(
-                _put_annotated, annotate, stream.put_nowait, item)
+                self._deliver, outputs, self.stream_annotation, stamp)
+
+    def _deliver(self, outputs, annotate, stamp) -> None:
+        """The event loop's side: each output onto its stream, in the
+        engine's order, so a stream's tokens stay in order and its
+        finish comes last. A stream that was finished or aborted
+        meanwhile is gone from ``_streams`` and its outputs dropped."""
+        with (contextlib.nullcontext() if annotate is None
+              else annotate("server.stream_token")):
+            for out in outputs:
+                stream = self._streams.get(out.seq_id)
+                if stream is not None:
+                    stream.put_nowait(out)
+        if stamp is not None:
+            stamp()
 
     async def submit(self, prompt: List[int], sampling: SamplingParams,
                      lora_name: Optional[str] = None,

@@ -42,6 +42,7 @@ check, so the disabled hot path allocates no span objects at all.
 
 from __future__ import annotations
 
+import functools
 import json
 import statistics
 import threading
@@ -95,7 +96,7 @@ TURN_PHASES = (
     "wait",      # blocked on the device's results
     "parse",     # device arrays to Python lists
     "commit",    # scheduler and sequence updates under the lock
-    "emit",      # handing the outputs to the event loop
+    "emit",      # one call that hands the turn's outputs to the event loop
     "other",     # autotuner tick, back-off waits, the rest
 )
 
@@ -352,12 +353,24 @@ class EngineTracer:
         self._open_turn()
         return record
 
+    def handoff_stamp(self) -> Optional[Callable[[], None]]:
+        """For the loop thread, as it enters ``emit``: what the event
+        loop is to call once it has delivered the turn's outputs, or
+        None where the turn accounted no step. The record is the one
+        end_turn() closes a moment later."""
+        if self._open is None:
+            return None
+        return functools.partial(self.on_handoff, self._open,
+                                 time.perf_counter())
+
     def on_handoff(self, record: Dict[str, Any],
                    emit_start: float) -> None:
-        """Runs on the event loop, queued behind the turn's outputs:
-        ``handoff_ms`` is how long after the loop thread began to emit
-        them the event loop had taken the last. The readers of the ring
-        are on the event loop too, so the store races with none."""
+        """Runs on the event loop as the last act of the call that
+        delivered the turn's outputs: ``handoff_ms`` is how long after
+        the loop thread entered ``emit`` the event loop had put the
+        last of them on its stream. The streams' consumers run after
+        it and are not in it. The loop thread may still be closing the
+        record: both sides only store keys of their own."""
         taken = time.perf_counter() - emit_start
         record["handoff_ms"] = round(taken * 1e3, 3)
         median = self._slow(self._handoffs, record, taken)
