@@ -26,7 +26,7 @@ KV pages.
 Phase B: ``python -m production_stack_tpu.engine.server`` (every
 selector at ``auto``) behind ``python -m production_stack_tpu.router.app``;
 through the router one non-streaming and one streaming chat completion,
-then concurrent ~512-token prompts in two waves so a batched prefill
+then concurrent ~512-token prompts, all at once, so a batched prefill
 chunk, a short prefill bucket, the decode burst and the unified ragged
 step all compile and run. Then the engine is asked what it is
 (``GET /version``, ``/metrics``, ``/debug/compiles``) and the answers
@@ -49,7 +49,6 @@ import signal
 import socket
 import subprocess
 import sys
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -399,8 +398,7 @@ def _wait_http_ok(url, proc, name, deadline, limit):
 # ---- parent: requests -----------------------------------------------------
 
 
-def _chat(base, model, content, max_tokens, stream, timeout,
-          on_first_delta=None):
+def _chat(base, model, content, max_tokens, stream, timeout):
     """One greedy chat completion. Returns (text, completion_tokens,
     saw_done); raises SmokeFailure on a non-200 or a malformed reply."""
     body = {"model": model, "max_tokens": max_tokens,
@@ -442,8 +440,6 @@ def _chat(base, model, content, max_tokens, stream, timeout,
                 finish = choice.get("finish_reason") or finish
                 delta = choice.get("delta", {}).get("content")
                 if delta:
-                    if not pieces and on_first_delta is not None:
-                        on_first_delta()
                     pieces.append(delta)
         if finish != "length":
             raise SmokeFailure(f"stream finish_reason {finish!r}")
@@ -480,33 +476,28 @@ def drive_requests(base, model, cfg, deadline):
                            f"{text_a!r} vs {text_b!r}")
     served, asked = served + 2, asked + 2 * n_out
 
-    # 3. Concurrent long prompts in two waves: the second is released
-    # by the first token of the first, so its prompts arrive while
-    # others decode (-> the unified ragged step), and each wave fills
-    # batched prefill chunks (-> the batched step, then the burst).
+    # 3. Concurrent long prompts, all at once and twice as many as one
+    # prefill step takes rows: the first rows to finish their prompt
+    # decode while the others still wait for theirs (-> the unified
+    # ragged step), the prefill steps are batched (-> the batched
+    # step) and the rows decode on together (-> the burst). No wave
+    # waits for a token of another: the engine serves a tiny model's
+    # whole answer before a loaded client has read its first token.
     n = cfg["concurrent"]
-    second_wave = threading.Event()
     limit = _remaining(deadline, 600)
 
     def one(i):
-        if i >= n // 2:
-            second_wave.wait(limit)
         return _chat(base, model, _prompt(i, cfg["prompt_chars"]), n_out,
-                     True, limit,
-                     on_first_delta=(second_wave.set if i < n // 2
-                                     else None))
+                     True, limit)
 
     with concurrent.futures.ThreadPoolExecutor(max_workers=n) as pool:
         futures = [pool.submit(one, i) for i in range(n)]
-        try:
-            for i, fut in enumerate(futures):
-                text, tokens, done = fut.result(timeout=limit + 30)
-                if tokens != n_out or not done or not text:
-                    raise SmokeFailure(
-                        f"concurrent request {i}: tokens={tokens} "
-                        f"done={done} text={text[:40]!r}")
-        finally:
-            second_wave.set()  # never leave a worker parked
+        for i, fut in enumerate(futures):
+            text, tokens, done = fut.result(timeout=limit + 30)
+            if tokens != n_out or not done or not text:
+                raise SmokeFailure(
+                    f"concurrent request {i}: tokens={tokens} "
+                    f"done={done} text={text[:40]!r}")
     return served + n, asked + n * n_out
 
 

@@ -2,8 +2,7 @@
 
 Host-side closed-loop tuning: every knob a controller touches rides a
 non-shape input or an already-compiled bucket lattice, so a decision
-can never trigger an XLA recompile — the compile-ledger assertion in
-``bench.py --worker drift`` holds the framework to that.
+can never trigger an XLA recompile.
 
 One ``Autotuner`` owns a set of ``Controller`` objects and ticks them
 on a bounded cadence from the engine loop (or any host loop). Each

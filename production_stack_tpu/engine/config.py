@@ -93,9 +93,10 @@ class ModelConfig:
     attention_impl_decode: Optional[str] = None
     attention_impl_prefill: Optional[str] = None
     # Unified-step ([R, W] mixed batch) kernel, resolved separately: the
-    # fused ragged kernel (pallas_ragged) needs both a lowering probe
-    # AND a measured microbench win before auto serves it; None =
-    # compose the family prefill impl (model_runner._resolve_unified_impl).
+    # fused ragged kernel (pallas_ragged) is served under an explicit
+    # 'pallas' where it compiles, never under auto
+    # (model_runner.PALLAS_RAGGED_IN_AUTO); None = compose the family
+    # prefill impl (model_runner._resolve_unified_impl).
     attention_impl_unified: Optional[str] = None
 
     def __post_init__(self):
@@ -304,10 +305,8 @@ class CacheConfig:
     # HBM buffer layout (models/llama.py cached_attention):
     #   auto      -> per_layer, except pipeline/context-parallel
     #                configs (which shard or walk the stacked L axis)
-    #                resolve to stacked. Decided on-chip 2026-07-31
-    #                (benchmarks/results/decode_probe.json: per_layer
-    #                13.5 vs stacked 27.4 ms/token-step; engine bench
-    #                11.07 vs 5.94 req/s).
+    #                resolve to stacked (model_runner; a builder's
+    #                capture, no driver's number: ROADMAP D4).
     #   stacked   -> one [L, kv, pages, d, page_size] array per k/v;
     #                layer writes are in-place scatters at a static
     #                layer index.
@@ -915,9 +914,8 @@ EXCLUSIVITY_RULES = (
 
 
 def bench_1b_model_config() -> ModelConfig:
-    """The 1B-class llama geometry the TPU bench serves (bench.py) and
-    the ``--model bench-1b`` server builds (chip_smoke.py) — one
-    definition so the server runs exactly the benched config."""
+    """The 1B-class llama geometry the ``--model bench-1b`` server
+    builds (chip_smoke.py, tests/conftest.py)."""
     return ModelConfig(
         name="llama-1b-class",
         architecture="llama",

@@ -10,7 +10,6 @@ every step so the arrays are updated in place in HBM.
 from __future__ import annotations
 
 import functools
-import json
 import os
 import time
 from typing import List, Optional, Tuple
@@ -58,18 +57,16 @@ logger = init_logger(__name__)
 # full set; the burst merely speculates a little further.
 STOP_SET_WIDTH = 16
 
-# Measured decode-kernel verdict (benchmarks/results/
-# kernel_microbench.json, TPU v5e, 2026-07-31, post-aliasing-fix):
-# the Pallas decode kernel loses to the XLA gather path at every
-# serving shape measured — 0.42-0.65x at ctx 2k-16k for batch 8-32 —
-# and wins only the single thin cell batch=8/ctx=512, where decode is
-# cheap anyway. The pre-fix ">=8k crossover" no longer exists, so
-# attention_impl='auto' serves XLA decode at ALL shapes; an explicit
-# attention_impl='pallas' still forces the kernel (operator override,
-# e.g. for re-measurement with benchmarks/kernel_microbench.py).
-# Prefill is the opposite story: the Pallas prefill kernel wins
-# every measured cell (1.25-2.3x), so 'auto' keeps serving it.
+# What attention_impl='auto' serves on a TPU besides the prefill
+# kernel. False: decode runs the XLA gather path and the unified step
+# composes the prefill kernel; neither Pallas kernel is probed. An
+# explicit 'pallas' (or attention_impl_unified='pallas_ragged') skips
+# both and probes and serves them. Neither rests on a number from the
+# driver: the capture behind them was a builder's, ctx 2k-16k at batch
+# 8-32, before PR 28's block gather. ROADMAP S2 / D2 own the
+# re-measurement on a cell.
 PALLAS_DECODE_IN_AUTO = False
+PALLAS_RAGGED_IN_AUTO = False
 
 # Compiled top-logprobs width: OpenAI allows top_logprobs 0-20 but a
 # per-request width would compile a program per value; requests are
@@ -91,10 +88,10 @@ def deferred_kv_eligible(architecture: str, decode_steps: int,
     """The ONE eligibility predicate for deferred KV writes.
 
     Used by the runner's capability guard (which raises on explicit
-    ineligible 'on'), the server's '--deferred-kv-writes auto'
-    resolution, and bench.py's impl gating — one definition so the
-    three call sites cannot drift (e.g. re-enabling Pallas decode in
-    'auto' or adding an exclusion must flow to all of them).
+    ineligible 'on') and the server's '--deferred-kv-writes auto'
+    resolution — one definition so the two cannot drift (e.g.
+    re-enabling Pallas decode in 'auto' or adding an exclusion must
+    flow to both).
     Speculative decoding excludes deferral: the verify step must
     write draft KV eagerly so later draft positions attend to
     earlier ones (docs/speculative.md §interactions)."""
@@ -111,40 +108,32 @@ def async_scheduling_eligible(decode_steps: int, speculative_k: int,
     """The ONE eligibility predicate for the overlapped async
     execution pipeline (docs/async_pipeline.md).
 
-    Used by EngineConfig's hard validation error message, the server's
-    '--async-scheduling auto' resolution and bench.py's pass gating —
-    one definition so the call sites cannot drift (the
-    deferred_kv_eligible pattern). The pipeline's plan-ahead step
-    assumes every running row commits exactly one token per dispatch,
-    so multi-step bursts and speculative verify (data-dependent commit
-    counts) are out; multihost serving is out because the step
-    broadcast ships host-resident numpy payloads, while the ahead
-    dispatch feeds device-resident arrays forward."""
+    Used by EngineConfig's hard validation error message and the
+    server's '--async-scheduling auto' resolution — one definition so
+    the two cannot drift (the deferred_kv_eligible pattern). The
+    pipeline's plan-ahead step assumes every running row commits
+    exactly one token per dispatch, so multi-step bursts and
+    speculative verify (data-dependent commit counts) are out;
+    multihost serving is out because the step broadcast ships
+    host-resident numpy payloads, while the ahead dispatch feeds
+    device-resident arrays forward."""
     return (decode_steps == 1 and speculative_k == 0
             and not distributed)
 
 
-def unified_step_eligible(pipeline_parallel: int = 1,
-                          context_parallel: int = 1,
-                          distributed: bool = False,
+def unified_step_eligible(distributed: bool = False,
                           engine_role: str = "both") -> bool:
-    """The ONE eligibility predicate for the unified ragged step
-    (docs/unified_step.md).
+    """The eligibility predicate for the unified ragged step
+    (docs/unified_step.md), used by the server's '--unified-step auto'
+    resolution.
 
-    Used by the server's '--unified-step auto' resolution and
-    bench.py's pass gating — one definition so the call sites cannot
-    drift (the deferred_kv_eligible pattern). The pp and cp runners
-    now execute the ragged [R, W] block natively — pipeline stages
-    thread the per-row descriptor triple through their microbatch
-    handoffs, and the sp runner shards the W axis
-    (docs/parallelism.md) — so pp/cp no longer disqualify. Still out:
-    the multihost bridge broadcasts bimodal payload kinds, and a
+    The pp and cp runners execute the ragged [R, W] block natively —
+    pipeline stages thread the per-row descriptor triple through
+    their microbatch handoffs, and the sp runner shards the W axis
+    (docs/parallelism.md) — so neither disqualifies. Out: the
+    multihost bridge broadcasts bimodal payload kinds, and a
     disaggregated role engine by construction never holds prefill and
-    decode work at once, so neither can mix rows. The pp/cp arguments
-    stay in the signature so the call sites (server resolution, bench
-    gating) keep passing their full config — a future disqualifier
-    lands in one place."""
-    del pipeline_parallel, context_parallel  # no longer disqualifying
+    decode work at once, so neither can mix rows."""
     return not distributed and engine_role == "both"
 
 
@@ -346,13 +335,11 @@ class ModelRunner:
         # payload arity) keys off this flag.
         self.kv_quantized = config.cache.resolved_kv_dtype() == "int8"
         if config.cache.cache_layout == "auto":
-            # Measured default (benchmarks/results/decode_probe.json,
-            # TPU v5e, 2026-07-31): per_layer decode bursts run 2.0x
-            # faster than the stacked layout (13.5 vs 27.4 ms per
-            # token-step at the 1B bench config) and the engine bench
-            # follows (11.07 vs 5.94 req/s). pp shards the stacked L
-            # axis and the sp ring walks the stacked cache, so those
-            # configs resolve to stacked.
+            # per_layer on a builder's capture from before the driver
+            # had a chip, which no cell has repeated (both run
+            # per_layer; ROADMAP D4 owns the comparison). pp shards
+            # the stacked L axis and the sp ring walks the stacked
+            # cache, so those configs resolve to stacked.
             config.cache.cache_layout = (
                 "stacked"
                 if (config.parallel.pipeline_parallel_size > 1
@@ -371,12 +358,11 @@ class ModelRunner:
             # away the working decode kernel when prefill didn't
             # compile). Lowering runs Pallas's Mosaic rules (tiling,
             # layouts, scalar prefetch) without burning a full compile.
-            # Under ``auto`` the choice is additionally *empirical*:
-            # the measured-winner table (kernel microbench) decides,
-            # not lowering success alone. An explicit "pallas" skips
-            # the table (operator override).
+            # Under ``auto`` PALLAS_DECODE_IN_AUTO also decides, not
+            # lowering success alone. An explicit "pallas" skips the
+            # constant (operator override).
             self._resolve_pallas_impls(model_config, config,
-                                       empirical=auto_impl)
+                                       auto_impl=auto_impl)
         logger.info(
             "Attention impls: decode=%s prefill=%s",
             model_config.attention_impl_decode
@@ -728,7 +714,7 @@ class ModelRunner:
             # (decode_width, S) verify shape (Mosaic tiling rules are
             # shape-specific), so probe exactly that shape and degrade
             # ONLY the verify program to XLA attention — real prefill
-            # keeps its measured-winner kernel.
+            # keeps its kernel.
             spec_model = model_config
             prefill_impl = (model_config.attention_impl_prefill
                             or model_config.attention_impl)
@@ -789,11 +775,11 @@ class ModelRunner:
             # Composes with pp (the ragged [R, W] block rides the
             # staged forward — rows become microbatches, the per-row
             # descriptor triple threads through each ppermute handoff)
-            # and with cp (the sp wrapper shards the W axis) —
-            # unified_step_eligible dropped both disqualifiers.
+            # and with cp (the sp wrapper shards the W axis).
             # Resolve the unified step's own attention impl: the
-            # fused ragged kernel when it lowers AND is the measured
-            # winner, else the composed prefill kernel (probed at the
+            # fused ragged kernel when 'auto' admits it
+            # (PALLAS_RAGGED_IN_AUTO) or 'pallas' is explicit and it
+            # lowers, else the composed prefill kernel (probed at the
             # [R, W] shapes the per-bucket probe never saw), else XLA
             # — degrading ONLY the ragged program, never real prefill
             # (the _spec_model pattern).
@@ -911,35 +897,6 @@ class ModelRunner:
                 return err
         return None
 
-    @staticmethod
-    def _ragged_microbench_verdict() -> Optional[bool]:
-        """Measured-winner verdict for the fused ragged kernel.
-
-        Reads the ragged-suite rows (kind == 'ragged') of
-        benchmarks/results/kernel_microbench.json: True when every
-        measured cell wins (speedup >= 1.0), False when any loses,
-        None when the file or the suite is absent — under 'auto' an
-        absent measurement composes the prefill kernel rather than
-        serving an unmeasured one (round-3's mistake was serving
-        whatever merely compiled).
-        """
-        path = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "..", "..",
-            "benchmarks", "results", "kernel_microbench.json")
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except (OSError, ValueError):
-            return None
-        if data.get("backend") != "tpu":
-            return None
-        rows = [row for row in data.get("rows", [])
-                if row.get("kind") == "ragged"]
-        if not rows:
-            return None
-        return all(float(row.get("speedup", 0.0)) >= 1.0
-                   for row in rows)
-
     def _resolve_unified_impl(self, base_model, config,
                               auto_impl: bool):
         """Resolve the attention impl serving the unified [R, W] step.
@@ -955,11 +912,10 @@ class ModelRunner:
              error; served verbatim in interpret/CPU testing — that
              pin is how tier-1 holds byte-parity),
           2. the fused ragged kernel (pallas_ragged) when the family
-             prefill impl is Pallas on TPU, — under 'auto' — the
-             kernel microbench table records a measured win (an
-             explicit family-wide 'pallas' skips the table as an
-             operator override), AND it compiles at every ragged
-             shape,
+             prefill impl is Pallas on TPU, 'auto' admits it
+             (PALLAS_RAGGED_IN_AUTO; an explicit family-wide 'pallas'
+             skips the constant as an operator override), AND it
+             compiles at every ragged shape,
           3. the composed prefill kernel when IT compiles at the
              ragged shapes (the pre-fusion path),
           4. XLA attention under 'auto'; a start-up error under an
@@ -1008,24 +964,10 @@ class ModelRunner:
             logger.error("%s; unified step serves via XLA attention",
                          berr)
             return with_impl("xla")
-        # Under 'auto' the microbench table is read BEFORE the probe:
-        # a kernel that will not be served is not compiled at start-up
-        # (each probe is a real Mosaic compile per ragged width). An
-        # explicit family-wide 'pallas' is an operator override and
-        # skips the table.
-        verdict = (self._ragged_microbench_verdict() if auto_impl
-                   else True)
-        if verdict is None:
-            logger.info(
-                "Fused ragged kernel has no measured rows in "
-                "kernel_microbench.json — composing the prefill "
-                "kernel; run benchmarks/kernel_microbench.py (ragged "
-                "suite) on this device to qualify it for 'auto'")
-        elif verdict is False:
-            logger.info(
-                "Fused ragged kernel loses the measured microbench "
-                "at serving shapes; composing the prefill kernel")
-        else:
+        # The constant is read BEFORE the probe: a kernel that will
+        # not be served is not compiled at start-up (each probe is a
+        # real Mosaic compile per ragged width).
+        if PALLAS_RAGGED_IN_AUTO or not auto_impl:
             ragged_err = self._ragged_lowering_error(base_model,
                                                      config)
             if ragged_err is None:
@@ -1062,19 +1004,15 @@ class ModelRunner:
             return repr(e)[:400]
 
     def _resolve_pallas_impls(self, model_config, config,
-                              empirical: bool = False) -> None:
+                              auto_impl: bool = False) -> None:
         """Probe each Pallas kernel's TPU lowering at serving shapes.
 
-        With ``empirical=True`` (attention_impl='auto'), a kernel that
-        lowers must ALSO be the measured winner at the engine's shapes
-        to be served (benchmarks/results/kernel_microbench.json, TPU
-        v5e, 2026-07-31 post-aliasing-fix): the prefill kernel wins
-        1.25-2.3x at every cell, but the decode kernel loses every
-        serving cell (0.42-0.65x at ctx 2k-16k) — it is retired from
-        'auto' entirely (PALLAS_DECODE_IN_AUTO). Serving the slower
-        impl because it merely compiles was round-3's mistake. An
-        explicit 'pallas' (``empirical=False``) skips the table, and a
-        kernel it cannot serve is a start-up error.
+        Under attention_impl='auto' (``auto_impl``) the prefill kernel
+        is served where it lowers, and the decode kernel only where
+        PALLAS_DECODE_IN_AUTO also admits it (a builder's capture, not
+        a driver's number: see the constant). An explicit 'pallas'
+        skips the constant, and a kernel it cannot serve is a
+        start-up error.
         """
         nh, nkv, d = (model_config.num_attention_heads,
                       model_config.num_key_value_heads,
@@ -1102,7 +1040,7 @@ class ModelRunner:
         if berr is not None:
             # Shared backend rule (pallas_backend_error), gated here
             # and at the spec/unified resolution sites.
-            if not empirical:
+            if not auto_impl:
                 raise ValueError(
                     f"attention_impl='pallas' cannot be served: {berr}")
             logger.error("%s; serving via XLA attention", berr)
@@ -1139,26 +1077,23 @@ class ModelRunner:
                 config.scheduler.prefill_chunk_size)],
         }
         for name, cases in probes.items():
-            if (empirical and name == "decode"
+            if (auto_impl and name == "decode"
                     and not PALLAS_DECODE_IN_AUTO):
-                # Retired from 'auto' by the post-aliasing-fix
-                # microbench (XLA decode 1.5-2.4x faster at every
-                # serving shape — see PALLAS_DECODE_IN_AUTO): skip
-                # the lowering probe too, so startup neither burns a
-                # trace nor logs a lowering error for a path that
-                # was never going to serve.
+                # Skip the lowering probe too, so startup neither
+                # burns a compile nor logs a lowering error for a
+                # path that was never going to serve.
                 model_config.attention_impl_decode = "xla"
                 logger.info(
-                    "Decode attention: XLA (measured winner at all "
-                    "serving shapes; Pallas decode retired from "
-                    "'auto' — kernel_microbench.json 2026-07-31)")
+                    "Decode attention: XLA (Pallas decode is not "
+                    "served under 'auto'; --attention-impl pallas "
+                    "forces it)")
                 continue
             err = next(
                 (e for fn, shapes in cases
                  for e in [self._lowering_error(fn, *shapes)]
                  if e is not None), None)
             impl = "pallas" if err is None else "xla"
-            if err and not empirical:
+            if err and not auto_impl:
                 # Explicit selection that cannot be honoured is a
                 # start-up error, never a quiet XLA server.
                 raise RuntimeError(

@@ -37,11 +37,10 @@ import statistics
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-# Peak bf16 matmul FLOP/s per chip, the ONE table (the engine's MFU
-# gauge and bench.py both read it). Prefix-matched against
-# ``device.device_kind``; an unknown device (including CPU) resolves
-# to 0.0 so the gauge reads 0 instead of lying, and bench.py treats
-# 0.0 as an error rather than assuming a peak.
+# Peak bf16 matmul FLOP/s per chip, read by the engine's MFU gauge.
+# Prefix-matched against ``device.device_kind``; an unknown device
+# (including CPU) resolves to 0.0 so the gauge reads 0 instead of
+# lying.
 PEAK_FLOPS_BY_DEVICE_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,

@@ -2499,11 +2499,10 @@ class EngineServer:
 def _resolve_deferred_kv(args, model_config) -> bool:
     """--deferred-kv-writes auto|on|off -> bool.
 
-    'auto' serves the measured winner where the capability guards
-    pass (model_runner rejects ineligible explicit 'on' loudly):
-    deferring decode KV writes to one batched flush per burst measured
-    +15%% engine throughput (12.76 vs 11.07 req/s; builder-captured
-    2026-07-31, not measured by the driver)."""
+    'auto' defers decode KV writes to one batched flush per burst
+    where the capability guards pass (model_runner rejects an
+    ineligible explicit 'on' loudly). The default rests on a builder's
+    capture, not a driver's number; ROADMAP D5 owns the comparison."""
     if args.deferred_kv_writes == "on":
         return True
     if args.deferred_kv_writes == "off":
@@ -2551,10 +2550,9 @@ def _resolve_unified_step(args, model_config=None) -> bool:
 
     'auto' enables the unified ragged step (docs/unified_step.md) —
     prefill chunks admitted into decode steps as one fixed-shape
-    mixed batch — wherever it can run: single-host, no pp/sp
-    sharding, a monolithic engine role. An explicit 'on' outside
-    that envelope fails loudly at runner init
-    (model_runner.unified_step_eligible)."""
+    mixed batch — wherever it can run: single-host, a monolithic
+    engine role. An explicit 'on' outside that envelope fails loudly
+    at runner init (model_runner.unified_step_eligible)."""
     if args.unified_step == "on":
         return True
     if args.unified_step == "off":
@@ -2567,7 +2565,6 @@ def _resolve_unified_step(args, model_config=None) -> bool:
         unified_step_eligible,
     )
     return unified_step_eligible(
-        args.pipeline_parallel_size, args.context_parallel_size,
         distributed=args.distributed,
         engine_role=getattr(args, "engine_role", "both"))
 
@@ -2586,14 +2583,13 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
         tokenizer = BenchTokenizer(model_config.vocab_size)
         served_name = args.served_model_name or args.model
     elif args.model == "bench-1b":
-        # The 1B-class bench geometry (shared with bench.py via
-        # config.bench_1b_model_config), random weights + bench
-        # tokenizer: lets chip_smoke.py drive the real HTTP server at
-        # full width without a checkpoint on disk. The
-        # bench tokenizer (not byte): random-weight greedy tokens are
-        # almost surely >= 256, which ByteTokenizer.decode drops —
-        # streaming clients would see zero non-empty deltas (no TTFT
-        # signal, gen_tokens 0).
+        # The 1B-class geometry (config.bench_1b_model_config),
+        # random weights + bench tokenizer: lets chip_smoke.py drive
+        # the real HTTP server at full width without a checkpoint on
+        # disk. The bench tokenizer (not byte): random-weight greedy
+        # tokens are almost surely >= 256, which ByteTokenizer.decode
+        # drops — streaming clients would see zero non-empty deltas
+        # (no TTFT signal, gen_tokens 0).
         model_config = bench_1b_model_config()
         params = None
         from production_stack_tpu.engine.tokenizer import BenchTokenizer
@@ -2743,8 +2739,9 @@ def parse_args(argv=None):
     parser.add_argument("--attention-impl", default="auto",
                         choices=["auto", "xla", "pallas",
                                  "pallas-interpret"],
-                        help="auto = empirical dispatch by the "
-                             "measured-winner table (model_runner)")
+                        help="auto = the Pallas prefill kernel "
+                             "where it compiles, XLA decode "
+                             "(model_runner)")
     parser.add_argument("--quantization", default="none",
                         choices=["none", "int8"],
                         help="Weight-only quantization (halves weight "
@@ -2763,8 +2760,8 @@ def parse_args(argv=None):
                              "(docs/kv_quantization.md)")
     parser.add_argument("--cache-layout", default="auto",
                         choices=["auto", "stacked", "per_layer"],
-                        help="KV cache HBM layout: auto (measured "
-                             "winner: per_layer unless pp/sp), one "
+                        help="KV cache HBM layout: auto "
+                             "(per_layer unless pp/sp), one "
                              "stacked [L,...] array, or a tuple of "
                              "per-layer buffers (engine/config.py "
                              "CacheConfig)")
@@ -2809,8 +2806,7 @@ def parse_args(argv=None):
     parser.add_argument("--deferred-kv-writes", default="auto",
                         choices=["auto", "on", "off"],
                         help="Defer decode KV writes to one batched "
-                             "flush per burst (round-5 measured +15%% "
-                             "decode throughput). 'auto' enables it "
+                             "flush per burst. 'auto' enables it "
                              "when eligible (llama-family, "
                              "decode-steps > 1, xla decode, no pp/sp)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)

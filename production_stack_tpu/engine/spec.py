@@ -5,8 +5,8 @@ family, Leviathan et al.) drafts continuation tokens from the
 sequence's OWN history: if the trailing ``min_match``-gram of
 prompt + output has occurred before, the tokens that followed that
 occurrence are proposed as drafts. No second model, no extra HBM —
-ideal for the multi-round-QA serving shape (bench.py) where answers
-quote prompts and follow-ups replay history.
+ideal for the multi-round-QA serving shape
+(benchmarks/multi_round_qa.py) where answers quote prompts and follow-ups replay history.
 
 The proposer is pure host-side bookkeeping; verification happens in
 one fixed-shape device program (model_runner._spec_verify_impl) and

@@ -5,7 +5,7 @@ a bounded ring of recent per-kind step durations and exports their
 medians as ``vllm:engine_step_time_median_seconds{kind}``. This
 sentinel compares the scraped medians against a committed baseline
 file and flips ``vllm:perf_drift{phase}`` when any server's median
-drifts beyond the band — turning silent regressions (the BENCH_r02
+drifts beyond the band — turning silent regressions (the
 silent-XLA-fallback class) into an alertable gauge instead of a
 number an operator derives by hand.
 

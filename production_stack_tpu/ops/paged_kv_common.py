@@ -33,7 +33,7 @@ NEG_INF = -1e30
 # a block's last two dims must each be a multiple of these or equal to
 # the whole array dim — and the *backend* (machine-code) pass is
 # stricter than the Python lowering rules about the "or equal" escape
-# hatch for the query/output blocks (BENCH_r02: head_dim=64 block
+# hatch for the query/output blocks (head_dim=64 block
 # shapes lowered fine cross-platform and then failed on the chip).
 # The query-side kernels therefore pad to true tile multiples.
 SUBLANE_TILE = 8

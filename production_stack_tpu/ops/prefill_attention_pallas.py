@@ -21,15 +21,15 @@ of T query tokens per sequence:
   contractions, zero-padded to true (8, 128) tile multiples — the
   whole-dim block escape hatch the Python lowering rules allow is not
   honored by Mosaic's machine-code pass for small-head models
-  (head_dim=64 lowered cross-platform and then failed on chip,
-  BENCH_r02), so the wrapper pads rows/head_dim outright and the
+  (head_dim=64 lowered cross-platform and then failed on chip),
+  so the wrapper pads rows/head_dim outright and the
   kernel zeroes the matching KV-scratch pad sublanes,
 - causal masking is rebuilt in-kernel from a scalar-prefetched per-row
   chunk start: query positions within a prefill chunk are contiguous
   (engine/model_runner.py run_prefill), so ``start + iota`` recovers
   them without shipping a [B, T] positions array through VMEM (a
   (1, T) int32 VMEM block violates Mosaic's (8, 128) tiling rule —
-  the round-2 on-chip compile failure, BENCH_r02 ``pallas_error``),
+  the round-2 on-chip compile failure),
 - flash-style online softmax in VMEM scratch across the page walk.
 
 Contract matches ops.attention.paged_attention for contiguous per-row
@@ -190,7 +190,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
     # queries, flattened so kernel matmuls are 2D, then tile-padded
     # to true (8, 128) multiples. Mosaic's machine-code pass is
     # stricter than the Python lowering rules about whole-dim q/o
-    # blocks (the BENCH_r02 small-head failure: head_dim=64 lowered
+    # blocks (the small-head failure: head_dim=64 lowered
     # cross-platform and failed on chip), so the wrapper pads and the
     # kernel zeroes the matching KV-scratch sublanes.
     rows = group * t

@@ -221,7 +221,7 @@ class LeastLoadedPolicy(RoutingPolicy):
     lowest-index engine, so consecutive arrivals burst onto one
     backend between count updates — measured 10-15% lower throughput
     and ~2x p99 TTFT vs roundrobin at 16 QPS on the fake-engine rig
-    (benchmarks/results/llq_tiebreak.md). Randomizing the tie spreads
+    (a CPU behaviour check, not a chip number). Randomizing the tie spreads
     those bursts without weakening the load signal.
     """
 

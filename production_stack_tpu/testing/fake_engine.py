@@ -216,7 +216,7 @@ class FakeEngineState:
         # classes and these counters back vllm:qos_shed_total.
         self.priority_aware = priority_aware
         self.qos_shed_counts = shed_counter_dict()
-        # Capacity model (bench.py overload phase): > 0 = that many
+        # Capacity model (--max-concurrency): > 0 = that many
         # decode slots; excess requests QUEUE (waiting gauge rises,
         # TTFT inflates) exactly like a saturated pod — without it the
         # fake serves unlimited concurrency and overload is invisible.

@@ -1,9 +1,8 @@
 """Where the persistent XLA compilation cache lives.
 
 One rule for every entry point that touches JAX (engine server,
-bench.py workers, benchmarks/*.py, chip_smoke.py children, the test
-session): call :func:`configure_compile_cache` before the first
-compile.
+chip_smoke.py children, the test session): call
+:func:`configure_compile_cache` before the first compile.
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
   sets no directory in code, so whoever placed the cache from outside

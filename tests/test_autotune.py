@@ -628,15 +628,3 @@ async def test_fake_engine_autotune_knob_echo_roundtrip():
 def test_autotune_decision_span_event_is_registered():
     from production_stack_tpu.engine.tracing import SPAN_EVENTS
     assert "autotune_decision" in SPAN_EVENTS
-
-
-def test_drift_bench_extra_keys_have_directions():
-    """The drift A/B keys bench.py merges must classify, so
-    benchcompare can hold goodput/freeze/parity as directions."""
-    from production_stack_tpu.benchcompare import classify
-    assert classify("autotune_on_goodput_tok_s") == "higher"
-    assert classify("autotune_off_itl_p99_s") == "lower"
-    assert classify("autotune_on_frozen_controllers") == "lower"
-    assert classify("autotune_on_extra_compile_events") == "lower"
-    assert classify("autotune_shadow_byte_identical") == "higher"
-    assert classify("autotune_on_compile_events_delta") == "lower"

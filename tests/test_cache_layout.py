@@ -93,9 +93,7 @@ def test_per_layer_offload_page_roundtrip():
     assert ks.shape == k.shape
 
 def test_auto_layout_resolves_per_layer():
-    """The 'auto' default resolves to per_layer (the on-chip measured
-    winner, benchmarks/results/decode_probe.json 2026-07-31) for
-    plain configs."""
+    """The 'auto' default resolves to per_layer for plain configs."""
     config = EngineConfig(
         model=tiny_model_config("llama"),
         cache=CacheConfig(page_size=16, num_pages=64),

@@ -5,8 +5,8 @@ tiny-llama`` with children and the router; fail without an
 accelerator at the default expectation; a parent that never imports
 jax), the compile-cache helper, and the places where the program used
 to step aside quietly: a failing engine step, an explicit
-``--attention-impl pallas`` that cannot be served, bench.py without a
-chip or with a device it has no peak for.
+``--attention-impl pallas`` that cannot be served; and what
+``--attention-impl auto`` resolves to on a TPU, site by site.
 """
 
 import asyncio
@@ -176,7 +176,7 @@ def test_failed_step_ends_its_requests_and_flips_health():
 
 
 def _runner_config(page_size, attention_impl="pallas", unified=None,
-                   tp=1):
+                   tp=1, unified_step=False):
     from production_stack_tpu.engine.config import (
         CacheConfig,
         EngineConfig,
@@ -192,7 +192,8 @@ def _runner_config(page_size, attention_impl="pallas", unified=None,
         cache=CacheConfig(page_size=page_size, num_pages=32),
         scheduler=SchedulerConfig(max_num_seqs=4, max_model_len=256,
                                   prefill_chunk_size=64,
-                                  unified_step=unified is not None),
+                                  unified_step=(unified_step
+                                                or unified is not None)),
         parallel=ParallelConfig(tensor_parallel_size=tp))
 
 
@@ -235,20 +236,44 @@ def test_explicit_unified_impl_that_cannot_be_served_fails(monkeypatch):
                                      auto_impl=False)
 
 
-# ---- bench.py device handling ----------------------------------------------
+# ---- what 'auto' serves on a TPU -----------------------------------------
 
 
-def test_bench_without_an_accelerator_exits_nonzero_and_prints_nothing():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = _run([sys.executable, "bench.py"], env=env)
-    assert proc.returncode != 0
-    assert _result_lines(proc.stdout) == []
-    assert "no accelerator" in proc.stderr
+@pytest.mark.parametrize("page_size, attention_impl, served, probed", [
+    # 'auto': the prefill kernel where it compiles, composed for the
+    # unified step; neither constant admits the decode or the fused
+    # ragged kernel, so neither is compiled.
+    (128, "auto",
+     {"decode": "xla", "prefill": "pallas", "unified": "pallas"},
+     {"paged_prefill_attention"}),
+    # The default page size cannot serve any kernel: XLA at all three
+    # sites, and nothing compiled to find that out.
+    (16, "auto",
+     {"decode": "xla", "prefill": "xla", "unified": "xla"}, set()),
+    # An explicit 'pallas' skips both constants.
+    (128, "pallas",
+     {"decode": "pallas", "prefill": "pallas",
+      "unified": "pallas_ragged"},
+     {"paged_decode_attention", "paged_prefill_attention",
+      "paged_ragged_attention"}),
+], ids=["auto-page128", "auto-page16", "pallas-page128"])
+def test_attention_impl_resolution_on_a_tpu(
+        monkeypatch, page_size, attention_impl, served, probed):
+    import jax
 
+    from production_stack_tpu.engine.model_runner import ModelRunner
 
-def test_bench_unknown_device_kind_is_an_error(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-    assert bench._peak_flops("TPU v5 lite") == 197e12
-    with pytest.raises(SystemExit, match="Mystery 9000"):
-        bench._peak_flops("Mystery 9000")
+    seen = set()
+
+    def every_probe_compiles(fn, *args):
+        seen.add(fn.__name__)
+        return None
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ModelRunner, "_lowering_error",
+                        staticmethod(every_probe_compiles))
+    runner = ModelRunner(_runner_config(
+        page_size=page_size, attention_impl=attention_impl,
+        unified_step=True))
+    assert runner.observatory.attention_impls() == served
+    assert seen == probed

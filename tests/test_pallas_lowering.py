@@ -3,16 +3,15 @@
 Round-2 lesson: ``interpret=True`` parity tests validate numerics but
 none of Mosaic's tiling/layout rules — the prefill kernel passed every
 interpret test and then failed to compile on the real chip (a (1, T)
-int32 VMEM block violates the (8, 128) tiling rule; BENCH_r02
-``pallas_error``). These tests cross-lower the kernels for the TPU
+int32 VMEM block violates the (8, 128) tiling rule). These tests cross-lower the kernels for the TPU
 platform from the CPU host (no chip needed): the Pallas→Mosaic lowering
 rules — including the BlockSpec tiling checks that failed on hardware —
 run in Python during lowering, so the exact class of bug that slipped
 through round 2 now fails in CI.
 
 This validates lowering (tiling, layouts, scalar prefetch plumbing),
-not Mosaic's final machine-code pass; the bench still reports which
-impl actually served on the chip.
+not Mosaic's final machine-code pass; the server's ``/version`` says
+which impl actually served on the chip.
 """
 
 import numpy as np
@@ -210,7 +209,7 @@ def test_ragged_kernel_lowers_small_head_thin_rows():
     """head_dim=64 with a thin row block: the q/o blocks are not
     naturally (8, 128)-divisible and must pad to true tile multiples
     — the class of shape that lowered cross-platform and then failed
-    Mosaic's machine-code pass on chip in BENCH_r02."""
+    Mosaic's machine-code pass on chip."""
     from production_stack_tpu.ops.ragged_attention_pallas import (
         paged_ragged_attention,
     )
@@ -220,7 +219,7 @@ def test_ragged_kernel_lowers_small_head_thin_rows():
 
 
 def test_prefill_kernel_lowers_small_head_thin_rows():
-    """The BENCH_r02 failing class for the prefill kernel: MHA
+    """The class that failed on chip for the prefill kernel: MHA
     (group 1) at a thin verify-style chunk with head_dim=64 — the
     whole-array block escape hatch the Python lowering rules allow is
     NOT honored by the machine-code pass, so the kernel now pads to

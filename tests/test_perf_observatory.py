@@ -3,8 +3,8 @@ ledger exactly-once semantics under shape perturbation, HBM ledger
 page-math invariants for bf16 and int8 KV, useful-token MFU
 arithmetic, zero-overhead byte parity with the observatory removed,
 the /debug/compiles + /debug/memory endpoint matrix, the profiler
-start/stop guard with span events, the engine /metrics exposition and
-its router scrape/re-export round trip, and benchcompare exit codes.
+start/stop guard with span events, and the engine /metrics exposition
+and its router scrape/re-export round trip.
 """
 
 import asyncio
@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
-from production_stack_tpu.benchcompare import main as benchcompare_main
 from production_stack_tpu.engine.config import (
     CacheConfig,
     EngineConfig,
@@ -334,36 +333,3 @@ def test_metrics_exposition_and_router_roundtrip():
                         impl=impl)._value.get() == 1.0
     finally:
         scraper.close()
-
-
-# ---- benchcompare ---------------------------------------------------------
-
-
-def _bench_record(req_per_s, compile_events, mfu):
-    return {"metric": "bench_tiny", "value": req_per_s,
-            "unit": "req/s",
-            "extra": {"compile_events": {"step": compile_events},
-                      "observatory_mfu": mfu,
-                      "hbm_bytes": {"weights": 1048576}}}
-
-
-def test_benchcompare_exit_codes(tmp_path, capsys):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(_bench_record(10.0, 5, 0.4)))
-    # Identical runs: exit 0.
-    new.write_text(json.dumps(_bench_record(10.0, 5, 0.4)))
-    assert benchcompare_main([str(old), str(new)]) == 0
-    # Throughput regression beyond the 5% default: exit 1.
-    new.write_text(json.dumps(_bench_record(8.0, 5, 0.4)))
-    assert benchcompare_main([str(old), str(new)]) == 1
-    # A compile storm is a regression even with throughput flat.
-    new.write_text(json.dumps(_bench_record(10.0, 50, 0.4)))
-    assert benchcompare_main([str(old), str(new)]) == 1
-    # ...unless it is inside the caller's threshold.
-    assert benchcompare_main(
-        [str(old), str(new), "--threshold", "20"]) == 0
-    # MFU going up is an improvement, not a regression.
-    new.write_text(json.dumps(_bench_record(10.0, 5, 0.8)))
-    assert benchcompare_main([str(old), str(new)]) == 0
-    capsys.readouterr()

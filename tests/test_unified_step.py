@@ -305,10 +305,6 @@ def test_eligibility_and_server_resolution():
         unified_step_eligible,
     )
     assert unified_step_eligible()
-    # pp and cp runners execute the ragged [R, W] block natively
-    # (docs/parallelism.md), so neither disqualifies any more.
-    assert unified_step_eligible(pipeline_parallel=4)
-    assert unified_step_eligible(context_parallel=8)
     assert not unified_step_eligible(distributed=True)
     assert not unified_step_eligible(engine_role="prefill")
     assert not unified_step_eligible(engine_role="decode")
