@@ -375,8 +375,9 @@ class SchedulerConfig:
     # by a decode ablation (builder-captured 2026-07-31, not measured
     # by the driver): the per-step paged scatters cost ~5.1 of 11.1 ms
     # for ~1 MB written.
-    # Llama-family single-runner path only (guarded in model_runner);
-    # requires decode_steps > 1.
+    # Single-runner path of the families whose forward takes kv_tail
+    # (model_runner.DEFERRED_KV_FAMILIES, guarded there); requires
+    # decode_steps > 1.
     deferred_kv_writes: bool = False
     # Draft-free speculative decoding (prompt lookup, docs/
     # speculative.md): propose up to K continuation tokens per row
@@ -797,8 +798,6 @@ def _recurrent_state_refusals(config: "EngineConfig"):
         (s.unified_step, "the unified ragged step",
          "its rows mix decode tokens and prompt chunks in one block, "
          "which the recurrent layers do not take"),
-        (s.deferred_kv_writes, "deferred KV writes",
-         "the tail path is the llama family's"),
         (config.lora.enable, "LoRA", "the model has no LoRA targets"),
         (config.cache.resolved_kv_dtype() == "int8", "int8 KV pages",
          "the hybrid cache is not quantized"),

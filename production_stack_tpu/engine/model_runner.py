@@ -76,9 +76,11 @@ PALLAS_RAGGED_IN_AUTO = False
 # rejects top_logprobs > 20 with a 400).
 TOP_LOGPROBS_WIDTH = 20
 
-# Model families served by the deferred-KV-write burst (the kv_tail
-# path exists in models/llama.py, which also serves mistral/qwen2).
-DEFERRED_KV_FAMILIES = ("llama", "mistral", "qwen2")
+# Model families served by the deferred-KV-write burst: those whose
+# forward takes kv_tail (models/llama.py, which also serves
+# mistral/qwen2, and models/qwen3_next.py, whose full-attention layers
+# alone have tails).
+DEFERRED_KV_FAMILIES = ("llama", "mistral", "qwen2", "qwen3_next")
 
 
 def deferred_kv_eligible(architecture: str, decode_steps: int,
@@ -92,6 +94,10 @@ def deferred_kv_eligible(architecture: str, decode_steps: int,
     resolution — one definition so the two cannot drift (e.g.
     re-enabling Pallas decode in 'auto' or adding an exclusion must
     flow to both).
+    ``architecture`` must be one whose forward takes ``kv_tail``
+    (DEFERRED_KV_FAMILIES): the Llama family, every layer of which has
+    a tail, and qwen3_next, whose full-attention layers have one while
+    its recurrent state rides the burst's carry.
     Speculative decoding excludes deferral: the verify step must
     write draft KV eagerly so later draft positions attend to
     earlier ones (docs/speculative.md §interactions)."""
@@ -448,13 +454,14 @@ class ModelRunner:
         self._deferred = config.scheduler.deferred_kv_writes
         if self._deferred:
             # Deferred per-burst KV writes (ops/attention.write_to_tail
-            # + the kv_tail path in models/llama.forward): motivated by
+            # + the kv_tail path of the family's forward): motivated by
             # the round-5 ablation — the per-step scatter + same-buffer
             # gather interaction costs ~4.4 of 8.3 ms/step (XLA
-            # copy-insertion). Llama-family single-runner decode only;
-            # reject loudly otherwise. The SAME predicate drives the
-            # server's and bench's 'auto' resolution
-            # (deferred_kv_eligible) — keep them in lockstep.
+            # copy-insertion). Single-runner decode of the families
+            # whose forward takes kv_tail only; reject loudly
+            # otherwise. The SAME predicate drives the server's and
+            # bench's 'auto' resolution (deferred_kv_eligible) — keep
+            # them in lockstep.
             if config.scheduler.decode_steps <= 1:
                 raise ValueError(
                     "deferred_kv_writes needs decode_steps > 1 (the "
@@ -467,7 +474,8 @@ class ModelRunner:
                     "burst bodies)")
             if model_config.architecture not in DEFERRED_KV_FAMILIES:
                 raise NotImplementedError(
-                    "deferred_kv_writes serves the llama family (got "
+                    "deferred_kv_writes serves "
+                    f"{', '.join(DEFERRED_KV_FAMILIES)} (got "
                     f"{model_config.architecture!r})")
             decode_impl = (model_config.attention_impl_decode
                            or model_config.attention_impl)
@@ -1365,18 +1373,30 @@ class ModelRunner:
                                     top_k, rng, lora, lora_ids,
                                     penalties, seeding, bias,
                                     suppress, fsm, num_steps: int,
-                                    want_logprobs: bool = False):
+                                    want_logprobs: bool = False,
+                                    state_slots=None):
         """_decode_burst_impl with per-burst (not per-step) KV writes.
 
         Same contract and carry discipline, except: each step's K/V
         goes into dense per-layer tail buffers ([B, S, kv, d] one-hot
         selects — ops/attention.write_to_tail) and attention covers
         pages + tail positionally (paged_attention k_tail/v_tail);
-        the paged caches stay READ-ONLY through the scan (loop
+        the page planes stay READ-ONLY through the scan (loop
         invariants, not carry) and the tails flush to the pages with
         one write_to_pages per layer at burst end. A decode ablation
         (builder-captured 2026-07-31, not measured by the driver) put
-        the per-step scatters at ~5.1 of 11.1 ms for ~1 MB of writes.
+        the per-step scatters at ~5.1 of 11.1 ms for ~1 MB of writes;
+        on the hybrid cell the eager burst copied both planes of each
+        full layer every step (ledger, PR 32: 12.8% of the slice).
+
+        The scan carries what changes and closes over what does not.
+        Per cache entry: a layer that has pages
+        (``not layer_is_linear``) carries its tail; any other entry of
+        a hybrid model's caches (a linear layer's ``S`` pool and
+        convolution tails, the expert counters at the end of
+        ``k_cache``) is read and written every step and rides the
+        carry itself. A model whose every layer has pages carries L
+        tails and nothing else, under either cache layout.
 
         The pages hold exactly the pre-burst tokens throughout, so
         the frozen cached-token count is positions[:, 0] (the first
@@ -1392,12 +1412,28 @@ class ModelRunner:
 
         kv_lens0 = positions[:, 0]  # pages hold this many tokens
         tail_shape = (b, num_steps, m.num_key_value_heads, m.head_dim)
-        dtype = m.jax_dtype
-        k_tails0 = tuple(jnp.zeros(tail_shape, dtype)
-                         for _ in range(m.num_hidden_layers))
-        v_tails0 = tuple(jnp.zeros(tail_shape, dtype)
-                         for _ in range(m.num_hidden_layers))
+        # Which cache entries are page planes: the layers that are not
+        # linear; a hybrid k_cache ends in the expert counters.
+        paged = tuple(not linear for linear in m.layer_is_linear) + (
+            False,)
+        per_layer = isinstance(k_cache, tuple)
 
+        def carried(cache):
+            # A tail where the layer has pages, else the entry itself.
+            if not per_layer:  # stacked: every layer has pages
+                cache = (None,) * m.num_hidden_layers
+            return tuple(jnp.zeros(tail_shape, m.jax_dtype) if p else c
+                         for c, p in zip(cache, paged))
+
+        def served(cache, carry):
+            # What the forward reads: the planes from outside the
+            # scan, everything else from the carry.
+            if not per_layer:
+                return cache
+            return tuple(c if p else s
+                         for c, s, p in zip(cache, carry, paged))
+
+        k_carry0, v_carry0 = carried(k_cache), carried(v_cache)
         sample_step = self._burst_sample_step(
             b, penalties, seeding, bias, suppress, temperature,
             top_p, top_k, stop_tokens, budgets, want_logprobs)
@@ -1407,8 +1443,9 @@ class ModelRunner:
             tok, pos, act, emitted, counts, fs, kt, vt = carry
             logits, kt, vt = self._forward(
                 params, m, tok, pos, page_table, kv_lens0,
-                act[:, None], k_cache, v_cache, lora=lora,
-                lora_ids=lora_ids, kv_tail=(kt, vt),
+                act[:, None], served(k_cache, kt), served(v_cache, vt),
+                lora=lora, lora_ids=lora_ids, kv_tail=(kt, vt),
+                **self._state_kwargs(state_slots),
             )
             out, sampled, emitted, counts, act_next, fs = \
                 sample_step(logits, step_rng, act, emitted, counts,
@@ -1421,33 +1458,29 @@ class ModelRunner:
         rngs = jax.random.split(rng, num_steps)
         emitted0 = jnp.zeros(active.shape, jnp.int32)
         carry = (tokens, positions, active, emitted0, counts0, fsm0,
-                 k_tails0, v_tails0)
-        (_, _, _, emitted, _, _, k_tails, v_tails), out = jax.lax.scan(
+                 k_carry0, v_carry0)
+        (_, _, _, emitted, _, _, kt, vt), out = jax.lax.scan(
             body, carry, rngs
         )
 
-        # Flush: one batched scatter per layer for the whole burst.
+        # Flush: one batched scatter per paged layer for the whole
+        # burst; the other entries are the carry's last.
         tail_pos = kv_lens0[:, None] + jnp.arange(num_steps)[None, :]
         tail_valid = (jnp.arange(num_steps)[None, :]
                       < emitted[:, None])
-        if isinstance(k_cache, tuple):
-            k_cache = tuple(
-                write_to_pages(c, k_tails[l], page_table, tail_pos,
-                               tail_valid)
-                for l, c in enumerate(k_cache))
-            v_cache = tuple(
-                write_to_pages(c, v_tails[l], page_table, tail_pos,
-                               tail_valid)
-                for l, c in enumerate(v_cache))
-        else:
-            for l in range(m.num_hidden_layers):
-                k_cache = write_to_pages(k_cache, k_tails[l],
-                                         page_table, tail_pos,
-                                         tail_valid, layer=l)
-                v_cache = write_to_pages(v_cache, v_tails[l],
-                                         page_table, tail_pos,
-                                         tail_valid, layer=l)
-        return out, k_cache, v_cache
+
+        def flush(cache, carry):
+            if per_layer:
+                return tuple(
+                    write_to_pages(c, s, page_table, tail_pos,
+                                   tail_valid) if p else s
+                    for c, s, p in zip(cache, carry, paged))
+            for l, tail in enumerate(carry):
+                cache = write_to_pages(cache, tail, page_table,
+                                       tail_pos, tail_valid, layer=l)
+            return cache
+
+        return out, flush(k_cache, kt), flush(v_cache, vt)
 
     def _spec_verify_impl(self, params, k_cache, v_cache, tokens,
                           positions, page_table, kv_lens, valid,

@@ -2194,7 +2194,8 @@ class EngineServer:
 
     async def version(self, request: web.Request):
         # The device is named by the process that holds it, so a smoke
-        # or a benchmark never infers it from logs (chip_smoke.py).
+        # or a benchmark never infers it from logs (chip_smoke.py);
+        # kv_writes says which decode burst the runner compiled.
         import jax
         devices = jax.devices()
         obs = getattr(self.engine.runner, "observatory", None)
@@ -2206,6 +2207,8 @@ class EngineServer:
             "num_devices": len(devices),
             "attention_impl": (obs.attention_impls()
                                if obs is not None else {}),
+            "kv_writes": ("deferred" if self.engine.config.scheduler
+                          .deferred_kv_writes else "eager"),
         })
 
     async def kv_summary_handler(self, request: web.Request):
@@ -2807,8 +2810,10 @@ def parse_args(argv=None):
                         choices=["auto", "on", "off"],
                         help="Defer decode KV writes to one batched "
                              "flush per burst. 'auto' enables it "
-                             "when eligible (llama-family, "
-                             "decode-steps > 1, xla decode, no pp/sp)")
+                             "when eligible (llama, mistral, qwen2, "
+                             "qwen3_next; decode-steps > 1, xla "
+                             "decode, no pp/sp); /version says which "
+                             "is served (kv_writes)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)
     parser.add_argument("--pipeline-parallel-size", type=int, default=1,
                         help="Layer stages over the pp mesh axis "

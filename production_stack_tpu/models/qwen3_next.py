@@ -21,7 +21,9 @@ whose block starts at position 0 starts from a zero state whatever its
 slot holds, so a slot needs no clearing between sequences or before a
 recompute. ``k_cache`` carries one entry more than there are layers:
 ``k_cache[L]``, five float32 counters of the expert layer's decode
-steps that the runner reads and zeroes (``MOE_STATS``).
+steps that the runner reads and zeroes (``MOE_STATS``). With
+``kv_tail`` (a deferred-write decode burst) the full-attention layers
+append to tails and leave their planes unwritten (``forward``).
 
 Parameters are two stacks beside the common one: ``gdn_*`` over the
 linear layers and ``wqg/wk/wv/wo/q_norm/k_norm`` over the full ones,
@@ -42,6 +44,10 @@ import jax.numpy as jnp
 
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.models.llama import cached_attention
+from production_stack_tpu.ops.attention import (
+    paged_attention,
+    write_to_tail,
+)
 from production_stack_tpu.ops.gated_delta import (
     causal_conv,
     gated_delta_chunked,
@@ -163,7 +169,7 @@ def init_cache(config: ModelConfig, num_pages: int, page_size: int,
 
 
 def _gated_attention(config, lp, x, positions, page_table, kv_lens,
-                     valid, k_cache, v_cache, layer):
+                     valid, k_cache, v_cache, layer, kv_tail=None):
     nh, nkv, d = (config.num_attention_heads, config.num_key_value_heads,
                   config.head_dim)
     b, t, _ = x.shape
@@ -177,9 +183,22 @@ def _gated_attention(config, lp, x, positions, page_table, kv_lens,
     q = apply_rope(q, positions, config.rope_theta, rotary)
     k = apply_rope(k, positions, config.rope_theta, rotary)
     with jax.named_scope("gated_attn"):
-        attn, k_cache, v_cache = cached_attention(
-            config, q, k, v, k_cache, v_cache, page_table, positions,
-            kv_lens, valid, layer)
+        if kv_tail is None:
+            attn, k_cache, v_cache = cached_attention(
+                config, q, k, v, k_cache, v_cache, page_table, positions,
+                kv_lens, valid, layer)
+        else:
+            # Deferred writes: this step's K/V go to the layer's tail,
+            # the planes are read and not written, and the tails come
+            # back in the layer's cache slots.
+            slot, act = positions[:, 0] - kv_lens, valid[:, 0]
+            kt = write_to_tail(kv_tail[0][layer], k, slot, act)
+            vt = write_to_tail(kv_tail[1][layer], v, slot, act)
+            attn = paged_attention(
+                q, k_cache[layer], v_cache[layer], page_table, positions,
+                kv_lens, k_tail=kt, v_tail=vt)
+            k_cache = k_cache[:layer] + (kt,) + k_cache[layer + 1:]
+            v_cache = v_cache[:layer] + (vt,) + v_cache[layer + 1:]
     attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
         attn.dtype)
     return attn.reshape(b, t, nh * d) @ lp["wo"], k_cache, v_cache
@@ -305,10 +324,22 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, page_table: jnp.ndarray,
             kv_lens: jnp.ndarray, valid: jnp.ndarray,
             k_cache, v_cache, lora=None, lora_ids=None,
-            state_slots=None,
+            kv_tail=None, state_slots=None,
             ) -> Tuple[jnp.ndarray, tuple, tuple]:
     """Same contract as models.llama.forward, with ``state_slots [B]``
-    (None: every row the trash slot). No LoRA targets."""
+    (None: every row the trash slot). No LoRA targets.
+
+    ``kv_tail`` (a deferred-write decode burst, T == 1) is
+    ``(k_tails, v_tails)``, each indexed by layer and read at the
+    full-attention layers alone: a linear layer has no pages and so no
+    tail, and its entry is whatever the caller keeps there. A full
+    layer then appends this step's K (after ``k_norm`` and the rotary)
+    and V to its tails, attends over its page planes, which it does
+    not write, and the tails, with ``kv_lens`` the frozen pre-burst
+    count; the caches come back with each full layer's planes replaced
+    by its updated tails. The linear layers' pools and the counters are
+    read and written every step either way. The runner flushes the
+    tails to the planes once a burst."""
     if lora is not None:
         raise NotImplementedError("qwen3_next has no LoRA targets")
     if not isinstance(k_cache, (list, tuple)):
@@ -340,7 +371,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             n_full += 1
             mixed, kc, vc = _gated_attention(
                 config, lp, a_in, positions, page_table, kv_lens, valid,
-                tuple(k_cache), tuple(v_cache), layer)
+                tuple(k_cache), tuple(v_cache), layer, kv_tail)
             k_cache, v_cache = list(kc), list(vc)
         x = x + mixed
         m_in = rms_norm(x, common["mlp_norm"], config.rms_norm_eps)

@@ -58,6 +58,8 @@ def test_models_health_version():
         assert version["device_kind"] and version["num_devices"] >= 1
         assert version["attention_impl"]["decode"] == "xla"
         assert version["attention_impl"]["prefill"] == "xla"
+        # Single-step decode writes K/V as it goes.
+        assert version["kv_writes"] == "eager"
     asyncio.run(_with_client(run))
 
 

@@ -210,9 +210,6 @@ REFUSED = {
         parallel=ParallelConfig(tensor_parallel_size=2)),
     "the unified ragged step": dict(
         scheduler=SchedulerConfig(unified_step=True)),
-    "deferred KV writes": dict(
-        scheduler=SchedulerConfig(decode_steps=4,
-                                  deferred_kv_writes=True)),
     "LoRA": dict(lora=LoRAConfig(enable=True)),
     "int8 KV pages": dict(cache=CacheConfig(kv_cache_dtype="int8")),
     "weight quantization": dict(
