@@ -23,12 +23,20 @@ _DTYPE_MAP = {
 }
 
 
+# The architectures read as a Llama shape (from_hf_config's last
+# branch): as ``architectures`` gives them, or as ``model_type``.
+_LLAMA_SHAPES = frozenset(
+    f"{family}{suffix}" for family in ("llama", "mistral", "qwen2")
+    for suffix in ("", "forcausallm"))
+
+
 @dataclasses.dataclass
 class ModelConfig:
     """Architecture hyperparameters (HF-config compatible field names)."""
 
     name: str = "tiny-llama"
-    # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next
+    # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
+    # (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -56,7 +64,8 @@ class ModelConfig:
     # (linear attention) layer otherwise; 0 = every layer is full
     # attention (every other architecture). The linear layers keep a
     # recurrent state per sequence instead of pages
-    # (engine/kv_cache.py state slots).
+    # (engine/kv_cache.py state slots). Which layers those are, and
+    # what one keeps, is its family's to say (models/registry.py).
     full_attention_interval: int = 0
     # Share of each head's dimensions the rotary embedding turns.
     partial_rotary_factor: float = 1.0
@@ -77,6 +86,19 @@ class ModelConfig:
     moe_intermediate_size: int = 0
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
+    # Jamba hybrid decoders (architecture == "jamba", models/jamba.py).
+    # Layer i is attention (no positional encoding) when
+    # i % attn_layer_period == attn_layer_offset and a Mamba-1 mixer
+    # otherwise, which keeps per sequence the selective scan's state
+    # h [mamba_d_inner, mamba_d_state] and the last mamba_d_conv - 1
+    # inputs of its convolution; mamba_d_inner = mamba_expand *
+    # hidden_size.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -108,13 +130,27 @@ class ModelConfig:
         return _DTYPE_MAP[self.dtype]
 
     @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def family(self):
+        """What the architecture's family declares
+        (models/registry.py)."""
+        from production_stack_tpu.models.registry import family
+        return family(self.architecture)
+
+    @property
     def layer_is_linear(self) -> tuple:
         """Per layer: True where the layer keeps a recurrent state
-        (Gated DeltaNet) and no pages, False where it attends over
-        the paged cache. The pattern is static."""
-        n = self.full_attention_interval
-        return tuple(bool(n) and (i + 1) % n != 0
-                     for i in range(self.num_hidden_layers))
+        (Gated DeltaNet, a Mamba mixer) and no pages, False where it
+        attends over the paged cache. The pattern is static and the
+        family's to give; a family that declares none has pages in
+        every layer."""
+        layers = self.family.recurrent_layers
+        if layers is None:
+            return (False,) * self.num_hidden_layers
+        return layers(self)
 
     @property
     def num_kv_layers(self) -> int:
@@ -131,31 +167,32 @@ class ModelConfig:
         return self.num_experts * self.expert_parallel_size
 
     def recurrent_state_shapes(self):
-        """One sequence's state in one linear layer: the delta rule's
-        ``S`` (float32, as the published recurrence keeps it) and the
-        causal convolution's tail of inputs (model dtype)."""
-        conv_channels = (2 * self.linear_num_key_heads
-                         * self.linear_key_head_dim
-                         + self.linear_num_value_heads
-                         * self.linear_value_head_dim)
-        return ((self.linear_num_value_heads, self.linear_key_head_dim,
-                 self.linear_value_head_dim),
-                (self.linear_conv_kernel_dim - 1, conv_channels))
+        """One sequence's state in one recurrent layer, as its family
+        declares it: the shapes of the recurrence's own state
+        (float32) and of the causal convolution's tail of inputs
+        (model dtype)."""
+        (state, _), (tail, _) = self.family.state(self)
+        return state, tail
 
     def recurrent_state_bytes(self) -> int:
-        """Bytes of one sequence's recurrent state over all linear
+        """Bytes of one sequence's recurrent state over all recurrent
         layers (0 for a model with none)."""
         if not self.has_recurrent_state:
             return 0
-        s, tail = self.recurrent_state_shapes()
-        per_layer = (math.prod(s) * 4
-                     + math.prod(tail) * jnp.dtype(self.jax_dtype).itemsize)
+        per_layer = sum(
+            math.prod(shape) * jnp.dtype(
+                self.jax_dtype if dtype == "model" else dtype).itemsize
+            for shape, dtype in self.family.state(self))
         return per_layer * self.layer_is_linear.count(True)
 
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "") -> "ModelConfig":
-        """Build from a HuggingFace config.json dict."""
-        arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0].lower()
+        """Build from a HuggingFace config.json dict. The
+        architecture is the first of ``architectures``, else
+        ``model_type``, else a Llama; one that no branch below knows
+        is refused and not read as a Llama shape."""
+        arch = (hf.get("architectures")
+                or [hf.get("model_type") or "LlamaForCausalLM"])[0].lower()
         if "gpt2" in arch:
             return cls(
                 name=name or hf.get("_name_or_path", "gpt2"),
@@ -230,6 +267,54 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "jamba" in arch:
+            refused = [why for bad, why in (
+                (hf.get("num_experts", 1) > 1,
+                 f"num_experts {hf.get('num_experts')}: the feed-forward "
+                 "of every layer is served as one dense SwiGLU MLP, "
+                 "and a Jamba with routed experts has no expert layer "
+                 "here"),
+                (hf.get("sliding_window") is not None,
+                 f"sliding_window {hf.get('sliding_window')}: the "
+                 "attention layers are served as full causal attention "
+                 "over the paged cache"),
+                (bool(hf.get("mamba_proj_bias", False)),
+                 "mamba_proj_bias: the Mamba mixer's in and out "
+                 "projections are served without a bias"),
+                (not hf.get("mamba_conv_bias", True),
+                 "mamba_conv_bias false: the convolution is served "
+                 "with its bias"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "Jamba config this engine does not serve: "
+                    + "; ".join(refused))
+            return cls(
+                name=name or hf.get("_name_or_path", "jamba"),
+                architecture="jamba",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf["intermediate_size"],
+                num_hidden_layers=hf["num_hidden_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                num_key_value_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim"),
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 262144),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           False),
+                attn_layer_period=hf["attn_layer_period"],
+                attn_layer_offset=hf["attn_layer_offset"],
+                mamba_d_state=hf.get("mamba_d_state", 16),
+                mamba_d_conv=hf.get("mamba_d_conv", 4),
+                mamba_expand=hf.get("mamba_expand", 2),
+                # The published default: ceil(hidden_size / 16).
+                mamba_dt_rank=(hf.get("mamba_dt_rank")
+                               or -(-hf["hidden_size"] // 16)),
+                activation="silu",
+                dtype="bfloat16",
+            )
         if "mixtral" in arch:
             return cls(
                 name=name or hf.get("_name_or_path", "mixtral"),
@@ -269,6 +354,13 @@ class ModelConfig:
                 activation="relu",
                 dtype="bfloat16",
             )
+        if arch not in _LLAMA_SHAPES:
+            raise ValueError(
+                f"architecture {arch!r} is none this engine serves "
+                "(config.json 'architectures', else 'model_type'): it "
+                "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba and the "
+                f"Llama shapes {sorted(_LLAMA_SHAPES)}, and reads no "
+                "other as one of them")
         qwen = "qwen2" in arch
         return cls(
             name=name or hf.get("_name_or_path", "llama"),
@@ -775,9 +867,13 @@ class EngineConfig:
 
 def _recurrent_state_refusals(config: "EngineConfig"):
     """(feature, why) for every configured feature that moves, skips
-    or rolls back K/V pages without the recurrent state of a model
-    with linear-attention layers, or has no path for that state."""
+    or rolls back K/V pages without the recurrent state of a hybrid
+    model, or has no path for that state. What is true of any such
+    model is worded here; where the reason is the family's own
+    (what it has no sharding rule or quantized form for) the family
+    words it (models/registry.py ``refusals``)."""
     s, p = config.scheduler, config.parallel
+    own = config.model.family.refusals
     checks = (
         (config.offload.enable, "KV offload",
          "it moves pages to another tier and back without the state"),
@@ -794,7 +890,7 @@ def _recurrent_state_refusals(config: "EngineConfig"):
         (p.context_parallel_size > 1, "context-parallel prefill",
          "the ring prefill has no state pools"),
         (p.tensor_parallel_size > 1, "tensor parallelism",
-         "the state pools and the expert layer have no sharding rules"),
+         own["tensor parallelism"]),
         (s.unified_step, "the unified ragged step",
          "its rows mix decode tokens and prompt chunks in one block, "
          "which the recurrent layers do not take"),
@@ -802,7 +898,7 @@ def _recurrent_state_refusals(config: "EngineConfig"):
         (config.cache.resolved_kv_dtype() == "int8", "int8 KV pages",
          "the hybrid cache is not quantized"),
         (config.model.quantization != "none", "weight quantization",
-         "the fused projections and experts have no quantized form"),
+         own["weight quantization"]),
         (config.cache.cache_layout == "stacked",
          "cache_layout='stacked'",
          "pages and state pools are per-layer buffers"),
@@ -879,6 +975,12 @@ INTERNAL_FIELDS = {
     "model.moe_intermediate_size",
     "model.shared_expert_intermediate_size",
     "model.norm_topk_prob",
+    "model.attn_layer_period",
+    "model.attn_layer_offset",
+    "model.mamba_d_state",
+    "model.mamba_d_conv",
+    "model.mamba_expand",
+    "model.mamba_dt_rank",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -944,6 +1046,32 @@ def tiny_model_config(architecture: str = "llama") -> ModelConfig:
         max_position_embeddings=512,
         activation={"llama": "silu", "opt": "relu",
                     "gpt2": "gelu"}[architecture],
+        dtype="float32",
+    )
+
+
+def tiny_jamba_config() -> ModelConfig:
+    """A tiny Jamba (both layer kinds, the attention layer neither
+    first nor last, one KV head under four query heads) for tests that
+    run anywhere."""
+    return ModelConfig(
+        name="tiny-jamba",
+        architecture="jamba",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_hidden_layers=4,
+        num_attention_heads=4,
+        num_key_value_heads=1,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        attn_layer_period=3,
+        attn_layer_offset=1,
+        mamba_d_state=8,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=4,
         dtype="float32",
     )
 

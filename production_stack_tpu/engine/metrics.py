@@ -133,7 +133,8 @@ class EngineMetrics:
 
     def on_moe_stats(self, stats: dict) -> dict:
         """The held experts' load over the decode steps one dispatch
-        ran (models/qwen3_next.MOE_STATS, sums over layer-steps), as
+        ran (the counters its family declares, models/registry.py;
+        sums over layer-steps), as
         the dispatch's figures: returned for the step record and kept
         as the gauges' values until the next one."""
         steps = stats["layer_steps"]

@@ -25,7 +25,11 @@ from production_stack_tpu.engine.perf_observatory import (
 )
 from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
 from production_stack_tpu.engine.sequence import Sequence, decode_budget
-from production_stack_tpu.models.registry import get_model
+from production_stack_tpu.models.registry import (
+    deferred_kv_architectures,
+    get_model,
+    init_hybrid_cache,
+)
 from production_stack_tpu.ops.attention import (
     block_pages,
     gathered_blocks,
@@ -76,11 +80,11 @@ PALLAS_RAGGED_IN_AUTO = False
 # rejects top_logprobs > 20 with a 400).
 TOP_LOGPROBS_WIDTH = 20
 
-# Model families served by the deferred-KV-write burst: those whose
-# forward takes kv_tail (models/llama.py, which also serves
-# mistral/qwen2, and models/qwen3_next.py, whose full-attention layers
-# alone have tails).
-DEFERRED_KV_FAMILIES = ("llama", "mistral", "qwen2", "qwen3_next")
+# Model families served by the deferred-KV-write burst: those that
+# declare that their forward takes kv_tail (models/registry.py: the
+# Llama shapes, every layer of which has a tail, and the hybrids, whose
+# attention layers alone have one).
+DEFERRED_KV_FAMILIES = deferred_kv_architectures()
 
 
 def deferred_kv_eligible(architecture: str, decode_steps: int,
@@ -96,8 +100,8 @@ def deferred_kv_eligible(architecture: str, decode_steps: int,
     flow to both).
     ``architecture`` must be one whose forward takes ``kv_tail``
     (DEFERRED_KV_FAMILIES): the Llama family, every layer of which has
-    a tail, and qwen3_next, whose full-attention layers have one while
-    its recurrent state rides the burst's carry.
+    a tail, and the hybrids, whose attention layers have one while
+    their recurrent state rides the burst's carry.
     Speculative decoding excludes deferral: the verify step must
     write draft KV eagerly so later draft positions attend to
     earlier ones (docs/speculative.md §interactions)."""
@@ -557,13 +561,13 @@ class ModelRunner:
 
         self.cache_layout = config.cache.cache_layout
         # A model with recurrent layers: its per-layer cache tuples
-        # hold state pools where a linear layer has no pages
-        # (models/qwen3_next.py), and every step hands the forward
-        # each row's state slot beside its page table.
+        # hold state pools where a recurrent layer has no pages, as
+        # its family declares them (models/registry.py), and every
+        # step hands the forward each row's state slot beside its
+        # page table.
         self._hybrid = model_config.has_recurrent_state
         if self._hybrid:
-            from production_stack_tpu.models.qwen3_next import init_cache
-            self.k_cache, self.v_cache = init_cache(
+            self.k_cache, self.v_cache = init_hybrid_cache(
                 model_config, config.cache.num_pages,
                 config.cache.page_size, config.cache.num_state_slots)
         elif self.cache_layout == "per_layer":
@@ -1208,19 +1212,20 @@ class ModelRunner:
 
     def read_moe_stats(self) -> Optional[dict]:
         """The expert layer's counters over the decode steps since the
-        last call (models/qwen3_next.MOE_STATS), read from the device
-        and zeroed; None for a model without them or with no decode
-        step to report. Blocks on the last dispatched program, so call
-        it where that program's result has been read already."""
-        if not self._hybrid:
+        last call (the names its family declares: the entry after the
+        layers in ``k_cache``), read from the device and zeroed; None
+        for a model without them or with no decode step to report.
+        Blocks on the last dispatched program, so call it where that
+        program's result has been read already."""
+        names = self.config.model.family.counters
+        if not names:
             return None
-        from production_stack_tpu.models.qwen3_next import MOE_STATS
         values = np.asarray(jax.device_get(self.k_cache[-1]))
         if values[0] == 0:
             return None
         self.k_cache = self.k_cache[:-1] + (
             jnp.zeros_like(self.k_cache[-1]),)
-        return dict(zip(MOE_STATS, (float(v) for v in values)))
+        return dict(zip(names, (float(v) for v in values)))
 
     def _decode_burst_impl(self, params, k_cache, v_cache, tokens,
                            positions, page_table, kv_lens, active,
@@ -1392,11 +1397,12 @@ class ModelRunner:
         The scan carries what changes and closes over what does not.
         Per cache entry: a layer that has pages
         (``not layer_is_linear``) carries its tail; any other entry of
-        a hybrid model's caches (a linear layer's ``S`` pool and
-        convolution tails, the expert counters at the end of
-        ``k_cache``) is read and written every step and rides the
-        carry itself. A model whose every layer has pages carries L
-        tails and nothing else, under either cache layout.
+        a hybrid model's caches (a recurrent layer's state pool and
+        convolution tails, the family's counters at the end of
+        ``k_cache`` where it keeps any) is read and written every step
+        and rides the carry itself. A model whose every layer has
+        pages carries L tails and nothing else, under either cache
+        layout.
 
         The pages hold exactly the pre-burst tokens throughout, so
         the frozen cached-token count is positions[:, 0] (the first
@@ -1413,9 +1419,10 @@ class ModelRunner:
         kv_lens0 = positions[:, 0]  # pages hold this many tokens
         tail_shape = (b, num_steps, m.num_key_value_heads, m.head_dim)
         # Which cache entries are page planes: the layers that are not
-        # linear; a hybrid k_cache ends in the expert counters.
+        # recurrent; a k_cache that ends in its family's counters has
+        # one entry more than that.
         paged = tuple(not linear for linear in m.layer_is_linear) + (
-            False,)
+            False,) * bool(m.family.counters)
         per_layer = isinstance(k_cache, tuple)
 
         def carried(cache):

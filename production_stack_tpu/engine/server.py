@@ -2811,7 +2811,7 @@ def parse_args(argv=None):
                         help="Defer decode KV writes to one batched "
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
-                             "qwen3_next; decode-steps > 1, xla "
+                             "qwen3_next, jamba; decode-steps > 1, xla "
                              "decode, no pp/sp); /version says which "
                              "is served (kv_writes)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)

@@ -252,4 +252,14 @@ def load_weights(model_dir: str, config: ModelConfig,
             "reading a Qwen3-Next checkpoint into this engine's fused "
             "layout is not written yet: serve the architecture with "
             "--random-weights")
+    if config.architecture == "jamba":
+        raise NotImplementedError(
+            "reading a Jamba checkpoint into this engine's stacks "
+            "(the Mamba mixers' and the attention layers' apart, A_log "
+            "transposed) is not written yet: serve the architecture "
+            "with --random-weights")
+    if config.architecture not in ("llama", "mistral", "qwen2"):
+        raise NotImplementedError(
+            f"no reader for a {config.architecture!r} checkpoint, and "
+            "it is not read as a Llama's")
     return load_llama_weights(model_dir, config, dtype)

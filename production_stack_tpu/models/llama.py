@@ -150,6 +150,44 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
                               positions, kv_lens, layer=layer)
 
 
+def hybrid_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
+                     page_table, positions, kv_lens, valid, layer: int,
+                     kv_tail=None):
+    """``cached_attention`` for one attention layer of a hybrid model
+    (per-layer cache tuples in which only some entries are pages),
+    under eager or deferred K/V writes.
+
+    With ``kv_tail`` (a deferred-write decode burst, T == 1) this
+    step's K/V go to the layer's tail, the planes are read and not
+    written, and the updated tails come back in the layer's cache
+    entries; ``kv_lens`` is then the frozen pre-burst count."""
+    if kv_tail is None:
+        return cached_attention(config, q, k, v, k_cache, v_cache,
+                                page_table, positions, kv_lens, valid,
+                                layer)
+    slot, act = positions[:, 0] - kv_lens, valid[:, 0]
+    kt = write_to_tail(kv_tail[0][layer], k, slot, act)
+    vt = write_to_tail(kv_tail[1][layer], v, slot, act)
+    attn = paged_attention(q, k_cache[layer], v_cache[layer], page_table,
+                           positions, kv_lens, k_tail=kt, v_tail=vt)
+    return (attn, k_cache[:layer] + (kt,) + k_cache[layer + 1:],
+            v_cache[:layer] + (vt,) + v_cache[layer + 1:])
+
+
+def hybrid_kernel_impl(config: ModelConfig) -> str:
+    """Which form a hybrid model's own kernels take (a recurrence's
+    decode step over the state pool, a grouped expert product): the
+    Pallas kernels where the attention's are Pallas and a TPU is there
+    to run them (the runner resolves ``auto`` so), their interpreter
+    where the tests ask for it, else plain XLA."""
+    impl = config.attention_impl
+    if impl == "pallas-interpret":
+        return impl
+    if impl.startswith("pallas") and jax.default_backend() == "tpu":
+        return "pallas"
+    return "xla"
+
+
 def slice_layer_params(params: Params, names, layer: int) -> Params:
     """One layer's weights out of the layer-stacked param dict.
 

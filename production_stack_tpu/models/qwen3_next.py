@@ -21,7 +21,8 @@ whose block starts at position 0 starts from a zero state whatever its
 slot holds, so a slot needs no clearing between sequences or before a
 recompute. ``k_cache`` carries one entry more than there are layers:
 ``k_cache[L]``, five float32 counters of the expert layer's decode
-steps that the runner reads and zeroes (``MOE_STATS``). With
+steps that the runner reads and zeroes (the family's ``counters``,
+``models/registry.py``; ``_count`` fills them in that order). With
 ``kv_tail`` (a deferred-write decode burst) the full-attention layers
 append to tails and leave their planes unwritten (``forward``).
 
@@ -43,10 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.engine.config import ModelConfig
-from production_stack_tpu.models.llama import cached_attention
-from production_stack_tpu.ops.attention import (
-    paged_attention,
-    write_to_tail,
+from production_stack_tpu.models.llama import (
+    hybrid_attention,
+    hybrid_kernel_impl,
 )
 from production_stack_tpu.ops.gated_delta import (
     causal_conv,
@@ -59,11 +59,6 @@ from production_stack_tpu.ops.moe import held_experts, route, swiglu
 from production_stack_tpu.ops.rope import apply_rope
 
 Params = Dict[str, jnp.ndarray]
-
-# k_cache[L]: sums over the expert layer's decode steps (T == 1) since
-# the runner last read them.
-MOE_STATS = ("layer_steps", "choices", "held_choices", "max_load",
-             "experts_hit")
 
 COMMON = ("attn_norm", "mlp_norm", "router", "shared_gate_up",
           "shared_down", "shared_gate")
@@ -145,29 +140,6 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
-def init_cache(config: ModelConfig, num_pages: int, page_size: int,
-               num_state_slots: int):
-    """The per-layer cache tuples: page buffers for the full-attention
-    layers, state pools (``num_state_slots`` + the trash slot 0) for
-    the linear ones, and the expert counters as ``k_cache[L]``."""
-    s_shape, tail_shape = config.recurrent_state_shapes()
-    dtype = config.jax_dtype
-    page_shape = (config.num_key_value_heads, num_pages, config.head_dim,
-                  page_size)
-    k_cache, v_cache = [], []
-    for linear in config.layer_is_linear:
-        if linear:
-            k_cache.append(jnp.zeros((num_state_slots + 1,) + s_shape,
-                                     jnp.float32))
-            v_cache.append(jnp.zeros((num_state_slots + 1,) + tail_shape,
-                                     dtype))
-        else:
-            k_cache.append(jnp.zeros(page_shape, dtype))
-            v_cache.append(jnp.zeros(page_shape, dtype))
-    k_cache.append(jnp.zeros((len(MOE_STATS),), jnp.float32))
-    return tuple(k_cache), tuple(v_cache)
-
-
 def _gated_attention(config, lp, x, positions, page_table, kv_lens,
                      valid, k_cache, v_cache, layer, kv_tail=None):
     nh, nkv, d = (config.num_attention_heads, config.num_key_value_heads,
@@ -183,39 +155,12 @@ def _gated_attention(config, lp, x, positions, page_table, kv_lens,
     q = apply_rope(q, positions, config.rope_theta, rotary)
     k = apply_rope(k, positions, config.rope_theta, rotary)
     with jax.named_scope("gated_attn"):
-        if kv_tail is None:
-            attn, k_cache, v_cache = cached_attention(
-                config, q, k, v, k_cache, v_cache, page_table, positions,
-                kv_lens, valid, layer)
-        else:
-            # Deferred writes: this step's K/V go to the layer's tail,
-            # the planes are read and not written, and the tails come
-            # back in the layer's cache slots.
-            slot, act = positions[:, 0] - kv_lens, valid[:, 0]
-            kt = write_to_tail(kv_tail[0][layer], k, slot, act)
-            vt = write_to_tail(kv_tail[1][layer], v, slot, act)
-            attn = paged_attention(
-                q, k_cache[layer], v_cache[layer], page_table, positions,
-                kv_lens, k_tail=kt, v_tail=vt)
-            k_cache = k_cache[:layer] + (kt,) + k_cache[layer + 1:]
-            v_cache = v_cache[:layer] + (vt,) + v_cache[layer + 1:]
+        attn, k_cache, v_cache = hybrid_attention(
+            config, q, k, v, k_cache, v_cache, page_table, positions,
+            kv_lens, valid, layer, kv_tail)
     attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
         attn.dtype)
     return attn.reshape(b, t, nh * d) @ lp["wo"], k_cache, v_cache
-
-
-def kernel_impl(config: ModelConfig) -> str:
-    """Which form the model's own kernels take (the delta rule's
-    decode step, the grouped expert product): the Pallas kernels where
-    the attention's are Pallas and a TPU is there to run them (the
-    runner resolves ``auto`` so), their interpreter where the tests ask
-    for it, else plain XLA."""
-    impl = config.attention_impl
-    if impl == "pallas-interpret":
-        return impl
-    if impl.startswith("pallas") and jax.default_backend() == "tpu":
-        return "pallas"
-    return "xla"
 
 
 def _gated_delta_net(config, lp, x, fresh, valid, slots, s_pool,
@@ -352,7 +297,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
     stats = k_cache[layers]
     k_cache, v_cache = list(k_cache[:layers]), list(v_cache)
     fresh = (positions[:, 0] == 0) & valid[:, 0]
-    impl = kernel_impl(config)
+    impl = hybrid_kernel_impl(config)
 
     x = params["embed"][tokens]
     n_full = n_lin = 0

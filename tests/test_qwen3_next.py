@@ -43,6 +43,7 @@ from production_stack_tpu.engine.kv_cache import (
 )
 from production_stack_tpu.engine.sequence import SamplingParams
 from production_stack_tpu.models import qwen3_next
+from production_stack_tpu.models.registry import init_hybrid_cache
 from production_stack_tpu.ops import gated_delta, moe
 from production_stack_tpu.ops.rope import apply_rope
 
@@ -94,7 +95,7 @@ def test_chunked_prefill_then_decode_agree_with_one_full_forward():
     of every position agree with the reference's one forward."""
     config = model_config()
     params = qwen3_next.init_params(config, jax.random.PRNGKey(0))
-    k_cache, v_cache = qwen3_next.init_cache(config, 32, 16, 4)
+    k_cache, v_cache = init_hybrid_cache(config, 32, 16, 4)
     total, prompt = 56, 50
     tokens = np.asarray(prompt_of(total, seed=1))
     want = reference.log_probs(reference.model_of(config, params),
@@ -293,7 +294,7 @@ def test_head_dim_256_through_the_pallas_kernels_in_interpret_mode():
         config = model_config(head_dim=256, num_hidden_layers=3,
                               attention_impl=impl)
         params = qwen3_next.init_params(config, jax.random.PRNGKey(0))
-        k_cache, v_cache = qwen3_next.init_cache(config, 6, 128, 2)
+        k_cache, v_cache = init_hybrid_cache(config, 6, 128, 2)
         tokens = np.asarray(prompt_of(21, seed=5))
         table = np.array([[1, 2, 0, 0]], np.int32)
         slots = jnp.array([1])

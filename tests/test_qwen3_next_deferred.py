@@ -155,13 +155,17 @@ def burst_scan(runner, deferred):
     return shapes[:consts], shapes[consts:consts + carry]
 
 
-@pytest.mark.parametrize("family", ["qwen3_next", "llama"])
+@pytest.mark.parametrize("family", ["qwen3_next", "jamba", "llama"])
 def test_no_page_plane_rides_the_deferred_scan(family):
     """The planes are constants of the scan and not its carry, which
     is what keeps XLA from copying them around the block loop; of a
     hybrid model's caches the pools and the counters are carried."""
     if family == "qwen3_next":
         runner = hybrid_engine("xla", True).runner
+    elif family == "jamba":
+        import test_jamba_engine
+        runner = LLMEngine(test_jamba_engine.engine_config(
+            deferred_kv_writes=True)).runner
     else:
         from test_deferred_kv import _engine
         runner = _engine(decode_steps=4, deferred=True).runner
@@ -171,8 +175,9 @@ def test_no_page_plane_rides_the_deferred_scan(family):
     consts, carry = burst_scan(runner, deferred=True)
     assert plane not in carry
     assert consts.count(plane) == planes
-    if family == "qwen3_next":
+    if family != "llama":
         linear = runner.config.model.layer_is_linear.index(True)
+        # The last entry: qwen3_next's counters, a Mamba layer's pool.
         for pool in (runner.k_cache[linear], runner.v_cache[linear],
                      runner.k_cache[-1]):
             assert pool.shape in carry and pool.shape not in consts
@@ -181,17 +186,20 @@ def test_no_page_plane_rides_the_deferred_scan(family):
     assert carry.count(plane) == planes and plane not in consts
 
 
-def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell():
+@pytest.mark.parametrize("architecture,file", [
+    ("qwen3_next", "qwen3-next-80b-a3b-ep4.json"),
+    ("jamba", "jamba2-3b.json")])
+def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell(
+        architecture, file):
     from production_stack_tpu.engine.server import (
         _resolve_deferred_kv,
         parse_args,
     )
-    assert deferred_kv_eligible("qwen3_next", 32, "auto")
-    assert not deferred_kv_eligible("qwen3_next", 1, "auto")
+    assert deferred_kv_eligible(architecture, 32, "auto")
+    assert not deferred_kv_eligible(architecture, 1, "auto")
     assert not deferred_kv_eligible("mixtral", 32, "auto")
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench", "configs",
-        "qwen3-next-80b-a3b-ep4.json")
+        os.path.abspath(__file__))), "chipbench", "configs", file)
     with open(path) as f:
         hf = json.load(f)
     flags = hf["chipbench"]["server_flags"]
@@ -201,6 +209,7 @@ def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell():
         argv += [f"--{name}", str(value)]
     args = parse_args(argv)
     config = ModelConfig.from_hf_config(hf)
+    assert config.architecture == architecture
     assert args.deferred_kv_writes == "auto"
     assert _resolve_deferred_kv(args, config) is True
     args.deferred_kv_writes = "off"
