@@ -1396,13 +1396,17 @@ class ModelRunner:
 
         The scan carries what changes and closes over what does not.
         Per cache entry: a layer that has pages
-        (``not layer_is_linear``) carries its tail; any other entry of
-        a hybrid model's caches (a recurrent layer's state pool and
-        convolution tails, the family's counters at the end of
-        ``k_cache`` where it keeps any) is read and written every step
-        and rides the carry itself. A model whose every layer has
-        pages carries L tails and nothing else, under either cache
-        layout.
+        (``not layer_is_linear``) carries its K/V tail; a recurrent
+        layer's convolution tails, where the family's forward takes
+        ``conv_tail``, are gathered from their pool by ``state_slots``
+        before the scan, carried as the rows' K-1 held inputs
+        (``[B, channels]`` each) and scattered back after it; any
+        other entry of a hybrid model's caches (a recurrent layer's
+        state pool, whose kernel works in place by slot; the family's
+        counters at the end of ``k_cache`` where it keeps any) is read
+        and written every step and rides the carry itself. A model
+        whose every layer has pages carries L tails and nothing else,
+        under either cache layout.
 
         The pages hold exactly the pre-burst tokens throughout, so
         the frozen cached-token count is positions[:, 0] (the first
@@ -1418,29 +1422,45 @@ class ModelRunner:
 
         kv_lens0 = positions[:, 0]  # pages hold this many tokens
         tail_shape = (b, num_steps, m.num_key_value_heads, m.head_dim)
-        # Which cache entries are page planes: the layers that are not
-        # recurrent; a k_cache that ends in its family's counters has
-        # one entry more than that.
-        paged = tuple(not linear for linear in m.layer_is_linear) + (
-            False,) * bool(m.family.counters)
+        # What each cache entry is to the burst: page planes where the
+        # layer is not recurrent; of a recurrent layer the state pool
+        # in k_cache and in v_cache the convolution tails, dense in
+        # the carry where the family's forward takes them so; a
+        # k_cache that ends in its family's counters has one entry
+        # more than there are layers.
+        conv = "conv" if m.family.conv_tail else "ride"
+        k_kinds = tuple("ride" if linear else "pages"
+                        for linear in m.layer_is_linear) + (
+            "ride",) * bool(m.family.counters)
+        v_kinds = tuple(conv if linear else "pages"
+                        for linear in m.layer_is_linear)
         per_layer = isinstance(k_cache, tuple)
 
-        def carried(cache):
-            # A tail where the layer has pages, else the entry itself.
+        def carried(cache, kinds):
+            # A K/V tail where the layer has pages, the rows' held
+            # inputs where it has a convolution, else the entry itself.
             if not per_layer:  # stacked: every layer has pages
                 cache = (None,) * m.num_hidden_layers
-            return tuple(jnp.zeros(tail_shape, m.jax_dtype) if p else c
-                         for c, p in zip(cache, paged))
 
-        def served(cache, carry):
+            def entry(c, kind):
+                if kind == "pages":
+                    return jnp.zeros(tail_shape, m.jax_dtype)
+                if kind == "conv":
+                    held = c[state_slots]
+                    return tuple(held[:, j] for j in range(c.shape[1]))
+                return c
+            return tuple(entry(c, k) for c, k in zip(cache, kinds))
+
+        def served(cache, carry, kinds):
             # What the forward reads: the planes from outside the
             # scan, everything else from the carry.
             if not per_layer:
                 return cache
-            return tuple(c if p else s
-                         for c, s, p in zip(cache, carry, paged))
+            return tuple(c if k == "pages" else s
+                         for c, s, k in zip(cache, carry, kinds))
 
-        k_carry0, v_carry0 = carried(k_cache), carried(v_cache)
+        k_carry0 = carried(k_cache, k_kinds)
+        v_carry0 = carried(v_cache, v_kinds)
         sample_step = self._burst_sample_step(
             b, penalties, seeding, bias, suppress, temperature,
             top_p, top_k, stop_tokens, budgets, want_logprobs)
@@ -1450,9 +1470,11 @@ class ModelRunner:
             tok, pos, act, emitted, counts, fs, kt, vt = carry
             logits, kt, vt = self._forward(
                 params, m, tok, pos, page_table, kv_lens0,
-                act[:, None], served(k_cache, kt), served(v_cache, vt),
+                act[:, None], served(k_cache, kt, k_kinds),
+                served(v_cache, vt, v_kinds),
                 lora=lora, lora_ids=lora_ids, kv_tail=(kt, vt),
                 **self._state_kwargs(state_slots),
+                **({"conv_tail": vt} if m.family.conv_tail else {}),
             )
             out, sampled, emitted, counts, act_next, fs = \
                 sample_step(logits, step_rng, act, emitted, counts,
@@ -1470,24 +1492,33 @@ class ModelRunner:
             body, carry, rngs
         )
 
-        # Flush: one batched scatter per paged layer for the whole
-        # burst; the other entries are the carry's last.
+        # Flush: one batched scatter per paged layer and per
+        # convolution tail for the whole burst (a row that stopped
+        # inside it holds the tail it stopped with; padded rows write
+        # to the trash slot); the other entries are the carry's last.
         tail_pos = kv_lens0[:, None] + jnp.arange(num_steps)[None, :]
         tail_valid = (jnp.arange(num_steps)[None, :]
                       < emitted[:, None])
 
-        def flush(cache, carry):
-            if per_layer:
-                return tuple(
-                    write_to_pages(c, s, page_table, tail_pos,
-                                   tail_valid) if p else s
-                    for c, s, p in zip(cache, carry, paged))
-            for l, tail in enumerate(carry):
-                cache = write_to_pages(cache, tail, page_table,
-                                       tail_pos, tail_valid, layer=l)
-            return cache
+        def flush(cache, carry, kinds):
+            if not per_layer:
+                for l, tail in enumerate(carry):
+                    cache = write_to_pages(cache, tail, page_table,
+                                           tail_pos, tail_valid, layer=l)
+                return cache
 
-        return out, flush(k_cache, kt), flush(v_cache, vt)
+            def entry(c, s, kind):
+                if kind == "pages":
+                    return write_to_pages(c, s, page_table, tail_pos,
+                                          tail_valid)
+                if kind == "conv":
+                    return c.at[state_slots].set(jnp.stack(s, axis=1))
+                return s
+            return tuple(entry(c, s, k)
+                         for c, s, k in zip(cache, carry, kinds))
+
+        return (out, flush(k_cache, kt, k_kinds),
+                flush(v_cache, vt, v_kinds))
 
     def _spec_verify_impl(self, params, k_cache, v_cache, tokens,
                           positions, page_table, kv_lens, valid,

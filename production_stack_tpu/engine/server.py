@@ -2195,10 +2195,17 @@ class EngineServer:
     async def version(self, request: web.Request):
         # The device is named by the process that holds it, so a smoke
         # or a benchmark never infers it from logs (chip_smoke.py);
-        # kv_writes says which decode burst the runner compiled.
+        # kv_writes says which decode burst the runner compiled, and
+        # conv_tails, for a family whose recurrent layers hold a
+        # convolution's tail, whether that burst carries the tails
+        # ("burst") or each step goes to the slot pool ("step").
         import jax
         devices = jax.devices()
         obs = getattr(self.engine.runner, "observatory", None)
+        config = self.engine.config
+        deferred = config.scheduler.deferred_kv_writes
+        conv_tails = ({"conv_tails": "burst" if deferred else "step"}
+                      if config.model.family.conv_tail else {})
         return web.json_response({
             "version": __version__,
             "build_id": self.build_id,
@@ -2207,8 +2214,8 @@ class EngineServer:
             "num_devices": len(devices),
             "attention_impl": (obs.attention_impls()
                                if obs is not None else {}),
-            "kv_writes": ("deferred" if self.engine.config.scheduler
-                          .deferred_kv_writes else "eager"),
+            "kv_writes": "deferred" if deferred else "eager",
+            **conv_tails,
         })
 
     async def kv_summary_handler(self, request: web.Request):

@@ -19,7 +19,9 @@ each row's sequence owns (slot 0 is the trash slot of padded rows). A
 row whose block starts at position 0 starts from a zero state whatever
 its slot holds, so a slot needs no clearing. With ``kv_tail`` (a
 deferred-write decode burst) the attention layers append to tails and
-leave their planes unwritten. The family keeps no counters.
+leave their planes unwritten; with ``conv_tail`` the Mamba layers take
+their rows' convolution tails dense from the burst's carry and leave
+the tail pool alone. The family keeps no counters.
 
 Parameters are two stacks beside the common one: ``m_*`` over the Mamba
 layers and ``wq/wk/wv/wo`` over the attention layers, the norms and the
@@ -41,7 +43,7 @@ from production_stack_tpu.models.llama import (
     hybrid_kernel_impl,
     rms_norm,
 )
-from production_stack_tpu.ops.gated_delta import causal_conv
+from production_stack_tpu.ops.gated_delta import slot_causal_conv
 from production_stack_tpu.ops.selective_scan import (
     selective_scan_block,
     selective_scan_step,
@@ -141,23 +143,19 @@ def _attention(config, lp, x, positions, page_table, kv_lens, valid,
 
 
 def _mamba(config, lp, x, fresh, valid, slots, h_pool, tail_pool,
-           impl="xla"):
+           impl="xla", conv_tail=None):
+    """One mixer. With ``conv_tail`` (a deferred burst: this layer's
+    K-1 held inputs, a ``[B, d_inner]`` array each) the shifted ones
+    come back in ``tail_pool``'s place (``slot_causal_conv``)."""
     c = config
     di, n, r = c.mamba_d_inner, c.mamba_d_state, c.mamba_dt_rank
     b, t, _ = x.shape
-    live = valid[:, 0]
     f32 = jnp.float32
 
     xz = x @ lp["m_in"]
     xs, z = xz[..., :di], xz[..., di:]
-    held = tail_pool[slots]
-    xs, new_tail = causal_conv(
-        xs, jnp.where(fresh[:, None, None], 0, held), lp["m_conv"],
-        jnp.sum(valid, axis=1, dtype=jnp.int32))
-    # A row with no real token leaves its slot as it was (its slot is
-    # the trash slot, or a sequence that stopped inside a burst).
-    new_tail = jnp.where(live[:, None, None], new_tail, held)
-    tail_pool = tail_pool.at[slots].set(new_tail)
+    xs, tail_pool = slot_causal_conv(xs, lp["m_conv"], fresh, valid,
+                                     slots, tail_pool, conv_tail)
     xs = jax.nn.silu(xs.astype(f32) + lp["m_conv_b"].astype(f32))
 
     dbc = xs.astype(x.dtype) @ lp["m_x"]
@@ -209,12 +207,14 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, page_table: jnp.ndarray,
             kv_lens: jnp.ndarray, valid: jnp.ndarray,
             k_cache, v_cache, lora=None, lora_ids=None,
-            kv_tail=None, state_slots=None,
+            kv_tail=None, state_slots=None, conv_tail=None,
             ) -> Tuple[jnp.ndarray, tuple, tuple]:
     """Same contract as models.qwen3_next.forward: ``state_slots [B]``
-    (None: every row the trash slot), per-layer caches, and with
+    (None: every row the trash slot), per-layer caches, with
     ``kv_tail`` the attention layers' planes replaced by their updated
-    tails in what comes back. No LoRA targets."""
+    tails in what comes back, and with ``conv_tail`` the Mamba layers'
+    ``v_cache`` entries replaced by their shifted convolution tails.
+    No LoRA targets."""
     if lora is not None:
         raise NotImplementedError("jamba has no LoRA targets")
     if not isinstance(k_cache, (list, tuple)):
@@ -237,7 +237,8 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             n_mamba += 1
             mixed, k_cache[layer], v_cache[layer] = _mamba(
                 config, lp, a_in, fresh, valid, state_slots,
-                k_cache[layer], v_cache[layer], impl)
+                k_cache[layer], v_cache[layer], impl,
+                None if conv_tail is None else conv_tail[layer])
         else:
             lp = {k: params[k][n_attn] for k in ATTENTION}
             n_attn += 1

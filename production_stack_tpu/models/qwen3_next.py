@@ -24,7 +24,9 @@ recompute. ``k_cache`` carries one entry more than there are layers:
 steps that the runner reads and zeroes (the family's ``counters``,
 ``models/registry.py``; ``_count`` fills them in that order). With
 ``kv_tail`` (a deferred-write decode burst) the full-attention layers
-append to tails and leave their planes unwritten (``forward``).
+append to tails and leave their planes unwritten, and with
+``conv_tail`` the linear layers take their rows' convolution tails
+dense from the burst's carry (``forward``).
 
 Parameters are two stacks beside the common one: ``gdn_*`` over the
 linear layers and ``wqg/wk/wv/wo/q_norm/k_norm`` over the full ones,
@@ -49,10 +51,10 @@ from production_stack_tpu.models.llama import (
     hybrid_kernel_impl,
 )
 from production_stack_tpu.ops.gated_delta import (
-    causal_conv,
     gated_delta_chunked,
     gated_delta_step,
     l2_normalize,
+    slot_causal_conv,
 )
 from production_stack_tpu.ops.gated_delta_pallas import gated_delta_decode
 from production_stack_tpu.ops.moe import held_experts, route, swiglu
@@ -164,22 +166,19 @@ def _gated_attention(config, lp, x, positions, page_table, kv_lens,
 
 
 def _gated_delta_net(config, lp, x, fresh, valid, slots, s_pool,
-                     tail_pool, impl="xla"):
+                     tail_pool, impl="xla", conv_tail=None):
+    """One linear layer. With ``conv_tail`` (a deferred burst: this
+    layer's K-1 held inputs, a ``[B, channels]`` array each) the shifted
+    ones come back in ``tail_pool``'s place (``slot_causal_conv``)."""
     c = config
     hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
     dk, dv = c.linear_key_head_dim, c.linear_value_head_dim
     b, t, _ = x.shape
     rep = hv // hk
-    live = valid[:, 0]
 
-    tail = jnp.where(fresh[:, None, None], 0, tail_pool[slots])
-    qkv, new_tail = causal_conv(x @ lp["gdn_qkv"], tail, lp["gdn_conv"],
-                                jnp.sum(valid, axis=1, dtype=jnp.int32))
-    # A row with no real token leaves its slot as it was (its slot is
-    # the trash slot, or a sequence that stopped inside a burst).
-    new_tail = jnp.where(live[:, None, None], new_tail, tail_pool[slots])
-    tail_pool = tail_pool.at[slots].set(new_tail)
-
+    qkv, tail_pool = slot_causal_conv(
+        x @ lp["gdn_qkv"], lp["gdn_conv"], fresh, valid, slots, tail_pool,
+        conv_tail)
     qkv = jax.nn.silu(qkv.astype(jnp.float32))
     q = qkv[..., :hk * dk].reshape(b, t, hk, dk)
     k = qkv[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk)
@@ -269,7 +268,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, page_table: jnp.ndarray,
             kv_lens: jnp.ndarray, valid: jnp.ndarray,
             k_cache, v_cache, lora=None, lora_ids=None,
-            kv_tail=None, state_slots=None,
+            kv_tail=None, state_slots=None, conv_tail=None,
             ) -> Tuple[jnp.ndarray, tuple, tuple]:
     """Same contract as models.llama.forward, with ``state_slots [B]``
     (None: every row the trash slot). No LoRA targets.
@@ -282,9 +281,18 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
     and V to its tails, attends over its page planes, which it does
     not write, and the tails, with ``kv_lens`` the frozen pre-burst
     count; the caches come back with each full layer's planes replaced
-    by its updated tails. The linear layers' pools and the counters are
-    read and written every step either way. The runner flushes the
-    tails to the planes once a burst."""
+    by its updated tails. The linear layers' ``S`` pools and the
+    counters are read and written every step either way. The runner
+    flushes the tails to the planes once a burst.
+
+    ``conv_tail`` (the same burst) is indexed by layer and read at the
+    linear layers alone: the K-1 inputs each row's convolution holds,
+    a ``[B, channels]`` array each, oldest first, which the runner
+    gathered from the tail pool before the burst. A linear layer then
+    shifts them in one pass (``causal_conv_step``), its ``v_cache``
+    entry comes back replaced by the shifted ones, and the tail pool is
+    neither read nor written; the runner scatters them back once a
+    burst."""
     if lora is not None:
         raise NotImplementedError("qwen3_next has no LoRA targets")
     if not isinstance(k_cache, (list, tuple)):
@@ -310,7 +318,8 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             n_lin += 1
             mixed, k_cache[layer], v_cache[layer] = _gated_delta_net(
                 config, lp, a_in, fresh, valid, state_slots,
-                k_cache[layer], v_cache[layer], impl)
+                k_cache[layer], v_cache[layer], impl,
+                None if conv_tail is None else conv_tail[layer])
         else:
             lp = {k: params[k][n_full] for k in FULL}
             n_full += 1

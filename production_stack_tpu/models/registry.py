@@ -14,6 +14,11 @@ and the runner ask the family and name no model:
   ``(shape, dtype name)`` entries, the first kept in the layer's
   ``k_cache`` entry and the second in its ``v_cache`` entry
   (``"model"`` is the model's own dtype);
+- ``conv_tail``: the second of those is the tail of a short causal
+  convolution, ``[K-1, channels]``, and the forward takes
+  ``conv_tail``: in a deferred-write burst the runner gathers each
+  row's tail from the pool once, carries it dense and scatters it
+  back once (``ops/gated_delta.py`` ``causal_conv_step``);
 - ``counters``: names of the float32 counters the forward keeps in one
   extra ``k_cache`` entry after the layers (none: no such entry);
 - ``refusals``: in the family's own words, why it refuses the features
@@ -37,6 +42,7 @@ class Family:
     deferred_kv: bool = False
     recurrent_layers: Optional[Callable] = None
     state: Optional[Callable] = None
+    conv_tail: bool = False
     counters: Tuple[str, ...] = ()
     refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
 
@@ -87,6 +93,7 @@ FAMILIES: Dict[str, Family] = {
     "qwen3_next": Family(
         "qwen3_next", deferred_kv=True,
         recurrent_layers=_qwen3_next_layers, state=_qwen3_next_state,
+        conv_tail=True,
         counters=("layer_steps", "choices", "held_choices", "max_load",
                   "experts_hit"),
         refusals={
@@ -98,6 +105,7 @@ FAMILIES: Dict[str, Family] = {
     "jamba": Family(
         "jamba", deferred_kv=True,
         recurrent_layers=_jamba_layers, state=_jamba_state,
+        conv_tail=True,
         refusals={
             "tensor parallelism": "the state pools and the Mamba "
                                   "mixer have no sharding rules",

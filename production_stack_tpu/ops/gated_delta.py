@@ -61,6 +61,65 @@ def causal_conv(x: jnp.ndarray, tail: jnp.ndarray, w: jnp.ndarray,
     return y.astype(x.dtype), new_tail.astype(tail.dtype)
 
 
+def causal_conv_step(x: jnp.ndarray, tail, w: jnp.ndarray,
+                     live: jnp.ndarray):
+    """``causal_conv`` for one token a row (a decode step), with the
+    tail held as its K-1 rows, so that shifting it moves no row.
+
+    Args:
+      x:    [B, C] this step's inputs
+      tail: K-1 arrays [B, C], the inputs before this one, oldest first
+      w:    [K, C]; ``w[K-1]`` weighs the current token
+      live: [B] bool, False for a row with no real token
+
+    Returns (y [B, C] without activation, the new tail: shifted by one
+    where the row is live, as it was where it is not). The products and
+    the order of the sum are ``causal_conv``'s, so at T = 1 the two
+    agree to the bit.
+    """
+    k = w.shape[0]
+    rows = tuple(t.astype(x.dtype) for t in tail) + (x,)
+    w32 = w.astype(jnp.float32)
+    y = sum(rows[j].astype(jnp.float32) * w32[j] for j in range(k))
+    new_tail = tuple(
+        jnp.where(live[:, None], rows[j + 1], rows[j]).astype(tail[j].dtype)
+        for j in range(k - 1))
+    return y.astype(x.dtype), new_tail
+
+
+def slot_causal_conv(x: jnp.ndarray, w: jnp.ndarray, fresh: jnp.ndarray,
+                     valid: jnp.ndarray, slots: jnp.ndarray,
+                     tail_pool: jnp.ndarray, conv_tail=None):
+    """A recurrent layer's convolution over a block ``x [B, T, C]``
+    whose tails are kept a sequence in ``tail_pool [slots, K-1, C]``.
+
+    A row whose block starts its sequence (``fresh [B]``) starts from a
+    zero tail whatever its slot holds; a row with no real token
+    (``valid [B, T]``) leaves its tail as it was (its slot is the trash
+    slot, or a sequence that stopped inside a burst).
+
+    Without ``conv_tail`` every row's tail is gathered from the pool by
+    ``slots [B]`` and scattered back: returns (y, the pool). With
+    ``conv_tail`` (a deferred-write decode burst, T == 1: the rows' K-1
+    held inputs, a ``[B, C]`` array each, oldest first, which the
+    runner gathered before the burst and scatters back after it) those
+    are read and shifted in one pass and the pool is neither read nor
+    written: returns (y, the new held inputs).
+    """
+    live = valid[:, 0]
+    if conv_tail is not None:
+        y, conv_tail = causal_conv_step(
+            x[:, 0], tuple(jnp.where(fresh[:, None], 0, row)
+                           for row in conv_tail), w, live)
+        return y[:, None], conv_tail
+    held = tail_pool[slots]
+    y, new_tail = causal_conv(
+        x, jnp.where(fresh[:, None, None], 0, held), w,
+        jnp.sum(valid, axis=1, dtype=jnp.int32))
+    new_tail = jnp.where(live[:, None, None], new_tail, held)
+    return y, tail_pool.at[slots].set(new_tail)
+
+
 def l2_normalize(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)

@@ -1,9 +1,9 @@
 """Deferred K/V writes for a model that keeps a recurrent state beside
 its pages (Qwen3-Next): the burst keeps each step's K/V of the
 full-attention layers in a tail and flushes once, while the linear
-layers' pools, the convolution tails and the expert counters go on
-being read and written every step (the Llama family's cases:
-tests/test_deferred_kv.py).
+layers' state pools and the expert counters go on being read and
+written every step (the Llama family's cases: tests/test_deferred_kv.py;
+the convolution tails, carried dense: tests/test_conv_tails_burst.py).
 
 Tiny widths, float32, on the CPU, in the two forms the model's own
 kernels take: plain XLA, and the Pallas kernels in interpret mode
@@ -129,9 +129,9 @@ def test_the_expert_counters_ride_the_deferred_burst(form):
     assert engine.runner.read_moe_stats() is None          # zeroed
 
 
-def burst_scan(runner, deferred):
-    """The scan of the runner's burst program, traced on the runner's
-    own caches: (shapes of its constants, shapes of its carry)."""
+def burst_jaxpr(runner, deferred):
+    """The runner's burst program, four rows and four steps, traced on
+    the runner's own caches."""
     b, steps = 4, 4
     pages = runner.config.scheduler.max_model_len // \
         runner.config.cache.page_size
@@ -140,7 +140,7 @@ def burst_scan(runner, deferred):
             else runner._decode_burst_impl)
     state = ({"state_slots": row(jnp.int32)}
              if runner.config.model.has_recurrent_state else {})
-    jaxpr = jax.make_jaxpr(functools.partial(
+    return jax.make_jaxpr(functools.partial(
         impl, num_steps=steps, **state))(
         runner.params, runner.k_cache, runner.v_cache,
         jnp.zeros((b, 1), jnp.int32), jnp.zeros((b, 1), jnp.int32),
@@ -148,6 +148,12 @@ def burst_scan(runner, deferred):
         row(jnp.int32), jnp.full((b, 1), -1, jnp.int32),
         row(jnp.float32), row(jnp.float32), row(jnp.int32),
         jax.random.PRNGKey(0), None, None, None, None, None, None, None)
+
+
+def burst_scan(runner, deferred):
+    """The scan of that program: (shapes of its constants, shapes of
+    its carry)."""
+    jaxpr = burst_jaxpr(runner, deferred)
     scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
     consts = scan.params["num_consts"]
     carry = scan.params["num_carry"]
@@ -159,7 +165,8 @@ def burst_scan(runner, deferred):
 def test_no_page_plane_rides_the_deferred_scan(family):
     """The planes are constants of the scan and not its carry, which
     is what keeps XLA from copying them around the block loop; of a
-    hybrid model's caches the pools and the counters are carried."""
+    hybrid model's caches the state pools and the counters are
+    carried."""
     if family == "qwen3_next":
         runner = hybrid_engine("xla", True).runner
     elif family == "jamba":
@@ -178,9 +185,12 @@ def test_no_page_plane_rides_the_deferred_scan(family):
     if family != "llama":
         linear = runner.config.model.layer_is_linear.index(True)
         # The last entry: qwen3_next's counters, a Mamba layer's pool.
-        for pool in (runner.k_cache[linear], runner.v_cache[linear],
-                     runner.k_cache[-1]):
+        for pool in (runner.k_cache[linear], runner.k_cache[-1]):
             assert pool.shape in carry and pool.shape not in consts
+        # The convolution tails' pool is read before the scan and
+        # written after it (tests/test_conv_tails_burst.py).
+        tails = runner.v_cache[linear].shape
+        assert tails not in carry and tails not in consts
     # The eager burst carries them: the guard can tell the two apart.
     consts, carry = burst_scan(runner, deferred=False)
     assert carry.count(plane) == planes and plane not in consts
