@@ -36,7 +36,7 @@ class ModelConfig:
 
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
-    # (models/registry.py FAMILIES)
+    # | lfm2_moe (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -99,6 +99,18 @@ class ModelConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # LFM2-MoE hybrid decoders (architecture == "lfm2_moe",
+    # models/lfm2_moe.py). ``layer_types`` lists every layer as "conv"
+    # (a gated short convolution, which keeps per sequence the last
+    # conv_L_cache - 1 inputs of its convolution and nothing else) or
+    # "full_attention" (QK-normed GQA over pages); the published order
+    # follows no period. The first num_dense_layers feed-forwards are
+    # SwiGLU MLPs of intermediate_size, the rest routed experts
+    # (num_experts held, as above) chosen by sigmoid score plus a
+    # learned bias and weighted by the scores alone over their sum.
+    layer_types: tuple = ()
+    conv_L_cache: int = 3
+    num_dense_layers: int = 0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -169,10 +181,11 @@ class ModelConfig:
     def recurrent_state_shapes(self):
         """One sequence's state in one recurrent layer, as its family
         declares it: the shapes of the recurrence's own state
-        (float32) and of the causal convolution's tail of inputs
-        (model dtype)."""
-        (state, _), (tail, _) = self.family.state(self)
-        return state, tail
+        (float32; None for a layer that keeps none) and of the causal
+        convolution's tail of inputs (model dtype)."""
+        from production_stack_tpu.models.registry import state_pools
+        return tuple(entry and tuple(entry[0])
+                     for entry in state_pools(self))
 
     def recurrent_state_bytes(self) -> int:
         """Bytes of one sequence's recurrent state over all recurrent
@@ -315,6 +328,78 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "lfm2moe" in arch.replace("_", ""):
+            ep = int(hf.get("expert_parallel_size", 1))
+            rank = int(hf.get("expert_parallel_rank", 0))
+            if not 0 <= rank < ep:
+                raise ValueError(
+                    f"expert_parallel_rank {rank} is not one of "
+                    f"expert_parallel_size {ep} blocks")
+            layer_types = tuple(hf["layer_types"])
+            other = sorted(set(layer_types) - {"conv", "full_attention"})
+            refused = [why for bad, why in (
+                (bool(other),
+                 f"layer_types entries {other}: a layer is served as "
+                 "'conv' (the gated short convolution) or "
+                 "'full_attention' (causal attention over the paged "
+                 "cache), and no other kind has a path"),
+                (len(layer_types) != hf["num_hidden_layers"],
+                 f"layer_types lists {len(layer_types)} layers and "
+                 f"num_hidden_layers says {hf['num_hidden_layers']}"),
+                (bool(hf.get("conv_bias", False)),
+                 "conv_bias true: the convolution and its two "
+                 "projections are served without a bias"),
+                (hf.get("rope_scaling") is not None,
+                 "rope_scaling: the rotary embedding is served "
+                 "unscaled"),
+                (not 0 <= hf.get("num_dense_layers", 0)
+                 <= hf["num_hidden_layers"],
+                 f"num_dense_layers {hf.get('num_dense_layers')} of "
+                 f"{hf['num_hidden_layers']} layers"),
+                (not hf.get("use_expert_bias", True),
+                 "use_expert_bias false: the experts are chosen by "
+                 "score plus the learned bias"),
+                (not hf.get("norm_topk_prob", True),
+                 "norm_topk_prob false: the chosen experts' scores are "
+                 "divided by their sum"),
+                (float(hf.get("routed_scaling_factor", 1.0)) != 1.0,
+                 f"routed_scaling_factor "
+                 f"{hf.get('routed_scaling_factor')}: the routed sum "
+                 "is served unscaled"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "LFM2-MoE config this engine does not serve: "
+                    + "; ".join(refused))
+            return cls(
+                name=name or hf.get("_name_or_path", "lfm2-moe"),
+                architecture="lfm2_moe",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf["intermediate_size"],
+                num_hidden_layers=hf["num_hidden_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                num_key_value_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim"),
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 128000),
+                rms_norm_eps=hf.get("norm_eps", 1e-5),
+                rope_theta=hf.get("rope_theta", 1e6),
+                # The family's convention: the head is the embedding.
+                tie_word_embeddings=hf.get("tie_word_embeddings", True),
+                layer_types=layer_types,
+                conv_L_cache=hf.get("conv_L_cache", 3),
+                num_dense_layers=hf.get("num_dense_layers", 2),
+                # The count this engine holds; the router's width is
+                # this times expert_parallel_size.
+                num_experts=hf["num_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                activation="silu",
+                dtype="bfloat16",
+            )
         if "mixtral" in arch:
             return cls(
                 name=name or hf.get("_name_or_path", "mixtral"),
@@ -358,7 +443,8 @@ class ModelConfig:
             raise ValueError(
                 f"architecture {arch!r} is none this engine serves "
                 "(config.json 'architectures', else 'model_type'): it "
-                "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba and the "
+                "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba, LFM2-MoE "
+                "and the "
                 f"Llama shapes {sorted(_LLAMA_SHAPES)}, and reads no "
                 "other as one of them")
         qwen = "qwen2" in arch
@@ -981,6 +1067,9 @@ INTERNAL_FIELDS = {
     "model.mamba_d_conv",
     "model.mamba_expand",
     "model.mamba_dt_rank",
+    "model.layer_types",
+    "model.conv_L_cache",
+    "model.num_dense_layers",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -1072,6 +1161,38 @@ def tiny_jamba_config() -> ModelConfig:
         mamba_d_conv=4,
         mamba_expand=2,
         mamba_dt_rank=4,
+        dtype="float32",
+    )
+
+
+def tiny_lfm2_moe_config(expert_parallel_size: int = 1,
+                         expert_parallel_rank: int = 0) -> ModelConfig:
+    """A tiny LFM2-MoE (both layer kinds in an order no period gives,
+    one dense feed-forward before the expert layers, held experts of a
+    wider router, two query heads a KV head) for tests that run
+    anywhere."""
+    return ModelConfig(
+        name="tiny-lfm2-moe",
+        architecture="lfm2_moe",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_hidden_layers=5,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        rope_theta=1e6,
+        tie_word_embeddings=True,
+        layer_types=("conv", "full_attention", "conv", "conv",
+                     "full_attention"),
+        conv_L_cache=3,
+        num_dense_layers=1,
+        num_experts=8 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
         dtype="float32",
     )
 

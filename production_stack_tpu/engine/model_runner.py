@@ -562,7 +562,8 @@ class ModelRunner:
         self.cache_layout = config.cache.cache_layout
         # A model with recurrent layers: its per-layer cache tuples
         # hold state pools where a recurrent layer has no pages, as
-        # its family declares them (models/registry.py), and every
+        # its family declares them (models/registry.py: one or two a
+        # layer, None where it declares none), and every
         # step hands the forward each row's state slot beside its
         # page table.
         self._hybrid = model_config.has_recurrent_state
@@ -1424,10 +1425,11 @@ class ModelRunner:
         tail_shape = (b, num_steps, m.num_key_value_heads, m.head_dim)
         # What each cache entry is to the burst: page planes where the
         # layer is not recurrent; of a recurrent layer the state pool
-        # in k_cache and in v_cache the convolution tails, dense in
-        # the carry where the family's forward takes them so; a
-        # k_cache that ends in its family's counters has one entry
-        # more than there are layers.
+        # in k_cache (None, which rides as nothing, where its family
+        # declares the tail alone) and in v_cache the convolution
+        # tails, dense in the carry where the family's forward takes
+        # them so; a k_cache that ends in its family's counters has
+        # one entry more than there are layers.
         conv = "conv" if m.family.conv_tail else "ride"
         k_kinds = tuple("ride" if linear else "pages"
                         for linear in m.layer_is_linear) + (

@@ -2818,8 +2818,9 @@ def parse_args(argv=None):
                         help="Defer decode KV writes to one batched "
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
-                             "qwen3_next, jamba; decode-steps > 1, xla "
-                             "decode, no pp/sp); /version says which "
+                             "qwen3_next, jamba, lfm2_moe; decode-steps "
+                             "> 1, xla decode, no pp/sp); /version "
+                             "says which "
                              "is served (kv_writes)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)
     parser.add_argument("--pipeline-parallel-size", type=int, default=1,

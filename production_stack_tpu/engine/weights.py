@@ -258,6 +258,12 @@ def load_weights(model_dir: str, config: ModelConfig,
             "(the Mamba mixers' and the attention layers' apart, A_log "
             "transposed) is not written yet: serve the architecture "
             "with --random-weights")
+    if config.architecture == "lfm2_moe":
+        raise NotImplementedError(
+            "reading an LFM2-MoE checkpoint into this engine's stacks "
+            "(the conv, attention, dense and expert layers' apart, "
+            "gate | up fused) is not written yet: serve the "
+            "architecture with --random-weights")
     if config.architecture not in ("llama", "mistral", "qwen2"):
         raise NotImplementedError(
             f"no reader for a {config.architecture!r} checkpoint, and "

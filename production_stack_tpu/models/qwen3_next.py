@@ -22,7 +22,8 @@ slot holds, so a slot needs no clearing between sequences or before a
 recompute. ``k_cache`` carries one entry more than there are layers:
 ``k_cache[L]``, five float32 counters of the expert layer's decode
 steps that the runner reads and zeroes (the family's ``counters``,
-``models/registry.py``; ``_count`` fills them in that order). With
+``models/registry.py``; ``ops/moe.py`` ``count_step`` fills them in that
+order). With
 ``kv_tail`` (a deferred-write decode burst) the full-attention layers
 append to tails and leave their planes unwritten, and with
 ``conv_tail`` the linear layers take their rows' convolution tails
@@ -57,7 +58,12 @@ from production_stack_tpu.ops.gated_delta import (
     slot_causal_conv,
 )
 from production_stack_tpu.ops.gated_delta_pallas import gated_delta_decode
-from production_stack_tpu.ops.moe import held_experts, route, swiglu
+from production_stack_tpu.ops.moe import (
+    count_step,
+    held_experts,
+    route,
+    swiglu,
+)
 from production_stack_tpu.ops.rope import apply_rope
 
 Params = Dict[str, jnp.ndarray]
@@ -252,18 +258,6 @@ def sparse_block(config: ModelConfig, lp, x, valid, moe_impl="xla"):
     return y.reshape(b, t, h), load
 
 
-def _count(stats, config, load, valid):
-    """Add one decode step of one expert layer to the counters."""
-    rows = jnp.sum(valid).astype(jnp.float32)
-    return stats + jnp.stack([
-        jnp.float32(1.0),
-        rows * config.num_experts_per_tok,
-        jnp.sum(load).astype(jnp.float32),
-        jnp.max(load).astype(jnp.float32),
-        jnp.sum(load > 0).astype(jnp.float32),
-    ])
-
-
 def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, page_table: jnp.ndarray,
             kv_lens: jnp.ndarray, valid: jnp.ndarray,
@@ -331,7 +325,8 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         m_in = rms_norm(x, common["mlp_norm"], config.rms_norm_eps)
         y, load = sparse_block(config, common, m_in, valid, impl)
         if t == 1:
-            stats = _count(stats, config, load, valid)
+            stats = count_step(stats, config.num_experts_per_tok, load,
+                               valid)
         x = x + y
 
     x = rms_norm(x, params["final_norm"], config.rms_norm_eps)

@@ -10,11 +10,13 @@ and the runner ask the family and name no model:
 - ``recurrent_layers(config)``: per layer, True where the layer keeps a
   recurrent state a sequence (in a slot of the state pool,
   ``engine/kv_cache.py``) and no pages;
-- ``state(config)``: one sequence's state in one such layer as two
-  ``(shape, dtype name)`` entries, the first kept in the layer's
-  ``k_cache`` entry and the second in its ``v_cache`` entry
-  (``"model"`` is the model's own dtype);
-- ``conv_tail``: the second of those is the tail of a short causal
+- ``state(config)``: one sequence's state in one such layer as one
+  or two ``(shape, dtype name)`` entries (``"model"`` is the model's
+  own dtype). The last is kept in the layer's ``v_cache`` entry; the
+  one before it, a recurrence's own state, in its ``k_cache`` entry,
+  which is ``None`` for a family that declares one entry: no pool is
+  made, read or written for it (``state_pools``);
+- ``conv_tail``: the last of those is the tail of a short causal
   convolution, ``[K-1, channels]``, and the forward takes
   ``conv_tail``: in a deferred-write burst the runner gathers each
   row's tail from the pool once, carries it dense and scatters it
@@ -81,6 +83,22 @@ def _jamba_state(c) -> tuple:
             ((c.mamba_d_conv - 1, c.mamba_d_inner), "model"))
 
 
+def _lfm2_moe_layers(c) -> tuple:
+    """A gated short convolution where ``layer_types`` says ``conv``,
+    attention where it says ``full_attention``: the published list,
+    which no period reproduces."""
+    return tuple(kind == "conv" for kind in c.layer_types)
+
+
+def _lfm2_moe_state(c) -> tuple:
+    """The tail of the convolution over the hidden channels, and
+    nothing else: the layer has no recurrence of its own."""
+    return (((c.conv_L_cache - 1, c.hidden_size), "model"),)
+
+
+_EXPERT_COUNTERS = ("layer_steps", "choices", "held_choices", "max_load",
+                    "experts_hit")
+
 _LLAMA = Family("llama", deferred_kv=True)
 
 FAMILIES: Dict[str, Family] = {
@@ -94,8 +112,7 @@ FAMILIES: Dict[str, Family] = {
         "qwen3_next", deferred_kv=True,
         recurrent_layers=_qwen3_next_layers, state=_qwen3_next_state,
         conv_tail=True,
-        counters=("layer_steps", "choices", "held_choices", "max_load",
-                  "experts_hit"),
+        counters=_EXPERT_COUNTERS,
         refusals={
             "tensor parallelism": "the state pools and the expert "
                                   "layer have no sharding rules",
@@ -111,6 +128,17 @@ FAMILIES: Dict[str, Family] = {
                                   "mixer have no sharding rules",
             "weight quantization": "the Mamba mixer's projections "
                                    "have no quantized form",
+        }),
+    "lfm2_moe": Family(
+        "lfm2_moe", deferred_kv=True,
+        recurrent_layers=_lfm2_moe_layers, state=_lfm2_moe_state,
+        conv_tail=True, counters=_EXPERT_COUNTERS,
+        refusals={
+            "tensor parallelism": "the convolution's tail pool and the "
+                                  "expert layer have no sharding rules",
+            "weight quantization": "the convolution's fused projection "
+                                   "and the experts have no quantized "
+                                   "form",
         }),
 }
 
@@ -138,26 +166,49 @@ def deferred_kv_architectures() -> tuple:
     return tuple(a for a, f in FAMILIES.items() if f.deferred_kv)
 
 
+def state_pools(config) -> tuple:
+    """One sequence's state in one recurrent layer as ``(k entry, v
+    entry)``, each ``(shape, dtype name)`` or ``None`` where the family
+    keeps nothing there: the one reading of ``Family.state``'s one or
+    two entries that the configuration, the cache builder and the
+    runner share."""
+    entries = tuple(family(config.architecture).state(config))
+    if not 1 <= len(entries) <= 2:
+        raise ValueError(
+            f"{config.architecture} declares {len(entries)} state "
+            "entries a recurrent layer; a family declares one (kept in "
+            "v_cache) or two (k_cache, v_cache)")
+    return (None,) * (2 - len(entries)) + entries
+
+
 def init_hybrid_cache(config, num_pages: int, page_size: int,
                       num_state_slots: int):
     """A hybrid family's per-layer cache tuples: page buffers for the
-    attention layers, the two state pools (``num_state_slots`` + the
-    trash slot 0) for the recurrent ones, and after the layers the
-    family's counters, if it keeps any, as one more ``k_cache`` entry."""
+    attention layers, the state pools it declares (``num_state_slots``
+    + the trash slot 0; ``None`` where it declares none) for the
+    recurrent ones, and after the layers the family's counters, if it
+    keeps any, as one more ``k_cache`` entry."""
     import jax.numpy as jnp
 
     fam = family(config.architecture)
     model_dtype = config.jax_dtype
-    pools = [((num_state_slots + 1,) + shape,
-              model_dtype if dtype == "model" else jnp.dtype(dtype))
-             for shape, dtype in fam.state(config)]
+
+    def pool(entry):
+        if entry is None:
+            return None
+        shape, dtype = entry
+        return jnp.zeros(
+            (num_state_slots + 1,) + tuple(shape),
+            model_dtype if dtype == "model" else jnp.dtype(dtype))
+
+    k_entry, v_entry = state_pools(config)
     page_shape = (config.num_key_value_heads, num_pages, config.head_dim,
                   page_size)
     k_cache, v_cache = [], []
     for recurrent in fam.recurrent_layers(config):
         if recurrent:
-            k_cache.append(jnp.zeros(*pools[0]))
-            v_cache.append(jnp.zeros(*pools[1]))
+            k_cache.append(pool(k_entry))
+            v_cache.append(pool(v_entry))
         else:
             k_cache.append(jnp.zeros(page_shape, model_dtype))
             v_cache.append(jnp.zeros(page_shape, model_dtype))

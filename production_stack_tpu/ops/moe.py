@@ -1,7 +1,9 @@
 """A routed expert layer that is told which experts it holds.
 
 The router scores every expert of the model (``router_w`` is as wide
-as the published count) and a token keeps its ``top_k`` choices. This
+as the published count) and a token keeps its ``top_k`` choices
+(``route``: softmax over all experts; ``route_sigmoid``: a sigmoid an
+expert, chosen with a learned bias and weighted without it). This
 engine holds one contiguous block of the experts,
 ``[first_expert, first_expert + E)``, and computes for each token the
 part of the weighted sum that its held choices give; what experts held
@@ -46,6 +48,23 @@ def route(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
     if norm_topk:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, ids
+
+
+def route_sigmoid(x: jnp.ndarray, router_w: jnp.ndarray,
+                  expert_bias: jnp.ndarray, top_k: int):
+    """``route`` for a router that scores each expert alone: a sigmoid
+    over ALL experts in float32; the ``top_k`` largest of score +
+    ``expert_bias`` (learned, [E_all] float32) are chosen, and their
+    weights are the scores WITHOUT the bias, divided by their sum +
+    1e-6 as the published ``lfm2_moe`` code has it.
+
+    x [N, H], router_w [H, E_all] -> (weights [N, k] f32, ids [N, k]).
+    """
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32))
+    _, ids = jax.lax.top_k(scores + expert_bias, top_k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    return weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6), ids
 
 
 def _grouped_dot(lhs, rhs, group_sizes, impl: str):
@@ -115,6 +134,22 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
         y = jnp.sum(out[inverse].reshape(n, top_k, -1), axis=1,
                     dtype=jnp.float32)
         return y.astype(x.dtype), load
+
+
+def count_step(stats: jnp.ndarray, top_k: int, load: jnp.ndarray,
+               valid: jnp.ndarray) -> jnp.ndarray:
+    """Add one decode step of one expert layer to a family's five
+    float32 counters (``models/registry.py``: layer_steps, choices,
+    held_choices, max_load, experts_hit). ``load [E]`` is
+    ``held_experts``'; ``valid`` marks the real rows."""
+    rows = jnp.sum(valid).astype(jnp.float32)
+    return stats + jnp.stack([
+        jnp.float32(1.0),
+        rows * top_k,
+        jnp.sum(load).astype(jnp.float32),
+        jnp.max(load).astype(jnp.float32),
+        jnp.sum(load > 0).astype(jnp.float32),
+    ])
 
 
 def swiglu(x, w_gate_up, w_down):

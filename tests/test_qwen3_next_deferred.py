@@ -161,7 +161,8 @@ def burst_scan(runner, deferred):
     return shapes[:consts], shapes[consts:consts + carry]
 
 
-@pytest.mark.parametrize("family", ["qwen3_next", "jamba", "llama"])
+@pytest.mark.parametrize("family", ["qwen3_next", "jamba", "lfm2_moe",
+                                    "llama"])
 def test_no_page_plane_rides_the_deferred_scan(family):
     """The planes are constants of the scan and not its carry, which
     is what keeps XLA from copying them around the block loop; of a
@@ -169,9 +170,10 @@ def test_no_page_plane_rides_the_deferred_scan(family):
     carried."""
     if family == "qwen3_next":
         runner = hybrid_engine("xla", True).runner
-    elif family == "jamba":
-        import test_jamba_engine
-        runner = LLMEngine(test_jamba_engine.engine_config(
+    elif family in ("jamba", "lfm2_moe"):
+        import importlib
+        runner = LLMEngine(importlib.import_module(
+            f"test_{family}_engine").engine_config(
             deferred_kv_writes=True)).runner
     else:
         from test_deferred_kv import _engine
@@ -184,9 +186,11 @@ def test_no_page_plane_rides_the_deferred_scan(family):
     assert consts.count(plane) == planes
     if family != "llama":
         linear = runner.config.model.layer_is_linear.index(True)
-        # The last entry: qwen3_next's counters, a Mamba layer's pool.
+        # The last entry: the expert counters, a Mamba layer's pool;
+        # a layer that keeps the tail alone has no k entry to ride.
         for pool in (runner.k_cache[linear], runner.k_cache[-1]):
-            assert pool.shape in carry and pool.shape not in consts
+            if pool is not None:
+                assert pool.shape in carry and pool.shape not in consts
         # The convolution tails' pool is read before the scan and
         # written after it (tests/test_conv_tails_burst.py).
         tails = runner.v_cache[linear].shape
@@ -198,7 +202,8 @@ def test_no_page_plane_rides_the_deferred_scan(family):
 
 @pytest.mark.parametrize("architecture,file", [
     ("qwen3_next", "qwen3-next-80b-a3b-ep4.json"),
-    ("jamba", "jamba2-3b.json")])
+    ("jamba", "jamba2-3b.json"),
+    ("lfm2_moe", "lfm2-8b-a1b-ep4.json")])
 def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell(
         architecture, file):
     from production_stack_tpu.engine.server import (
