@@ -77,13 +77,13 @@ SMOKE_CONFIGS = {
 }
 
 # What ``--attention-impl auto`` is documented to serve (README
-# "Attention kernels"): on a TPU XLA decode, the Pallas prefill kernel,
+# "Attention kernels"): on a TPU the Pallas decode and prefill kernels
 # and a Pallas impl for the unified step; XLA everywhere on a CPU, and
 # under tensor parallelism (GSPMD cannot partition a Mosaic call).
 XLA_EVERYWHERE = {"decode": ("xla",), "prefill": ("xla",),
                   "unified": ("xla",)}
 EXPECTED_IMPLS = {
-    "tpu": {"decode": ("xla",), "prefill": ("pallas",),
+    "tpu": {"decode": ("pallas",), "prefill": ("pallas",),
             "unified": ("pallas", "pallas_ragged")},
     "cpu": XLA_EVERYWHERE,
 }

@@ -61,15 +61,14 @@ logger = init_logger(__name__)
 # full set; the burst merely speculates a little further.
 STOP_SET_WIDTH = 16
 
-# What attention_impl='auto' serves on a TPU besides the prefill
-# kernel. False: decode runs the XLA gather path and the unified step
-# composes the prefill kernel; neither Pallas kernel is probed. An
-# explicit 'pallas' (or attention_impl_unified='pallas_ragged') skips
-# both and probes and serves them. Neither rests on a number from the
-# driver: the capture behind them was a builder's, ctx 2k-16k at batch
-# 8-32, before PR 28's block gather. ROADMAP S2 / D2 own the
-# re-measurement on a cell.
-PALLAS_DECODE_IN_AUTO = False
+# What attention_impl='auto' serves for the unified step on a TPU
+# besides the composed prefill kernel. False: the fused ragged kernel
+# is not probed under 'auto'; an explicit 'pallas' (or
+# attention_impl_unified='pallas_ragged') probes and serves it. It
+# rests on no number from the driver: the capture behind it was a
+# builder's, ctx 2k-16k at batch 8-32, before PR 28's block gather.
+# ROADMAP S2 / D2 own the re-measurement on a cell. (The decode and the
+# prefill kernels are served under 'auto' wherever they compile.)
 PALLAS_RAGGED_IN_AUTO = False
 
 # Compiled top-logprobs width: OpenAI allows top_logprobs 0-20 but a
@@ -88,16 +87,18 @@ DEFERRED_KV_FAMILIES = deferred_kv_architectures()
 
 
 def deferred_kv_eligible(architecture: str, decode_steps: int,
-                         attention_impl: str, pipeline_parallel: int = 1,
+                         pipeline_parallel: int = 1,
                          context_parallel: int = 1,
                          speculative_k: int = 0) -> bool:
     """The ONE eligibility predicate for deferred KV writes.
 
     Used by the runner's capability guard (which raises on explicit
     ineligible 'on') and the server's '--deferred-kv-writes auto'
-    resolution — one definition so the two cannot drift (e.g.
-    re-enabling Pallas decode in 'auto' or adding an exclusion must
-    flow to both).
+    resolution — one definition so the two cannot drift (an added
+    exclusion must flow to both). Every decode attention form serves
+    the burst's tail (models/llama.py ``deferred_attention``: the XLA
+    form and the Pallas paged decode kernel), so the attention impl
+    does not enter.
     ``architecture`` must be one whose forward takes ``kv_tail``
     (DEFERRED_KV_FAMILIES): the Llama family, every layer of which has
     a tail, and the hybrids, whose attention layers have one while
@@ -107,7 +108,6 @@ def deferred_kv_eligible(architecture: str, decode_steps: int,
     earlier ones (docs/speculative.md §interactions)."""
     return (decode_steps > 1
             and architecture in DEFERRED_KV_FAMILIES
-            and attention_impl in ("xla", "auto")
             and pipeline_parallel == 1
             and context_parallel == 1
             and speculative_k == 0)
@@ -366,11 +366,9 @@ class ModelRunner:
             # shapes: decode and prefill degrade to XLA independently
             # (round-2 failure mode was a *global* fallback that threw
             # away the working decode kernel when prefill didn't
-            # compile). Lowering runs Pallas's Mosaic rules (tiling,
-            # layouts, scalar prefetch) without burning a full compile.
-            # Under ``auto`` PALLAS_DECODE_IN_AUTO also decides, not
-            # lowering success alone. An explicit "pallas" skips the
-            # constant (operator override).
+            # compile). Under ``auto`` each kernel is served where it
+            # compiles; an explicit "pallas" that cannot be honoured
+            # is a start-up error.
             self._resolve_pallas_impls(model_config, config,
                                        auto_impl=auto_impl)
         logger.info(
@@ -481,12 +479,6 @@ class ModelRunner:
                     "deferred_kv_writes serves "
                     f"{', '.join(DEFERRED_KV_FAMILIES)} (got "
                     f"{model_config.architecture!r})")
-            decode_impl = (model_config.attention_impl_decode
-                           or model_config.attention_impl)
-            if decode_impl not in ("xla", "auto"):
-                raise NotImplementedError(
-                    "deferred_kv_writes uses the XLA paged+tail "
-                    f"attention path (decode impl {decode_impl!r})")
 
         if params is None and model_config.quantization == "int8":
             # Direct int8 init: full-precision init + quantize peaks
@@ -1020,34 +1012,17 @@ class ModelRunner:
                               auto_impl: bool = False) -> None:
         """Probe each Pallas kernel's TPU lowering at serving shapes.
 
-        Under attention_impl='auto' (``auto_impl``) the prefill kernel
-        is served where it lowers, and the decode kernel only where
-        PALLAS_DECODE_IN_AUTO also admits it (a builder's capture, not
-        a driver's number: see the constant). An explicit 'pallas'
-        skips the constant, and a kernel it cannot serve is a
-        start-up error.
+        Under attention_impl='auto' (``auto_impl``) each kernel is
+        served where it compiles and falls back to XLA, with the
+        reason logged, where it does not; under an explicit 'pallas' a
+        kernel that cannot be served is a start-up error. The decode
+        kernel is probed in the form the burst calls: with the tails
+        of a deferred-write burst where those are on.
         """
-        nh, nkv, d = (model_config.num_attention_heads,
-                      model_config.num_key_value_heads,
-                      model_config.head_dim)
-        dtype = model_config.jax_dtype
-        max_pages = config.scheduler.max_pages_per_seq(
-            config.cache.page_size)
-        # Probe the exact serving form. Stacked layout: the full
-        # stacked cache with a dynamic layer index (models pass layer
-        # through SMEM prefetch). Per-layer layout: one layer's buffer
-        # with no layer operand.
-        if config.cache.cache_layout == "per_layer":
-            cache_shape = (nkv, config.cache.num_pages, d,
-                           config.cache.page_size)
-            layer0 = None
-        else:
-            cache_shape = (model_config.num_hidden_layers, nkv,
-                           config.cache.num_pages, d,
-                           config.cache.page_size)
-            layer0 = jax.ShapeDtypeStruct((), np.int32)
-        cache = (quant_cache_struct(cache_shape) if self.kv_quantized
-                 else jax.ShapeDtypeStruct(cache_shape, dtype))
+        # The exact serving form of the cache (_probe_cache_struct).
+        nh, d, dtype, max_pages, cache, layer0 = \
+            self._probe_cache_struct(model_config, config)
+        nkv = model_config.num_key_value_heads
 
         berr = pallas_backend_error(config)
         if berr is not None:
@@ -1069,12 +1044,17 @@ class ModelRunner:
         )
         b = config.scheduler.max_num_seqs
         pb = config.scheduler.prefill_batch_size
+        rows_i32 = jax.ShapeDtypeStruct((b,), np.int32)
+        tail = (jax.ShapeDtypeStruct(
+            (b, config.scheduler.decode_steps, nkv, d), dtype)
+            if config.scheduler.deferred_kv_writes else None)
         probes = {
             "decode": [(
                 paged_decode_attention,
                 (jax.ShapeDtypeStruct((b, nh, d), dtype), cache, cache,
                  jax.ShapeDtypeStruct((b, max_pages), np.int32),
-                 jax.ShapeDtypeStruct((b,), np.int32), layer0),
+                 rows_i32, layer0, tail, tail,
+                 None if tail is None else rows_i32),
             )],
             # Serving compiles one prefill program per bucket — probe
             # them all, not just the widest (a Mosaic rule can fail at
@@ -1090,17 +1070,6 @@ class ModelRunner:
                 config.scheduler.prefill_chunk_size)],
         }
         for name, cases in probes.items():
-            if (auto_impl and name == "decode"
-                    and not PALLAS_DECODE_IN_AUTO):
-                # Skip the lowering probe too, so startup neither
-                # burns a compile nor logs a lowering error for a
-                # path that was never going to serve.
-                model_config.attention_impl_decode = "xla"
-                logger.info(
-                    "Decode attention: XLA (Pallas decode is not "
-                    "served under 'auto'; --attention-impl pallas "
-                    "forces it)")
-                continue
             err = next(
                 (e for fn, shapes in cases
                  for e in [self._lowering_error(fn, *shapes)]

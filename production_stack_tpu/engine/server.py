@@ -948,7 +948,8 @@ class EngineServer:
                 ],
             }
 
-        async def consume_choice(seq_id, stream, on_delta=None):
+        async def consume_choice(seq_id, stream, on_delta=None,
+                                 on_idle=None):
             """Drain one sequence's stream with stop-string scanning.
 
             Returns (text, n_tokens, finish_reason, lp_content);
@@ -957,6 +958,10 @@ class EngineServer:
             positions consumed since the previous emit (the
             detokenizer may buffer partial UTF-8, so text deltas and
             token positions align only at emit points).
+            ``on_idle()`` is awaited whenever the stream has nothing
+            more to hand over at once: a decode burst's tokens arrive
+            together (AsyncEngine._hand_over), and the streaming side
+            puts their frames on the wire in one write there.
 
             Logprob entries are released by CHARACTER accounting: a
             token's entry joins logprobs.content only once its decoded
@@ -1011,6 +1016,8 @@ class EngineServer:
 
             try:
                 while True:
+                    if on_idle is not None and stream.empty():
+                        await on_idle()
                     out = await stream.get()
                     if out.new_token is not None:
                         n_tokens += 1
@@ -1174,37 +1181,53 @@ class EngineServer:
             return f": checkpoint {json.dumps(desc)}\n\n".encode()
 
         async def stream_choice(index, seq_id, stream):
+            # Frames of this choice not yet on the wire. One socket
+            # write a frame was over half of what the event loop spent
+            # on a token, and the loop is what bounds a full batch of
+            # short steps (PERF.md, PR 38): the frames of one hand-over
+            # go out together once the stream has no more to give.
+            pending: List[bytes] = []
+
+            async def flush():
+                if pending:
+                    frames = b"".join(pending)
+                    pending.clear()
+                    async with write_lock:
+                        await resp.write(frames)
+
             async def on_delta(text, lps):
+                pending.append(sse(chunk(index, text, None, lps=lps)))
+                if not relay_ckpt:
+                    return
+                # A resume descriptor follows the frame it describes.
+                await flush()
+                ckpt = self.engine.take_checkpoint(seq_id)
+                if ckpt is None:
+                    return
                 async with write_lock:
-                    await resp.write(sse(chunk(index, text, None,
-                                               lps=lps)))
-                    if relay_ckpt:
-                        ckpt = self.engine.take_checkpoint(seq_id)
-                        if ckpt is not None:
-                            await resp.write(ckpt_frame(ckpt))
-                            if self.migrate_drain:
-                                # Migrate-mode drain (docs/fleet.md):
-                                # the frame just written is the full
-                                # resume state, so cut the connection
-                                # abruptly — a clean EOF would read as
-                                # a finished stream, while an abrupt
-                                # close makes the router resume it on
-                                # another replica byte-exactly.
-                                tracer = self.engine.tracer
-                                if tracer is not None:
-                                    tracer.event(seq_id, "migrate_ship")
-                                # In-band marker: the router's config
-                                # watcher polls too slowly to classify
-                                # this cut as a migration on its own.
-                                await resp.write(b": migrating\n\n")
-                                if request.transport is not None:
-                                    request.transport.close()
+                    await resp.write(ckpt_frame(ckpt))
+                    if self.migrate_drain:
+                        # Migrate-mode drain (docs/fleet.md): the frame
+                        # just written is the full resume state, so cut
+                        # the connection abruptly — a clean EOF would
+                        # read as a finished stream, while an abrupt
+                        # close makes the router resume it on another
+                        # replica byte-exactly.
+                        tracer = self.engine.tracer
+                        if tracer is not None:
+                            tracer.event(seq_id, "migrate_ship")
+                        # In-band marker: the router's config watcher
+                        # polls too slowly to classify this cut as a
+                        # migration on its own.
+                        await resp.write(b": migrating\n\n")
+                        if request.transport is not None:
+                            request.transport.close()
 
             _, n_toks, finish_reason, _ = await consume_choice(
-                seq_id, stream, on_delta=on_delta)
+                seq_id, stream, on_delta=on_delta, on_idle=flush)
             completion_tokens[index] = n_toks
-            async with write_lock:
-                await resp.write(sse(chunk(index, None, finish_reason)))
+            pending.append(sse(chunk(index, None, finish_reason)))
+            await flush()
 
         tasks = [asyncio.ensure_future(stream_choice(i, sid, stream))
                  for i, (sid, stream) in enumerate(subs)]
@@ -2522,8 +2545,8 @@ def _resolve_deferred_kv(args, model_config) -> bool:
     )
     return deferred_kv_eligible(
         model_config.architecture, args.decode_steps,
-        args.attention_impl, args.pipeline_parallel_size,
-        args.context_parallel_size, args.speculative_k)
+        args.pipeline_parallel_size, args.context_parallel_size,
+        args.speculative_k)
 
 
 def _resolve_async_scheduling(args) -> bool:
@@ -2749,8 +2772,8 @@ def parse_args(argv=None):
     parser.add_argument("--attention-impl", default="auto",
                         choices=["auto", "xla", "pallas",
                                  "pallas-interpret"],
-                        help="auto = the Pallas prefill kernel "
-                             "where it compiles, XLA decode "
+                        help="auto = the Pallas decode and prefill "
+                             "kernels where each compiles, else XLA "
                              "(model_runner)")
     parser.add_argument("--quantization", default="none",
                         choices=["none", "int8"],
@@ -2819,7 +2842,7 @@ def parse_args(argv=None):
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
                              "qwen3_next, jamba, lfm2_moe; decode-steps "
-                             "> 1, xla decode, no pp/sp); /version "
+                             "> 1, no pp/sp); /version "
                              "says which "
                              "is served (kv_writes)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)

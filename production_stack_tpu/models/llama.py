@@ -150,6 +150,44 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
                               positions, kv_lens, layer=layer)
 
 
+def deferred_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
+                       page_table, positions, kv_lens, valid, layer: int,
+                       kv_tail):
+    """One attention layer of a deferred-write decode burst (T == 1):
+    this step's K/V go to the layer's tail, and the query attends the
+    row's pages and the tail in one softmax.
+
+    The planes are read and never written (the runner flushes the
+    tails once a burst), ``kv_lens`` is the frozen pre-burst count, and
+    ``k_cache``/``v_cache`` are the stacked [L, ...] caches or tuples of
+    per-layer buffers. Under the pallas decode impl the pages are read
+    in place by the paged decode kernel and the tail's state is merged
+    beside it; the XLA form gathers them. Shared by the Llama family's
+    forward and the hybrids' attention layers.
+
+    Returns ``(attn, k_tail, v_tail)``: the layer's updated tails."""
+    slot, act = positions[:, 0] - kv_lens, valid[:, 0]
+    kt = write_to_tail(kv_tail[0][layer], k, slot, act)
+    vt = write_to_tail(kv_tail[1][layer], v, slot, act)
+    per_layer = isinstance(k_cache, (list, tuple))
+    kc, vc = ((k_cache[layer], v_cache[layer]) if per_layer
+              else (k_cache, v_cache))
+    plane = None if per_layer else layer
+    impl = config.attention_impl_decode or config.attention_impl
+    if impl.startswith("pallas"):
+        from production_stack_tpu.ops.paged_attention_pallas import (
+            paged_decode_attention,
+        )
+        attn = paged_decode_attention(
+            q[:, 0], kc, vc, page_table, kv_lens, layer=plane,
+            k_tail=kt, v_tail=vt, q_positions=positions[:, 0],
+            interpret=impl == "pallas-interpret")[:, None]
+    else:
+        attn = paged_attention(q, kc, vc, page_table, positions, kv_lens,
+                               layer=plane, k_tail=kt, v_tail=vt)
+    return attn, kt, vt
+
+
 def hybrid_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
                      page_table, positions, kv_lens, valid, layer: int,
                      kv_tail=None):
@@ -165,11 +203,9 @@ def hybrid_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
         return cached_attention(config, q, k, v, k_cache, v_cache,
                                 page_table, positions, kv_lens, valid,
                                 layer)
-    slot, act = positions[:, 0] - kv_lens, valid[:, 0]
-    kt = write_to_tail(kv_tail[0][layer], k, slot, act)
-    vt = write_to_tail(kv_tail[1][layer], v, slot, act)
-    attn = paged_attention(q, k_cache[layer], v_cache[layer], page_table,
-                           positions, kv_lens, k_tail=kt, v_tail=vt)
+    attn, kt, vt = deferred_attention(
+        config, q, k, v, k_cache, v_cache, page_table, positions,
+        kv_lens, valid, layer, kv_tail)
     return (attn, k_cache[:layer] + (kt,) + k_cache[layer + 1:],
             v_cache[:layer] + (vt,) + v_cache[layer + 1:])
 
@@ -329,18 +365,9 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         k = apply_rope(k, positions, config.rope_theta)
         if kv_tail is not None:
             k_tails, v_tails = kv_tail
-            slot = positions[:, 0] - kv_lens
-            act = valid[:, 0]
-            kt = write_to_tail(k_tails[layer], k, slot, act)
-            vt = write_to_tail(v_tails[layer], v, slot, act)
-            kc, vc = ((k_cache[layer], v_cache[layer])
-                      if isinstance(k_cache, (list, tuple))
-                      else (k_cache, v_cache))
-            attn = paged_attention(
-                q, kc, vc, page_table, positions, kv_lens,
-                layer=None if isinstance(k_cache, (list, tuple))
-                else layer,
-                k_tail=kt, v_tail=vt)
+            attn, kt, vt = deferred_attention(
+                config, q, k, v, k_cache, v_cache, page_table,
+                positions, kv_lens, valid, layer, kv_tail)
             k_tails = (tuple(k_tails[:layer]) + (kt,)
                        + tuple(k_tails[layer + 1:]))
             v_tails = (tuple(v_tails[:layer]) + (vt,)

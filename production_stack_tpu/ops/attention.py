@@ -190,6 +190,37 @@ def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
     return jnp.where(hit[..., None, None], new_kv, tail)
 
 
+def tail_softmax_state(qg: jnp.ndarray, k_tail: jnp.ndarray,
+                       v_tail: jnp.ndarray, q_positions: jnp.ndarray,
+                       kv_lens: jnp.ndarray):
+    """The softmax's running state over a burst tail alone.
+
+    ``qg`` is the grouped query [B, T, kv, group, d]; the tail's S
+    un-flushed tokens sit at positions ``kv_lens + s`` and stay full
+    precision. Returns the running maximum and sum [B, kv, group, T]
+    and the weighted values [B, kv, group, T, d], float32: what
+    ``paged_attention`` starts its blocks from, and what the Pallas
+    decode form (ops/paged_attention_pallas.py) merges its pages'
+    state with."""
+    head_dim = qg.shape[-1]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, dtype=jnp.float32))
+    s_len = k_tail.shape[1]
+    t_scores = jnp.einsum(
+        "btkgd,bskd->bkgts", qg, k_tail,
+        preferred_element_type=jnp.float32,
+    ) * scale  # [B, kv, group, T, S]
+    tail_pos = (kv_lens[:, None]
+                + jnp.arange(s_len)[None, :])  # [B, S]
+    t_mask = (tail_pos[:, None, :]
+              <= q_positions[:, :, None])  # [B, T, S]
+    t_scores = jnp.where(t_mask[:, None, None], t_scores, NEG_INF)
+    m = t_scores.max(axis=-1)
+    t_probs = jnp.exp(t_scores - m[..., None])
+    return (m, t_probs.sum(axis=-1), jnp.einsum(
+        "bkgts,bskd->bkgtd", t_probs.astype(v_tail.dtype), v_tail,
+        preferred_element_type=jnp.float32))
+
+
 def paged_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                     v_cache_layer: jnp.ndarray, page_table: jnp.ndarray,
                     q_positions: jnp.ndarray,
@@ -308,23 +339,8 @@ def paged_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
 
     stat = (b, num_kv_heads, group, t)
     if k_tail is not None:
-        # Burst tail: S un-flushed tokens at positions kv_lens + s;
-        # the tail itself stays full precision.
-        s_len = k_tail.shape[1]
-        t_scores = jnp.einsum(
-            "btkgd,bskd->bkgts", qg, k_tail,
-            preferred_element_type=jnp.float32,
-        ) * scale  # [B, kv, group, T, S]
-        tail_pos = (kv_lens[:, None]
-                    + jnp.arange(s_len)[None, :])  # [B, S]
-        t_mask = (tail_pos[:, None, :]
-                  <= q_positions[:, :, None])  # [B, T, S]
-        t_scores = jnp.where(t_mask[:, None, None], t_scores, NEG_INF)
-        m = t_scores.max(axis=-1)
-        t_probs = jnp.exp(t_scores - m[..., None])
-        carry = (m, t_probs.sum(axis=-1), jnp.einsum(
-            "bkgts,bskd->bkgtd", t_probs.astype(v_tail.dtype), v_tail,
-            preferred_element_type=jnp.float32))
+        carry = tail_softmax_state(qg, k_tail, v_tail, q_positions,
+                                   kv_lens)
     else:
         carry = (jnp.full(stat, NEG_INF, jnp.float32),
                  jnp.zeros(stat, jnp.float32),

@@ -1,16 +1,23 @@
 """Shared grid/index-map + DMA layer for the paged-KV Pallas kernels.
 
-The prefill (ops/prefill_attention_pallas.py) and decode
-(ops/paged_attention_pallas.py) kernels are the same machine with a
-different query block: grid (batch, kv_head), the whole page walk
-inside one kernel instance as a static unroll, KV pages double-buffer
-DMA'd from HBM in bursts of C token-minor pages, int8 dequant scales
-streamed alongside as (1, page_size) tiles, flash-style online
-softmax in VMEM scratch. Historically each kernel carried its own
-copy of that machinery; this module is the single definition both
-import (the unified ragged step rides the same layer — see
-docs/unified_step.md). Kernel-specific remains only the query layout
+The prefill (ops/prefill_attention_pallas.py) and the unified ragged
+(ops/ragged_attention_pallas.py, docs/unified_step.md) kernels are the
+same machine with a different query block: grid (batch, kv_head), the
+whole page walk inside one kernel instance as a static unroll, KV
+pages double-buffer DMA'd from HBM in bursts of C token-minor pages,
+int8 dequant scales streamed alongside as (1, page_size) tiles,
+flash-style online softmax in VMEM scratch. Historically each kernel
+carried its own copy of that machinery; this module is the single
+definition both import. Kernel-specific remains only the query layout
 and the score mask.
+
+The decode kernel (ops/paged_attention_pallas.py) shares the
+wrapper-level helpers (operand unwrap, table padding, the stacked
+form's pass-through aliasing) and walks the pages its own way: one
+instance a row over all kv heads, its buffers and semaphores carried
+across grid steps, which ``make_page_dma``/``run_page_walk``'s
+(row, kv head) instances cannot express. They are left as the prefill
+and the ragged kernel lower them.
 
 Everything here is either called at trace time from inside a
 pallas_call kernel body (the closures built by ``make_page_dma`` /

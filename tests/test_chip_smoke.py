@@ -240,17 +240,17 @@ def test_explicit_unified_impl_that_cannot_be_served_fails(monkeypatch):
 
 
 @pytest.mark.parametrize("page_size, attention_impl, served, probed", [
-    # 'auto': the prefill kernel where it compiles, composed for the
-    # unified step; neither constant admits the decode or the fused
-    # ragged kernel, so neither is compiled.
+    # 'auto': the decode and the prefill kernel where each compiles,
+    # the prefill kernel composed for the unified step; the constant
+    # does not admit the fused ragged kernel, so it is not compiled.
     (128, "auto",
-     {"decode": "xla", "prefill": "pallas", "unified": "pallas"},
-     {"paged_prefill_attention"}),
+     {"decode": "pallas", "prefill": "pallas", "unified": "pallas"},
+     {"paged_decode_attention", "paged_prefill_attention"}),
     # The default page size cannot serve any kernel: XLA at all three
     # sites, and nothing compiled to find that out.
     (16, "auto",
      {"decode": "xla", "prefill": "xla", "unified": "xla"}, set()),
-    # An explicit 'pallas' skips both constants.
+    # An explicit 'pallas' skips the constant.
     (128, "pallas",
      {"decode": "pallas", "prefill": "pallas",
       "unified": "pallas_ragged"},

@@ -26,8 +26,10 @@ from production_stack_tpu.engine.sequence import SamplingParams
 
 def _engine(decode_steps, deferred=False, max_num_seqs=4, arch="llama",
             quantization=None, cache_layout="auto", max_model_len=256,
-            num_pages=128, prefill_chunk_size=32):
+            num_pages=128, prefill_chunk_size=32, attention_impl=None):
     model = tiny_model_config(arch)
+    if attention_impl:
+        model.attention_impl = attention_impl
     if quantization:
         model.quantization = quantization
     config = EngineConfig(
@@ -204,3 +206,131 @@ def test_deferred_longer_row_joining_compiles_no_new_burst():
     _gen(engine, short + _prompts(sizes=(1100,), seed=6), max_tokens=16)
     assert obs.compile_events_total("decode_burst") == warm
     assert engine.runner.last_attn_pages == 128
+
+
+# ---- the Pallas paged decode kernel serves the deferred burst -------------
+
+
+@pytest.mark.parametrize("layout", ["per_layer", "stacked"])
+def test_deferred_pallas_decode_matches_the_xla_burst(layout):
+    """The deferred burst through the paged decode kernel (interpret
+    mode) gives the XLA burst's greedy tokens, rows crossing a page's
+    edge inside a burst and over several bursts, and flushes the same
+    tails to the pages."""
+    prompts = _prompts(sizes=(7, 20, 41))
+
+    def run(impl):
+        engine = _engine(decode_steps=4, deferred=True,
+                         cache_layout=layout, attention_impl=impl)
+        return _gen(engine, prompts), engine.runner
+
+    want, xla = run("xla")
+    got, pallas = run("pallas-interpret")
+    assert got == want
+    assert pallas.observatory.attention_impls()["decode"] == \
+        "pallas-interpret"
+    for name in ("k_cache", "v_cache"):
+        for w, g in zip(getattr(xla, name), getattr(pallas, name)):
+            # Page 0 is the trash page.
+            w, g = np.asarray(w)[..., 1:, :, :], np.asarray(g)[..., 1:, :, :]
+            assert np.abs(w).max() > 0
+            np.testing.assert_allclose(g, w, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["auto", "xla", "pallas",
+                                  "pallas-interpret"])
+def test_auto_defers_whatever_the_attention_impl(impl):
+    """No decode form refuses the burst's tail, so the flag's 'auto'
+    resolves on under every --attention-impl."""
+    from production_stack_tpu.engine.server import (
+        _resolve_deferred_kv,
+        parse_args,
+    )
+    args = parse_args(["--model", "tiny-llama", "--random-weights",
+                       "--decode-steps", "8", "--attention-impl", impl])
+    assert args.deferred_kv_writes == "auto"
+    assert _resolve_deferred_kv(args, tiny_model_config("llama")) is True
+    args.decode_steps = 1
+    assert _resolve_deferred_kv(args, tiny_model_config("llama")) is False
+
+
+def _tpu_runner(monkeypatch, lowering_error, attention_impl, errors):
+    """A deferred-write runner as a TPU host would build it, the
+    kernels' compile probes stubbed; what it logs as an error goes to
+    ``errors``."""
+    import jax
+
+    from production_stack_tpu.engine import model_runner
+    from production_stack_tpu.engine.model_runner import ModelRunner
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(model_runner.logger, "error",
+                        lambda msg, *args: errors.append(msg % args))
+    monkeypatch.setattr(ModelRunner, "_lowering_error",
+                        staticmethod(lowering_error))
+    model = tiny_model_config("llama")
+    model.attention_impl = attention_impl
+    config = EngineConfig(
+        model=model,
+        cache=CacheConfig(page_size=128, num_pages=32),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_model_len=256,
+                                  prefill_chunk_size=64, decode_steps=8,
+                                  deferred_kv_writes=True))
+    return ModelRunner(config)
+
+
+def test_auto_probes_the_decode_kernel_in_the_bursts_form(monkeypatch):
+    """Under 'auto' on a TPU the decode kernel is compiled at the
+    serving shapes as the prefill kernel is, with the tails the
+    deferred burst hands it, and served where it compiles."""
+    seen, errors = {}, []
+
+    def compiles(fn, *args):
+        seen.setdefault(fn.__name__, []).append(args)
+        return None
+
+    runner = _tpu_runner(monkeypatch, compiles, "auto", errors)
+    assert runner.observatory.attention_impls()["decode"] == "pallas"
+    assert runner.observatory.attention_impls()["prefill"] == "pallas"
+    (q, _, _, table, lens, layer, k_tail, v_tail, q_pos), = \
+        seen["paged_decode_attention"]
+    kv, d = (runner.config.model.num_key_value_heads,
+             runner.config.model.head_dim)
+    assert q.shape[0] == 4 and table.shape == (4, 2) and layer is None
+    assert k_tail.shape == v_tail.shape == (4, 8, kv, d)
+    assert q_pos.shape == lens.shape == (4,)
+    assert not errors
+
+
+def test_auto_falls_back_to_xla_decode_where_the_kernel_does_not_compile(
+        monkeypatch):
+    errors = []
+
+    def only_prefill_compiles(fn, *args):
+        return ("Mosaic says no"
+                if fn.__name__ == "paged_decode_attention" else None)
+
+    runner = _tpu_runner(monkeypatch, only_prefill_compiles, "auto",
+                         errors)
+    assert runner.observatory.attention_impls()["decode"] == "xla"
+    assert runner.observatory.attention_impls()["prefill"] == "pallas"
+    assert any("DECODE" in e and "Mosaic says no" in e for e in errors)
+    # The explicit form cannot be served: a start-up error.
+    with pytest.raises(RuntimeError, match="Mosaic says no"):
+        _tpu_runner(monkeypatch, only_prefill_compiles, "pallas", errors)
+
+
+def test_the_constant_that_kept_the_decode_kernel_out_of_auto_is_gone():
+    import os
+
+    from production_stack_tpu.engine import model_runner
+
+    assert not hasattr(model_runner, "PALLAS_DECODE_IN_AUTO")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = "PALLAS_" + "DECODE_IN_AUTO"
+    for path in ("README.md", "tutorials/12-long-context-serving.md",
+                 "production_stack_tpu/engine/model_runner.py",
+                 "production_stack_tpu/engine/config.py",
+                 "chip_smoke.py"):
+        with open(os.path.join(root, path)) as f:
+            assert name not in f.read(), path

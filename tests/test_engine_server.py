@@ -588,6 +588,62 @@ def test_completions_echo_streaming():
     asyncio.run(_with_client(run))
 
 
+def test_a_bursts_frames_share_one_write():
+    """A decode burst hands a row's tokens over together; the stream
+    keeps one frame a token and puts the frames of one hand-over on
+    the wire in one write (a write a frame was over half of the event
+    loop's cost a token: PERF.md, PR 38)."""
+    from aiohttp import web
+
+    config = EngineConfig(
+        model=tiny_model_config("llama"),
+        cache=CacheConfig(page_size=16, num_pages=128),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_model_len=256,
+                                  prefill_chunk_size=64, decode_steps=8),
+    )
+    from production_stack_tpu.engine.tokenizer import BenchTokenizer
+    # An id from 258 up decodes to one visible character.
+    server = EngineServer(
+        LLMEngine(config, tokenizer=BenchTokenizer(
+            config.model.vocab_size)), "tiny-llama")
+    writes = []
+    real_write = web.StreamResponse.write
+
+    async def counting_write(self, data):
+        writes.append(bytes(data))
+        return await real_write(self, data)
+
+    async def run():
+        client = TestClient(TestServer(server.build_app()))
+        await client.start_server()
+        try:
+            web.StreamResponse.write = counting_write
+            resp = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": "abc", "stream": True,
+                "max_tokens": 33, "temperature": 0, "ignore_eos": True,
+            })
+            body = (await resp.read()).decode()
+        finally:
+            web.StreamResponse.write = real_write
+            await client.close()
+        frames = [line for line in body.splitlines()
+                  if line.startswith("data: ")]
+        assert frames[-1] == "data: [DONE]"
+        chunks = [json.loads(f[len("data: "):]) for f in frames[:-1]]
+        assert chunks[-1]["choices"][0]["finish_reason"] == "length"
+        # One frame a token that has text of its own (an id under 258
+        # is a byte the detokenizer may hold back), then the finish.
+        assert 16 <= len(chunks) <= 34
+        # 33 tokens are the prefill's and four bursts of eight: their
+        # frames take a write a hand-over, not a write a frame.
+        with_frames = [w for w in writes if w.startswith(b"data: {")]
+        assert b"".join(writes).decode() == body
+        assert len(with_frames) <= 7 < len(chunks)
+        assert max(w.count(b"data: {") for w in with_frames) >= 4
+
+    asyncio.run(run())
+
+
 def test_stream_options_include_usage():
     """OpenAI stream_options.include_usage: a final pre-[DONE] chunk
     with empty choices and aggregate usage."""

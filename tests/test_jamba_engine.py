@@ -67,19 +67,25 @@ def greedy(engine, prompts, max_tokens=9):
 
 
 @pytest.mark.parametrize("form", ["eager", "deferred",
-                                  "deferred pallas-interpret"])
+                                  "deferred pallas-interpret",
+                                  "deferred pallas-interpret-decode"])
 def test_engine_prefill_chunks_and_bursts_agree_with_the_reference(form):
     """Through the scheduler, the cache manager and the decode burst:
     six prompts over four rows (more sequences than rows: two wait for
     a row and take a slot another left full), prompts of up to three
     chunks, bursts of four steps; the top log-probabilities of every
     answer agree."""
-    # The Pallas kernels in interpret mode beside XLA decode attention
-    # is what ``auto`` resolves on the chip (the deferred burst attends
-    # through ``paged_attention``).
-    model = model_config(**(dict(attention_impl="pallas-interpret",
-                                 attention_impl_decode="xla")
-                            if "pallas" in form else {}))
+    # The Pallas kernels in interpret mode: ``-decode`` is what
+    # ``auto`` resolves on the chip (the deferred burst attends through
+    # the paged decode kernel, the tail's state merged beside it), the
+    # other what it falls back to where the decode probe fails (the
+    # burst attends through ``paged_attention``).
+    over = {}
+    if "pallas" in form:
+        over = dict(attention_impl="pallas-interpret")
+        if not form.endswith("-decode"):
+            over["attention_impl_decode"] = "xla"
+    model = model_config(**over)
     engine = LLMEngine(engine_config(
         model, deferred_kv_writes=form.startswith("deferred")))
     prompts = [prompt_of(n, seed=n) for n in (70, 20, 45, 33, 64, 12)]

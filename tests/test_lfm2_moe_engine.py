@@ -71,6 +71,7 @@ def greedy(engine, prompts, max_tokens=9):
 
 @pytest.mark.parametrize("form", ["eager", "deferred",
                                   "deferred pallas-interpret",
+                                  "deferred pallas-interpret-decode",
                                   "deferred rank 1 of 2"])
 def test_engine_prefill_chunks_and_bursts_agree_with_the_reference(form):
     """Through the scheduler, the cache manager and the decode burst:
@@ -81,10 +82,13 @@ def test_engine_prefill_chunks_and_bursts_agree_with_the_reference(form):
     upper half of the experts: the reference is given the same share."""
     over = {}
     if "pallas" in form:
-        # What ``auto`` resolves on the chip: the Pallas kernels beside
-        # XLA decode attention.
-        over = dict(attention_impl="pallas-interpret",
-                    attention_impl_decode="xla")
+        # ``-decode`` is what ``auto`` resolves on the chip: the
+        # Pallas kernels, the paged decode kernel beside the burst's
+        # tail among them. The other is its fallback where the decode
+        # probe fails: the Pallas kernels beside XLA decode attention.
+        over = dict(attention_impl="pallas-interpret")
+        if not form.endswith("-decode"):
+            over["attention_impl_decode"] = "xla"
     if "rank" in form:
         over = dict(num_experts=4, expert_parallel_size=2,
                     expert_parallel_rank=1)

@@ -338,6 +338,114 @@ def test_decode_int8_stacked_cache_layer_form():
                                       np.asarray(src.scale))
 
 
+# ---- the decode kernel beside a deferred-write burst's tail -----------------
+
+# kv heads, head_dim, group: the four cells' attention layers (LFM2,
+# Qwen2.5, Qwen3-Next, Jamba).
+CELL_HEADS = {"kv8_d64_g4": (8, 64, 4), "kv2_d128_g8": (2, 128, 8),
+              "kv2_d256_g8": (2, 256, 8), "kv1_d128_g20": (1, 128, 20)}
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+@pytest.mark.parametrize("fill", [0, 13, 32])
+@pytest.mark.parametrize("heads", sorted(CELL_HEADS))
+def test_decode_with_a_burst_tail_matches_xla(heads, fill, cache):
+    """Pages of 128 as the cells keep them, ragged rows: a pad row
+    (0), one token, a page's edge from both sides, and a row that
+    fills its table; the tail empty (the query at the last cached
+    token), part-filled and full. Ground truth is ``paged_attention``
+    with the same tails."""
+    kv, d, group = CELL_HEADS[heads]
+    page, max_pages, slots = 128, 6, 32
+    lens = np.array([0, 1, 128, 129, 300, max_pages * page], np.int32)
+    b = len(lens)
+    rng = np.random.RandomState(kv * d + fill)
+    table = np.zeros((b, max_pages), np.int32)
+    next_page = 1
+    for i, n in enumerate(lens):
+        for j in range(-(-int(n) // page)):
+            table[i, j] = next_page
+            next_page += 1
+
+    def normal(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+
+    k_cache, v_cache = (normal(kv, next_page, d, page) for _ in "kv")
+    if cache == "int8":
+        k_cache, v_cache = (_quantize_cache(c.astype(jnp.float32))
+                            for c in (k_cache, v_cache))
+    q = normal(b, kv * group, d)
+    k_tail, v_tail = (normal(b, slots, kv, d) for _ in "kv")
+    lens, table = jnp.asarray(lens), jnp.asarray(table)
+    q_pos = lens + fill - 1
+    got = paged_decode_attention(
+        q, k_cache, v_cache, table, lens, k_tail=k_tail, v_tail=v_tail,
+        q_positions=q_pos, interpret=True)
+    want = paged_attention(
+        q[:, None], k_cache, v_cache, table, q_pos[:, None], lens,
+        k_tail=k_tail, v_tail=v_tail)[:, 0]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    # A pad row with an empty tail attends nothing: any finite answer.
+    rows = slice(1, None) if fill == 0 else slice(None)
+    np.testing.assert_allclose(got[rows], want[rows], atol=0.03)
+
+
+def test_decode_tail_and_positions_go_together():
+    q, k_cache, v_cache, page_table, kv_lens = _setup()
+    tail = jnp.zeros((q.shape[0], 4, k_cache.shape[0], q.shape[2]))
+    with pytest.raises(ValueError, match="go together"):
+        paged_decode_attention(q, k_cache, v_cache, page_table, kv_lens,
+                               k_tail=tail, v_tail=tail, interpret=True)
+
+
+def test_decode_stacked_form_with_a_tail_returns_the_output_alone():
+    """The burst reads the planes and never writes them: with a tail
+    nothing is aliased and nothing is handed back."""
+    q, k_cache, v_cache, page_table, kv_lens = _setup(seed=5)
+    k5, v5 = (jnp.stack([c * 0, c]) for c in (k_cache, v_cache))
+    rng = np.random.RandomState(6)
+    tails = [jnp.asarray(rng.randn(q.shape[0], 4, k_cache.shape[0],
+                                   q.shape[2]), jnp.float32)
+             for _ in "kv"]
+    kw = dict(k_tail=tails[0], v_tail=tails[1], q_positions=kv_lens + 2,
+              interpret=True)
+    out = paged_decode_attention(q, k5, v5, page_table, kv_lens,
+                                 layer=1, **kw)
+    ref = paged_decode_attention(q, k_cache, v_cache, page_table,
+                                 kv_lens, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_decode_chunks_grow_where_the_table_outgrows_the_unroll(
+        monkeypatch):
+    """A chunk follows the bytes of a page over the kv heads until the
+    table would take over MAX_CHUNKS chunks of it; then the chunk
+    grows and the unroll stays. The answer is the XLA form's."""
+    from production_stack_tpu.ops import paged_attention_pallas as mod
+    # 8 kv heads of 128 in bfloat16, pages of 128: two pages a chunk by
+    # bytes, four under a table of 32k tokens (tests/
+    # test_pallas_lowering.py compiles that shape); the LFM2 cell's
+    # four pages stand.
+    assert mod.pages_per_chunk(8, 128, 128, 2, 128) == 2
+    assert mod.pages_per_chunk(8, 128, 128, 2, 256) == 4
+    assert mod.pages_per_chunk(8, 64, 128, 2, 32) == 4
+    monkeypatch.setattr(mod, "CHUNK_BYTES", 4096)
+    monkeypatch.setattr(mod, "MAX_CHUNKS", 2)
+    q, k_cache, v_cache, page_table, kv_lens = _setup(b=4, seed=11)
+    assert mod.pages_per_chunk(2, 64, 8, 4, page_table.shape[1]) == 3
+    # Not through the jit: a cached trace would keep the old chunk.
+    out = paged_decode_attention.__wrapped__(
+        q, k_cache, v_cache, page_table, kv_lens, interpret=True)
+    ref = paged_attention(
+        q[:, None], k_cache, v_cache, page_table,
+        (kv_lens - 1)[:, None], kv_lens)[:, 0]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
 # ---- fused ragged kernel (unified step, docs/unified_step.md) ---------------
 
 

@@ -168,6 +168,127 @@ def test_decode_burst_program_lowers_for_tpu():
     traced.lower(lowering_platforms=("tpu",))
 
 
+# The four cells' decode batches (chipbench/configs/*.json): rows,
+# query heads, kv heads, head_dim, pages, table width (max-model-len
+# over the page of 128).
+CELL_DECODE = {
+    "lfm2-8b-a1b-ep4": (256, 32, 8, 64, 4096, 32),
+    "qwen2.5-3b": (64, 16, 2, 128, 1408, 64),
+    "qwen3-next-80b-a3b-ep4": (128, 16, 2, 256, 2048, 64),
+    "jamba2-3b": (128, 20, 1, 128, 3072, 32),
+    # No cell: 8 KV heads of 128 (a page over the heads is 256 KB, so
+    # a chunk is two pages) under a table of 32k tokens, the longest
+    # static unroll ``auto`` serves unmeasured (ROADMAP S2).
+    "kv8-d128-32k-table": (64, 32, 8, 128, 8192, 256),
+}
+cells = pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+
+
+def _cell_decode_shapes(cell, sharding=None):
+    """(q, k plane, v plane, table, kv_lens, layer, k tail, v tail,
+    q_positions) of one layer's call in the cell's deferred burst of
+    32 steps, as shapes."""
+    rows, q_heads, kv, d, pages, max_pages = CELL_DECODE[cell]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    plane = shape((kv, pages, d, 128), jnp.bfloat16)
+    tail = shape((rows, 32, kv, d), jnp.bfloat16)
+    rows_i32 = shape((rows,), jnp.int32)
+    return (shape((rows, q_heads, d), jnp.bfloat16), plane, plane,
+            shape((rows, max_pages), jnp.int32), rows_i32, None,
+            tail, tail, rows_i32)
+
+
+@cells
+def test_decode_kernel_with_a_tail_lowers_at_the_cells_shapes(cell):
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention,
+    )
+    text = _lower_for_tpu(
+        paged_decode_attention, *_cell_decode_shapes(cell)).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described (not attached) v5e host: the TPU's own
+    compiler, Mosaic's machine-code pass and the scoped-VMEM budget
+    included, which the Python lowering rules do not run. The
+    persistent cache is off meanwhile: such a compile cannot be read
+    back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@cells
+def test_decode_kernel_with_a_tail_compiles_for_a_v5e(cell, one_chip):
+    """What ``auto`` probes at start-up on the chip, made here."""
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention,
+    )
+    compiled = jax.jit(paged_decode_attention).lower(
+        *_cell_decode_shapes(cell, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("layout", ["per_layer", "stacked"])
+def test_deferred_decode_burst_program_lowers_for_tpu(layout):
+    """The deferred-write burst with the pallas decode form: the
+    kernel reads the planes from outside the scan (nothing aliased,
+    nothing threaded) and the tails ride the carry."""
+    from production_stack_tpu.engine.config import (
+        CacheConfig, EngineConfig, SchedulerConfig, tiny_model_config,
+    )
+    from production_stack_tpu.engine.model_runner import ModelRunner
+
+    model = tiny_model_config("llama")
+    model.attention_impl = "pallas"
+    config = EngineConfig(
+        model=model,
+        cache=CacheConfig(page_size=128, num_pages=32,
+                          cache_layout=layout),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_model_len=256,
+                                  prefill_chunk_size=64,
+                                  decode_steps=8,
+                                  deferred_kv_writes=True),
+    )
+    runner = ModelRunner(config)
+    b = 4
+    args = (
+        runner.params, runner.k_cache, runner.v_cache,
+        jnp.zeros((b, 1), jnp.int32), jnp.zeros((b, 1), jnp.int32),
+        jnp.zeros((b, runner.max_pages_per_seq), jnp.int32),
+        jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
+        jnp.zeros((b,), jnp.int32),
+        jnp.full((b, 16), -1, jnp.int32),
+        jnp.zeros((b,), jnp.float32), jnp.ones((b,), jnp.float32),
+        jnp.zeros((b,), jnp.int32), jax.random.PRNGKey(0),
+        None, None,   # lora, lora_ids
+        None, None,   # penalties, seeding
+        None, None, None,  # bias, suppress, fsm
+    )
+    traced = jax.jit(
+        runner._decode_burst_deferred_impl,
+        static_argnames=("num_steps",)
+    ).trace(*args, num_steps=8)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
 def _ragged_args(r=8, w=512, num_pages=64, page_size=128, kv_heads=8,
                  q_heads=32, head_dim=64, max_pages=64):
     rng = np.random.RandomState(0)

@@ -5,10 +5,11 @@ layers' state pools and the expert counters go on being read and
 written every step (the Llama family's cases: tests/test_deferred_kv.py;
 the convolution tails, carried dense: tests/test_conv_tails_burst.py).
 
-Tiny widths, float32, on the CPU, in the two forms the model's own
-kernels take: plain XLA, and the Pallas kernels in interpret mode
-beside XLA decode attention, which is what ``auto`` resolves on the
-chip (the deferred burst attends through ``paged_attention``).
+Tiny widths, float32, on the CPU, in the forms the model's kernels
+take: plain XLA; the model's own Pallas kernels in interpret mode
+beside XLA decode attention; and those with the Pallas paged decode
+kernel too, which is what ``auto`` resolves on the chip (the deferred
+burst attends through ``models/llama.py`` ``deferred_attention``).
 
 ``ORDER`` 1e-5: the deferred burst sums the softmax tail first, then
 blocks, so whatever follows the first full-attention layer differs
@@ -44,6 +45,7 @@ FORMS = {
     "xla": dict(attention_impl="xla"),
     "pallas-interpret": dict(attention_impl="pallas-interpret",
                              attention_impl_decode="xla"),
+    "pallas-interpret-decode": dict(attention_impl="pallas-interpret"),
 }
 forms = pytest.mark.parametrize("form", sorted(FORMS))
 
@@ -210,9 +212,9 @@ def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell(
         _resolve_deferred_kv,
         parse_args,
     )
-    assert deferred_kv_eligible(architecture, 32, "auto")
-    assert not deferred_kv_eligible(architecture, 1, "auto")
-    assert not deferred_kv_eligible("mixtral", 32, "auto")
+    assert deferred_kv_eligible(architecture, 32)
+    assert not deferred_kv_eligible(architecture, 1)
+    assert not deferred_kv_eligible("mixtral", 32)
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "chipbench", "configs", file)
     with open(path) as f:

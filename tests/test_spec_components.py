@@ -221,8 +221,7 @@ def test_deferred_kv_eligibility_excludes_spec():
         deferred_kv_eligible,
     )
 
-    base = dict(architecture="llama", decode_steps=4,
-                attention_impl="xla")
+    base = dict(architecture="llama", decode_steps=4)
     assert deferred_kv_eligible(**base)
     assert not deferred_kv_eligible(**base, speculative_k=4)
 
