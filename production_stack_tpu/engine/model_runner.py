@@ -10,8 +10,6 @@ every step so the arrays are updated in place in HBM.
 from __future__ import annotations
 
 import functools
-import os
-import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -180,20 +178,6 @@ def pallas_backend_error(config: EngineConfig) -> Optional[str]:
                 " are not wrapped in shard_map"
                 % par.tensor_parallel_size)
     return None
-
-
-# PSTPU_TIMING=1: log every dispatch's wall time (dispatch ->
-# device_get of the sampled tokens, i.e. including device execution)
-# to stderr as "timing <kind> t=<window|bucket> <seconds>".
-# Timing mode forces a sync even on prefill dispatches that would
-# otherwise return async (no last chunk), so every logged wall really
-# contains its device execution.
-_TIMING = (os.environ.get("PSTPU_TIMING", "0").strip().lower()
-           in ("1", "true", "yes", "on"))
-
-
-def _timing_log(kind: str, t: int, wall: float) -> None:
-    logger.info("timing %s t=%d %.4f", kind, t, wall)
 
 
 def _as_device(x):
@@ -799,15 +783,6 @@ class ModelRunner:
                 static_argnames=("want_logprobs",),
                 donate_argnums=(1, 2),  # k_cache, v_cache
             ), self)
-
-    def _record_timing(self, kind: str, t: int, wall: float) -> None:
-        """PSTPU_TIMING walls: keep the log line, and fold the same
-        wall into the observatory's dispatch ledger so
-        ``GET /debug/compiles`` carries per-kind timing aggregates."""
-        _timing_log(kind, t, wall)
-        obs = self.observatory
-        if obs is not None:
-            obs.on_timing(kind, wall)
 
     def _probe_cache_struct(self, model_config, config):
         """Shared probe boilerplate: the exact serving cache struct
@@ -2092,7 +2067,6 @@ class ModelRunner:
         if want_lp:
             payload["want_logprobs"] = True
 
-        t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(1, t, payload)
         host = None
         out: List[Optional[int]] = []
@@ -2113,10 +2087,6 @@ class ModelRunner:
             else:
                 out.append(None)
                 lps.append(None)
-        if _TIMING:
-            if host is None:  # async dispatch: sync so the wall is real
-                jax.device_get(sampled)
-            self._record_timing("prefill", t, time.perf_counter() - t0)
         return out, (lps if want_lp else None)
 
     # ---- decode -----------------------------------------------------------
@@ -2293,12 +2263,7 @@ class ModelRunner:
             # pipeline's dispatch path (staged inputs, one fused
             # transfer, one fused device_get) even in sync mode, so
             # sync-vs-async parity is the same code path.
-            t0 = time.perf_counter() if _TIMING else 0.0
-            out = self.dispatch_decode(seqs).result()
-            if _TIMING:
-                self._record_timing("decode", 1,
-                                    time.perf_counter() - t0)
-            return out
+            return self.dispatch_decode(seqs).result()
         stop_w = STOP_SET_WIDTH
         if self.tracer is not None:
             self.tracer.phase("build")
@@ -2369,12 +2334,8 @@ class ModelRunner:
         # at kv_lens and may take more blocks as its rows grow.
         self._note_attn_pages(positions if self._deferred and window > 1
                               else kv_lens)
-        t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(2, window, payload)
         host = self.read_back(sampled)
-        if _TIMING:
-            self._record_timing("decode", window,
-                                time.perf_counter() - t0)
         if not want_lp:
             if window == 1:
                 return [[int(host[i])] for i in range(len(seqs))], None
@@ -2487,12 +2448,7 @@ class ModelRunner:
     def _run_spec_decode(self, plan: DecodePlan
                          ) -> Tuple[List[List[int]], Optional[list]]:
         """Synchronous verify step: dispatch + immediate readback."""
-        t0 = time.perf_counter() if _TIMING else 0.0
-        out = self.dispatch_spec(plan).result()
-        if _TIMING:
-            self._record_timing("spec", self.spec_width,
-                                time.perf_counter() - t0)
-        return out
+        return self.dispatch_spec(plan).result()
 
     # ---- unified ragged step (docs/unified_step.md) -----------------------
 
@@ -2602,12 +2558,8 @@ class ModelRunner:
         if want_lp:
             payload["want_logprobs"] = True
 
-        t0 = time.perf_counter() if _TIMING else 0.0
         sampled = self._dispatch(KIND_UNIFIED, w, payload)
         host = self.read_back(sampled)
-        if _TIMING:
-            self._record_timing("unified", w,
-                                time.perf_counter() - t0)
         if want_lp:
             toks, slp, tids, tlps = host
         else:

@@ -1,6 +1,6 @@
 """Device-level performance observatory (docs/observability.md).
 
-Four ledgers the serving layer was previously blind to, all host-side
+Three ledgers the serving layer was previously blind to, all host-side
 and allocation-free on the committed-token path:
 
 - **compile ledger** — every jitted step program is wrapped in
@@ -20,9 +20,6 @@ and allocation-free on the committed-token path:
   utilization figure against a per-device peak-FLOPs table (or the
   ``--device-peak-flops`` override). Unknown devices report MFU 0
   rather than a guessed peak.
-- **dispatch timing fold-in** — the PSTPU_TIMING wall clocks that
-  previously only went to the log also accumulate here, so
-  ``GET /debug/compiles`` carries per-kind dispatch statistics.
 
 Everything is plain-Python counter arithmetic on the single step
 thread: no device transfers, no jax imports at call time, and every
@@ -107,10 +104,6 @@ class PerfObservatory:
         self._step_durations: Dict[str, Deque[float]] = {}
         self._step_ring_size = 512
 
-        # ---- dispatch-timing fold-in (PSTPU_TIMING walls) ------------
-        self._dispatch_count: Dict[str, int] = {}
-        self._dispatch_seconds: Dict[str, float] = {}
-
         # ---- attention-impl info ledger ------------------------------
         self._attention_impls: Dict[str, str] = {}
 
@@ -174,19 +167,6 @@ class PerfObservatory:
             items = items[-limit:]
         return items
 
-    # ---- dispatch timing -------------------------------------------------
-
-    def on_timing(self, kind: str, wall: float) -> None:
-        self._dispatch_count[kind] = self._dispatch_count.get(kind, 0) + 1
-        self._dispatch_seconds[kind] = (
-            self._dispatch_seconds.get(kind, 0.0) + float(wall))
-
-    def dispatch_timings(self) -> Dict[str, Dict[str, float]]:
-        return {kind: {"count": self._dispatch_count[kind],
-                       "wall_seconds": round(
-                           self._dispatch_seconds.get(kind, 0.0), 6)}
-                for kind in sorted(self._dispatch_count)}
-
     def compile_report(self, limit: int = 32) -> Dict[str, Any]:
         return {
             "events": self.compile_events_by_kind(),
@@ -194,7 +174,6 @@ class PerfObservatory:
                         for k, v in self._compile_seconds.items()},
             "executable_cache_sizes": self.executable_cache_sizes(),
             "recent": self.recent_compiles(limit),
-            "timings": self.dispatch_timings(),
         }
 
     # ---- HBM memory ledger -----------------------------------------------

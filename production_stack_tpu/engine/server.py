@@ -76,11 +76,13 @@ STEP_FAILURE_LIMIT = 3
 
 def slice_options():
     """What a /debug/profiler slice records: the device's planes and
-    the host's TraceAnnotations (``engine.*``, ``server.stream_token``)
-    but no Python frames. The Python tracer writes an event per call
-    on every thread: it doubled the hand-over it was there to measure,
-    made a slice of 8 s 100 MB, and stopping it held the interpreter
-    for tens of seconds (PERF.md, PR 26 and PR 28)."""
+    the host's TraceAnnotations (``engine.*`` on the loop thread;
+    ``server.stream_token``, ``server.consume`` and ``server.write``
+    on the event loop's) but no Python frames. The Python tracer
+    writes an event per call on every thread: it doubled the hand-over
+    it was there to measure, made a slice of 8 s 100 MB, and stopping
+    it held the interpreter for tens of seconds (PERF.md, PR 26 and
+    PR 28)."""
     import jax
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -121,8 +123,15 @@ class AsyncEngine:
         # The profiler endpoints set this to the tracer's annotation
         # factory while a slice runs: each delivery of a turn's
         # outputs to their streams is then one ``server.stream_token``
-        # event on the event loop's thread. None outside a slice.
+        # event on the event loop's thread, and each wake of a
+        # stream's consumer and each socket write that follow it a
+        # ``server.consume`` / ``server.write`` event (``front`` keeps
+        # the factory of the newest delivery). None outside a slice.
         self.stream_annotation = None
+        # The event loop's side of the turn records (engine/tracing.py
+        # FrontClock): the tracer's, once start() has bound it to the
+        # event loop's thread; None without a tracer.
+        self.front = None
 
     def current_step_s(self) -> float:
         """Seconds the in-flight engine step has been running
@@ -133,7 +142,12 @@ class AsyncEngine:
         return time.time() - started
 
     def start(self, loop: asyncio.AbstractEventLoop) -> None:
+        """Runs on the event loop's thread."""
         self._loop = loop
+        tracer = self.engine.tracer
+        if tracer is not None:
+            tracer.front.bind()
+            self.front = tracer.front
         self._thread.start()
         self._started.set()
 
@@ -263,7 +277,11 @@ class AsyncEngine:
         """The event loop's side: each output onto its stream, in the
         engine's order, so a stream's tokens stay in order and its
         finish comes last. A stream that was finished or aborted
-        meanwhile is gone from ``_streams`` and its outputs dropped."""
+        meanwhile is gone from ``_streams`` and its outputs dropped.
+        Feeds no accumulator of the front's: it hands the consumers
+        it wakes the slice's annotation factory (None outside one)."""
+        if self.front is not None:
+            self.front.annotate = annotate
         with (contextlib.nullcontext() if annotate is None
               else annotate("server.stream_token")):
             for out in outputs:
@@ -963,6 +981,13 @@ class EngineServer:
             together (AsyncEngine._hand_over), and the streaming side
             puts their frames on the wire in one write there.
 
+            With a tracer, each wake (from ``stream.get()`` returning
+            until the stream is empty again) feeds the front's
+            ``tokens``, streaming or not: one addition a wake, whatever
+            its tokens. Inside a profiler slice the wake is one
+            ``server.consume`` event, closed before anything that can
+            yield (engine/tracing.py FrontClock).
+
             Logprob entries are released by CHARACTER accounting: a
             token's entry joins logprobs.content only once its decoded
             text has fully left the stop-string hold-back buffer, so a
@@ -979,6 +1004,8 @@ class EngineServer:
             emitted_chars = 0
             n_tokens = 0
             finish_reason = "stop"
+            front = self.async_engine.front
+            awake, counted = False, 0
 
             def release_entries():
                 ready = []
@@ -1016,9 +1043,17 @@ class EngineServer:
 
             try:
                 while True:
-                    if on_idle is not None and stream.empty():
-                        await on_idle()
+                    if stream.empty():
+                        if awake:
+                            awake = False
+                            front.wake_done(n_tokens - counted)
+                            counted = n_tokens
+                        if on_idle is not None:
+                            await on_idle()
                     out = await stream.get()
+                    if front is not None and not awake:
+                        awake = True
+                        front.consume_begin()
                     if out.new_token is not None:
                         n_tokens += 1
                         token_text = decoder(out.new_token)
@@ -1050,6 +1085,8 @@ class EngineServer:
                             finish_reason = "stop"
                         break
             finally:
+                if awake:
+                    front.wake_done(n_tokens - counted)
                 self.async_engine.finish_stream(seq_id)
             return ("".join(pieces), n_tokens, finish_reason,
                     lp_content)
@@ -1187,18 +1224,35 @@ class EngineServer:
             # short steps (PERF.md, PR 38): the frames of one hand-over
             # go out together once the stream has no more to give.
             pending: List[bytes] = []
+            front = self.async_engine.front
 
             async def flush():
                 if pending:
                     frames = b"".join(pending)
                     pending.clear()
                     async with write_lock:
-                        await resp.write(frames)
+                        if front is None or front.annotate is None:
+                            await resp.write(frames)
+                        else:
+                            # Inside a slice: a ``server.write`` event
+                            # around the write's synchronous part.
+                            await front.write(resp.write(frames))
 
             async def on_delta(text, lps):
                 pending.append(sse(chunk(index, text, None, lps=lps)))
                 if not relay_ckpt:
                     return
+                if front is None:
+                    return await relay()
+                # Inside the consumer's wake, and the writes can park:
+                # its ``server.consume`` event is closed meanwhile.
+                front.consume_end()
+                try:
+                    await relay()
+                finally:
+                    front.consume_begin()
+
+            async def relay():
                 # A resume descriptor follows the frame it describes.
                 await flush()
                 ckpt = self.engine.take_checkpoint(seq_id)
@@ -2188,9 +2242,9 @@ class EngineServer:
     async def debug_compiles(self, request: web.Request):
         """GET /debug/compiles[?limit=N]: the device performance
         observatory's compile ledger — per-kind event/seconds
-        counters, live executable-cache sizes, the bounded ring of
-        recent compiles with their (rows, W) shape keys, and the
-        PSTPU_TIMING dispatch aggregates (docs/observability.md)."""
+        counters, live executable-cache sizes and the bounded ring
+        of recent compiles with their (rows, W) shape keys
+        (docs/observability.md)."""
         obs = getattr(self.engine.runner, "observatory", None)
         if obs is None:
             return web.json_response(
@@ -2435,6 +2489,21 @@ class EngineServer:
             for phase, impl in sorted(obs.attention_impls().items()):
                 lines.append("vllm:engine_attention_impl{phase=\""
                              f"{phase}\",impl=\"{impl}\"}} 1.0")
+        # The interpreter's two threads (docs/observability.md, "Is
+        # the front the wall?"): the event loop thread's CPU clock, and
+        # the loop thread's wall less CPU while it had work to do.
+        tracer = self.engine.tracer
+        if tracer is not None:
+            front_cpu_s = tracer.front.cpu_s()
+            if front_cpu_s is not None:
+                lines.append(
+                    "# TYPE vllm:engine_front_cpu_seconds_total counter")
+                lines.append("vllm:engine_front_cpu_seconds_total "
+                             f"{front_cpu_s}")
+            lines.append(
+                "# TYPE vllm:engine_loop_offcpu_seconds_total counter")
+            lines.append("vllm:engine_loop_offcpu_seconds_total "
+                         f"{tracer.loop_offcpu_s}")
         # Topology observability (docs/parallelism.md): the mesh the
         # engine actually runs on, which slice this process owns, and
         # per-slice liveness from the multihost bridge (a dead host

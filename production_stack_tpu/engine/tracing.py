@@ -22,22 +22,42 @@ Under the server loop a step record is one *turn* of that loop: the
 loop thread is always in exactly one of ``TURN_PHASES``, the record
 carries the milliseconds spent in each between ``t_start`` and
 ``t_end``, and turns are contiguous, so nothing the loop thread does
-falls between two records. With an
+falls between two records. Beside each phase's wall the record has the
+thread's CPU clock over it (``cpu``): a phase's wall less its CPU is
+the time the thread was kept off a core (the clock is the host's: where
+it advances in ticks of 10 ms, as on the v5e hosts, read sums over many
+turns, not one record). The interpreter has a second
+thread, the event loop, which turns the tokens into frames and writes
+the sockets: every turn closes what that thread's CPU clock read
+between the turn's ``t_start`` and ``t_end``, and the tokens its
+consumers took meanwhile, into the record's ``front`` (``FrontClock``,
+``tracer.front``). With an
 annotation factory (the server hands over
 ``jax.profiler.TraceAnnotation``) each phase and the turn around it
-are also profiler events on the loop thread, so a profiler slice shows
-what the host did while the device idled. A turn far slower than its
+are also profiler events on the loop thread, and each delivery, each
+wake of a stream's consumer and each socket write an event on the
+event loop's thread, so a profiler slice shows what both threads of
+the host did while the device idled. A turn far slower than its
 kind's recent median logs one ``slow turn`` WARNING.
 
 Concurrency: the engine's device loop, the asyncio handlers, and the
 drain path all touch the tracer. Every mutation is a GIL-atomic dict
 or ``deque(maxlen=...)`` operation — no lock is taken on the step or
-token path. The module is stdlib-only (no JAX, no aiohttp) so the
-fake engine reuses it verbatim.
+token path. The front's token count is a plain attribute that the
+event loop's thread alone writes and the loop thread reads once a
+turn. The module is stdlib-only (no JAX, no aiohttp) so the fake
+engine reuses it verbatim; it binds no event loop, and its records
+have no ``front``.
 
 Disabled cost: the engine holds ``tracer = None`` unless a tracer is
 explicitly installed; every emission site is behind an ``is None``
-check, so the disabled hot path allocates no span objects at all.
+check, so the disabled hot path allocates no span objects at all, and
+the front's sites (``AsyncEngine.front`` is None then) do nothing.
+With a tracer and no profiler slice the loop thread pays two clock
+reads a phase switch and one read of the other thread's CPU clock a
+turn (a thread's CPU clock is a system call: 0.5 us on a plain kernel,
+6 us on the v5e hosts' sandboxed one, PERF.md PR 39), and the event
+loop's thread reads no clock: an addition a wake and ``is None`` checks.
 """
 
 from __future__ import annotations
@@ -47,6 +67,7 @@ import json
 import statistics
 import threading
 import time
+import types
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
@@ -99,6 +120,12 @@ TURN_PHASES = (
     "emit",      # one call that hands the turn's outputs to the event loop
     "other",     # autotuner tick, back-off waits, the rest
 )
+
+# Phases in which the loop thread is off the CPU because that is what
+# the phase is: blocked on the device, or parked with nothing to serve.
+# In every other phase the thread has work, and wall less CPU there is
+# time it was kept from it.
+PARKED_PHASES = ("wait", "idle")
 
 # A turn is slow when its wall (less ``idle``) is more than
 # SLOW_TURN_FACTOR times the median of the last SLOW_TURN_HISTORY turns
@@ -173,6 +200,110 @@ class _SpanSink:
             self._fh.write(line + "\n")
 
 
+class FrontClock:
+    """What the event loop's thread did, counted on that thread: its
+    CPU clock, and the tokens that the streams' consumers (detokeniser,
+    stop scanner, one frame a token) took, added a wake and not a
+    token. ``tokens`` only grows; ``close_interval()`` gives the loop
+    thread what both grew by since it last asked. Inside a profiler
+    slice (``annotate`` set) a consumer's wake is one ``server.consume``
+    event and a socket write's synchronous part one ``server.write``
+    event; neither spans a point where its coroutine yields, so at
+    most one is open at any instant. Outside a slice the thread reads
+    no clock for this.
+    """
+
+    def __init__(self):
+        self.tokens = 0
+        # The annotation factory of the newest hand-over: set by each
+        # delivery, so the consumers it wakes and their writes are
+        # events of the slice that the turn was handed over in.
+        self.annotate: Optional[Callable[..., Any]] = None
+        self._cpu_clock: Optional[int] = None
+        self._mark: Any = None
+        # The loop thread's: what close_interval() last read. None
+        # until bind().
+        self._last: Optional[tuple] = None
+
+    def bind(self) -> None:
+        """Called once, on the event loop's thread, before the loop
+        thread starts: from here on the turns' records have ``front``.
+        Where the platform gives no clock of another thread's CPU time
+        ``cpu_ms`` is absent from it."""
+        if hasattr(time, "pthread_getcpuclockid"):
+            self._cpu_clock = time.pthread_getcpuclockid(
+                threading.get_ident())
+        self._last = (self.cpu_s(), self.tokens)
+
+    def cpu_s(self) -> Optional[float]:
+        """The bound thread's CPU clock, from any thread; None where
+        there is none to read (not bound, no such call, thread gone)."""
+        if self._cpu_clock is None:
+            return None
+        try:
+            return time.clock_gettime(self._cpu_clock)
+        except OSError:
+            return None
+
+    # -- the event loop's thread --------------------------------------------
+
+    def consume_begin(self) -> None:
+        """A consumer has an output in hand: inside a slice a
+        ``server.consume`` event is open until ``consume_end()``,
+        which comes before anything that can yield."""
+        if self.annotate is not None:
+            self._mark = self.annotate("server.consume")
+            self._mark.__enter__()
+
+    def consume_end(self) -> None:
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
+
+    def wake_done(self, tokens: int) -> None:
+        """The consumer's stream is empty again (or it is leaving)."""
+        self.consume_end()
+        self.tokens += tokens
+
+    @types.coroutine
+    def write(self, writing):
+        """Inside a slice only: awaits ``writing``, a coroutine that
+        writes to a socket, with one ``server.write`` event around its
+        synchronous part: up to where it returns or, with a transport
+        over its high-water mark, first suspends to drain. Parked, the
+        coroutine is nobody's."""
+        with self.annotate("server.write"):
+            try:
+                step = writing.send(None)
+            except StopIteration as done:
+                return done.value
+        try:
+            while True:  # ``yield from`` for a coroutine already begun
+                try:
+                    sent = yield step
+                except BaseException as thrown:
+                    step = writing.throw(thrown)
+                else:
+                    step = writing.send(sent)
+        except StopIteration as done:
+            return done.value
+
+    # -- the loop thread ----------------------------------------------------
+
+    def close_interval(self) -> Optional[Dict[str, Any]]:
+        """What the event loop's thread did since the last call (or
+        since ``bind()``), as the record's ``front``; None unbound."""
+        last = self._last
+        if last is None:
+            return None
+        now = self._last = (self.cpu_s(), self.tokens)
+        front: Dict[str, Any] = {}
+        if last[0] is not None and now[0] is not None:
+            front["cpu_ms"] = round((now[0] - last[0]) * 1e3, 3)
+        front["tokens"] = now[1] - last[1]
+        return front
+
+
 class EngineTracer:
     """Per-request timelines + step flight recorder for one engine.
 
@@ -198,8 +329,21 @@ class EngineTracer:
         self.annotate = annotate
         self._phase: Optional[str] = None
         self._phase_t = 0.0
+        self._cpu_t = 0.0
         self._turn_t = 0.0
         self._acc: Dict[str, float] = {}
+        self._cpu: Dict[str, float] = {}
+        # The loop thread's wall less CPU outside PARKED_PHASES, summed
+        # with its sign over every closed turn (on a CPU clock that
+        # advances in ticks a phase's difference is as often below
+        # zero as above, and only the sum is true), and the largest
+        # that sum has been, which never decreases:
+        # vllm:engine_loop_offcpu_seconds_total.
+        self._offcpu_s = 0.0
+        self.loop_offcpu_s = 0.0
+        # The event loop's side of the turns (AsyncEngine.start binds
+        # it to its thread).
+        self.front = FrontClock()
         self._turn_step = 0
         self._open: Optional[Dict[str, Any]] = None
         self._marks: List[Any] = []
@@ -291,6 +435,7 @@ class EngineTracer:
         ledger's total so far, start-up's, which is no turn's."""
         self._phase = "other"
         self._phase_t = self._turn_t = time.perf_counter()
+        self._cpu_t = time.thread_time()
         self._compiles_seen = compiles
         self._open_turn()
 
@@ -308,19 +453,23 @@ class EngineTracer:
         return old
 
     def _close_phase(self, name: str) -> None:
-        now = time.perf_counter()
-        self._acc[self._phase] = (self._acc.get(self._phase, 0.0)
-                                  + now - self._phase_t)
-        self._phase_t = now
+        now, cpu = time.perf_counter(), time.thread_time()
+        old = self._phase
+        self._acc[old] = self._acc.get(old, 0.0) + now - self._phase_t
+        self._cpu[old] = self._cpu.get(old, 0.0) + cpu - self._cpu_t
+        self._phase_t, self._cpu_t = now, cpu
         self._phase = name
 
     def end_turn(self, emitted: int,
                  compiles: int = 0) -> Optional[Dict[str, Any]]:
         """The turn ends here if it accounted a step: its record gets
         ``t_start``, ``t_end``, ``phases`` (ms, summing to the wall),
-        ``emitted`` and, where the compile ledger's total ``compiles``
-        grew during it, ``compiles``; it goes into the ring and is
-        returned. A turn without a step (nothing planned) goes on."""
+        ``cpu`` (the thread's CPU clock over the same phases),
+        ``front`` (what the event loop's thread did meanwhile, where
+        one is bound), ``emitted`` and, where the compile ledger's
+        total ``compiles`` grew during it, ``compiles``; it goes into
+        the ring and is returned. A turn without a step (nothing
+        planned) goes on."""
         record = self._open
         if record is None:
             return None
@@ -329,11 +478,19 @@ class EngineTracer:
         while self._marks:
             self._marks.pop().__exit__(None, None, None)
         phases, self._acc = self._acc, {}
+        cpu, self._cpu = self._cpu, {}
         t_start, self._turn_t = self._turn_t, self._phase_t
         record["t_start"] = round(self._unix0 + t_start, 6)
         record["t_end"] = round(self._unix0 + self._turn_t, 6)
         record["phases"] = {k: round(v * 1e3, 3)
                             for k, v in phases.items()}
+        record["cpu"] = {k: round(v * 1e3, 3) for k, v in cpu.items()}
+        self._offcpu_s += sum(wall - cpu[k] for k, wall in phases.items()
+                              if k not in PARKED_PHASES)
+        self.loop_offcpu_s = max(self.loop_offcpu_s, self._offcpu_s)
+        front = self.front.close_interval()
+        if front is not None:
+            record["front"] = front
         record["emitted"] = emitted
         if compiles > self._compiles_seen:
             record["compiles"] = compiles - self._compiles_seen

@@ -1262,7 +1262,6 @@ async def debug_compiles(request: web.Request) -> web.Response:
         "seconds": {"step": 1.25, "unified": 0.5},
         "executable_cache_sizes": {"step": 3, "unified": 1},
         "recent": recent[-limit:] if limit >= 0 else recent,
-        "timings": {},
     })
 
 

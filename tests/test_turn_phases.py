@@ -135,7 +135,7 @@ async def test_without_a_tracer_no_record_and_no_annotation(monkeypatch):
         "--trace-ring-size", "0"]))
     assert engine.tracer is None and engine.runner.tracer is None
     served = await _serve(engine, requests=1, max_tokens=4)
-    assert served.stream_annotation is None
+    assert served.stream_annotation is None and served.front is None
     assert made == []
     # And with the recorder on (the default) the tracer has the factory.
     engine, _ = build_engine_from_args(parse_args([
@@ -200,6 +200,7 @@ def test_a_record_stays_small_however_long_the_loop_idles():
     grow the next record, which the ring keeps, /debug/steps serves
     and a slow-turn line prints whole."""
     tracer = EngineTracer()
+    tracer.front.bind()  # as AsyncEngine.start does
     tracer.start_turns(compiles=40)  # start-up's compiles
     for _ in range(100_000):
         tracer.phase("idle")
@@ -209,8 +210,10 @@ def test_a_record_stays_small_however_long_the_loop_idles():
     tracer.on_step(kind="decode")
     record = tracer.end_turn(emitted=1, compiles=40)
     assert set(record["phases"]) == {"idle", "other", "wait"}
+    assert set(record["cpu"]) == set(record["phases"])
+    assert set(record["front"]) == {"cpu_ms", "tokens"}
     assert "compiles" not in record
-    assert len(json.dumps(record)) < 400
+    assert len(json.dumps(record)) < 500
 
 
 def _phase_literals():
@@ -609,6 +612,9 @@ class _Clock:
         self.now = 1000.0
 
     def perf_counter(self):
+        return self.now
+
+    def thread_time(self):  # a thread that is never kept off a core
         return self.now
 
     def time(self):
