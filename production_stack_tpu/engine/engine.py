@@ -890,6 +890,7 @@ class LLMEngine:
                 self._step_note = {
                     "kind": "prefill",
                     "prefill_rows": len(plan.prefill.chunks),
+                    "prefill_chain": plan.prefill.chain,
                     "row_bucket": self.runner.prefill_width,
                 }
         self._obs_note = ("prefill",
@@ -1267,6 +1268,8 @@ class LLMEngine:
             "gpu_prefix_cache_hit_rate":
                 self.cache_manager.prefix_hit_rate(),
             "num_preemptions_total": self.scheduler.num_preemptions,
+            "engine_prefill_chained_steps_total":
+                self.scheduler.num_chained_prefill_steps,
             "spec_decode_num_draft_tokens_total":
                 self.metrics.spec_draft_tokens_total,
             "spec_decode_num_accepted_tokens_total":

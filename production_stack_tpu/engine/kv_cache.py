@@ -109,8 +109,12 @@ class PagedCacheManager:
         return self.prefix_hit_tokens / self.prefix_query_tokens
 
     @property
+    def num_free_state_slots(self) -> int:
+        return len(self._free_state)
+
+    @property
     def num_used_state_slots(self) -> int:
-        return self.num_state_slots - len(self._free_state)
+        return self.num_state_slots - self.num_free_state_slots
 
     def allocate_state_slot(self) -> Optional[int]:
         """A recurrent-state slot for a sequence that has just been

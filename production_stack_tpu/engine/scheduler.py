@@ -6,10 +6,12 @@ XLA every shape is a compiled program, so this scheduler plans work in
 *fixed* shapes — prefill chunks padded to buckets, decode as a constant-
 width slot batch — and the runner caches one executable per shape.
 
-A step is either one prefill chunk (chunked prefill, reference flag
---enable-chunked-prefill, deployment-vllm-multi.yaml:69-71) or one
-decode batch over all running sequences; the two alternate when both
-have work so neither starves.
+A step is either one batch of prefill chunks (chunked prefill,
+reference flag --enable-chunked-prefill,
+deployment-vllm-multi.yaml:69-71) or one decode batch over all running
+sequences; the two alternate when both have work so neither starves,
+and a further prefill step runs before the burst only while it would
+be full and rows are free (plan_step, "prefill chain").
 """
 
 from __future__ import annotations
@@ -64,6 +66,10 @@ class PrefillPlan:
 
     chunks: List[PrefillChunk]
     sp: bool = False
+    # Place in the chain of prefill steps since the last burst: 1 is
+    # the step alternation plans, 2.. are the full steps plan_step
+    # put before the burst (the turn record's ``prefill_chain``).
+    chain: int = 1
 
 
 @dataclass
@@ -109,6 +115,14 @@ class Scheduler:
         self.waiting: Deque[Sequence] = deque()
         self.running: List[Sequence] = []
         self._last_was_prefill = False
+        # The prefill chain (plan_step): the last prefill plan's place
+        # in it, how many steps it may have (set by its first step
+        # from the rows free then), and how many steps chains have put
+        # before a burst so far
+        # (vllm:engine_prefill_chained_steps_total).
+        self._prefill_chain = 0
+        self._chain_limit = 1
+        self.num_chained_prefill_steps = 0
         # Optional offload-tier restore hook:
         # (prompt_token_ids, matched_pages) -> extra restored page ids.
         self.restore_hook = None
@@ -238,15 +252,36 @@ class Scheduler:
             # state the ragged program doesn't compile.
             plan = self._plan_mixed()
             if plan is not None and not plan.empty:
+                self._chain_limit = 0  # a mixed step starts no chain
                 return plan
-        if want_prefill and want_decode:
-            # Alternate so neither side starves.
-            do_prefill = not self._last_was_prefill
+        # Alternate so neither side starves. The rows a prefill step
+        # makes wait are the running ones and a step costs the same
+        # whatever it carries (its rows are padded to the compiled
+        # width), so a further one goes before the burst only while it
+        # would be FULL, and a chain has at most one step for each
+        # ``prefill_batch_size`` rows that were free at its start: a
+        # batch with fewer free rows than a step alternates strictly,
+        # a nearly empty one fills before it decodes.
+        chained = (want_prefill and want_decode
+                   and self._last_was_prefill)
+        if chained:
+            do_prefill = (self._prefill_chain < self._chain_limit
+                          and self._full_prefill_step_waits())
         else:
             do_prefill = want_prefill
         if do_prefill:
             plan = self._plan_prefill()
             if plan is not None:
+                if chained:
+                    self._prefill_chain += 1
+                    self.num_chained_prefill_steps += 1
+                else:
+                    free_rows = (self.config.max_num_seqs
+                                 - len(self.running))
+                    self._prefill_chain = 1
+                    self._chain_limit = -(
+                        -free_rows // self.config.prefill_batch_size)
+                plan.chain = self._prefill_chain
                 self._last_was_prefill = True
                 return StepPlan(prefill=plan)
             want_decode = bool(self.running)
@@ -478,6 +513,54 @@ class Scheduler:
             limit = min(limit, seq.spec_k_cap)
         return limit
 
+    def _admission_order(self) -> List[Sequence]:
+        """QoS admission order (docs/qos.md): priority class first,
+        then arrival. The sort is stable and preempted victims keep
+        their original arrival_time, so a restored victim leads its
+        class rather than re-queueing at the back."""
+        return sorted(self.waiting,
+                      key=lambda s: (s.priority, s.arrival_time))
+
+    def _full_prefill_step_waits(self) -> bool:
+        """Whether _plan_prefill would fill a whole step now: at least
+        ``prefill_batch_size`` chunk rows, counted as it would take
+        them (one a waiting sequence, mid-prompt ones included, parked
+        hand-offs and aborted ones not, admissions only while
+        ``running`` has rows for them) and as far as the free pages
+        and state slots reach. Counted, not planned: planning gives a
+        sequence its pages and slot at first touch and cannot be taken
+        back. A prefix hit is not looked up here, so a first-touch
+        prompt counts with all its pages."""
+        rows = admitting = 0
+        pages = self.cache.num_free_pages
+        # A model that keeps no recurrent state is never short of a
+        # slot for one.
+        slots = (self.cache.num_free_state_slots
+                 if self.cache.num_state_slots
+                 else self.config.prefill_batch_size)
+        for seq in self._admission_order():
+            if seq.state in (SequenceState.ABORTED,
+                             SequenceState.AWAITING_KV):
+                continue
+            if (len(self.running) + admitting
+                    >= self.config.max_num_seqs):
+                break
+            if seq.num_computed_tokens == 0 and not seq.pages:
+                if (self.sp_threshold is not None
+                        and seq.num_prompt_tokens >= self.sp_threshold):
+                    break  # a whole-prompt plan runs alone
+                pages -= self._pages_needed(seq, seq.num_prompt_tokens)
+                slots -= 1
+                if pages < 0 or slots < 0:
+                    break
+            rows += 1
+            if rows >= self.config.prefill_batch_size:
+                return True
+            if (seq.num_computed_tokens + self.config.prefill_chunk_size
+                    >= seq.num_prompt_tokens):
+                admitting += 1
+        return False
+
     def _plan_prefill(self, max_tokens: Optional[int] = None
                       ) -> Optional[PrefillPlan]:
         # ``max_tokens`` caps the total prompt tokens admitted this
@@ -487,12 +570,7 @@ class Scheduler:
         chunks: List[PrefillChunk] = []
         tokens_planned = 0
         admitting = 0  # rows that will join `running` this step
-        # QoS admission order (docs/qos.md): priority class first, then
-        # arrival. The sort is stable and preempted victims keep their
-        # original arrival_time, so a restored victim leads its class
-        # rather than re-queueing at the back.
-        for seq in sorted(self.waiting,
-                          key=lambda s: (s.priority, s.arrival_time)):
+        for seq in self._admission_order():
             if len(chunks) >= self.config.prefill_batch_size:
                 break
             if seq.state == SequenceState.ABORTED:

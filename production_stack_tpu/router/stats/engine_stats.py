@@ -134,6 +134,9 @@ _ROUTER_UNSCRAPED = frozenset({
     "vllm:request_success_total",
     "vllm:request_failure_total",
     "vllm:num_preemptions_total",
+    # Prefill steps chained before a burst (engine scheduler): says
+    # a replica is admission-bound; an operator's rate.
+    "vllm:engine_prefill_chained_steps_total",
     # Autotune decision counts are an operator/dashboard rate, not a
     # routing signal — cluster Prometheus reads them directly.
     "vllm:autotune_decisions_total",
