@@ -30,13 +30,25 @@ _LLAMA_SHAPES = frozenset(
     for suffix in ("", "forcausallm"))
 
 
+def _expert_parallel_share(hf: dict):
+    """(expert_parallel_size, expert_parallel_rank) of a config.json
+    that says which block of the routed experts this engine holds."""
+    ep = int(hf.get("expert_parallel_size", 1))
+    rank = int(hf.get("expert_parallel_rank", 0))
+    if not 0 <= rank < ep:
+        raise ValueError(
+            f"expert_parallel_rank {rank} is not one of "
+            f"expert_parallel_size {ep} blocks")
+    return ep, rank
+
+
 @dataclasses.dataclass
 class ModelConfig:
     """Architecture hyperparameters (HF-config compatible field names)."""
 
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
-    # | lfm2_moe (models/registry.py FAMILIES)
+    # | lfm2_moe | longcat_flash (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -111,6 +123,28 @@ class ModelConfig:
     layer_types: tuple = ()
     conv_L_cache: int = 3
     num_dense_layers: int = 0
+    # LongCat-Flash decoders (architecture == "longcat_flash",
+    # models/longcat_flash.py). A layer is two latent-attention (MLA)
+    # sublayers and two dense SwiGLU feed-forwards of
+    # intermediate_size around one routed-expert branch. A sublayer
+    # caches per token one latent of kv_lora_rank values and one
+    # rotary key of qk_rope_head_dim shared by every head, and nothing
+    # else; a query head is qk_nope_head_dim + qk_rope_head_dim wide, a
+    # value head v_head_dim. The two scales are the published
+    # mla_scale_q_lora / mla_scale_kv_lora (1.0: off). The router is a
+    # softmax over the routed experts (num_experts held, as above) and
+    # zero_expert_num identity experts after them, chosen with a
+    # learned bias, weighted by routed_scaling_factor times the
+    # scores alone.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
+    zero_expert_num: int = 0
+    routed_scaling_factor: float = 1.0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -174,9 +208,37 @@ class ModelConfig:
         return any(self.layer_is_linear)
 
     @property
+    def page_cache(self):
+        """What the paged cache holds a token, as the family declares
+        it (models/registry.py ``PageCache``): the paged entries, the
+        heads and rows of one plane, and the planes an entry has (2: K
+        and V; 1: a latent stored once). A family that declares
+        nothing keeps K and V of ``num_key_value_heads`` heads of
+        ``head_dim`` in every layer that is not recurrent."""
+        from production_stack_tpu.models.registry import page_cache
+        return page_cache(self)
+
+    @property
+    def cache_entry_is_state(self) -> tuple:
+        """Per cache entry: True where it is a recurrent layer's state
+        pool, False where it is pages. One entry a layer, unless the
+        family declares its paged entries itself."""
+        if self.family.page_cache is None:
+            return self.layer_is_linear
+        return (False,) * self.page_cache.entries
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """The paged entries are one plane each: a latent in the place
+        of a (K, V) pair."""
+        return self.page_cache.planes == 1
+
+    @property
     def router_width(self) -> int:
-        """Experts the router chooses among (the published count)."""
-        return self.num_experts * self.expert_parallel_size
+        """Outputs the router chooses among: the published count of
+        routed experts and, after them, the zero-compute ones."""
+        return (self.num_experts * self.expert_parallel_size
+                + self.zero_expert_num)
 
     def recurrent_state_shapes(self):
         """One sequence's state in one recurrent layer, as its family
@@ -222,12 +284,7 @@ class ModelConfig:
                 dtype="bfloat16",
             )
         if "qwen3next" in arch:
-            ep = int(hf.get("expert_parallel_size", 1))
-            rank = int(hf.get("expert_parallel_rank", 0))
-            if not 0 <= rank < ep:
-                raise ValueError(
-                    f"expert_parallel_rank {rank} is not one of "
-                    f"expert_parallel_size {ep} blocks")
+            ep, rank = _expert_parallel_share(hf)
             unsupported = [k for k, bad in (
                 ("mlp_only_layers", bool(hf.get("mlp_only_layers"))),
                 ("decoder_sparse_step",
@@ -329,12 +386,7 @@ class ModelConfig:
                 dtype="bfloat16",
             )
         if "lfm2moe" in arch.replace("_", ""):
-            ep = int(hf.get("expert_parallel_size", 1))
-            rank = int(hf.get("expert_parallel_rank", 0))
-            if not 0 <= rank < ep:
-                raise ValueError(
-                    f"expert_parallel_rank {rank} is not one of "
-                    f"expert_parallel_size {ep} blocks")
+            ep, rank = _expert_parallel_share(hf)
             layer_types = tuple(hf["layer_types"])
             other = sorted(set(layer_types) - {"conv", "full_attention"})
             refused = [why for bad, why in (
@@ -400,6 +452,78 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "longcatflash" in arch.replace("_", ""):
+            ep, rank = _expert_parallel_share(hf)
+            refused = [why for bad, why in (
+                (hf.get("attention_method", "MLA") != "MLA",
+                 f"attention_method {hf.get('attention_method')!r}: "
+                 "every attention sublayer is served as latent "
+                 "attention (MLA) over the latent pages"),
+                (not hf.get("q_lora_rank"),
+                 "q_lora_rank unset: the query is served through its "
+                 "low-rank pair and its norm"),
+                (hf.get("zero_expert_type", "identity") != "identity",
+                 f"zero_expert_type {hf.get('zero_expert_type')!r}: a "
+                 "zero-compute expert is served as the identity"),
+                (bool(hf.get("attention_bias", False)),
+                 "attention_bias true: the attention projections are "
+                 "served without a bias"),
+                (hf.get("rope_scaling") is not None,
+                 "rope_scaling: the rotary embedding is served "
+                 "unscaled"),
+                (bool(hf.get("norm_topk_prob", False)),
+                 "norm_topk_prob true: the chosen scores are served "
+                 "scaled by routed_scaling_factor and not divided by "
+                 "their sum"),
+                (hf.get("hidden_act", "silu") != "silu",
+                 f"hidden_act {hf.get('hidden_act')!r}: the "
+                 "feed-forwards and the experts are SwiGLU"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "LongCat-Flash config this engine does not serve: "
+                    + "; ".join(refused))
+            h = hf["hidden_size"]
+            return cls(
+                name=name or hf.get("_name_or_path", "longcat-flash"),
+                architecture="longcat_flash",
+                vocab_size=hf["vocab_size"],
+                hidden_size=h,
+                intermediate_size=hf["ffn_hidden_size"],
+                num_hidden_layers=hf["num_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                # One latent a token serves every head.
+                num_key_value_heads=1,
+                head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 131072),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+                rope_theta=hf.get("rope_theta", 1e7),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           False),
+                kv_lora_rank=hf["kv_lora_rank"],
+                q_lora_rank=hf["q_lora_rank"],
+                qk_nope_head_dim=hf["qk_nope_head_dim"],
+                qk_rope_head_dim=hf["qk_rope_head_dim"],
+                v_head_dim=hf["v_head_dim"],
+                mla_q_scale=((h / hf["q_lora_rank"]) ** 0.5
+                             if hf.get("mla_scale_q_lora") else 1.0),
+                mla_kv_scale=((h / hf["kv_lora_rank"]) ** 0.5
+                              if hf.get("mla_scale_kv_lora") else 1.0),
+                # The count this engine holds; the router's width is
+                # this times expert_parallel_size plus the
+                # zero-compute experts, which no engine holds.
+                num_experts=hf["n_routed_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["moe_topk"],
+                moe_intermediate_size=hf["expert_ffn_hidden_size"],
+                zero_expert_num=hf.get("zero_expert_num", 0),
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                activation="silu",
+                dtype="bfloat16",
+            )
         if "mixtral" in arch:
             return cls(
                 name=name or hf.get("_name_or_path", "mixtral"),
@@ -443,8 +567,8 @@ class ModelConfig:
             raise ValueError(
                 f"architecture {arch!r} is none this engine serves "
                 "(config.json 'architectures', else 'model_type'): it "
-                "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba, LFM2-MoE "
-                "and the "
+                "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba, LFM2-MoE, "
+                "LongCat-Flash and the "
                 f"Llama shapes {sorted(_LLAMA_SHAPES)}, and reads no "
                 "other as one of them")
         qwen = "qwen2" in arch
@@ -520,17 +644,20 @@ class CacheConfig:
         return "int8" if self.kv_cache_dtype == "int8" else "bf16"
 
     def kv_slot_bytes(self, model: "ModelConfig") -> int:
-        """HBM bytes one cached token costs per kv head per k-or-v
-        plane: head_dim values plus, for int8, one f32 scale."""
+        """HBM bytes one cached token costs per head of one plane of
+        one paged entry: the plane's rows plus, for int8, one f32
+        scale."""
+        width = model.page_cache.width
         if self.resolved_kv_dtype() == "int8":
-            return model.head_dim + 4
-        return model.head_dim * jnp.dtype(model.jax_dtype).itemsize
+            return width + 4
+        return width * jnp.dtype(model.jax_dtype).itemsize
 
     def kv_bytes_per_token(self, model: "ModelConfig") -> int:
-        """Total KV bytes appended per committed token (k and v,
-        all layers, all kv heads)."""
-        return (2 * model.num_kv_layers
-                * model.num_key_value_heads
+        """Total cache bytes appended per committed token: every paged
+        entry, every plane it has (K and V, or one latent), every
+        head."""
+        pc = model.page_cache
+        return (pc.planes * pc.entries * pc.heads
                 * self.kv_slot_bytes(model))
 
 
@@ -892,6 +1019,17 @@ class EngineConfig:
                 self.cache,
                 num_state_slots=(self.scheduler.max_num_seqs
                                  + self.scheduler.prefill_batch_size))
+        if self.model.has_latent_cache:
+            refused = _latent_cache_refusals(self)
+            if refused:
+                raise ValueError(
+                    f"{self.model.architecture} caches one latent a "
+                    "token a sublayer in the place of a (K, V) pair; "
+                    "refused: " + "; ".join(
+                        f"{feature} ({why})" for feature, why in refused))
+            if self.cache.cache_layout == "auto":
+                self.cache = dataclasses.replace(
+                    self.cache, cache_layout="per_layer")
         if self.cache.resolved_kv_dtype() == "int8":
             # int8 now composes with pipeline/context parallelism:
             # the pp/sp shard_map boundaries carry QuantKV pytree
@@ -992,6 +1130,43 @@ def _recurrent_state_refusals(config: "EngineConfig"):
     return [(feature, why) for on, feature, why in checks if on]
 
 
+def _latent_cache_refusals(config: "EngineConfig"):
+    """(feature, why) for every configured feature that reads, writes
+    or ships the cache as a (K, V) pair of one head size, and has not
+    been given a form for one latent plane an entry."""
+    s, p = config.scheduler, config.parallel
+    own = config.model.family.refusals
+    checks = (
+        (config.cache.resolved_kv_dtype() == "int8", "int8 KV pages",
+         "QuantKV quantizes a K and a V plane a head; the latent plane "
+         "has no quantized form"),
+        (config.engine_role != "both", "disaggregated prefill/decode",
+         "the handoff's KV_WIRE_VERSION frames carry K and V planes"),
+        (config.offload.enable, "KV offload",
+         "a page's payload is its K and V planes"),
+        (config.checkpoint_interval_tokens > 0,
+         "mid-stream checkpoint descriptors",
+         "a descriptor restores K and V pages"),
+        (s.speculative_k > 0, "speculative decoding",
+         "the verify step has no latent-attention form"),
+        (p.tensor_parallel_size > 1, "tensor parallelism",
+         own["tensor parallelism"]),
+        (p.pipeline_parallel_size > 1, "pipeline-parallel serving",
+         "the staged forward builds K and V planes"),
+        (p.context_parallel_size > 1, "context-parallel prefill",
+         "the ring prefill walks K and V planes"),
+        (config.model.quantization != "none", "weight quantization",
+         own["weight quantization"]),
+        (s.unified_step, "the unified ragged step",
+         "its kernels read K and V pages of one head size"),
+        (config.lora.enable, "LoRA", "the model has no LoRA targets"),
+        (config.cache.cache_layout == "stacked",
+         "cache_layout='stacked'",
+         "the latent planes are per-entry buffers, two a layer"),
+    )
+    return [(feature, why) for on, feature, why in checks if on]
+
+
 # ---- staticcheck config-contract markers -------------------------------
 # Read statically by staticcheck/analyzers/config_contract.py (keep
 # them literals). Every field reachable from EngineConfig must map to
@@ -1070,6 +1245,15 @@ INTERNAL_FIELDS = {
     "model.layer_types",
     "model.conv_L_cache",
     "model.num_dense_layers",
+    "model.kv_lora_rank",
+    "model.q_lora_rank",
+    "model.qk_nope_head_dim",
+    "model.qk_rope_head_dim",
+    "model.v_head_dim",
+    "model.mla_q_scale",
+    "model.mla_kv_scale",
+    "model.zero_expert_num",
+    "model.routed_scaling_factor",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -1193,6 +1377,45 @@ def tiny_lfm2_moe_config(expert_parallel_size: int = 1,
         expert_parallel_rank=expert_parallel_rank,
         num_experts_per_tok=2,
         moe_intermediate_size=32,
+        dtype="float32",
+    )
+
+
+def tiny_longcat_flash_config(expert_parallel_size: int = 1,
+                              expert_parallel_rank: int = 0
+                              ) -> ModelConfig:
+    """A tiny LongCat-Flash (two layers of two latent-attention
+    sublayers around a shortcut-connected expert branch, 8 routed + 4
+    zero-compute experts chosen 3 at a time, both low-rank scales on)
+    for tests that run anywhere."""
+    return ModelConfig(
+        name="tiny-longcat-flash",
+        architecture="longcat_flash",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=128,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=1,
+        head_dim=24,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        rope_theta=1e7,
+        tie_word_embeddings=False,
+        kv_lora_rank=24,
+        q_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        mla_q_scale=(64 / 32) ** 0.5,
+        mla_kv_scale=(64 / 24) ** 0.5,
+        num_experts=8 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        zero_expert_num=4,
+        routed_scaling_factor=6.0,
         dtype="float32",
     )
 

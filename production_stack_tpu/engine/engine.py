@@ -1337,6 +1337,8 @@ class LLMEngine:
                 self.metrics.moe_last["moe_tokens_per_expert_mean"],
             "engine_moe_held_choice_share":
                 self.metrics.moe_last["moe_held_choice_share"],
+            "engine_moe_zero_choice_share":
+                self.metrics.moe_last["moe_zero_choice_share"],
         }
         if self.offload is not None:
             out.update({

@@ -68,6 +68,7 @@ class EngineMetrics:
             "moe_tokens_per_expert_mean": 0.0,
             "moe_held_choice_share": 0.0,
             "moe_experts_hit": 0.0,
+            "moe_zero_choice_share": 0.0,
         }
         self.ttft = Histogram(_TTFT_BUCKETS)
         self.itl = Histogram(_ITL_BUCKETS)
@@ -146,6 +147,11 @@ class EngineMetrics:
             "moe_held_choice_share":
                 stats["held_choices"] / max(stats["choices"], 1.0),
             "moe_experts_hit": stats["experts_hit"] / steps,
+            # Choices that fell on zero-compute (identity) experts,
+            # for a family whose router has them; 0 otherwise.
+            "moe_zero_choice_share":
+                stats.get("zero_choices", 0.0)
+                / max(stats["choices"], 1.0),
         }
         self.moe_last = last
         return last

@@ -543,7 +543,7 @@ class ModelRunner:
         # step hands the forward each row's state slot beside its
         # page table.
         self._hybrid = model_config.has_recurrent_state
-        if self._hybrid:
+        if self._hybrid or model_config.family.page_cache is not None:
             self.k_cache, self.v_cache = init_hybrid_cache(
                 model_config, config.cache.num_pages,
                 config.cache.page_size, config.cache.num_state_slots)
@@ -970,7 +970,7 @@ class ModelRunner:
         return base_model, prefill_impl
 
     @staticmethod
-    def _lowering_error(fn, *args) -> Optional[str]:
+    def _lowering_error(fn, *args, **kwargs) -> Optional[str]:
         """Compile ``fn`` for the backend in use at ``args``' shapes;
         the refusal as a string, or None. A real compile, not only the
         Python lowering rules: Mosaic's machine-code pass and the
@@ -978,7 +978,7 @@ class ModelRunner:
         refusal has to be a start-up fact rather than the first
         request's surprise."""
         try:
-            jax.jit(fn).lower(*args).compile()
+            jax.jit(fn).lower(*args, **kwargs).compile()
             return None
         except Exception as e:  # noqa: BLE001 — any compile failure
             return repr(e)[:400]
@@ -994,6 +994,9 @@ class ModelRunner:
         kernel is probed in the form the burst calls: with the tails
         of a deferred-write burst where those are on.
         """
+        if model_config.has_latent_cache:
+            return self._resolve_latent_impls(model_config, config,
+                                              auto_impl)
         # The exact serving form of the cache (_probe_cache_struct).
         nh, d, dtype, max_pages, cache, layer0 = \
             self._probe_cache_struct(model_config, config)
@@ -1062,6 +1065,51 @@ class ModelRunner:
                     "Pallas %s kernel failed TPU lowering; this shape "
                     "serves via XLA attention: %s", name.upper(), err)
             setattr(model_config, f"attention_impl_{name}", impl)
+
+    def _resolve_latent_impls(self, model_config, config,
+                              auto_impl: bool) -> None:
+        """``_resolve_pallas_impls`` for a family whose pages hold one
+        latent plane an entry: the decode step has its own Pallas
+        kernel (ops/mla_attention_pallas.py), probed in the form the
+        burst calls; a prefill chunk is served by the XLA form, which
+        is said and not probed."""
+        from production_stack_tpu.ops.mla_attention_pallas import (
+            latent_paged_decode_attention,
+        )
+        m, dtype = model_config, model_config.jax_dtype
+        model_config.attention_impl_prefill = "xla"
+        err = pallas_backend_error(config)
+        if err is None:
+            b = config.scheduler.max_num_seqs
+            n, dn = m.num_attention_heads, m.qk_nope_head_dim
+            pages = m.page_cache
+            rows_i32 = jax.ShapeDtypeStruct((b,), np.int32)
+            tail = (jax.ShapeDtypeStruct(
+                (b, config.scheduler.decode_steps, 1, pages.width), dtype)
+                if config.scheduler.deferred_kv_writes else None)
+            err = self._lowering_error(
+                functools.partial(latent_paged_decode_attention,
+                                  scale=float(m.head_dim) ** -0.5),
+                jax.ShapeDtypeStruct((b, n, m.head_dim), dtype),
+                jax.ShapeDtypeStruct(
+                    (1, config.cache.num_pages, pages.width,
+                     config.cache.page_size), dtype),
+                jax.ShapeDtypeStruct(
+                    (b, config.scheduler.max_pages_per_seq(
+                        config.cache.page_size)), np.int32),
+                rows_i32,
+                jax.ShapeDtypeStruct((n, dn, m.kv_lora_rank), dtype),
+                jax.ShapeDtypeStruct((n, m.kv_lora_rank, m.v_head_dim),
+                                     dtype),
+                tail=tail, q_positions=None if tail is None else rows_i32)
+        if err and not auto_impl:
+            raise RuntimeError(
+                "attention_impl='pallas': the Pallas latent decode "
+                f"kernel cannot be served: {err}")
+        if err:
+            logger.error("Pallas latent decode kernel cannot be served; "
+                         "decode serves via XLA attention: %s", err)
+        model_config.attention_impl_decode = "xla" if err else "pallas"
 
     def set_guided_tables(self, fsm) -> None:
         """Device copies of the guided-decoding automaton tables
@@ -1366,20 +1414,24 @@ class ModelRunner:
             counts0 = jnp.zeros((b, 0), jnp.int32)
 
         kv_lens0 = positions[:, 0]  # pages hold this many tokens
-        tail_shape = (b, num_steps, m.num_key_value_heads, m.head_dim)
+        pages = m.page_cache
+        tail_shape = (b, num_steps, pages.heads, pages.width)
         # What each cache entry is to the burst: page planes where the
-        # layer is not recurrent; of a recurrent layer the state pool
-        # in k_cache (None, which rides as nothing, where its family
+        # entry is not a recurrent layer's (a family that stores one
+        # latent plane has None for the second, which rides as
+        # nothing); of a recurrent layer the state pool
+        # in k_cache (None again where its family
         # declares the tail alone) and in v_cache the convolution
         # tails, dense in the carry where the family's forward takes
         # them so; a k_cache that ends in its family's counters has
-        # one entry more than there are layers.
+        # one entry more than there are entries.
         conv = "conv" if m.family.conv_tail else "ride"
-        k_kinds = tuple("ride" if linear else "pages"
-                        for linear in m.layer_is_linear) + (
+        second = "pages" if pages.planes == 2 else "ride"
+        k_kinds = tuple("ride" if state else "pages"
+                        for state in m.cache_entry_is_state) + (
             "ride",) * bool(m.family.counters)
-        v_kinds = tuple(conv if linear else "pages"
-                        for linear in m.layer_is_linear)
+        v_kinds = tuple(conv if state else second
+                        for state in m.cache_entry_is_state)
         per_layer = isinstance(k_cache, tuple)
 
         def carried(cache, kinds):

@@ -186,15 +186,16 @@ class PerfObservatory:
         model = self.config.model
         cache = self.config.cache
         sched = self.config.scheduler
-        slots = 2 * model.num_kv_layers * model.num_key_value_heads
+        pages = model.page_cache
+        slots = pages.planes * pages.entries * pages.heads
         tokens = cache.num_pages * cache.page_size
         if cache.resolved_kv_dtype() == "int8":
-            kv_pages = slots * tokens * model.head_dim  # int8 data
+            kv_pages = slots * tokens * pages.width  # int8 data
             kv_scales = slots * tokens * 4  # f32 per-slot scales
         else:
             import jax.numpy as jnp
             itemsize = jnp.dtype(model.jax_dtype).itemsize
-            kv_pages = slots * tokens * model.head_dim * itemsize
+            kv_pages = slots * tokens * pages.width * itemsize
             kv_scales = 0
         rows = sched.max_num_seqs + sched.prefill_batch_size
         width = sched.prefill_chunk_size

@@ -2283,6 +2283,12 @@ class EngineServer:
         deferred = config.scheduler.deferred_kv_writes
         conv_tails = ({"conv_tails": "burst" if deferred else "step"}
                       if config.model.family.conv_tail else {})
+        # What a page holds: K and V planes, or one latent a token an
+        # entry stored once; and the bytes a committed token costs.
+        kv = {"kv": ("latent" if config.model.has_latent_cache
+                     else "pair"),
+              "kv_bytes_per_token":
+                  config.cache.kv_bytes_per_token(config.model)}
         return web.json_response({
             "version": __version__,
             "build_id": self.build_id,
@@ -2293,6 +2299,8 @@ class EngineServer:
                                if obs is not None else {}),
             "kv_writes": "deferred" if deferred else "eager",
             **conv_tails,
+            "family": config.model.architecture,
+            **kv,
         })
 
     async def kv_summary_handler(self, request: web.Request):
@@ -2342,7 +2350,8 @@ class EngineServer:
                 ("vllm:engine_prefix_declined_tokens_total", "counter"),
                 ("vllm:engine_moe_tokens_per_expert_max", "gauge"),
                 ("vllm:engine_moe_tokens_per_expert_mean", "gauge"),
-                ("vllm:engine_moe_held_choice_share", "gauge")):
+                ("vllm:engine_moe_held_choice_share", "gauge"),
+                ("vllm:engine_moe_zero_choice_share", "gauge")):
             lines.append(f"# TYPE {name} {kind}")
             lines.append(f"{name} {float(stats[name[5:]])}")
         # KV quantization telemetry: page budget after any int8
@@ -2662,9 +2671,10 @@ def _resolve_unified_step(args, model_config=None) -> bool:
         return True
     if args.unified_step == "off":
         return False
-    if model_config is not None and model_config.has_recurrent_state:
-        # The ragged rows have no path for a recurrent state
-        # (engine/config.py refuses an explicit 'on').
+    if model_config is not None and (model_config.has_recurrent_state
+                                     or model_config.has_latent_cache):
+        # The ragged rows have no path for a recurrent state or for a
+        # latent plane (engine/config.py refuses an explicit 'on').
         return False
     from production_stack_tpu.engine.model_runner import (
         unified_step_eligible,
@@ -2913,7 +2923,8 @@ def parse_args(argv=None):
                         help="Defer decode KV writes to one batched "
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
-                             "qwen3_next, jamba, lfm2_moe; decode-steps "
+                             "qwen3_next, jamba, lfm2_moe, longcat_flash; "
+                             "decode-steps "
                              "> 1, no pp/sp); /version "
                              "says which "
                              "is served (kv_writes)")

@@ -27,6 +27,17 @@ and the runner ask the family and name no model:
   that not every hybrid refuses for the same reason
   (``engine/config.py`` ``_recurrent_state_refusals`` words the rest).
 
+A family whose pages do not hold K and V of ``num_key_value_heads``
+heads of ``head_dim``, one entry a layer, declares what they hold:
+
+- ``page_cache(config)``: a ``PageCache``: how many paged entries the
+  model keeps (a layer may have more than one attention sublayer),
+  the heads and the rows a token of one plane, and the planes an
+  entry has: 2 is K and V, 1 is a latent that serves as both and is
+  stored once, its second plane ``None`` and never made, read, written
+  or counted. The cache builder, the burst's tails and flush, the
+  bytes a token and the page budget follow it.
+
 This module imports no model and nothing of the engine at load, so
 ``engine/config.py`` can ask it.
 """
@@ -35,7 +46,14 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
+
+
+class PageCache(NamedTuple):
+    entries: int   # paged cache entries of the whole model
+    heads: int     # heads of one plane
+    width: int     # rows a token a head
+    planes: int    # 2: K and V; 1: one latent, stored once
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +65,7 @@ class Family:
     conv_tail: bool = False
     counters: Tuple[str, ...] = ()
     refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
+    page_cache: Optional[Callable] = None
 
 
 def _qwen3_next_layers(c) -> tuple:
@@ -96,6 +115,16 @@ def _lfm2_moe_state(c) -> tuple:
     return (((c.conv_L_cache - 1, c.hidden_size), "model"),)
 
 
+def _longcat_flash_pages(c) -> PageCache:
+    """Two latent-attention sublayers a layer, each with its own
+    cache: per token the compressed latent (``kv_lora_rank``) and the
+    one rotary key every head shares (``qk_rope_head_dim``), side by
+    side in one plane of one head. The latent's rows are the values
+    too, so there is no second plane."""
+    return PageCache(entries=2 * c.num_hidden_layers, heads=1,
+                     width=c.kv_lora_rank + c.qk_rope_head_dim, planes=1)
+
+
 _EXPERT_COUNTERS = ("layer_steps", "choices", "held_choices", "max_load",
                     "experts_hit")
 
@@ -140,6 +169,17 @@ FAMILIES: Dict[str, Family] = {
                                    "and the experts have no quantized "
                                    "form",
         }),
+    "longcat_flash": Family(
+        "longcat_flash", deferred_kv=True,
+        page_cache=_longcat_flash_pages,
+        counters=_EXPERT_COUNTERS + ("zero_choices",),
+        refusals={
+            "tensor parallelism": "the latent is one head shared by "
+                                  "every query head, and the expert "
+                                  "layer has no sharding rules",
+            "weight quantization": "the low-rank projections and the "
+                                   "experts have no quantized form",
+        }),
 }
 
 
@@ -181,13 +221,25 @@ def state_pools(config) -> tuple:
     return (None,) * (2 - len(entries)) + entries
 
 
+def page_cache(config) -> PageCache:
+    """What the configuration's paged cache holds, as its family
+    declares it, or K and V of every layer that is not recurrent."""
+    declared = family(config.architecture).page_cache
+    if declared is not None:
+        return declared(config)
+    return PageCache(entries=config.num_kv_layers,
+                     heads=config.num_key_value_heads,
+                     width=config.head_dim, planes=2)
+
+
 def init_hybrid_cache(config, num_pages: int, page_size: int,
                       num_state_slots: int):
-    """A hybrid family's per-layer cache tuples: page buffers for the
-    attention layers, the state pools it declares (``num_state_slots``
-    + the trash slot 0; ``None`` where it declares none) for the
-    recurrent ones, and after the layers the family's counters, if it
-    keeps any, as one more ``k_cache`` entry."""
+    """The per-entry cache tuples of a family that declares its cache
+    here: page buffers for the paged entries (the second plane ``None``
+    where the family declares one), the state pools it declares
+    (``num_state_slots`` + the trash slot 0; ``None`` where it declares
+    none) for the recurrent layers, and after the entries the family's
+    counters, if it keeps any, as one more ``k_cache`` entry."""
     import jax.numpy as jnp
 
     fam = family(config.architecture)
@@ -201,17 +253,18 @@ def init_hybrid_cache(config, num_pages: int, page_size: int,
             (num_state_slots + 1,) + tuple(shape),
             model_dtype if dtype == "model" else jnp.dtype(dtype))
 
-    k_entry, v_entry = state_pools(config)
-    page_shape = (config.num_key_value_heads, num_pages, config.head_dim,
-                  page_size)
+    pages = page_cache(config)
+    page_shape = (pages.heads, num_pages, pages.width, page_size)
     k_cache, v_cache = [], []
-    for recurrent in fam.recurrent_layers(config):
+    for recurrent in config.cache_entry_is_state:
         if recurrent:
+            k_entry, v_entry = state_pools(config)
             k_cache.append(pool(k_entry))
             v_cache.append(pool(v_entry))
         else:
             k_cache.append(jnp.zeros(page_shape, model_dtype))
-            v_cache.append(jnp.zeros(page_shape, model_dtype))
+            v_cache.append(jnp.zeros(page_shape, model_dtype)
+                           if pages.planes == 2 else None)
     if fam.counters:
         k_cache.append(jnp.zeros((len(fam.counters),), jnp.float32))
     return tuple(k_cache), tuple(v_cache)

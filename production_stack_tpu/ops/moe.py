@@ -3,7 +3,10 @@
 The router scores every expert of the model (``router_w`` is as wide
 as the published count) and a token keeps its ``top_k`` choices
 (``route``: softmax over all experts; ``route_sigmoid``: a sigmoid an
-expert, chosen with a learned bias and weighted without it). This
+expert, chosen with a learned bias and weighted without it;
+``route_softmax_bias``: softmax scores chosen with a learned bias,
+scaled and not renormalised, over routed and zero-compute experts,
+whose part of the sum is ``identity_weight``). This
 engine holds one contiguous block of the experts,
 ``[first_expert, first_expert + E)``, and computes for each token the
 part of the weighted sum that its held choices give; what experts held
@@ -65,6 +68,35 @@ def route_sigmoid(x: jnp.ndarray, router_w: jnp.ndarray,
     _, ids = jax.lax.top_k(scores + expert_bias, top_k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     return weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6), ids
+
+
+def route_softmax_bias(x: jnp.ndarray, router_w: jnp.ndarray,
+                       expert_bias: jnp.ndarray, top_k: int,
+                       scale: float):
+    """``route`` for a router whose choice is biased and whose weights
+    are not renormalised: softmax over ALL the router's outputs in
+    float32 (zero-compute experts among them); the ``top_k`` largest
+    of score + ``expert_bias`` (learned, [E_all] float32) are chosen,
+    and their weights are ``scale`` times the scores WITHOUT the bias.
+
+    x [N, H], router_w [H, E_all] -> (weights [N, k] f32, ids [N, k]).
+    """
+    scores = jax.nn.softmax(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32),
+        axis=-1)
+    _, ids = jax.lax.top_k(scores + expert_bias, top_k)
+    return scale * jnp.take_along_axis(scores, ids, axis=-1), ids
+
+
+def identity_weight(weights: jnp.ndarray, ids: jnp.ndarray,
+                    first_zero_expert: int):
+    """What a token's choices of zero-compute (identity) experts, the
+    ids from ``first_zero_expert`` on, weigh together: ``[N]`` float32,
+    the factor of the token's own input in the routed sum; and how
+    many such choices each token made, ``[N]`` int32."""
+    zero = ids >= first_zero_expert
+    return (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1),
+            jnp.sum(zero, axis=-1, dtype=jnp.int32))
 
 
 def _grouped_dot(lhs, rhs, group_sizes, impl: str):

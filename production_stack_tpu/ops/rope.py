@@ -36,3 +36,20 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           theta: float) -> jnp.ndarray:
+    """``apply_rope`` for heads whose rotary pairs lie side by side,
+    ``(x[2i], x[2i+1])`` turning by ``positions / theta^(2i/d)`` (the
+    DeepSeek-family convention), where ``apply_rope`` pairs ``x[i]``
+    with ``x[i + d/2]``. Same argument shapes."""
+    d = x.shape[-1]
+    timescale = theta ** (jnp.arange(d // 2, dtype=jnp.float32) / (d // 2))
+    angles = (positions[..., None].astype(jnp.float32)
+              / timescale)[..., None, :]  # [..., seq, 1, d/2]
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    pairs = x.reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -5,7 +5,7 @@ name fails here and not first on the chip.
 
 The cases live with the benchmark (``chipbench/tests/``, run there by
 ``JAX_PLATFORMS=cpu python -m pytest chipbench/tests -q``); this module
-takes the test functions and fixtures of six of its files as they
+takes the test functions and fixtures of seven of its files as they
 are, so each is collected, run and counted here under its own name.
 No two of the files give a test or a fixture the same name.
 
@@ -30,6 +30,7 @@ import pytest
 from chipbench.tests.test_family import *  # noqa: F401,F403
 from chipbench.tests.test_jamba_family import *  # noqa: F401,F403
 from chipbench.tests.test_lfm2_family import *  # noqa: F401,F403
+from chipbench.tests.test_longcat_family import *  # noqa: F401,F403
 from chipbench.tests.test_mixtral_family import *  # noqa: F401,F403
 from chipbench.tests.test_qwen3_next_family import *  # noqa: F401,F403
 from chipbench.tests.test_scopes import *  # noqa: F401,F403
@@ -79,8 +80,8 @@ def test_no_two_files_share_a_name():
     import importlib
     seen = {}
     for name in ("test_family", "test_jamba_family", "test_lfm2_family",
-                 "test_mixtral_family", "test_qwen3_next_family",
-                 "test_scopes"):
+                 "test_longcat_family", "test_mixtral_family",
+                 "test_qwen3_next_family", "test_scopes"):
         module = importlib.import_module(f"chipbench.tests.{name}")
         for attr, value in vars(module).items():
             ours = getattr(value, "__module__", None) == module.__name__

@@ -438,3 +438,116 @@ def test_decode_burst_program_int8_lowers_for_tpu():
         runner._decode_burst_impl, static_argnames=("num_steps",)
     ).trace(*args, num_steps=8)
     traced.lower(lowering_platforms=("tpu",))
+
+
+# ---- the latent (MLA) decode kernel at the published sizes -----------------
+
+# longcat-flash-omni-ep32's decode batch: rows, heads, the query head
+# (128 + 64), the latent (512 + 64), the value head, pages, the table's
+# width (max-model-len 4352 over the page of 128).
+LATENT_CELL = (160, 64, 128, 64, 512, 128, 3328, 34)
+
+
+def _latent_decode_shapes(steps=32, sharding=None):
+    """(q, plane, table, kv_lens, w_uk, w_uv, tail, q_positions) of one
+    sublayer's call in the cell's deferred burst, as shapes."""
+    rows, n, dn, dr, rank, dv, pages, max_pages = LATENT_CELL
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    rows_i32 = shape((rows,), jnp.int32)
+    return (shape((rows, n, dn + dr)), shape((1, pages, rank + dr, 128)),
+            shape((rows, max_pages), jnp.int32), rows_i32,
+            shape((n, dn, rank)), shape((n, rank, dv)),
+            shape((rows, steps, 1, rank + dr)), rows_i32)
+
+
+def _latent_decode(q, plane, table, lens, w_uk, w_uv, tail, positions):
+    from production_stack_tpu.ops.mla_attention_pallas import (
+        latent_paged_decode_attention,
+    )
+    return latent_paged_decode_attention(
+        q, plane, table, lens, w_uk, w_uv, 192 ** -0.5, tail=tail,
+        q_positions=positions)
+
+
+def test_latent_decode_kernel_with_a_tail_lowers_at_the_cells_shapes():
+    text = _lower_for_tpu(_latent_decode,
+                          *_latent_decode_shapes()).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_latent_decode_kernel_with_a_tail_compiles_for_a_v5e(one_chip):
+    """What ``auto`` probes at start-up on the chip, made here: a
+    shape the compiler refuses is found on the CPU."""
+    compiled = jax.jit(_latent_decode).lower(
+        *_latent_decode_shapes(sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_latent_decode_step_never_expands_cached_tokens_to_heads():
+    """One decode step of the whole model at the published widths,
+    lowered for the TPU in the form the cell's burst runs (the Pallas
+    latent kernel, the grouped expert product, tails of 24 steps so
+    that the tail's axis is no other's): no array anywhere holds
+    per-head keys or values (64 heads of 192 or 128) for cached tokens,
+    the tail's 24 or the plane's 3328 pages: materialised, the tail
+    alone would be [160, 24, 64, 128] and the pages 35 times their
+    bytes, and every test of the numbers would pass."""
+    import json
+    import os
+    import re
+
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.models import longcat_flash
+    from production_stack_tpu.models.registry import init_hybrid_cache
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs",
+        "longcat-flash-omni-ep32.json")
+    with open(path) as f:
+        hf = json.load(f)
+    hf.pop("chipbench")
+    config = ModelConfig.from_hf_config(hf)
+    config.attention_impl = "pallas"
+    params = jax.eval_shape(
+        lambda key: longcat_flash.init_params(config, key),
+        jax.random.PRNGKey(0))
+    k_cache, v_cache = jax.eval_shape(
+        lambda: init_hybrid_cache(config, 3328, 128, 0))
+    rows, steps = 160, 24
+    tails = tuple(jax.ShapeDtypeStruct((rows, steps, 1, 576), jnp.bfloat16)
+                  for _ in range(8)) + (k_cache[-1],)
+
+    def i32(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32)
+
+    def step(params, k_cache, tails, tokens, positions, table, lens, valid):
+        return longcat_flash.forward(
+            params, config, tokens, positions, table, lens, valid, k_cache,
+            v_cache, kv_tail=(tails, v_cache))
+
+    real = longcat_flash.hybrid_kernel_impl
+    longcat_flash.hybrid_kernel_impl = lambda c: "pallas"
+    try:
+        text = jax.jit(step).trace(
+            params, k_cache, tails, i32(rows, 1), i32(rows, 1),
+            i32(rows, 34), i32(rows),
+            jax.ShapeDtypeStruct((rows, 1), jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        longcat_flash.hybrid_kernel_impl = real
+    # The latent kernel and the grouped product's two, each a function
+    # of the module that the sublayers and the branches call.
+    assert text.count("tpu_custom_call") >= 3
+    shapes = {tuple(int(d) for d in dims.split("x"))
+              for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text)
+              for dims in [dims.rstrip("x")]}
+    assert (rows, steps, 1, 576) in shapes       # the latent tail
+    assert (1, 3328, 576, 128) in shapes         # the plane
+    assert (rows, 64, 192) in shapes             # a row's own query
+    expanded = [s for s in shapes
+                if 64 in s and (192 in s or 128 in s)
+                and (steps in s or 3328 in s or 34 in s)]
+    assert not expanded, expanded
