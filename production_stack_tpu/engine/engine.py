@@ -891,6 +891,7 @@ class LLMEngine:
                     "kind": "prefill",
                     "prefill_rows": len(plan.prefill.chunks),
                     "prefill_chain": plan.prefill.chain,
+                    "prefill_width": self.runner.last_prefill_width,
                     "row_bucket": self.runner.prefill_width,
                 }
         self._obs_note = ("prefill",
@@ -1270,6 +1271,8 @@ class LLMEngine:
             "num_preemptions_total": self.scheduler.num_preemptions,
             "engine_prefill_chained_steps_total":
                 self.scheduler.num_chained_prefill_steps,
+            "engine_prefill_narrow_steps_total":
+                self.runner.num_narrow_prefill_steps,
             "spec_decode_num_draft_tokens_total":
                 self.metrics.spec_draft_tokens_total,
             "spec_decode_num_accepted_tokens_total":

@@ -10,6 +10,7 @@ every step so the arrays are updated in place in HBM.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import List, Optional, Tuple
 
 import jax
@@ -199,6 +200,32 @@ def prefill_buckets(chunk_size: int) -> List[int]:
         b *= 2
     buckets.append(chunk_size)
     return buckets
+
+
+def prefill_shapes(batch_size: int, chunk_size: int
+                   ) -> List[Tuple[int, int]]:
+    """Every (rows, tokens) shape a prefill step is compiled at: the
+    full width at each token bucket, and half the rows at the top
+    bucket. One half width and no more: each shape is one more
+    whole-model program in every start (the layers are unrolled), the
+    padding is at the top bucket (a wide step at a low bucket is few
+    token places already), and a quarter width would be a second
+    program for a few per cent of the one step it serves."""
+    shapes = [(batch_size, t) for t in prefill_buckets(chunk_size)]
+    half = -(-batch_size // 2)
+    if half < batch_size:
+        shapes.append((half, chunk_size))
+    return shapes
+
+
+def prefill_shape(rows: int, longest: int, batch_size: int,
+                  chunk_size: int) -> Tuple[int, int]:
+    """The compiled shape a plan of ``rows`` chunk rows whose longest
+    chunk has ``longest`` tokens runs at: of the shapes that hold it,
+    the one with the fewest token places; a tie stays wide."""
+    return min((s for s in prefill_shapes(batch_size, chunk_size)
+                if s[0] >= rows and s[1] >= longest),
+               key=lambda s: (s[0] * s[1], -s[0]))
 
 
 class DecodeStepHandle:
@@ -583,6 +610,13 @@ class ModelRunner:
         self.last_attn_pages: Optional[int] = None
         self.decode_width = config.scheduler.max_num_seqs
         self.prefill_width = config.scheduler.prefill_batch_size
+        # The rows the last prefill step ran at (the turn record's
+        # ``prefill_width``), the steps run under the full width
+        # (vllm:engine_prefill_narrow_steps_total), and whether both
+        # widths of the top bucket are up (_other_width_payloads).
+        self.last_prefill_width = self.prefill_width
+        self.num_narrow_prefill_steps = 0
+        self._top_bucket_warm = False
         self._buckets = prefill_buckets(
             config.scheduler.prefill_chunk_size
         )
@@ -1036,7 +1070,13 @@ class ModelRunner:
             )],
             # Serving compiles one prefill program per bucket — probe
             # them all, not just the widest (a Mosaic rule can fail at
-            # one bucket shape only).
+            # one bucket shape only). The half-width shape
+            # (prefill_shapes) is not probed: rows are an axis of the
+            # kernel's grid and of no block, so it compiles where the
+            # full width at that bucket does (tests/
+            # test_pallas_lowering.py compiles it for a v5e at the
+            # cells' head shapes), and a probe is seconds of every
+            # start.
             "prefill": [(
                 paged_prefill_attention,
                 (jax.ShapeDtypeStruct((pb, t, nh, d), dtype), cache,
@@ -1765,6 +1805,38 @@ class ModelRunner:
         )
         return sampled
 
+    def _load_step_program(self, payload: dict) -> threading.Thread:
+        """Bring up the plain prefill program of ``payload``'s shape
+        beside whatever the caller does next: lowered here (the
+        caches are read for their shapes, before the next dispatch
+        donates them), compiled or read from the compile cache on a
+        thread. The jitted step keeps the executable with its
+        lowering, so the dispatch of that shape that follows finds it
+        and is counted by ``/debug/compiles`` as any first call is."""
+        args = tuple(_as_device(payload[name]) for name in (
+            "tokens", "positions", "page_table", "kv_lens", "valid",
+            "last_index", "temperature", "top_p", "top_k", "rng"))
+        lora_ids = payload.get("lora_ids")
+        state = ({"state_slots": _as_device(payload["state_slots"])}
+                 if "state_slots" in payload else {})
+        lowered = self._step_jit.lower(
+            self.params, self.k_cache, self.v_cache, *args,
+            self._lora_stack,
+            None if lora_ids is None else _as_device(lora_ids),
+            None, None, None, None, None,
+            sample_index_mode="last", want_logprobs=False, **state)
+
+        def compile_it():
+            try:
+                lowered.compile()
+            except Exception:  # noqa: BLE001 — the dispatch raises it
+                logger.exception("prefill program failed to compile")
+
+        thread = threading.Thread(target=compile_it, daemon=True,
+                                  name="prefill-width-compile")
+        thread.start()
+        return thread
+
     @staticmethod
     def _lp_entry(seq, slp, tids, tlps):
         """One position's logprob info, trimmed to the row's request."""
@@ -2043,6 +2115,39 @@ class ModelRunner:
                     [self._lp_entry(seq, slp[0], tids[0], tlps[0])])
         return [int(host[0])], None
 
+    def _other_width_payloads(self, b: int, t: int) -> List[dict]:
+        """The top bucket has two widths and a smoke request or a
+        benchmark's warm prompt reaches one: the first step there
+        brings up the other in the same turn (run_prefill), so neither
+        compiles under load. Its step is all pad rows: nothing valid,
+        the trash page and state slot, temperature 0, and a constant
+        key, so the serving key stream, the live pages and the slots
+        stay as they were. It takes the plain form of the program; a
+        form that an option of a request adds compiles when one asks,
+        as at every shape."""
+        payloads = []
+        for rows, tokens in prefill_shapes(self.prefill_width, t):
+            if tokens != t or rows == b:
+                continue
+            payload = {
+                "tokens": np.zeros((rows, t), np.int32),
+                "positions": np.zeros((rows, t), np.int32),
+                "valid": np.zeros((rows, t), bool),
+                "page_table": self._page_table_rows([], pad_to=rows),
+                "kv_lens": np.zeros((rows,), np.int32),
+                "last_index": np.zeros((rows,), np.int32),
+                "temperature": np.zeros((rows,), np.float32),
+                "top_p": np.ones((rows,), np.float32),
+                "top_k": np.zeros((rows,), np.int32),
+                "rng": np.zeros((2,), np.uint32),
+            }
+            if self._hybrid:
+                payload["state_slots"] = self._state_slot_rows([], rows)
+            if self.lora_registry is not None:
+                payload["lora_ids"] = np.zeros((rows,), np.int32)
+            payloads.append(payload)
+        return payloads
+
     def run_prefill(self, plan: PrefillPlan
                     ) -> Tuple[List[Optional[int]], Optional[list]]:
         """Execute one batched prefill step (the next chunk of up to
@@ -2056,8 +2161,12 @@ class ModelRunner:
         if self.tracer is not None:
             self.tracer.phase("build")
         chunks = plan.chunks
-        b = self.prefill_width
-        t = self._bucket_for(max(len(c.chunk_tokens) for c in chunks))
+        b, t = prefill_shape(
+            len(chunks), max(len(c.chunk_tokens) for c in chunks),
+            self.prefill_width, self._buckets[-1])
+        self.last_prefill_width = b
+        if b < self.prefill_width:
+            self.num_narrow_prefill_steps += 1
 
         tokens = np.zeros((b, t), np.int32)
         positions = np.zeros((b, t), np.int32)
@@ -2119,7 +2228,17 @@ class ModelRunner:
         if want_lp:
             payload["want_logprobs"] = True
 
+        others = []
+        if t == self._buckets[-1] and not self._top_bucket_warm:
+            # The other width's program loads while this step's does.
+            self._top_bucket_warm = True
+            others = [(p, self._load_step_program(p))
+                      for p in self._other_width_payloads(b, t)]
         sampled = self._dispatch(1, t, payload)
+        for other, loading in others:
+            loading.join()
+            # Through _dispatch, so a multihost worker follows.
+            self._dispatch(1, t, other)
         host = None
         out: List[Optional[int]] = []
         lps: List[Optional[tuple]] = []

@@ -2338,13 +2338,15 @@ class EngineServer:
         lines.append("vllm:num_preemptions_total "
                      f"{float(stats['num_preemptions_total'])}")
         # The full prefill steps that plan_step's chains put before a
-        # burst, then the hybrid models' figures
+        # burst, the prefill steps run at the half width
+        # (model_runner.prefill_shape), then the hybrid models' figures
         # (docs/observability.md): the recurrent-state
         # pool beside the pages, prefix hits declined for want of a
         # state, and the held experts' load over the last decode
         # dispatch. Zeros for a model with neither.
         for name, kind in (
                 ("vllm:engine_prefill_chained_steps_total", "counter"),
+                ("vllm:engine_prefill_narrow_steps_total", "counter"),
                 ("vllm:engine_state_slots_used", "gauge"),
                 ("vllm:engine_state_slots_total", "gauge"),
                 ("vllm:engine_prefix_declined_tokens_total", "counter"),

@@ -259,7 +259,8 @@ class MultihostStepBridge:
     always offer a matching zero-filled structure to the endpoint's
     ``broadcast``. ``flags`` carries the presence of the optional
     per-request inputs (penalties, seeding, logprobs) whose keys are
-    request-dependent rather than config-dependent.
+    request-dependent rather than config-dependent, and the one row
+    width that is the plan's (a prefill step at half the rows).
 
     Rank 0 owns scheduling; followers mirror its dispatch sequence
     exactly. ``endpoint`` defaults to the real jax.distributed
@@ -275,6 +276,8 @@ class MultihostStepBridge:
     FLAG_BIAS = 8
     FLAG_SUPPRESS = 16
     FLAG_GUIDED = 32
+    # A prefill step at half the rows (model_runner.prefill_shape).
+    FLAG_NARROW = 64
 
     def __init__(self, runner, endpoint=None, num_slices: int = 1,
                  liveness_timeout_s: float = 10.0):
@@ -317,6 +320,8 @@ class MultihostStepBridge:
             }
         if kind == KIND_PREFILL:
             b, tt = r.prefill_width, t
+            if flags & self.FLAG_NARROW:
+                b = -(-b // 2)
         elif kind == KIND_SPEC:
             # Verify steps score t = speculative_k + 1 positions per
             # decode slot; t is static per engine config so the shape
@@ -395,9 +400,15 @@ class MultihostStepBridge:
 
     # -- host 0 --------------------------------------------------------------
 
-    def publish(self, kind: int, t: int,
-                payload: Dict[str, np.ndarray]) -> None:
+    def payload_flags(self, kind: int,
+                      payload: Dict[str, np.ndarray]) -> int:
+        """The header's third word: which optional inputs the payload
+        carries and whether a prefill step runs at half the rows —
+        with (kind, t), all a follower needs to offer its shapes."""
         flags = 0
+        if (kind == KIND_PREFILL
+                and len(payload["tokens"]) < self.runner.prefill_width):
+            flags |= self.FLAG_NARROW
         if "pen_prompt_mask" in payload:
             flags |= self.FLAG_PENALTIES
         if "seed_rows" in payload:
@@ -410,6 +421,11 @@ class MultihostStepBridge:
             flags |= self.FLAG_SUPPRESS
         if "fsm_state" in payload:
             flags |= self.FLAG_GUIDED
+        return flags
+
+    def publish(self, kind: int, t: int,
+                payload: Dict[str, np.ndarray]) -> None:
+        flags = self.payload_flags(kind, payload)
         header = np.asarray([kind, t, flags], np.int32)
         self.endpoint.broadcast(header)
         if kind != KIND_SHUTDOWN:

@@ -137,6 +137,9 @@ _ROUTER_UNSCRAPED = frozenset({
     # Prefill steps chained before a burst (engine scheduler): says
     # a replica is admission-bound; an operator's rate.
     "vllm:engine_prefill_chained_steps_total",
+    # Prefill steps run at the half width (engine model runner): how
+    # often a step is at most half full; an operator's rate.
+    "vllm:engine_prefill_narrow_steps_total",
     # Autotune decision counts are an operator/dashboard rate, not a
     # routing signal — cluster Prometheus reads them directly.
     "vllm:autotune_decisions_total",

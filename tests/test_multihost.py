@@ -121,19 +121,7 @@ def test_bridge_template_matches_real_payloads():
     published = []
 
     def fake_publish(kind, t, payload):
-        flags = 0
-        if "pen_prompt_mask" in payload:
-            flags |= bridge.FLAG_PENALTIES
-        if "seed_rows" in payload:
-            flags |= bridge.FLAG_SEEDING
-        if payload.get("want_logprobs"):
-            flags |= bridge.FLAG_LOGPROBS
-        if "logit_bias" in payload:
-            flags |= bridge.FLAG_BIAS
-        if "sup_ids" in payload:
-            flags |= bridge.FLAG_SUPPRESS
-        if "fsm_state" in payload:
-            flags |= bridge.FLAG_GUIDED
+        flags = bridge.payload_flags(kind, payload)
         arrays = {k: v for k, v in payload.items()
                   if k != "want_logprobs"}
         published.append((kind, t, flags, arrays))

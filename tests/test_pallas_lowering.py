@@ -77,13 +77,44 @@ def test_prefill_kernel_lowers_for_tpu():
     _lower_for_tpu(paged_prefill_attention, *_prefill_args())
 
 
-@pytest.mark.parametrize("t", [16, 64, 256])
-def test_prefill_kernel_lowers_every_bucket(t):
-    """All prefill buckets the model runner can emit must lower."""
+# The half-width prefill shape (model_runner.prefill_shapes) of the
+# cells that serve the Pallas prefill kernel (chipbench/configs/*.json):
+# rows, tokens, query heads, kv heads, head_dim, pages, table width.
+CELL_HALF_PREFILL = {
+    "qwen2.5-3b": (4, 256, 16, 2, 128, 1408, 64),
+    "qwen3-next-80b-a3b-ep4": (4, 256, 16, 2, 256, 2048, 64),
+    "jamba2-3b": (4, 128, 20, 1, 128, 3072, 32),
+    "lfm2-8b-a1b-ep4": (8, 128, 32, 8, 64, 4096, 32),
+}
+
+
+def _cell_half_prefill_shapes(cell, sharding=None):
+    """(q, k plane, v plane, table, positions, kv_lens) of one
+    layer's call in the cell's half-width prefill step, as shapes."""
+    rows, t, q_heads, kv, d, pages, max_pages = CELL_HALF_PREFILL[cell]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    plane = shape((kv, pages, d, 128), jnp.bfloat16)
+    return (shape((rows, t, q_heads, d), jnp.bfloat16), plane, plane,
+            shape((rows, max_pages), jnp.int32),
+            shape((rows, t), jnp.int32), shape((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("case", [16, 64, 256]
+                         + sorted(CELL_HALF_PREFILL))
+def test_prefill_kernel_lowers_every_bucket(case):
+    """All prefill shapes the model runner can emit must lower: every
+    token bucket at the full width, and the cells' half widths at
+    their own head shapes."""
     from production_stack_tpu.ops.prefill_attention_pallas import (
         paged_prefill_attention,
     )
-    _lower_for_tpu(paged_prefill_attention, *_prefill_args(t=t))
+    args = (_prefill_args(t=case) if isinstance(case, int)
+            else _cell_half_prefill_shapes(case))
+    text = _lower_for_tpu(paged_prefill_attention, *args).as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_decode_kernel_lowers_small_group():
@@ -232,6 +263,19 @@ def one_chip():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_HALF_PREFILL))
+def test_prefill_kernel_compiles_for_a_v5e_at_the_half_width(
+        cell, one_chip):
+    """What ``auto`` probes at start-up on the chip for the shape the
+    half-full steps run at, made here."""
+    from production_stack_tpu.ops.prefill_attention_pallas import (
+        paged_prefill_attention,
+    )
+    compiled = jax.jit(paged_prefill_attention).lower(
+        *_cell_half_prefill_shapes(cell, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @cells

@@ -107,17 +107,21 @@ def test_compile_ledger_first_run_then_stable():
 
 def test_shape_perturbation_compiles_exactly_once():
     """A prompt that crosses into the next W bucket (16 -> 32) adds
-    exactly one compile event, and the ledger records the shape key
-    that triggered it."""
+    exactly one compile event for each program of that bucket, and
+    the ledger records the shape keys: 32 is the top bucket here, whose
+    two row widths come up together (model_runner.prefill_shapes), the
+    half the one row runs at and the full one beside it."""
     engine = _engine()
     obs = engine.runner.observatory
     _run(engine, range(2, 12))  # 10 tokens: the W=16 prefill bucket
     warm = obs.compile_events_total("step")
     _run(engine, range(2, 22))  # 20 tokens: first W=32 prefill
-    assert obs.compile_events_total("step") == warm + 1
-    newest = obs.recent_compiles()[-1]
-    assert newest["kind"] == "step"
-    assert newest["key"][-1] == 32
+    assert obs.compile_events_total("step") == warm + 2
+    newest = obs.recent_compiles()[-2:]
+    assert [e["kind"] for e in newest] == ["step", "step"]
+    assert [e["key"] for e in newest] == [[2, 32], [4, 32]]
+    _run(engine, range(2, 23))  # the bucket again: nothing compiles
+    assert obs.compile_events_total("step") == warm + 2
 
 
 def test_observatory_none_is_passthrough_byte_identical():

@@ -407,6 +407,35 @@ def test_closed_loop_fills_the_rows():
     assert chained and set(chained) == {8}
 
 
+@pytest.mark.parametrize("cls", [Scheduler, Alternating])
+def test_a_chained_step_is_never_narrow(cls):
+    """Whatever the closed loop plans, a step of place 2 and up holds
+    ``prefill_batch_size`` rows, so the runner's rule (the fewest
+    token places among its shapes) keeps it at the full width; steps
+    of place 1 do go narrow there."""
+    from production_stack_tpu.engine.model_runner import prefill_shape
+
+    rs = random.Random(3)
+    eng = FakeEngine(cls=cls, max_num_seqs=128, chunk=256, page_size=128,
+                     num_pages=2048, max_model_len=8192)
+    for _ in range(160):
+        eng.add(rs.randint(64, 512), max_tokens=rs.randint(64, 256))
+    widths = {1: set(), 2: set()}
+    for _ in range(400):
+        done = len(eng.finished)
+        eng.turn()
+        for _ in range(len(eng.finished) - done):
+            eng.add(rs.randint(64, 512), max_tokens=rs.randint(64, 256))
+        plan = eng.last.prefill
+        if plan is not None:
+            rows, _ = prefill_shape(
+                len(plan.chunks),
+                max(len(c.chunk_tokens) for c in plan.chunks), WIDTH, 256)
+            widths[min(plan.chain, 2)].add(rows)
+    assert widths[1] == {WIDTH // 2, WIDTH}
+    assert widths[2] == ({WIDTH} if cls is Scheduler else set())
+
+
 # ---- the real engine: same tokens, the record and the counter --------------
 
 def _tiny_engine(prefill_batch_size, max_num_seqs=16):
@@ -460,6 +489,11 @@ def test_chained_steps_generate_what_serial_admission_does():
     chained = sum(c > 1 for c in chain)
     assert engine.stats()["engine_prefill_chained_steps_total"] \
         == chained >= 3
+    # A chained step is full, so it never runs the half-width program
+    # (model_runner.prefill_shape); the steps of place 1 may.
+    widths = [s.get("prefill_width") for s in steps]
+    assert all(w == 4 for c, w in zip(chain, widths) if c > 1)
+    assert 2 in widths
 
 
 def test_metrics_exposes_the_chained_steps_counter():
