@@ -48,7 +48,8 @@ class ModelConfig:
 
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
-    # | lfm2_moe | longcat_flash (models/registry.py FAMILIES)
+    # | lfm2_moe | longcat_flash | glm4_moe_lite (models/registry.py
+    # FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -145,6 +146,19 @@ class ModelConfig:
     mla_kv_scale: float = 1.0
     zero_expert_num: int = 0
     routed_scaling_factor: float = 1.0
+    # GLM-4 MoE lite decoders (architecture == "glm4_moe_lite",
+    # models/glm4_moe_lite.py): one MLA sublayer a layer (the fields
+    # above, both scales 1), the first num_dense_layers feed-forwards
+    # dense SwiGLUs of intermediate_size, the rest num_experts routed
+    # experts chosen by sigmoid score plus a learned bias and weighted
+    # routed_scaling_factor times the scores over their sum, beside a
+    # shared expert of shared_expert_intermediate_size added whole.
+    # num_nextn_predict_layers: multi-token-prediction layers the
+    # engine keeps (0 or 1): one more decoder layer with a cache entry
+    # of its own, which drafts inside the deferred burst. The engine's
+    # configuration sets it to 0 where scheduler.draft_module is off:
+    # the module is then neither made nor given pages.
+    num_nextn_predict_layers: int = 0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -232,6 +246,13 @@ class ModelConfig:
         """The paged entries are one plane each: a latent in the place
         of a (K, V) pair."""
         return self.page_cache.planes == 1
+
+    @property
+    def has_draft_module(self) -> bool:
+        """The family declares a draft module and the configuration
+        keeps it: the deferred burst drafts with it."""
+        return (self.family.draft_module
+                and self.num_nextn_predict_layers >= 1)
 
     @property
     def router_width(self) -> int:
@@ -524,6 +545,95 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "glm4moelite" in arch.replace("_", ""):
+            ep, rank = _expert_parallel_share(hf)
+            refused = [why for bad, why in (
+                (not hf.get("q_lora_rank"),
+                 "q_lora_rank unset: the query is served through its "
+                 "low-rank pair and its norm"),
+                (int(hf.get("n_group", 1)) > 1
+                 or int(hf.get("topk_group", 1)) > 1,
+                 f"n_group {hf.get('n_group')} / topk_group "
+                 f"{hf.get('topk_group')}: the experts are chosen "
+                 "among all of them, with no group limit"),
+                (hf.get("topk_method", "noaux_tc") != "noaux_tc",
+                 f"topk_method {hf.get('topk_method')!r}: the experts "
+                 "are chosen by sigmoid score plus the learned bias "
+                 "(noaux_tc)"),
+                (hf.get("rope_scaling") is not None,
+                 "rope_scaling: the rotary embedding is served "
+                 "unscaled"),
+                (int(hf.get("num_nextn_predict_layers", 0)) > 1,
+                 f"num_nextn_predict_layers "
+                 f"{hf.get('num_nextn_predict_layers')}: the burst "
+                 "verifies one draft a row an iteration, from one "
+                 "prediction layer"),
+                (bool(hf.get("attention_bias", False)),
+                 "attention_bias true: the attention projections are "
+                 "served without a bias"),
+                (not hf.get("norm_topk_prob", True),
+                 "norm_topk_prob false: the chosen experts' scores are "
+                 "divided by their sum"),
+                (float(hf.get("partial_rotary_factor", 1)) != 1.0,
+                 f"partial_rotary_factor "
+                 f"{hf.get('partial_rotary_factor')}: all of "
+                 "qk_rope_head_dim is turned"),
+                (int(hf.get("n_shared_experts", 1)) != 1,
+                 f"n_shared_experts {hf.get('n_shared_experts')}: one "
+                 "shared expert is added whole"),
+                (not 0 <= int(hf.get("first_k_dense_replace", 0))
+                 <= hf["num_hidden_layers"],
+                 f"first_k_dense_replace "
+                 f"{hf.get('first_k_dense_replace')} of "
+                 f"{hf['num_hidden_layers']} layers"),
+                (hf.get("hidden_act", "silu") != "silu",
+                 f"hidden_act {hf.get('hidden_act')!r}: the "
+                 "feed-forwards and the experts are SwiGLU"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "GLM-4 MoE lite config this engine does not serve: "
+                    + "; ".join(refused))
+            return cls(
+                name=name or hf.get("_name_or_path", "glm4-moe-lite"),
+                architecture="glm4_moe_lite",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf["intermediate_size"],
+                num_hidden_layers=hf["num_hidden_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                # One latent a token serves every head.
+                num_key_value_heads=1,
+                head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 202752),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+                rope_theta=hf.get("rope_theta", 1e6),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           False),
+                kv_lora_rank=hf["kv_lora_rank"],
+                q_lora_rank=hf["q_lora_rank"],
+                qk_nope_head_dim=hf["qk_nope_head_dim"],
+                qk_rope_head_dim=hf["qk_rope_head_dim"],
+                v_head_dim=hf["v_head_dim"],
+                num_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+                # The count this engine holds; the router's width is
+                # this times expert_parallel_size.
+                num_experts=hf["n_routed_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                shared_expert_intermediate_size=(
+                    hf["moe_intermediate_size"]
+                    * int(hf.get("n_shared_experts", 1))),
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                num_nextn_predict_layers=int(
+                    hf.get("num_nextn_predict_layers", 0)),
+                activation="silu",
+                dtype="bfloat16",
+            )
         if "mixtral" in arch:
             return cls(
                 name=name or hf.get("_name_or_path", "mixtral"),
@@ -697,6 +807,14 @@ class SchedulerConfig:
     # Minimum n-gram length the proposer must match in the sequence's
     # history before drafting its continuation.
     speculative_min_match: int = 2
+    # Drafting by the model's own multi-token-prediction module inside
+    # the deferred burst (docs/speculative.md, "The module as
+    # proposer"): an iteration verifies one draft a row and commits one
+    # or two tokens. The server's --draft-module auto resolves this on
+    # where the family declares a draft module and the checkpoint's
+    # configuration keeps one (num_nextn_predict_layers >= 1); off, the
+    # module is not made and has no pages. Needs deferred_kv_writes.
+    draft_module: bool = False
     # Overlapped async execution pipeline (docs/async_pipeline.md):
     # plan and dispatch decode step N+1 — feeding step N's sampled
     # tokens forward as a device array — before step N's results are
@@ -1019,6 +1137,30 @@ class EngineConfig:
                 self.cache,
                 num_state_slots=(self.scheduler.max_num_seqs
                                  + self.scheduler.prefill_batch_size))
+        if self.scheduler.draft_module:
+            if not self.model.has_draft_module:
+                raise ValueError(
+                    "draft_module needs a family that declares a draft "
+                    "module and a checkpoint whose configuration keeps "
+                    "one (num_nextn_predict_layers >= 1); "
+                    f"{self.model.architecture} with "
+                    "num_nextn_predict_layers "
+                    f"{self.model.num_nextn_predict_layers} has none")
+            if not self.scheduler.deferred_kv_writes:
+                raise ValueError(
+                    "draft_module needs deferred_kv_writes (and "
+                    "decode_steps > 1): the module drafts inside the "
+                    "deferred burst, whose tails are what a rejected "
+                    "draft is rolled back in (docs/speculative.md)")
+            if self.scheduler.speculative_k > 0:
+                raise ValueError(
+                    "draft_module is incompatible with speculative_k "
+                    "> 0: one proposer a row (docs/speculative.md "
+                    "§interactions)")
+        elif self.model.num_nextn_predict_layers:
+            # The module is not loaded: no weights, no cache entry.
+            self.model = dataclasses.replace(
+                self.model, num_nextn_predict_layers=0)
         if self.model.has_latent_cache:
             refused = _latent_cache_refusals(self)
             if refused:
@@ -1107,8 +1249,9 @@ def _recurrent_state_refusals(config: "EngineConfig"):
          "mid-stream checkpoint descriptors",
          "a resume restores pages without the state"),
         (s.speculative_k > 0, "speculative decoding",
-         "a rejected draft rolls the pages back and cannot roll the "
-         "state back"),
+         "a rejected draft's K/V is overwritten in place by the next "
+         "step, and the recurrent state it advanced cannot be rolled "
+         "back"),
         (p.pipeline_parallel_size > 1, "pipeline-parallel serving",
          "the staged forward has no state pools"),
         (p.context_parallel_size > 1, "context-parallel prefill",
@@ -1147,8 +1290,11 @@ def _latent_cache_refusals(config: "EngineConfig"):
         (config.checkpoint_interval_tokens > 0,
          "mid-stream checkpoint descriptors",
          "a descriptor restores K and V pages"),
-        (s.speculative_k > 0, "speculative decoding",
-         "the verify step has no latent-attention form"),
+        (s.speculative_k > 0, "speculative decoding by prompt lookup",
+         "its verify step is a single-step program that writes K and V "
+         "pages eagerly; over a latent a draft is verified inside the "
+         "deferred burst, by a family that declares a draft module "
+         "(--draft-module)"),
         (p.tensor_parallel_size > 1, "tensor parallelism",
          own["tensor parallelism"]),
         (p.pipeline_parallel_size > 1, "pipeline-parallel serving",
@@ -1253,6 +1399,7 @@ INTERNAL_FIELDS = {
     "model.mla_q_scale",
     "model.mla_kv_scale",
     "model.zero_expert_num",
+    "model.num_nextn_predict_layers",
     "model.routed_scaling_factor",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
@@ -1416,6 +1563,43 @@ def tiny_longcat_flash_config(expert_parallel_size: int = 1,
         moe_intermediate_size=32,
         zero_expert_num=4,
         routed_scaling_factor=6.0,
+        dtype="float32",
+    )
+
+
+def tiny_glm4_moe_lite_config(vocab_size: int = 512,
+                              num_nextn_predict_layers: int = 1
+                              ) -> ModelConfig:
+    """A tiny GLM-4 MoE lite (a dense layer, two expert layers of 8
+    sigmoid-routed experts chosen 3 at a time beside a shared expert,
+    latent attention, and the prediction module) for tests that run
+    anywhere."""
+    return ModelConfig(
+        name="tiny-glm4-moe-lite",
+        architecture="glm4_moe_lite",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=128,
+        num_hidden_layers=3,
+        num_attention_heads=4,
+        num_key_value_heads=1,
+        head_dim=24,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        rope_theta=1e6,
+        tie_word_embeddings=False,
+        kv_lora_rank=24,
+        q_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        num_dense_layers=1,
+        num_experts=8,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=32,
+        routed_scaling_factor=1.8,
+        num_nextn_predict_layers=num_nextn_predict_layers,
         dtype="float32",
     )
 

@@ -941,6 +941,15 @@ class LLMEngine:
                     self.scheduler.on_spec_executed(seq)
             if spec_drafts is not None:
                 self.metrics.on_spec_step(drafted, accepted)
+            module_note = {}
+            if moe and "drafts" in moe:
+                # A burst whose draft module proposed inside it: the
+                # program's own counts (drafts verified on live rows,
+                # drafts accepted), before any host-side truncation.
+                module_note = {"drafts": int(moe["drafts"]),
+                               "accepted": int(moe["accepted"])}
+                self.metrics.on_spec_step(module_note["drafts"],
+                                          module_note["accepted"])
             if self._tracer is not None:
                 self._step_note = {
                     "kind": "spec" if spec_drafts is not None
@@ -951,6 +960,7 @@ class LLMEngine:
                     "attn_pages": self.runner.last_attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
+                    **module_note,
                     **{k: round(v, 3) for k, v in moe_note.items()},
                 }
         self._obs_note = ("spec" if spec_drafts is not None

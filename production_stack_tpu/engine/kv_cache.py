@@ -190,10 +190,16 @@ class PagedCacheManager:
         return hashes
 
     def match_prefix(self, token_ids: Sequence[int],
-                     root: int = 0) -> List[int]:
+                     root: int = 0, reads_next_token: bool = False
+                     ) -> List[int]:
         """Longest chain of cached full pages matching the prompt prefix.
 
         Returns the page ids (ref-counted up; caller owns them).
+        ``reads_next_token``: an entry of the pages holds, at position
+        i, what was computed from token i + 1 as well (a draft
+        module's cache), so the last page of a chain, whose last
+        position read a token the hash does not cover, is left out and
+        computed again.
         """
         if not self.config.enable_prefix_caching:
             # Don't count queries the cache never sees: inflating the
@@ -205,11 +211,14 @@ class PagedCacheManager:
         # Never match the *entire* prompt: the final token must be
         # recomputed so prefill produces logits for sampling.
         usable = len(token_ids) - 1
+        chain = []
         for page_hash in self.chain_hashes(token_ids[:usable],
                                            self.page_size, root):
             page_id = self._hash_to_page.get(page_hash)
             if page_id is None:
                 break
+            chain.append(page_id)
+        for page_id in chain[:-1] if reads_next_token else chain:
             if self.num_state_slots:
                 # The pages are there, the state after them is not:
                 # the hit is not taken (snapshots are a later PR).

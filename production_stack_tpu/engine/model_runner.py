@@ -23,9 +23,14 @@ from production_stack_tpu.engine.perf_observatory import (
     PerfObservatory,
 )
 from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
-from production_stack_tpu.engine.sequence import Sequence, decode_budget
+from production_stack_tpu.engine.sequence import (
+    Sequence,
+    decode_budget,
+    draftless,
+)
 from production_stack_tpu.models.registry import (
     deferred_kv_architectures,
+    get_draft,
     get_model,
     init_hybrid_cache,
 )
@@ -42,6 +47,7 @@ from production_stack_tpu.ops.quant_kv import (
 from production_stack_tpu.ops.sampling import (
     apply_penalties,
     sample_tokens,
+    sampling_probs,
     spec_verify,
     token_logprobs,
 )
@@ -491,6 +497,13 @@ class ModelRunner:
                     f"{', '.join(DEFERRED_KV_FAMILIES)} (got "
                     f"{model_config.architecture!r})")
 
+        # A family's draft module proposing inside the deferred burst
+        # (docs/speculative.md): the burst body is the drafting one and
+        # the prefill step fills the module's cache entry.
+        self._drafts = config.scheduler.draft_module
+        if self._drafts:
+            self._draft = get_draft(model_config)
+
         if params is None and model_config.quantization == "int8":
             # Direct int8 init: full-precision init + quantize peaks
             # at 3x the serving footprint on device and OOMs the 8B
@@ -673,7 +686,8 @@ class ModelRunner:
         # mixed-progress batches). One dispatch + one device_get per K
         # tokens.
         self._decode_burst_jit = InstrumentedJit("decode_burst", jax.jit(
-            (self._decode_burst_deferred_impl if self._deferred
+            (self._decode_burst_draft_impl if self._drafts
+             else self._decode_burst_deferred_impl if self._deferred
              else self._decode_burst_impl),
             static_argnames=("num_steps", "want_logprobs"),
             donate_argnums=(1, 2),  # k_cache, v_cache
@@ -1111,10 +1125,12 @@ class ModelRunner:
         """``_resolve_pallas_impls`` for a family whose pages hold one
         latent plane an entry: the decode step has its own Pallas
         kernel (ops/mla_attention_pallas.py), probed in the form the
-        burst calls; a prefill chunk is served by the XLA form, which
-        is said and not probed."""
+        burst calls (one position a row, or two where the burst
+        drafts); a prefill chunk is served by the XLA form, which is
+        said and not probed."""
         from production_stack_tpu.ops.mla_attention_pallas import (
             latent_paged_decode_attention,
+            latent_paged_verify_attention,
         )
         m, dtype = model_config, model_config.jax_dtype
         model_config.attention_impl_prefill = "xla"
@@ -1123,25 +1139,40 @@ class ModelRunner:
             b = config.scheduler.max_num_seqs
             n, dn = m.num_attention_heads, m.qk_nope_head_dim
             pages = m.page_cache
-            rows_i32 = jax.ShapeDtypeStruct((b,), np.int32)
-            tail = (jax.ShapeDtypeStruct(
-                (b, config.scheduler.decode_steps, 1, pages.width), dtype)
-                if config.scheduler.deferred_kv_writes else None)
-            err = self._lowering_error(
-                functools.partial(latent_paged_decode_attention,
-                                  scale=float(m.head_dim) ** -0.5),
-                jax.ShapeDtypeStruct((b, n, m.head_dim), dtype),
+            steps = config.scheduler.decode_steps
+            # A burst that drafts runs two positions a row over tails
+            # of twice the steps (the verify form of the same kernel).
+            t = 2 if config.scheduler.draft_module else 1
+            tail = (jax.ShapeDtypeStruct((b, t * steps, 1, pages.width),
+                                         dtype)
+                    if config.scheduler.deferred_kv_writes else None)
+            shared = (
                 jax.ShapeDtypeStruct(
                     (1, config.cache.num_pages, pages.width,
                      config.cache.page_size), dtype),
                 jax.ShapeDtypeStruct(
                     (b, config.scheduler.max_pages_per_seq(
                         config.cache.page_size)), np.int32),
-                rows_i32,
+                jax.ShapeDtypeStruct((b,), np.int32),
                 jax.ShapeDtypeStruct((n, dn, m.kv_lora_rank), dtype),
                 jax.ShapeDtypeStruct((n, m.kv_lora_rank, m.v_head_dim),
-                                     dtype),
-                tail=tail, q_positions=None if tail is None else rows_i32)
+                                     dtype))
+            scale = float(m.head_dim) ** -0.5
+            if t == 1:
+                err = self._lowering_error(
+                    functools.partial(latent_paged_decode_attention,
+                                      scale=scale),
+                    jax.ShapeDtypeStruct((b, n, m.head_dim), dtype),
+                    *shared, tail=tail,
+                    q_positions=(None if tail is None else
+                                 jax.ShapeDtypeStruct((b,), np.int32)))
+            else:
+                err = self._lowering_error(
+                    functools.partial(latent_paged_verify_attention,
+                                      scale=scale),
+                    jax.ShapeDtypeStruct((b, t, n, m.head_dim), dtype),
+                    *shared, tail=tail,
+                    q_positions=jax.ShapeDtypeStruct((b, t), np.int32))
         if err and not auto_impl:
             raise RuntimeError(
                 "attention_impl='pallas': the Pallas latent decode "
@@ -1173,7 +1204,8 @@ class ModelRunner:
                    top_p, top_k, rng, lora, lora_ids, penalties,
                    seeding, bias, suppress, fsm,
                    sample_index_mode: str,
-                   want_logprobs: bool = False, state_slots=None):
+                   want_logprobs: bool = False, state_slots=None,
+                   next_tokens=None):
         # Deliberate two-shape specialization ([B] decode feed-forward
         # vs [B, T] prefill/burst): exactly two traces, cached for the
         # process lifetime — not a per-step retrace.
@@ -1185,11 +1217,15 @@ class ModelRunner:
             tokens = tokens[:, None]
             positions = positions.reshape(tokens.shape)
             valid = valid.reshape(tokens.shape)
-        logits, k_cache, v_cache = self._forward(
+        # A prefill step of a family that drafts also fills the draft
+        # module's cache entry (below, once the token is sampled).
+        fills_draft = self._drafts and sample_index_mode == "last"
+        logits, *hidden, k_cache, v_cache = self._forward(
             params, self.config.model, tokens, positions, page_table,
             kv_lens, valid, k_cache, v_cache,
             lora=lora, lora_ids=lora_ids,
             **self._state_kwargs(state_slots),
+            **({"return_hidden": True} if fills_draft else {}),
         )
         if sample_index_mode == "last":
             # Prefill: sample only from the final prompt position.
@@ -1221,6 +1257,21 @@ class ModelRunner:
         sampled = sample_tokens(row_logits, temperature, top_p, top_k,
                                 rng, seeds=seeds, emitted=emitted,
                                 seed_mask=seed_on)
+        if fills_draft:
+            # The module at position i reads the main model's hidden
+            # state there and the token at i + 1: within the chunk the
+            # next id; at its last position the next chunk's first id
+            # (``next_tokens``, -1 where the prompt ends here) or the
+            # token just sampled. What it would draft is not kept: a
+            # burst's first iteration offers no draft.
+            t = tokens.shape[1]
+            after = jnp.where(next_tokens >= 0, next_tokens, sampled)
+            shifted = jnp.where(
+                jnp.arange(t)[None, :] == last_index[:, None],
+                after[:, None], jnp.roll(tokens, -1, axis=1))
+            _, k_cache = self._draft(
+                params, self.config.model, hidden[0], shifted, positions,
+                page_table, kv_lens, valid, k_cache)
         if want_logprobs:
             # From the raw distribution (pre-penalty/temperature), the
             # OpenAI logprobs contract. raw_logits is bound before the
@@ -1404,6 +1455,95 @@ class ModelRunner:
 
         return sample_step
 
+    def _burst_tails(self, k_cache, v_cache, page_table, kv_lens0,
+                     slots: int, state_slots):
+        """What a deferred-write burst carries in the place of its
+        caches, for tails of ``slots`` tokens a row: ``(k_kinds,
+        v_kinds, k_carry0, v_carry0, served, flush)``. ``served(cache,
+        carry, kinds)`` is what the forward reads (the planes from
+        outside the scan, everything else from the carry);
+        ``flush(k_carry, v_carry, count)`` gives the two caches with
+        each row's first ``count [B]`` tail slots written to its pages
+        (slot s at position ``kv_lens0 + s``) and the convolution
+        tails scattered back."""
+        m = self.config.model
+        b = kv_lens0.shape[0]
+        pages = m.page_cache
+        tail_shape = (b, slots, pages.heads, pages.width)
+        # What each cache entry is to the burst: page planes where the
+        # entry is not a recurrent layer's (a family that stores one
+        # latent plane has None for the second, which rides as
+        # nothing); of a recurrent layer the state pool
+        # in k_cache (None again where its family
+        # declares the tail alone) and in v_cache the convolution
+        # tails, dense in the carry where the family's forward takes
+        # them so; a k_cache that ends in its family's counters has
+        # one entry more than there are entries.
+        conv = "conv" if m.family.conv_tail else "ride"
+        second = "pages" if pages.planes == 2 else "ride"
+        k_kinds = tuple("ride" if state else "pages"
+                        for state in m.cache_entry_is_state) + (
+            "ride",) * bool(m.family.counters)
+        v_kinds = tuple(conv if state else second
+                        for state in m.cache_entry_is_state)
+        per_layer = isinstance(k_cache, tuple)
+
+        def carried(cache, kinds):
+            # A K/V tail where the layer has pages, the rows' held
+            # inputs where it has a convolution, else the entry itself.
+            if not per_layer:  # stacked: every layer has pages
+                cache = (None,) * m.num_hidden_layers
+
+            def entry(c, kind):
+                if kind == "pages":
+                    return jnp.zeros(tail_shape, m.jax_dtype)
+                if kind == "conv":
+                    held = c[state_slots]
+                    return tuple(held[:, j] for j in range(c.shape[1]))
+                return c
+            return tuple(entry(c, k) for c, k in zip(cache, kinds))
+
+        def served(cache, carry, kinds):
+            # What the forward reads: the planes from outside the
+            # scan, everything else from the carry.
+            if not per_layer:
+                return cache
+            return tuple(c if k == "pages" else s
+                         for c, s, k in zip(cache, carry, kinds))
+
+        def flush_one(cache, carry, kinds, tail_pos, tail_valid):
+            if not per_layer:
+                for l, tail in enumerate(carry):
+                    cache = write_to_pages(cache, tail, page_table,
+                                           tail_pos, tail_valid, layer=l)
+                return cache
+
+            def entry(c, s, kind):
+                if kind == "pages":
+                    return write_to_pages(c, s, page_table, tail_pos,
+                                          tail_valid)
+                if kind == "conv":
+                    return c.at[state_slots].set(jnp.stack(s, axis=1))
+                return s
+            return tuple(entry(c, s, k)
+                         for c, s, k in zip(cache, carry, kinds))
+
+        def flush(k_carry, v_carry, count):
+            # One batched scatter per paged layer and per convolution
+            # tail for the whole burst, by each row's own count (a row
+            # that stopped inside it holds the tail it stopped with;
+            # padded rows write to the trash slot); the other entries
+            # are the carry's last.
+            tail_pos = kv_lens0[:, None] + jnp.arange(slots)[None, :]
+            tail_valid = jnp.arange(slots)[None, :] < count[:, None]
+            return (flush_one(k_cache, k_carry, k_kinds, tail_pos,
+                              tail_valid),
+                    flush_one(v_cache, v_carry, v_kinds, tail_pos,
+                              tail_valid))
+
+        return (k_kinds, v_kinds, carried(k_cache, k_kinds),
+                carried(v_cache, v_kinds), served, flush)
+
     def _decode_burst_deferred_impl(self, params, k_cache, v_cache,
                                     tokens, positions, page_table,
                                     kv_lens, active, budgets,
@@ -1454,51 +1594,9 @@ class ModelRunner:
             counts0 = jnp.zeros((b, 0), jnp.int32)
 
         kv_lens0 = positions[:, 0]  # pages hold this many tokens
-        pages = m.page_cache
-        tail_shape = (b, num_steps, pages.heads, pages.width)
-        # What each cache entry is to the burst: page planes where the
-        # entry is not a recurrent layer's (a family that stores one
-        # latent plane has None for the second, which rides as
-        # nothing); of a recurrent layer the state pool
-        # in k_cache (None again where its family
-        # declares the tail alone) and in v_cache the convolution
-        # tails, dense in the carry where the family's forward takes
-        # them so; a k_cache that ends in its family's counters has
-        # one entry more than there are entries.
-        conv = "conv" if m.family.conv_tail else "ride"
-        second = "pages" if pages.planes == 2 else "ride"
-        k_kinds = tuple("ride" if state else "pages"
-                        for state in m.cache_entry_is_state) + (
-            "ride",) * bool(m.family.counters)
-        v_kinds = tuple(conv if state else second
-                        for state in m.cache_entry_is_state)
-        per_layer = isinstance(k_cache, tuple)
-
-        def carried(cache, kinds):
-            # A K/V tail where the layer has pages, the rows' held
-            # inputs where it has a convolution, else the entry itself.
-            if not per_layer:  # stacked: every layer has pages
-                cache = (None,) * m.num_hidden_layers
-
-            def entry(c, kind):
-                if kind == "pages":
-                    return jnp.zeros(tail_shape, m.jax_dtype)
-                if kind == "conv":
-                    held = c[state_slots]
-                    return tuple(held[:, j] for j in range(c.shape[1]))
-                return c
-            return tuple(entry(c, k) for c, k in zip(cache, kinds))
-
-        def served(cache, carry, kinds):
-            # What the forward reads: the planes from outside the
-            # scan, everything else from the carry.
-            if not per_layer:
-                return cache
-            return tuple(c if k == "pages" else s
-                         for c, s, k in zip(cache, carry, kinds))
-
-        k_carry0 = carried(k_cache, k_kinds)
-        v_carry0 = carried(v_cache, v_kinds)
+        (k_kinds, v_kinds, k_carry0, v_carry0, served,
+         flush) = self._burst_tails(k_cache, v_cache, page_table,
+                                    kv_lens0, num_steps, state_slots)
         sample_step = self._burst_sample_step(
             b, penalties, seeding, bias, suppress, temperature,
             top_p, top_k, stop_tokens, budgets, want_logprobs)
@@ -1530,33 +1628,206 @@ class ModelRunner:
             body, carry, rngs
         )
 
-        # Flush: one batched scatter per paged layer and per
-        # convolution tail for the whole burst (a row that stopped
-        # inside it holds the tail it stopped with; padded rows write
-        # to the trash slot); the other entries are the carry's last.
-        tail_pos = kv_lens0[:, None] + jnp.arange(num_steps)[None, :]
-        tail_valid = (jnp.arange(num_steps)[None, :]
-                      < emitted[:, None])
+        return (out,) + flush(kt, vt, emitted)
 
-        def flush(cache, carry, kinds):
-            if not per_layer:
-                for l, tail in enumerate(carry):
-                    cache = write_to_pages(cache, tail, page_table,
-                                           tail_pos, tail_valid, layer=l)
-                return cache
+    def _decode_burst_draft_impl(self, params, k_cache, v_cache,
+                                 tokens, positions, page_table,
+                                 kv_lens, active, budgets,
+                                 stop_tokens, temperature, top_p,
+                                 top_k, rng, lora, lora_ids,
+                                 penalties, seeding, bias,
+                                 suppress, fsm, num_steps: int,
+                                 want_logprobs: bool = False,
+                                 state_slots=None, draft_rows=None):
+        """``_decode_burst_deferred_impl`` for a family whose draft
+        module proposes inside the burst (docs/speculative.md, "The
+        module as proposer"): an iteration commits one or two tokens a
+        row.
 
-            def entry(c, s, kind):
-                if kind == "pages":
-                    return write_to_pages(c, s, page_table, tail_pos,
-                                          tail_valid)
-                if kind == "conv":
-                    return c.at[state_slots].set(jnp.stack(s, axis=1))
-                return s
-            return tuple(entry(c, s, k)
-                         for c, s, k in zip(cache, carry, kinds))
+        An iteration, for a live row with last committed token ``x`` at
+        position ``P``, its tail count ``n = P - kv_lens0`` and a draft
+        ``d`` drawn from the module's distribution ``q`` (under the
+        row's own temperature, top-p and top-k; the argmax for a
+        greedy row): the main model runs positions ``P, P + 1`` on
+        ``(x, d)`` and appends both latents at tail slots ``n, n + 1``
+        of each of its entries; ``spec_verify`` accepts ``d`` with
+        ``min(1, p1(d) / q(d))`` and commits ``d`` and ``y ~ p2``, or
+        commits ``y' ~ norm(max(0, p1 - q))``; the module then runs on
+        the committed positions (``h_P`` with the first committed
+        token, ``h_{P+1}`` with ``y`` where ``d`` was accepted),
+        appends its own latents at the same slots of its own entry and
+        gives the next ``q`` and draft. A rejected draft's slot ``n +
+        1`` is overwritten by the next iteration (whose ``n`` is one
+        more) and is causally invisible until then: slot s is position
+        ``kv_lens0 + s``, which no query before it reads. The output
+        distribution is the target's.
 
-        return (out, flush(k_cache, kt, k_kinds),
-                flush(v_cache, vt, v_kinds))
+        A row has no draft in a burst's first iteration (the draft
+        after the last burst's, or the prefill's, last token is not
+        kept across programs), and none at all where ``draft_rows`` is
+        False: a row whose request carries penalties, a logit bias,
+        min_tokens suppression, a guided grammar or a seed, whose
+        logits the module does not see. Such a row's second position
+        is masked out and it commits one token an iteration by the
+        rule of ``_burst_sample_step`` (a seeded row by its seed), in
+        this same program.
+
+        Budgets and stop tokens cut inside a pair: an accepted ``d``
+        that ends the row drops ``y``. Tails are ``2 * num_steps``
+        slots, flushed by each row's own count. Returns tokens ``[2 *
+        num_steps, B]`` (-1 where nothing was committed), with
+        ``want_logprobs`` the target's raw log-probabilities at each
+        committed position beside them. The family's counters gain the
+        drafts offered and accepted.
+        """
+        b = active.shape[0]
+        m = self.config.model
+        names = m.family.counters
+        i_drafts, i_accepted = (names.index("drafts"),
+                                names.index("accepted"))
+        if penalties is not None:
+            counts0, penalties = penalties[0], penalties[1:]
+        else:
+            counts0 = jnp.zeros((b, 0), jnp.int32)
+        kv_lens0 = positions[:, 0]  # pages hold this many tokens
+        (k_kinds, v_kinds, k_carry0, v_carry0, served,
+         flush) = self._burst_tails(k_cache, v_cache, page_table,
+                                    kv_lens0, 2 * num_steps, state_slots)
+        guided = fsm is not None
+        first_logits = self._burst_row_logits(penalties, bias, suppress,
+                                              guided)
+        fsm0 = fsm if guided else jnp.zeros((0,), jnp.int32)
+        rows = jnp.arange(b)
+
+        def hit_stop(tok):
+            return jnp.any(tok[:, None] == stop_tokens, axis=-1)
+
+        def body(carry, step_rng):
+            (tok, pos, act, emitted, counts, fs, draft, q, has_draft,
+             kt, vt) = carry
+            key_verify, key_seeded, key_draft = jax.random.split(
+                step_rng, 3)
+            pos2 = jnp.concatenate([pos, pos + 1], axis=1)
+            valid2 = jnp.stack([act, act & has_draft], axis=1)
+            logits, hidden, kt, vt = self._forward(
+                params, m, jnp.stack([tok[:, 0], draft], axis=1), pos2,
+                page_table, kv_lens0, valid2,
+                served(k_cache, kt, k_kinds),
+                served(v_cache, vt, v_kinds), kv_tail=(kt, vt),
+                return_hidden=True)
+            with jax.named_scope("mtp_verify"):
+                row_logits = first_logits(logits[:, 0], counts, emitted,
+                                          fs)
+                out2 = spec_verify(
+                    jnp.stack([row_logits, logits[:, 1]], axis=1),
+                    draft[:, None], has_draft.astype(jnp.int32),
+                    temperature, top_p, top_k, key_verify,
+                    draft_probs=q[:, None])
+                first, second = out2[:, 0], out2[:, 1]
+                if seeding is not None:
+                    # A seeded row (never a drafting one) keeps its
+                    # own stream: (seed, emitted index) alone.
+                    seeds, seed_on, emitted_start = seeding
+                    first = jnp.where(seed_on, sample_tokens(
+                        row_logits, temperature, top_p, top_k,
+                        key_seeded, seeds=seeds,
+                        emitted=emitted_start + emitted,
+                        seed_mask=seed_on), first)
+                accepted = has_draft & (second >= 0)
+                # Lifecycle, a token at a time: the second is committed
+                # only where the first left the row alive.
+                emitted1 = emitted + act
+                alive = act & ~hit_stop(first) & (emitted1 < budgets)
+                emit2 = alive & accepted
+                emitted2 = emitted1 + emit2
+                act_next = (alive & ~(emit2 & hit_stop(second))
+                            & (emitted2 < budgets))
+                out = jnp.stack([jnp.where(act, first, -1),
+                                 jnp.where(emit2, second, -1)])
+                if want_logprobs:
+                    lps = [token_logprobs(logits[:, j], jnp.clip(t, 0),
+                                          TOP_LOGPROBS_WIDTH)
+                           for j, t in enumerate((first, second))]
+                    out = (out,) + tuple(jnp.stack(pair)
+                                         for pair in zip(*lps))
+                if penalties is not None:
+                    counts = counts.at[rows, first].add(
+                        act.astype(counts.dtype))
+                    counts = counts.at[rows, jnp.clip(second, 0)].add(
+                        emit2.astype(counts.dtype))
+                if guided:
+                    # A guided row never drafts: one token advances it.
+                    width = self._guided_trans.shape[1]
+                    nxt = self._guided_trans[
+                        jnp.clip(fs, 0), jnp.clip(first, 0, width - 1)]
+                    fs = jnp.where(act & (fs >= 0), nxt, fs)
+            # The module on what was committed: h_P with the first
+            # token, h_{P+1} with the second where there is one; its
+            # distribution after the last of them is the next draft's.
+            draft_logits, kt = self._draft(
+                params, m, hidden,
+                jnp.stack([first, jnp.clip(second, 0)], axis=1), pos2,
+                page_table, kv_lens0,
+                jnp.stack([act_next, act_next & emit2], axis=1),
+                served(k_cache, kt, k_kinds), kv_tail=(kt, vt),
+                head_index=emit2.astype(jnp.int32))
+            with jax.named_scope("mtp_draft"):
+                q = sampling_probs(draft_logits, temperature, top_p,
+                                   top_k)
+                draft = jnp.where(
+                    temperature > 0,
+                    jax.random.categorical(key_draft, jnp.log(q),
+                                           axis=-1),
+                    jnp.argmax(draft_logits, axis=-1)).astype(jnp.int32)
+            has_draft_next = act_next & draft_rows
+            stats = kt[-1]
+            stats = stats.at[i_drafts].add(
+                jnp.sum(act & has_draft).astype(stats.dtype))
+            stats = stats.at[i_accepted].add(
+                jnp.sum(act & accepted).astype(stats.dtype))
+            kt = kt[:-1] + (stats,)
+            step = jnp.where(act_next, emitted2 - emitted, 0)
+            tok = jnp.where(emit2, second, jnp.where(act, first,
+                                                     tok[:, 0]))
+            return ((tok[:, None], pos + step[:, None].astype(pos.dtype),
+                     act_next, emitted2, counts, fs, draft, q,
+                     has_draft_next, kt, vt), out)
+
+        rngs = jax.random.split(rng, num_steps)
+        zeros = jnp.zeros(active.shape, jnp.int32)
+        carry = (tokens, positions, active, zeros, counts0, fsm0, zeros,
+                 jnp.zeros((b, m.vocab_size), jnp.float32),
+                 jnp.zeros(active.shape, bool), k_carry0, v_carry0)
+        carry, out = jax.lax.scan(body, carry, rngs)
+        emitted, kt, vt = carry[3], carry[-2], carry[-1]
+        # [K, 2, B, ...] -> [2K, B, ...]: a row's tokens in order.
+        out = jax.tree_util.tree_map(
+            lambda x: x.reshape((2 * num_steps,) + x.shape[2:]), out)
+        return (out,) + flush(kt, vt, emitted)
+
+    def _burst_row_logits(self, penalties, bias, suppress, guided: bool):
+        """``_burst_sample_step``'s way from a row's raw logits to the
+        ones it is sampled from, for a burst body that samples by
+        another rule than ``sample_tokens``: penalties, logit bias,
+        min_tokens suppression, the guided mask last (``guided``: the
+        burst carries automaton states)."""
+
+        def row_logits(logits, counts, emitted, fsm):
+            if penalties is not None:
+                prompt_mask, presence, frequency, repetition = penalties
+                logits = apply_penalties(
+                    logits, counts, prompt_mask, presence, frequency,
+                    repetition)
+            if bias is not None:
+                logits = logits + bias
+            if suppress is not None:
+                logits = self._apply_suppression(logits, suppress,
+                                                 emitted=emitted)
+            if guided:
+                logits = self._apply_guided_mask(logits, fsm)
+            return logits
+
+        return row_logits
 
     def _spec_verify_impl(self, params, k_cache, v_cache, tokens,
                           positions, page_table, kv_lens, valid,
@@ -1784,8 +2055,12 @@ class ModelRunner:
                     self._lora_stack, lora_ids, penalties, seeding,
                     bias, suppress, fsm,
                     num_steps=t, want_logprobs=want_lp, **state,
+                    **({"draft_rows": _as_device(payload["draft_rows"])}
+                       if "draft_rows" in payload else {}),
                 )
-            return sampled  # [K, B] (+ logprob arrays when requested)
+            # [K, B], or [2K, B] where the burst drafts (+ logprob
+            # arrays when requested)
+            return sampled
         sampled, self.k_cache, self.v_cache = self._step_jit(
             self.params, self.k_cache, self.v_cache,
             _as_device(payload["tokens"]),
@@ -1802,6 +2077,8 @@ class ModelRunner:
             suppress, fsm,
             sample_index_mode=("last" if kind == 1 else "first"),
             want_logprobs=want_lp, **state,
+            **({"next_tokens": _as_device(payload["next_tokens"])}
+               if "next_tokens" in payload else {}),
         )
         return sampled
 
@@ -1819,6 +2096,8 @@ class ModelRunner:
         lora_ids = payload.get("lora_ids")
         state = ({"state_slots": _as_device(payload["state_slots"])}
                  if "state_slots" in payload else {})
+        if "next_tokens" in payload:
+            state["next_tokens"] = _as_device(payload["next_tokens"])
         lowered = self._step_jit.lower(
             self.params, self.k_cache, self.v_cache, *args,
             self._lora_stack,
@@ -2145,6 +2424,8 @@ class ModelRunner:
                 payload["state_slots"] = self._state_slot_rows([], rows)
             if self.lora_registry is not None:
                 payload["lora_ids"] = np.zeros((rows,), np.int32)
+            if self._drafts:
+                payload["next_tokens"] = np.full((rows,), -1, np.int32)
             payloads.append(payload)
         return payloads
 
@@ -2208,6 +2489,16 @@ class ModelRunner:
         if self._hybrid:
             payload["state_slots"] = self._state_slot_rows(
                 [c.seq for c in chunks], b)
+        if self._drafts:
+            # The draft module reads the token AFTER each position: a
+            # mid-prompt chunk's last position takes the next chunk's
+            # first id, a prompt's last the token the step samples (-1).
+            after = np.full((b,), -1, np.int32)
+            for i, chunk in enumerate(chunks):
+                if not chunk.is_last_chunk:
+                    after[i] = chunk.seq.prompt_token_ids[
+                        chunk.chunk_start + len(chunk.chunk_tokens)]
+            payload["next_tokens"] = after
         if self.lora_registry is not None:
             ids = np.zeros((b,), np.int32)
             for i, chunk in enumerate(chunks):
@@ -2486,6 +2777,15 @@ class ModelRunner:
             payload["active"] = valid[:, 0].copy()
             payload["budgets"] = budgets
             payload["stop_tokens"] = stop_tokens
+        if self._drafts:
+            # Rows the draft module proposes for: those whose logits
+            # are sampled as the model gives them (the exclusion set
+            # of scheduler._plan_spec); the others commit one token
+            # an iteration in the same program.
+            drafting = np.zeros((b,), bool)
+            for i, seq in enumerate(seqs):
+                drafting[i] = not draftless(seq)
+            payload["draft_rows"] = drafting
         if self.lora_registry is not None:
             ids = np.zeros((b,), np.int32)
             for i, seq in enumerate(seqs):
@@ -2510,8 +2810,9 @@ class ModelRunner:
         if not want_lp:
             if window == 1:
                 return [[int(host[i])] for i in range(len(seqs))], None
-            return [[int(host[k, i]) for k in range(window)
-                     if host[k, i] >= 0]
+            # A burst's slots: ``window``, or twice that where it
+            # drafts; -1 where a row committed nothing.
+            return [[int(tok) for tok in host[:, i] if tok >= 0]
                     for i in range(len(seqs))], None
         toks, slp, tids, tlps = host
         if window == 1:
@@ -2523,7 +2824,7 @@ class ModelRunner:
         token_lists, lp_lists = [], []
         for i, seq in enumerate(seqs):
             row_t, row_l = [], []
-            for k in range(window):
+            for k in range(toks.shape[0]):
                 if toks[k, i] < 0:
                     continue
                 row_t.append(int(toks[k, i]))

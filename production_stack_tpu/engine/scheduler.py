@@ -34,6 +34,7 @@ from production_stack_tpu.engine.sequence import (
     Sequence,
     SequenceState,
     decode_budget,
+    draftless,
 )
 from production_stack_tpu.utils.log import init_logger
 
@@ -292,7 +293,7 @@ class Scheduler:
                 if plan is not None:
                     return StepPlan(decode=plan)
             window = self._decode_window()
-            self._ensure_decode_capacity(window)
+            self._ensure_decode_capacity(self._window_tokens(window))
             if self.running:
                 # Re-check: preemption may have changed who can take a
                 # full window.
@@ -308,11 +309,7 @@ class Scheduler:
         decode-side programs ever compile: the S-wide verify and the
         decode_steps-window decode/burst the fallback uses."""
         for seq in self.running:
-            sp = seq.sampling
-            if (sp.needs_penalties or sp.seed is not None
-                    or sp.logit_bias
-                    or sp.min_tokens > seq.num_generated
-                    or seq.fsm_state is not None):
+            if draftless(seq):
                 # Whole-step fallback: padding these rows through the
                 # verify shape would need the penalty/seed/bias/
                 # suppress/guided inputs compiled into it; the normal
@@ -493,11 +490,21 @@ class Scheduler:
         return rows
 
     def _decode_window(self) -> int:
-        """The decode burst evaluates per-row budgets and stop sets on
-        device (model_runner._decode_burst_impl), so the full window
-        is always safe — rows with less than K remaining simply go
-        inactive mid-burst. One decode shape compiles, ever."""
+        """Iterations of the next decode burst. The burst evaluates
+        per-row budgets and stop sets on device
+        (model_runner._decode_burst_impl), so the full window is
+        always safe — rows with less than K remaining simply go
+        inactive mid-burst. One burst shape compiles, ever; what an
+        iteration commits is the burst's own affair: one token a row,
+        or one or two where a draft module proposes inside it
+        (``_window_tokens``)."""
         return max(1, self.config.decode_steps)
+
+    def _window_tokens(self, window: int) -> int:
+        """The most tokens a row commits in a burst of ``window``
+        iterations, which is what its pages must hold: an iteration
+        that verifies the draft module's proposal commits two."""
+        return window * (2 if self.config.draft_module else 1)
 
     def _seq_budget(self, seq: Sequence) -> int:
         return decode_budget(seq, self.config.max_model_len)
@@ -590,7 +597,8 @@ class Scheduler:
                 # First touch: reuse cached prefix pages, then allocate
                 # the remainder for the whole prompt up front.
                 matched = self.cache.match_prefix(
-                    seq.prompt_token_ids, seq.cache_salt)
+                    seq.prompt_token_ids, seq.cache_salt,
+                    reads_next_token=self.config.draft_module)
                 if self.restore_hook is not None:
                     restored = self.restore_hook(
                         seq.prompt_token_ids, matched,

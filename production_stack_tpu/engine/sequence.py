@@ -265,3 +265,16 @@ def decode_budget(seq: "Sequence", max_model_len: int) -> int:
         seq.sampling.max_tokens - seq.num_generated,
         max_model_len - seq.total_len,
     )
+
+
+def draftless(seq: "Sequence") -> bool:
+    """A row that commits one token a step whatever proposes drafts:
+    its logits are rewritten (penalties, a logit bias, min_tokens
+    suppression, a guided grammar) or its draws follow its own seed, so
+    a proposer's distribution is not the one it is sampled from. The
+    exclusion set of prompt-lookup speculation (scheduler._plan_spec),
+    for the draft module inside the burst (model_runner.run_decode)."""
+    sp = seq.sampling
+    return bool(sp.needs_penalties or sp.seed is not None
+                or sp.logit_bias or sp.min_tokens > seq.num_generated
+                or seq.fsm_state is not None)

@@ -2298,6 +2298,19 @@ class EngineServer:
             "attention_impl": (obs.attention_impls()
                                if obs is not None else {}),
             "kv_writes": "deferred" if deferred else "eager",
+            # What proposes drafts: the model's own prediction module
+            # inside the burst ("module", with its depth and the most
+            # tokens an iteration commits), the prompt-lookup proposer
+            # ("prompt_lookup"), or nothing.
+            "drafts": (
+                {"by": "module",
+                 "layers": config.model.num_nextn_predict_layers,
+                 "tokens_per_iteration": 2}
+                if config.scheduler.draft_module else
+                {"by": "prompt_lookup",
+                 "k": config.scheduler.speculative_k}
+                if config.scheduler.speculative_k > 0 else
+                {"by": "none"}),
             **conv_tails,
             "family": config.model.architecture,
             **kv,
@@ -2632,6 +2645,23 @@ def _resolve_deferred_kv(args, model_config) -> bool:
         args.speculative_k)
 
 
+def _resolve_draft_module(args, model_config, deferred: bool) -> bool:
+    """--draft-module auto|on|off -> bool.
+
+    'auto' drafts with the model's own multi-token-prediction module
+    wherever it can: the family declares one, the checkpoint's
+    configuration keeps one (num_nextn_predict_layers >= 1) and the
+    deferred burst, inside which it drafts, is served. 'on' where it
+    cannot is a start-up error (engine/config.py); 'off' serves the
+    model without the module: no weights, no cache entry."""
+    if args.draft_module == "on":
+        return True
+    if args.draft_module == "off":
+        return False
+    return (deferred and model_config.has_draft_module
+            and args.speculative_k == 0)
+
+
 def _resolve_async_scheduling(args) -> bool:
     """--async-scheduling auto|on|off -> bool.
 
@@ -2743,6 +2773,7 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
             placement=parse_placement(args.mesh_placement),
         )
 
+    deferred_kv = _resolve_deferred_kv(args, model_config)
     config = EngineConfig(
         model=model_config,
         cache=CacheConfig(
@@ -2758,7 +2789,9 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
             prefill_chunk_size=args.prefill_chunk_size,
             prefill_batch_size=args.prefill_batch_size,
             decode_steps=args.decode_steps,
-            deferred_kv_writes=_resolve_deferred_kv(args, model_config),
+            deferred_kv_writes=deferred_kv,
+            draft_module=_resolve_draft_module(args, model_config,
+                                               deferred_kv),
             speculative_k=args.speculative_k,
             speculative_min_match=args.speculative_min_match,
             async_scheduling=_resolve_async_scheduling(args),
@@ -2925,11 +2958,25 @@ def parse_args(argv=None):
                         help="Defer decode KV writes to one batched "
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
-                             "qwen3_next, jamba, lfm2_moe, longcat_flash; "
+                             "qwen3_next, jamba, lfm2_moe, longcat_flash, "
+                             "glm4_moe_lite; "
                              "decode-steps "
                              "> 1, no pp/sp); /version "
                              "says which "
                              "is served (kv_writes)")
+    parser.add_argument("--draft-module", default="auto",
+                        choices=["auto", "on", "off"],
+                        help="Draft with the model's own multi-token-"
+                             "prediction module inside the deferred "
+                             "burst: an iteration verifies one draft a "
+                             "row and commits one or two tokens "
+                             "(docs/speculative.md). 'auto' drafts "
+                             "where the family declares a module, the "
+                             "checkpoint's configuration keeps one "
+                             "(num_nextn_predict_layers >= 1) and KV "
+                             "writes are deferred; 'off' serves the "
+                             "model without the module; /version says "
+                             "which is served (drafts)")
     parser.add_argument("--tensor-parallel-size", type=int, default=1)
     parser.add_argument("--pipeline-parallel-size", type=int, default=1,
                         help="Layer stages over the pp mesh axis "

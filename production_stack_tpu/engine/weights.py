@@ -271,6 +271,13 @@ def load_weights(model_dir: str, config: ModelConfig,
             "w_uk and w_uv, gate | up fused, the held experts' block) "
             "is not written yet: serve the architecture with "
             "--random-weights")
+    if config.architecture == "glm4_moe_lite":
+        raise NotImplementedError(
+            "reading a GLM-4 MoE lite checkpoint into this engine's "
+            "stacks (kv_b split a head into w_uk and w_uv, gate | up "
+            "fused, the prediction layer's body after the main "
+            "layers') is not written yet: serve the architecture with "
+            "--random-weights")
     if config.architecture not in ("llama", "mistral", "qwen2"):
         raise NotImplementedError(
             f"no reader for a {config.architecture!r} checkpoint, and "

@@ -137,9 +137,11 @@ def mla(config: ModelConfig, lp, x, positions, page_table, kv_lens,
         valid, plane, tail=None):
     """One latent-attention sublayer on a normalised ``x [B, T, H]``.
     Returns ``(y [B, T, H], plane or tail)``: the plane with this
-    block's latents written, or with ``tail`` (a deferred-write burst,
-    T == 1) the tail with this step's appended and the plane left as
-    it is (``kv_lens`` is then the frozen pre-burst count)."""
+    block's latents written, or with ``tail`` (a deferred-write burst:
+    T == 1, or a committed token and its drafts at consecutive
+    positions) the tail with this step's appended at each position's
+    own slot and the plane left as it is (``kv_lens`` is then the
+    frozen pre-burst count)."""
     c = config
     b, t, _ = x.shape
     n, dn, dr = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
@@ -165,10 +167,15 @@ def mla(config: ModelConfig, lp, x, positions, page_table, kv_lens,
                                 c.rope_theta)],
         axis=-1)  # [B, T, 1, rank + dr]
     impl = c.attention_impl_decode or c.attention_impl
-    with jax.named_scope("mla_decode" if t == 1 else "mla_prefill"):
+    # A burst iteration is the decode step at any T: with a tail, T is
+    # the committed token and the drafts verified beside it.
+    decode = t == 1 or tail is not None
+    with jax.named_scope("mla_decode" if decode else "mla_prefill"):
         if tail is not None:
-            tail = write_to_tail(tail, latent, positions[:, 0] - kv_lens,
-                                 valid[:, 0])
+            for j in range(t):
+                tail = write_to_tail(
+                    tail, latent if t == 1 else latent[:, j:j + 1],
+                    positions[:, j] - kv_lens, valid[:, j])
         else:
             plane = write_to_pages(plane, latent, page_table, positions,
                                    valid)
@@ -181,6 +188,14 @@ def mla(config: ModelConfig, lp, x, positions, page_table, kv_lens,
                 lp["w_uv"], scale, tail=tail,
                 q_positions=None if tail is None else positions[:, 0],
                 interpret=impl == "pallas-interpret")[:, None]
+        elif decode and impl.startswith("pallas"):
+            from production_stack_tpu.ops.mla_attention_pallas import (
+                latent_paged_verify_attention,
+            )
+            attn = latent_paged_verify_attention(
+                q, plane, page_table, kv_lens, lp["w_uk"], lp["w_uv"],
+                scale, tail=tail, q_positions=positions,
+                interpret=impl == "pallas-interpret")
         else:
             attn = latent_paged_attention(
                 q, plane, page_table, positions, kv_lens, lp["w_uk"],

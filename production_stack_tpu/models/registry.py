@@ -38,6 +38,17 @@ heads of ``head_dim``, one entry a layer, declares what they hold:
   or counted. The cache builder, the burst's tails and flush, the
   bytes a token and the page budget follow it.
 
+A family whose checkpoints carry a multi-token-prediction module says
+so:
+
+- ``draft_module``: the family's module has ``draft`` beside
+  ``forward`` (``get_draft``), its ``forward`` takes ``return_hidden``
+  and, with ``kv_tail``, two positions a row, and its ``page_cache``
+  counts the module's entries after the layers'. Where the
+  configuration has the module (``num_nextn_predict_layers``) the
+  deferred burst drafts with it (``engine/model_runner.py``); the
+  counters then end in ``drafts`` and ``accepted``.
+
 This module imports no model and nothing of the engine at load, so
 ``engine/config.py`` can ask it.
 """
@@ -66,6 +77,7 @@ class Family:
     counters: Tuple[str, ...] = ()
     refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
     page_cache: Optional[Callable] = None
+    draft_module: bool = False
 
 
 def _qwen3_next_layers(c) -> tuple:
@@ -125,6 +137,15 @@ def _longcat_flash_pages(c) -> PageCache:
                      width=c.kv_lora_rank + c.qk_rope_head_dim, planes=1)
 
 
+def _glm4_moe_lite_pages(c) -> PageCache:
+    """One latent-attention sublayer a layer, and one more entry for
+    each prediction layer the configuration keeps: the module's layer
+    attends over its own latents, not the main model's."""
+    return PageCache(
+        entries=c.num_hidden_layers + c.num_nextn_predict_layers,
+        heads=1, width=c.kv_lora_rank + c.qk_rope_head_dim, planes=1)
+
+
 _EXPERT_COUNTERS = ("layer_steps", "choices", "held_choices", "max_load",
                     "experts_hit")
 
@@ -180,6 +201,18 @@ FAMILIES: Dict[str, Family] = {
             "weight quantization": "the low-rank projections and the "
                                    "experts have no quantized form",
         }),
+    "glm4_moe_lite": Family(
+        "glm4_moe_lite", deferred_kv=True,
+        page_cache=_glm4_moe_lite_pages,
+        counters=_EXPERT_COUNTERS + ("drafts", "accepted"),
+        draft_module=True,
+        refusals={
+            "tensor parallelism": "the latent is one head shared by "
+                                  "every query head, and the expert "
+                                  "layer has no sharding rules",
+            "weight quantization": "the low-rank projections and the "
+                                   "experts have no quantized form",
+        }),
 }
 
 
@@ -195,6 +228,15 @@ def get_model(config) -> Tuple[Callable, Callable]:
     module = importlib.import_module(
         "production_stack_tpu.models." + family(config.architecture).module)
     return module.init_params, module.forward
+
+
+def get_draft(config) -> Callable:
+    """``draft`` of a family that declares a draft module."""
+    fam = family(config.architecture)
+    if not fam.draft_module:
+        raise ValueError(f"{config.architecture} declares no draft module")
+    return importlib.import_module(
+        "production_stack_tpu.models." + fam.module).draft
 
 
 def list_architectures():

@@ -30,9 +30,17 @@ kernel file and not a form of the K/V kernel: that one's body is built
 around two planes of one head size, their int8 scales and the
 block-diagonal queries, and a latent form would fork each of them.
 
-Contract matches ops.mla_attention.latent_paged_attention at T = 1;
-parity is tested in tests/test_longcat_flash.py (interpret mode) and
-the compiled lowering in tests/test_pallas_lowering.py.
+``latent_paged_verify_attention`` is the same kernel body for ``T``
+query positions a row inside a deferred-write burst (a committed token
+and the drafts verified beside it, models/glm4_moe_lite.py): the pages
+hold only pre-burst tokens, which every position sees, so the ``T x n``
+heads are the query's rows of ONE walk of the row's pages, and the
+causal cut between the positions lies in the tail's state.
+
+Contract matches ops.mla_attention.latent_paged_attention, at T = 1
+and, with a tail, at any T; parity is tested in
+tests/test_longcat_flash.py and tests/test_glm4_moe_lite.py (interpret
+mode) and the compiled lowering in tests/test_pallas_lowering.py.
 """
 
 from __future__ import annotations
@@ -185,6 +193,61 @@ def _latent_decode_kernel(page_table_ref, kv_lens_ref, q_ref, plane_hbm,
                              jnp.where(lane == 1, l_ref[...], 0.0))
 
 
+def _walk_pages(qa: jnp.ndarray, plane: jnp.ndarray,
+                page_table: jnp.ndarray, kv_lens: jnp.ndarray, rank: int,
+                scale: float, interpret: bool):
+    """The kernel over absorbed queries ``qa [B, R, rank + dr]``: the
+    ``R`` query rows of a batch row (the heads of one position, or of
+    ``T`` positions that all see every cached token) against its
+    pages in ONE walk. Returns the running state with the rows padded
+    to the sublane tile: weighted latents ``[B, rows, rank]`` and
+    ``[B, rows, LANE_TILE]`` holding the maximum in lane 0 and the sum
+    in lane 1, float32."""
+    b, r, _ = qa.shape
+    _, _, width, page_size = plane.shape
+    rows = tile_pad(r, _ROW_TILE)
+    c = pages_per_chunk(1, width, page_size, plane.dtype.itemsize,
+                        page_table.shape[1])
+    page_table, max_pages = pad_page_table(page_table, c)
+
+    def row_block(lanes):
+        return pl.BlockSpec((1, rows, lanes),
+                            lambda bi, pt, kl: (bi, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # page_table, kv_lens
+        grid=(b,),
+        # The plane stays in HBM; the kernel DMAs pages itself.
+        in_specs=[row_block(width), hbm_block_spec()],
+        out_specs=[row_block(rank), row_block(LANE_TILE)],
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, width, c * page_size), plane.dtype),
+            pltpu.VMEM((rows, 1), jnp.float32),  # m
+            pltpu.VMEM((rows, 1), jnp.float32),  # l
+            pltpu.VMEM((rows, rank), jnp.float32),  # acc
+            pltpu.SMEM((2,), jnp.int32),  # chunks walked, row in flight
+            pltpu.SemaphoreType.DMA((2, c)),  # [slot, page]
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel, page_size=page_size,
+            pages_per_chunk=c, rank=rank, max_pages=max_pages,
+            scale=scale),
+        out_shape=[jax.ShapeDtypeStruct((b, rows, rank), jnp.float32),
+                   jax.ShapeDtypeStruct((b, rows, LANE_TILE),
+                                        jnp.float32)],
+        grid_spec=grid_spec,
+        # The rows run in order: a row's last chunk starts the next
+        # row's first, and the slot counter rides the scratch.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table, kv_lens,
+      jnp.pad(qa.astype(plane.dtype), ((0, 0), (0, rows - r), (0, 0))),
+      plane)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def latent_paged_decode_attention(
         q: jnp.ndarray, plane: jnp.ndarray, page_table: jnp.ndarray,
@@ -213,51 +276,11 @@ def latent_paged_decode_attention(
             "a burst tail and the queries' positions go together "
             f"(tail given: {tail is not None}, q_positions given: "
             f"{q_positions is not None})")
-    b, n, _ = q.shape
+    n = q.shape[1]
     dn, rank = w_uk.shape[1], w_uk.shape[2]
-    _, _, width, page_size = plane.shape
     qa = absorb_queries(q[..., :dn], q[..., dn:], w_uk)  # [B, n, W]
-    rows = tile_pad(n, _ROW_TILE)
-    c = pages_per_chunk(1, width, page_size, plane.dtype.itemsize,
-                        page_table.shape[1])
-    page_table, max_pages = pad_page_table(page_table, c)
-
-    def row_block(lanes):
-        return pl.BlockSpec((1, rows, lanes),
-                            lambda bi, pt, kl: (bi, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, kv_lens
-        grid=(b,),
-        # The plane stays in HBM; the kernel DMAs pages itself.
-        in_specs=[row_block(width), hbm_block_spec()],
-        out_specs=[row_block(rank), row_block(LANE_TILE)],
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, width, c * page_size), plane.dtype),
-            pltpu.VMEM((rows, 1), jnp.float32),  # m
-            pltpu.VMEM((rows, 1), jnp.float32),  # l
-            pltpu.VMEM((rows, rank), jnp.float32),  # acc
-            pltpu.SMEM((2,), jnp.int32),  # chunks walked, row in flight
-            pltpu.SemaphoreType.DMA((2, c)),  # [slot, page]
-        ],
-    )
-    acc, stats = pl.pallas_call(
-        functools.partial(
-            _latent_decode_kernel, page_size=page_size,
-            pages_per_chunk=c, rank=rank, max_pages=max_pages,
-            scale=scale),
-        out_shape=[jax.ShapeDtypeStruct((b, rows, rank), jnp.float32),
-                   jax.ShapeDtypeStruct((b, rows, LANE_TILE),
-                                        jnp.float32)],
-        grid_spec=grid_spec,
-        # The rows run in order: a row's last chunk starts the next
-        # row's first, and the slot counter rides the scratch.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(page_table, kv_lens,
-      jnp.pad(qa.astype(plane.dtype), ((0, 0), (0, rows - n), (0, 0))),
-      plane)
+    acc, stats = _walk_pages(qa, plane, page_table, kv_lens, rank, scale,
+                             interpret)
     state = (stats[:, :n, 0, None], stats[:, :n, 1, None],
              acc[:, :n, None])  # [B, n, T=1(, rank)]
     if tail is not None:
@@ -267,3 +290,42 @@ def latent_paged_decode_attention(
     _, denom, acc = state
     o_lat = (acc / jnp.maximum(denom, 1e-30)[..., None]).astype(q.dtype)
     return up_project_values(o_lat, w_uv, "bntr")[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def latent_paged_verify_attention(
+        q: jnp.ndarray, plane: jnp.ndarray, page_table: jnp.ndarray,
+        kv_lens: jnp.ndarray, w_uk: jnp.ndarray, w_uv: jnp.ndarray,
+        scale: float, tail: jnp.ndarray, q_positions: jnp.ndarray,
+        interpret: bool = False) -> jnp.ndarray:
+    """``latent_paged_decode_attention`` for ``T`` query positions a
+    row inside a deferred-write burst (a committed token and the
+    drafts after it): the pages hold only pre-burst tokens, every one
+    of which every position sees, so the ``T x n`` heads are the
+    query's rows of ONE walk of the row's pages (the kernel body is
+    the decode step's: it masks by ``kv_lens`` alone); the causal cut
+    between the positions lies in the tail, whose state
+    ``latent_tail_state`` takes per position.
+
+    Args as the decode form's, but ``q [B, T, n, dn + dr]`` and
+    ``q_positions [B, T]``; the tail is required (without one the
+    positions' own latents would be in the pages, under a causal cut
+    the walk does not make). Returns ``[B, T, n, dv]``.
+    """
+    b, t, n, _ = q.shape
+    dn, rank = w_uk.shape[1], w_uk.shape[2]
+    qa = absorb_queries(q[..., :dn], q[..., dn:], w_uk)  # [B, T, n, W]
+    acc, stats = _walk_pages(qa.reshape(b, t * n, -1), plane, page_table,
+                             kv_lens, rank, scale, interpret)
+
+    def per_position(x):  # [B, T * n, ...] -> [B, n, T, ...]
+        return jnp.swapaxes(x.reshape((b, t, n) + x.shape[2:]), 1, 2)
+
+    state = merge_softmax_states(
+        (per_position(stats[:, :t * n, 0]),
+         per_position(stats[:, :t * n, 1]),
+         per_position(acc[:, :t * n])),
+        latent_tail_state(qa, tail, q_positions, kv_lens, scale, rank))
+    _, denom, acc = state
+    o_lat = (acc / jnp.maximum(denom, 1e-30)[..., None]).astype(q.dtype)
+    return up_project_values(o_lat, w_uv, "bntr")

@@ -3,7 +3,8 @@
 The router scores every expert of the model (``router_w`` is as wide
 as the published count) and a token keeps its ``top_k`` choices
 (``route``: softmax over all experts; ``route_sigmoid``: a sigmoid an
-expert, chosen with a learned bias and weighted without it;
+expert, chosen with a learned bias and weighted without it, in the two
+published forms of its sum's epsilon and its scale;
 ``route_softmax_bias``: softmax scores chosen with a learned bias,
 scaled and not renormalised, over routed and zero-compute experts,
 whose part of the sum is ``identity_weight``). This
@@ -54,12 +55,16 @@ def route(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
 
 
 def route_sigmoid(x: jnp.ndarray, router_w: jnp.ndarray,
-                  expert_bias: jnp.ndarray, top_k: int):
+                  expert_bias: jnp.ndarray, top_k: int,
+                  scale: float = 1.0, eps: float = 1e-6):
     """``route`` for a router that scores each expert alone: a sigmoid
     over ALL experts in float32; the ``top_k`` largest of score +
     ``expert_bias`` (learned, [E_all] float32) are chosen, and their
     weights are the scores WITHOUT the bias, divided by their sum +
-    1e-6 as the published ``lfm2_moe`` code has it.
+    ``eps``. Two published forms: ``lfm2_moe`` (eps 1e-6, no scale)
+    and the DeepSeek-V3 kind that ``glm4_moe_lite`` follows (eps
+    1e-20, and the normalised weights times ``scale``, its
+    ``routed_scaling_factor``).
 
     x [N, H], router_w [H, E_all] -> (weights [N, k] f32, ids [N, k]).
     """
@@ -67,7 +72,8 @@ def route_sigmoid(x: jnp.ndarray, router_w: jnp.ndarray,
         jnp.dot(x, router_w, preferred_element_type=jnp.float32))
     _, ids = jax.lax.top_k(scores + expert_bias, top_k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
-    return weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6), ids
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
+    return (weights if scale == 1.0 else weights * scale), ids
 
 
 def route_softmax_bias(x: jnp.ndarray, router_w: jnp.ndarray,

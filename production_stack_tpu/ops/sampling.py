@@ -183,10 +183,66 @@ def sample_tokens(logits: jnp.ndarray, temperature: jnp.ndarray,
     )
 
 
+def sampling_probs(logits: jnp.ndarray, temperature: jnp.ndarray,
+                   top_p: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
+    """A stochastic row's FULL sampling distribution (temperature, then
+    top-k/top-p by ``sample_tokens``' mask): ``[B, vocab]``
+    probabilities. A greedy row (temperature 0) gets the softmax of its
+    raw logits, which nothing reads. What a proposer that samples
+    hands ``spec_verify`` as its ``draft_probs``, and what
+    ``spec_verify`` measures the target by. The vocabulary is sorted
+    only where some row has a top-k or a top-p."""
+    safe_temp = jnp.where(temperature > 0, temperature, 1.0)
+    scaled = logits / safe_temp[:, None]
+    needs_mask = jnp.any((top_k > 0) | (top_p < 1.0))
+    return jax.nn.softmax(jax.lax.cond(
+        needs_mask, lambda: _mask_top_k_top_p(scaled, top_p, top_k),
+        lambda: scaled), axis=-1)
+
+
+def _verify_proposal(logits, drafts, in_draft, draft_probs, temperature,
+                     top_p, top_k, key, accept_greedy, greedy_final):
+    """``spec_verify``'s stochastic rows for a proposal that is a
+    distribution ``q`` (``draft_probs [B, S-1, vocab]``): draft j is
+    accepted with ``min(1, p_j(d_j) / q_j(d_j))``; the one replacement
+    a row needs, at its first rejected offset ``a`` or at the bonus
+    offset, is drawn from ``norm(max(0, p_a - q_a))`` (``q`` is zero at
+    the bonus offset and where a row offered no draft, so there it is
+    ``p_a`` itself). One draw a row, at offset ``a`` alone."""
+    b, s, vocab = logits.shape
+    stochastic = temperature > 0
+    dsafe = jnp.clip(drafts, 0)
+    probs = sampling_probs(
+        logits.reshape(b * s, vocab), jnp.repeat(temperature, s),
+        jnp.repeat(top_p, s), jnp.repeat(top_k, s)).reshape(b, s, vocab)
+    q = jnp.where(in_draft[..., None], draft_probs, 0.0)
+    p_draft = jnp.take_along_axis(
+        probs[:, :-1], dsafe[..., None], axis=-1)[..., 0]
+    q_draft = jnp.take_along_axis(q, dsafe[..., None], axis=-1)[..., 0]
+    key_u, key_r = jax.random.split(key)
+    u = jax.random.uniform(key_u, (b, s - 1))
+    accept = jnp.where(stochastic[:, None], u * q_draft < p_draft,
+                       accept_greedy) & in_draft
+    a = jnp.cumprod(accept.astype(jnp.int32), axis=-1).sum(axis=-1)
+    at = a[:, None, None]
+    p_a = jnp.take_along_axis(probs, at, axis=1)[:, 0]
+    q_a = jnp.take_along_axis(
+        jnp.pad(q, ((0, 0), (0, 1), (0, 0))), at, axis=1)[:, 0]
+    residual = jnp.maximum(p_a - q_a, 0.0)
+    resampled = jax.random.categorical(
+        key_r, jnp.where(residual > 0, jnp.log(residual), NEG_INF),
+        axis=-1).astype(jnp.int32)
+    final_a = jnp.where(
+        stochastic, resampled,
+        jnp.take_along_axis(greedy_final, a[:, None], axis=1)[:, 0])
+    return accept, jnp.broadcast_to(final_a[:, None], (b, s))
+
+
 def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
                 draft_lens: jnp.ndarray, temperature: jnp.ndarray,
                 top_p: jnp.ndarray, top_k: jnp.ndarray,
-                key: jax.Array) -> jnp.ndarray:
+                key: jax.Array,
+                draft_probs: "jnp.ndarray | None" = None) -> jnp.ndarray:
     """Vectorized speculative-decoding acceptance rule.
 
     One verify forward pass scored S = K+1 positions per row: the
@@ -194,8 +250,14 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
     with invalid slots). ``logits[:, j]`` is the target model's
     distribution for the token at offset j past the committed length.
 
-    Acceptance (Leviathan et al. rejection sampling with a
-    deterministic point-mass proposal — the n-gram draft):
+    Acceptance (Leviathan et al. rejection sampling). Without
+    ``draft_probs`` the proposal is a deterministic point mass, the
+    n-gram draft (``q`` = one-hot at the draft: ``p/q`` is ``p(d)`` and
+    ``max(0, p - q)`` is ``p`` with the draft removed); with it, the
+    proposal is the distribution each draft was drawn from (a draft
+    model, a prediction module): accept with ``min(1, p(d)/q(d))``,
+    else draw from ``norm(max(0, p - q))`` (``_verify_proposal``).
+    Either way:
       * greedy rows (temperature 0): draft j is accepted iff it equals
         the raw-logits argmax at offset j — the emitted stream is
         byte-identical to non-speculative greedy decode.
@@ -217,6 +279,11 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
       top_p:       [B] (1.0 => disabled)
       top_k:       [B] int32 (0 => disabled)
       key:         PRNG key for acceptance draws + residual samples
+      draft_probs: optional [B, S-1, vocab] float32, the distribution
+                   each stochastic row's draft was drawn from, under
+                   the row's own sampling parameters
+                   (``sampling_probs``); a greedy row's draft is its
+                   proposer's argmax and its entry is not read
 
     Returns [B, S] int32: row i's emitted tokens in its first
     ``accepted_i + 1`` slots, -1 beyond.
@@ -272,8 +339,15 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
                           greedy_final)
         return accept & in_draft, final
 
-    accept, final = jax.lax.cond(jnp.any(stochastic),
-                                 with_stochastic, greedy_only)
+    def with_proposal():
+        return _verify_proposal(
+            logits, drafts, in_draft, draft_probs, temperature, top_p,
+            top_k, key, accept_greedy, greedy_final)
+
+    accept, final = jax.lax.cond(
+        jnp.any(stochastic),
+        with_stochastic if draft_probs is None else with_proposal,
+        greedy_only)
     # Accepted prefix length: drafts accept left-to-right until the
     # first rejection.
     a = jnp.cumprod(accept.astype(jnp.int32), axis=-1).sum(axis=-1)

@@ -595,3 +595,103 @@ def test_the_latent_decode_step_never_expands_cached_tokens_to_heads():
                 if 64 in s and (192 in s or 128 in s)
                 and (steps in s or 3328 in s or 34 in s)]
     assert not expanded, expanded
+
+
+# ---- the latent kernel's verify form and the drafting burst ----------------
+
+# glm-4.7-flash-pp8's decode batch: rows, heads, the query head (192 +
+# 64), the latent (512 + 64), the value head, pages, the table's width,
+# and the two positions a row a burst iteration verifies.
+VERIFY_CELL = (160, 20, 192, 64, 512, 256, 3328, 34, 2)
+
+
+def _latent_verify_shapes(steps=32, sharding=None):
+    """(q, plane, table, kv_lens, w_uk, w_uv, tail, q_positions) of one
+    entry's call in the cell's drafting burst, as shapes: tails of two
+    slots an iteration."""
+    rows, n, dn, dr, rank, dv, pages, max_pages, t = VERIFY_CELL
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    return (shape((rows, t, n, dn + dr)), shape((1, pages, rank + dr, 128)),
+            shape((rows, max_pages), jnp.int32), shape((rows,), jnp.int32),
+            shape((n, dn, rank)), shape((n, rank, dv)),
+            shape((rows, t * steps, 1, rank + dr)),
+            shape((rows, t), jnp.int32))
+
+
+def _latent_verify(q, plane, table, lens, w_uk, w_uv, tail, positions):
+    from production_stack_tpu.ops.mla_attention_pallas import (
+        latent_paged_verify_attention,
+    )
+    return latent_paged_verify_attention(
+        q, plane, table, lens, w_uk, w_uv, 256 ** -0.5, tail=tail,
+        q_positions=positions)
+
+
+def test_latent_verify_kernel_lowers_at_the_cells_shapes():
+    """2 x 20 heads are the kernel's 40 rows, padded to 48."""
+    text = _lower_for_tpu(_latent_verify,
+                          *_latent_verify_shapes()).as_text()
+    assert "tpu_custom_call" in text
+    assert "160x48x576xbf16" in text
+
+
+def test_latent_verify_kernel_compiles_for_a_v5e(one_chip):
+    """What ``auto`` probes at start-up on the chip where the burst
+    drafts, made here."""
+    compiled = jax.jit(_latent_verify).lower(
+        *_latent_verify_shapes(sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_drafting_burst_and_its_prefill_step_lower_for_tpu():
+    """The whole burst of a family that drafts (two positions a row
+    through the Pallas latent kernel's verify form, the verify rule
+    with the module's distribution, the module on what was committed,
+    tails of two slots an iteration) and the prefill step that fills
+    the module's cache entry, as TPU programs; the burst's named
+    scopes are in its text."""
+    from production_stack_tpu.engine import config as cfg
+    from production_stack_tpu.engine.model_runner import ModelRunner
+
+    rows, steps, chunk = 4, 8, 64
+    model = cfg.tiny_glm4_moe_lite_config()
+    model.attention_impl, model.dtype = "pallas", "bfloat16"
+    runner = ModelRunner(cfg.EngineConfig(
+        model=model,
+        cache=cfg.CacheConfig(page_size=128, num_pages=32),
+        scheduler=cfg.SchedulerConfig(
+            max_num_seqs=rows, max_model_len=256, prefill_chunk_size=chunk,
+            decode_steps=steps, deferred_kv_writes=True,
+            draft_module=True)))
+
+    def i32(*dims):
+        return jnp.zeros(dims, jnp.int32)
+
+    sampling = (jnp.zeros((rows,), jnp.float32),
+                jnp.ones((rows,), jnp.float32), i32(rows),
+                jax.random.PRNGKey(0)) + (None,) * 7
+    burst = jax.jit(runner._decode_burst_draft_impl,
+                    static_argnames=("num_steps",)).trace(
+        runner.params, runner.k_cache, runner.v_cache, i32(rows, 1),
+        i32(rows, 1), i32(rows, runner.max_pages_per_seq), i32(rows),
+        jnp.zeros((rows,), bool), i32(rows),
+        jnp.full((rows, 16), -1, jnp.int32), *sampling, num_steps=steps,
+        draft_rows=jnp.ones((rows,), bool))
+    text = burst.lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
+    assert "tpu_custom_call" in text
+    for scope in ("mtp_draft", "mtp_verify", "mla_decode", "moe_experts",
+                  "dense_ffn"):
+        assert scope in text, scope
+    assert f"{rows}x{2 * steps}x1x32xbf16" in text      # the tails
+    step = jax.jit(runner._step_impl, static_argnames=(
+        "sample_index_mode", "want_logprobs")).trace(
+        runner.params, runner.k_cache, runner.v_cache, i32(rows, chunk),
+        i32(rows, chunk), i32(rows, runner.max_pages_per_seq), i32(rows),
+        jnp.zeros((rows, chunk), bool), i32(rows), *sampling,
+        sample_index_mode="last", next_tokens=i32(rows))
+    assert "mtp_draft" in step.lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)

@@ -182,6 +182,131 @@ def test_verify_mixed_batch_keeps_greedy_rows_exact():
     assert out[1].tolist()[:3] == [3, 5, 7]
 
 
+# ---- spec_verify with a proposal that is a distribution -------------------
+
+
+def _verify_q(logits, drafts, lens, temps, q, seed=0, top_k=None):
+    from production_stack_tpu.ops.sampling import spec_verify
+
+    b = logits.shape[0]
+    return np.asarray(spec_verify(
+        logits, jnp.asarray(drafts, jnp.int32),
+        jnp.asarray(lens, jnp.int32),
+        jnp.asarray(temps, jnp.float32),
+        jnp.ones((b,), jnp.float32),
+        jnp.zeros((b,), jnp.int32) if top_k is None
+        else jnp.asarray(top_k, jnp.int32),
+        jax.random.PRNGKey(seed), draft_probs=jnp.asarray(q)))
+
+
+@pytest.mark.parametrize("case,logits,drafts,lens,temps,want", [
+    ("greedy partial accept", [3, 5, 7, 9], [[3, 5, 2]], [3], [0.0],
+     [3, 5, 7, -1]),
+    ("greedy full accept emits the bonus", [3, 5, 7, 9], [[3, 5, 7]], [3],
+     [0.0], [3, 5, 7, 9]),
+    ("zero drafts is plain decode", [3, 5, 7, 9], [[-1, -1, -1]], [0],
+     [0.0], [3, -1, -1, -1]),
+    ("the first reject stops acceptance", [3, 5, 7, 9], [[4, 5, 7]], [3],
+     [0.0], [3, -1, -1, -1]),
+    ("a stochastic point mass accepts", [3, 5, 7, 9], [[3, 5, 7]], [3],
+     [1.0], [3, 5, 7, 9]),
+    ("a stochastic off-mass draft is rejected", [3, 5, 7, 9], [[4, 5, 7]],
+     [3], [1.0], [3, -1, -1, -1]),
+])
+def test_the_one_hot_proposal_reads_as_the_point_mass_rule(
+        case, logits, drafts, lens, temps, want):
+    """``draft_probs`` = one-hot at each draft is the prompt-lookup
+    form: the same rows come out as from the rule without the
+    argument, case for case of the tests above."""
+    logits = _point_logits(logits)
+    one_hot = np.zeros((1, 3, 16), np.float32)
+    for j, d in enumerate(drafts[0]):
+        one_hot[0, j, max(d, 0)] = 1.0
+    assert _verify_q(logits, drafts, lens, temps,
+                     one_hot)[0].tolist() == want, case
+    assert _verify(logits, drafts, lens, temps)[0].tolist() == want
+
+
+def _proposal_case(n, temperature=0.8):
+    from production_stack_tpu.ops.sampling import sampling_probs
+    lp = jnp.asarray([0.5, 1.0, -1.0, 0.2, 2.0, 0.0])
+    lq = jnp.asarray([1.5, -1.0, 0.3, 0.2, 0.0, 1.0])
+    temps = jnp.full((n,), temperature)
+    logits = jnp.tile(jnp.stack([lp, lp * 0.5])[None], (n, 1, 1))
+    q = sampling_probs(jnp.tile(lq[None], (n, 1)), temps,
+                       jnp.ones((n,)), jnp.zeros((n,), jnp.int32))
+    drafts = jax.random.categorical(jax.random.PRNGKey(7),
+                                    jnp.log(q)).astype(jnp.int32)
+    p = np.asarray(jax.nn.softmax(lp / temperature))
+    p2 = np.asarray(jax.nn.softmax(lp * 0.5 / temperature))
+    return logits, drafts, temps, q, p, p2
+
+
+def test_a_sampled_proposal_leaves_the_targets_distribution():
+    """Drafts drawn from ``q``, verified against ``p``: the first
+    emitted token is distributed as ``p`` (accepted draft or residual
+    draw), the share accepted is ``1 - TV(p, q)``, and the token after
+    an accepted draft as the second position's ``p``. 40000 rows, six
+    tokens: sampling noise under 0.01."""
+    n = 40000
+    logits, drafts, temps, q, p, p2 = _proposal_case(n)
+    out = _verify_q(logits, np.asarray(drafts)[:, None], np.ones(n),
+                    np.asarray(temps), q[:, None], seed=1)
+    first = np.bincount(out[:, 0], minlength=6) / n
+    assert np.abs(first - p).max() < 0.01
+    accepted = out[:, 1] >= 0
+    overlap = 1 - 0.5 * np.abs(p - np.asarray(q[0])).sum()
+    assert abs(accepted.mean() - overlap) < 0.01
+    assert (out[accepted, 0] == np.asarray(drafts)[accepted]).all()
+    second = np.bincount(out[accepted, 1], minlength=6) / accepted.sum()
+    assert np.abs(second - p2).max() < 0.015
+
+
+def test_always_accepting_would_fail_that_bound():
+    """The control: the first token taken from ``q`` outright is 0.3
+    from ``p`` in its worst cell, thirty times the bound."""
+    _, _, _, q, p, _ = _proposal_case(4)
+    assert np.abs(np.asarray(q[0]) - p).max() > 0.3
+
+
+def test_a_row_without_a_draft_and_a_greedy_row_beside_sampled_ones():
+    """``draft_lens`` 0 draws one token from ``p`` whatever ``q`` holds;
+    a greedy row accepts its draft iff it is the argmax."""
+    n = 20000
+    logits, drafts, temps, q, p, _ = _proposal_case(n)
+    lens = np.ones(n, np.int32)
+    lens[::2] = 0
+    temps = np.asarray(temps).copy()
+    temps[1] = temps[3] = 0.0
+    drafts = np.asarray(drafts).copy()
+    drafts[1], drafts[3] = 4, 2               # the argmax, and not
+    out = _verify_q(logits, drafts[:, None], lens, temps, q[:, None],
+                    seed=2)
+    assert (out[::2, 1] == -1).all()
+    plain = np.bincount(out[::2, 0], minlength=6) / (n // 2)
+    assert np.abs(plain - p).max() < 0.015
+    assert out[1].tolist()[0] == 4 and out[1].tolist()[1] >= 0
+    assert out[3].tolist() == [4, -1]
+
+
+def test_top_k_masks_target_and_proposal_alike():
+    """Under top-k 2 both distributions live on their own two largest
+    tokens: nothing outside the target's two is ever emitted."""
+    from production_stack_tpu.ops.sampling import sampling_probs
+    n = 4000
+    logits, _, temps, _, _, _ = _proposal_case(n)
+    top_k = np.full(n, 2, np.int32)
+    lq = jnp.asarray([1.5, -1.0, 0.3, 0.2, 0.0, 1.0])
+    q = sampling_probs(jnp.tile(lq[None], (n, 1)), temps, jnp.ones((n,)),
+                       jnp.asarray(top_k))
+    assert set(np.flatnonzero(np.asarray(q[0]))) == {0, 5}
+    drafts = jax.random.categorical(jax.random.PRNGKey(3),
+                                    jnp.log(q)).astype(jnp.int32)
+    out = _verify_q(logits, np.asarray(drafts)[:, None], np.ones(n),
+                    np.asarray(temps), q[:, None], seed=4, top_k=top_k)
+    assert set(out[:, 0].tolist()) <= {1, 4}      # the target's two
+
+
 # ---- config + feature gating ----------------------------------------------
 
 
