@@ -46,10 +46,11 @@ from production_stack_tpu.ops.quant_kv import (
 )
 from production_stack_tpu.ops.sampling import (
     apply_penalties,
+    draw_proposal,
     sample_tokens,
-    sampling_probs,
     spec_verify,
     token_logprobs,
+    verify_proposal,
 )
 from production_stack_tpu.parallel.mesh import (
     shard_cache,
@@ -1650,17 +1651,29 @@ class ModelRunner:
         row's own temperature, top-p and top-k; the argmax for a
         greedy row): the main model runs positions ``P, P + 1`` on
         ``(x, d)`` and appends both latents at tail slots ``n, n + 1``
-        of each of its entries; ``spec_verify`` accepts ``d`` with
+        of each of its entries; ``verify_proposal`` accepts ``d`` with
         ``min(1, p1(d) / q(d))`` and commits ``d`` and ``y ~ p2``, or
         commits ``y' ~ norm(max(0, p1 - q))``; the module then runs on
         the committed positions (``h_P`` with the first committed
         token, ``h_{P+1}`` with ``y`` where ``d`` was accepted),
         appends its own latents at the same slots of its own entry and
-        gives the next ``q`` and draft. A rejected draft's slot ``n +
+        gives the next proposal and draft. A rejected draft's slot ``n +
         1`` is overwritten by the next iteration (whose ``n`` is one
         more) and is causally invisible until then: slot s is position
         ``kv_lens0 + s``, which no query before it reads. The output
         distribution is the target's.
+
+        What the sampler touches of the vocabulary (ops/sampling.py;
+        docs/speculative.md, "The module as proposer"): the two
+        positions' logits come from the head POSITION-MAJOR, ``[2, B,
+        vocab]`` float32, each position a dense plane (a ``[B, 2,
+        vocab]`` array lies in (2, 128) tiles, a quarter of a tile's
+        eight rows, and every pass over it pays for whole tiles); the proposal rides the carry between
+        iterations as the module's logits ``[B, vocab]`` as its head
+        wrote them, never as probabilities, and ``draw_proposal``
+        draws the next draft from them by Gumbel-max in one pass. An
+        iteration without top-k/top-p reads a plane some ten times, in
+        six reductions, and writes none.
 
         A row has no draft in a burst's first iteration (the draft
         after the last burst's, or the prefill's, last token is not
@@ -1703,8 +1716,8 @@ class ModelRunner:
             return jnp.any(tok[:, None] == stop_tokens, axis=-1)
 
         def body(carry, step_rng):
-            (tok, pos, act, emitted, counts, fs, draft, q, has_draft,
-             kt, vt) = carry
+            (tok, pos, act, emitted, counts, fs, draft, proposal,
+             has_draft, kt, vt) = carry
             key_verify, key_seeded, key_draft = jax.random.split(
                 step_rng, 3)
             pos2 = jnp.concatenate([pos, pos + 1], axis=1)
@@ -1714,15 +1727,19 @@ class ModelRunner:
                 page_table, kv_lens0, valid2,
                 served(k_cache, kt, k_kinds),
                 served(v_cache, vt, v_kinds), kv_tail=(kt, vt),
-                return_hidden=True)
+                return_hidden=True, position_major=True)
             with jax.named_scope("mtp_verify"):
-                row_logits = first_logits(logits[:, 0], counts, emitted,
-                                          fs)
-                out2 = spec_verify(
-                    jnp.stack([row_logits, logits[:, 1]], axis=1),
-                    draft[:, None], has_draft.astype(jnp.int32),
-                    temperature, top_p, top_k, key_verify,
-                    draft_probs=q[:, None])
+                row_logits = logits[0]
+                targets = logits
+                if first_logits is not None:
+                    # A plane of its own: written once, where some
+                    # row's request changes its logits.
+                    row_logits = first_logits(row_logits, counts,
+                                              emitted, fs)
+                    targets = (row_logits, logits[1])
+                out2 = verify_proposal(
+                    targets, draft[:, None], has_draft.astype(jnp.int32),
+                    (proposal,), temperature, top_p, top_k, key_verify)
                 first, second = out2[:, 0], out2[:, 1]
                 if seeding is not None:
                     # A seeded row (never a drafting one) keeps its
@@ -1745,7 +1762,7 @@ class ModelRunner:
                 out = jnp.stack([jnp.where(act, first, -1),
                                  jnp.where(emit2, second, -1)])
                 if want_logprobs:
-                    lps = [token_logprobs(logits[:, j], jnp.clip(t, 0),
+                    lps = [token_logprobs(logits[j], jnp.clip(t, 0),
                                           TOP_LOGPROBS_WIDTH)
                            for j, t in enumerate((first, second))]
                     out = (out,) + tuple(jnp.stack(pair)
@@ -1763,8 +1780,9 @@ class ModelRunner:
                     fs = jnp.where(act & (fs >= 0), nxt, fs)
             # The module on what was committed: h_P with the first
             # token, h_{P+1} with the second where there is one; its
-            # distribution after the last of them is the next draft's.
-            draft_logits, kt = self._draft(
+            # logits after the last of them are the next proposal, and
+            # ride the carry as the head wrote them.
+            proposal, kt = self._draft(
                 params, m, hidden,
                 jnp.stack([first, jnp.clip(second, 0)], axis=1), pos2,
                 page_table, kv_lens0,
@@ -1772,13 +1790,8 @@ class ModelRunner:
                 served(k_cache, kt, k_kinds), kv_tail=(kt, vt),
                 head_index=emit2.astype(jnp.int32))
             with jax.named_scope("mtp_draft"):
-                q = sampling_probs(draft_logits, temperature, top_p,
-                                   top_k)
-                draft = jnp.where(
-                    temperature > 0,
-                    jax.random.categorical(key_draft, jnp.log(q),
-                                           axis=-1),
-                    jnp.argmax(draft_logits, axis=-1)).astype(jnp.int32)
+                draft = draw_proposal(proposal, temperature, top_p,
+                                      top_k, key_draft)
             has_draft_next = act_next & draft_rows
             stats = kt[-1]
             stats = stats.at[i_drafts].add(
@@ -1790,7 +1803,7 @@ class ModelRunner:
             tok = jnp.where(emit2, second, jnp.where(act, first,
                                                      tok[:, 0]))
             return ((tok[:, None], pos + step[:, None].astype(pos.dtype),
-                     act_next, emitted2, counts, fs, draft, q,
+                     act_next, emitted2, counts, fs, draft, proposal,
                      has_draft_next, kt, vt), out)
 
         rngs = jax.random.split(rng, num_steps)
@@ -1810,7 +1823,11 @@ class ModelRunner:
         ones it is sampled from, for a burst body that samples by
         another rule than ``sample_tokens``: penalties, logit bias,
         min_tokens suppression, the guided mask last (``guided``: the
-        burst carries automaton states)."""
+        burst carries automaton states). None where the batch has none
+        of them: its rows are sampled from the logits as they are."""
+        if (penalties is None and bias is None and suppress is None
+                and not guided):
+            return None
 
         def row_logits(logits, counts, emitted, fsm):
             if penalties is not None:

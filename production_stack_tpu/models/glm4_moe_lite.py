@@ -231,13 +231,19 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, page_table: jnp.ndarray,
             kv_lens: jnp.ndarray, valid: jnp.ndarray,
             k_cache, v_cache, lora=None, lora_ids=None,
-            kv_tail=None, return_hidden: bool = False):
+            kv_tail=None, return_hidden: bool = False,
+            position_major: bool = False):
     """The main model. ``models.longcat_flash.forward``'s contract with
     per-entry caches (the module's text); with ``kv_tail`` the layers'
     planes are replaced by their updated tails in what comes back, and
     T may be 2: a committed token and the draft after it. With
     ``return_hidden`` the last layer's output before the final norm
-    comes back after the logits: what ``draft`` reads. No LoRA
+    comes back after the logits: what ``draft`` reads. With
+    ``position_major`` the logits come back ``[T, B, vocab]``, each
+    position a dense ``[B, vocab]`` plane (what the drafting burst's
+    sampler reads: ``ops/sampling.py`` ``verify_proposal``): the
+    normalised hidden state is transposed before the head product,
+    1.3 MB at the cell's shapes where the logits are 198 MB. No LoRA
     targets."""
     if lora is not None:
         raise NotImplementedError("glm4_moe_lite has no LoRA targets")
@@ -250,8 +256,10 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             c, params, layer, x, positions, page_table, kv_lens, valid,
             planes[layer], None if tails is None else tails[layer],
             stats, kv_tail is not None or tokens.shape[1] == 1, impl)
-    logits = (rms_norm(x, params["final_norm"], c.rms_norm_eps)
-              @ _head(params)).astype(jnp.float32)
+    last = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+    if position_major:
+        last = jnp.swapaxes(last, 0, 1)
+    logits = (last @ _head(params)).astype(jnp.float32)
     out = (tuple(kept) + (stats,), tuple(v_cache))
     return (logits, x) + out if return_hidden else (logits,) + out
 

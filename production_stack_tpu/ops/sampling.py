@@ -68,8 +68,9 @@ def _mask_top_k_top_p(scaled: jnp.ndarray, top_p: jnp.ndarray,
                       top_k: jnp.ndarray) -> jnp.ndarray:
     """NEG_INF-mask every logit outside its row's top-k/top-p set.
 
-    Shared by ``sample_tokens`` and ``spec_verify`` so the sampling
-    and speculative-verification distributions cannot drift.
+    Shared by ``sample_tokens``, ``spec_verify`` and the proposal's
+    pair (``draw_proposal``, ``verify_proposal``) so the sampling and
+    speculative-verification distributions cannot drift.
 
     Args:
       scaled: [B, vocab] temperature-scaled logits
@@ -183,66 +184,251 @@ def sample_tokens(logits: jnp.ndarray, temperature: jnp.ndarray,
     )
 
 
-def sampling_probs(logits: jnp.ndarray, temperature: jnp.ndarray,
-                   top_p: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
-    """A stochastic row's FULL sampling distribution (temperature, then
-    top-k/top-p by ``sample_tokens``' mask): ``[B, vocab]``
-    probabilities. A greedy row (temperature 0) gets the softmax of its
-    raw logits, which nothing reads. What a proposer that samples
-    hands ``spec_verify`` as its ``draft_probs``, and what
-    ``spec_verify`` measures the target by. The vocabulary is sorted
-    only where some row has a top-k or a top-p."""
-    safe_temp = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits / safe_temp[:, None]
-    needs_mask = jnp.any((top_k > 0) | (top_p < 1.0))
-    return jax.nn.softmax(jax.lax.cond(
-        needs_mask, lambda: _mask_top_k_top_p(scaled, top_p, top_k),
-        lambda: scaled), axis=-1)
+def _accepted_length(accept: jnp.ndarray) -> jnp.ndarray:
+    """Drafts accept left to right until the first rejection: ``[B]``
+    accepted prefix lengths of ``accept [B, K]``."""
+    return jnp.cumprod(accept.astype(jnp.int32), axis=-1).sum(axis=-1)
 
 
-def _verify_proposal(logits, drafts, in_draft, draft_probs, temperature,
-                     top_p, top_k, key, accept_greedy, greedy_final):
-    """``spec_verify``'s stochastic rows for a proposal that is a
-    distribution ``q`` (``draft_probs [B, S-1, vocab]``): draft j is
+def _emitted(drafts: jnp.ndarray, a: jnp.ndarray,
+             final: jnp.ndarray) -> jnp.ndarray:
+    """What both verify rules hand back: row i's accepted drafts in its
+    first ``a_i`` slots, ``final`` (``[B, 1]``, or ``[B, K + 1]`` by
+    offset) at slot ``a_i``, -1 beyond."""
+    pos = jnp.arange(drafts.shape[1] + 1)[None, :]
+    drafts_padded = jnp.pad(drafts, ((0, 0), (0, 1)))
+    return jnp.where(
+        pos < a[:, None], drafts_padded,
+        jnp.where(pos == a[:, None], final, -1)).astype(jnp.int32)
+
+
+def _inverse_temperature(temperature: jnp.ndarray) -> jnp.ndarray:
+    """``[B]`` factor of a row's exponent (1 for a greedy row): the
+    proposal is drawn and measured under the same product, so no
+    divided copy of the logits exists on either side."""
+    return 1.0 / jnp.where(temperature > 0, temperature, 1.0)
+
+
+def _needs_mask(top_p: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
+    return jnp.any((top_k > 0) | (top_p < 1.0))
+
+
+def draw_proposal(logits: jnp.ndarray, temperature: jnp.ndarray,
+                  top_p: jnp.ndarray, top_k: jnp.ndarray,
+                  key: jax.Array) -> jnp.ndarray:
+    """A proposer's draft from its own logits ``[B, vocab]`` float32,
+    ``[B]`` int32: a stochastic row's is drawn from its FULL sampling
+    distribution ``softmax(mask(logits / T))`` (``sample_tokens``'
+    mask), which ``verify_proposal`` measures it by from the same
+    logits; a greedy row's is the argmax. Gumbel-max on the scaled
+    logits in ONE pass over the plane: a greedy row's noise is
+    multiplied by zero, so one argmax serves every row. No
+    probabilities are written, and the vocabulary is sorted only where
+    some row has a top-k or a top-p."""
+    stochastic = temperature > 0
+    scale = _inverse_temperature(temperature)
+
+    def drawn(mask):
+        # Scaled inside the branch: the product is part of its pass.
+        scaled = mask(logits * scale[:, None])
+        noise = jax.random.gumbel(key, scaled.shape, scaled.dtype)
+        return jnp.argmax(
+            scaled + noise * stochastic.astype(scaled.dtype)[:, None],
+            axis=-1)
+
+    def sampled():
+        return jax.lax.cond(
+            _needs_mask(top_p, top_k),
+            lambda: drawn(lambda x: _mask_top_k_top_p(x, top_p, top_k)),
+            lambda: drawn(lambda x: x))
+
+    return jax.lax.cond(
+        jnp.any(stochastic), sampled,
+        lambda: jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+
+
+def _by_offset(a: jnp.ndarray, values) -> jnp.ndarray:
+    """Row i's ``values[a_i]`` (the last where ``a_i`` is beyond) of a
+    short sequence of ``[B]`` or ``[B, vocab]`` arrays: selects, which
+    fuse into the pass that reads the result, where a gather over an
+    axis of two or three would be a pass of its own."""
+    out = values[-1]
+    for j in range(len(values) - 2, -1, -1):
+        here = a == j
+        out = jnp.where(here if values[j].ndim == 1 else here[:, None],
+                        values[j], out)
+    return out
+
+
+def _element(planes, j: int, index: jnp.ndarray) -> jnp.ndarray:
+    """``planes[j][i, index[i]]`` for every row i, ``[B]``: one element
+    a row, gathered from ``[S, B, vocab]`` as it stands (plane j sliced
+    out for the gather would be written out first)."""
+    rows = jnp.arange(index.shape[0])
+    if isinstance(planes, (tuple, list)):
+        return planes[j][rows, index]
+    return planes[j, rows, index]
+
+
+def _log_norm(planes, j: int, scale: jnp.ndarray, top=None):
+    """``(m, lse)``, both ``[B]``: row i's ``softmax(planes[j] * scale)``
+    is ``exp((planes[j] - m) * scale - lse)``. ``top`` is the plane's
+    argmax where the caller has it (the max is then one element a row
+    and not a pass)."""
+    m = (jnp.max(planes[j], axis=-1) if top is None
+         else _element(planes, j, top))
+    lse = jnp.log(jnp.sum(
+        jnp.exp((planes[j] - m[:, None]) * scale[:, None]), axis=-1))
+    return m, lse
+
+
+def _proposal_rule(logits, proposal, drafts, draft_lens, temperature,
+                   top_p, top_k, tops, finish):
+    """The part of ``verify_proposal`` that draws nothing: the rows'
+    two distributions as statistics of the planes, for a batch with a
+    stochastic row. ``finish(p_draft, q_draft, log_weights)`` gets
+    ``p_j(d_j)`` and ``q_j(d_j)`` (both ``[B, K]``) and a function from
+    accepted lengths ``a [B]`` to the ``[B, vocab]`` log-weights of
+    each row's replacement at offset ``a``, ``log max(0, p_a - q_a)``
+    with ``q`` zero from ``draft_lens`` on; it is called inside the
+    branch that ran (plain, or masked where some row has a top-k or a
+    top-p) and what it returns comes back. ``tops`` are the target
+    planes' argmaxes, which the plain branch takes each max from.
+    Planes of ``[S, B, vocab]`` are taken inside the branch that reads
+    them, where the slice is part of the reading pass: sliced out
+    before, each would be a branch's operand, written out first."""
+    k = len(proposal)
+    b = drafts.shape[0]
+    dsafe = jnp.clip(drafts, 0)
+
+    def on(targets, proposals, scale, target_tops):
+        # ``softmax(plane * scale)`` are the rows' distributions.
+        def log_prob(x, norm):
+            shape = (b,) + (1,) * (x.ndim - 1)
+            m, lse = (n.reshape(shape) for n in norm)
+            return (x - m) * scale.reshape(shape) - lse
+
+        def at_drafts(planes, norms):
+            return jnp.stack([
+                jnp.exp(log_prob(_element(planes, j, dsafe[:, j]),
+                                 norms[j])) for j in range(k)], axis=1)
+
+        p_norm = [_log_norm(targets, j, scale, target_tops[j])
+                  for j in range(k + 1)]
+        q_norm = [_log_norm(proposals, j, scale) for j in range(k)]
+
+        def log_weights(a):
+            # One pass: the row's target plane at a, and the
+            # proposal's where its draft at a was rejected.
+            def at(planes, norms):
+                return jnp.exp(log_prob(
+                    _by_offset(a, [planes[j] for j in range(len(norms))]),
+                    [_by_offset(a, [n[i] for n in norms])
+                     for i in (0, 1)]))
+            residual = at(targets, p_norm) - jnp.where(
+                (a < draft_lens)[:, None], at(proposals, q_norm), 0.0)
+            return jnp.where(residual > 0, jnp.log(residual), NEG_INF)
+
+        return finish(at_drafts(targets, p_norm),
+                      at_drafts(proposals, q_norm), log_weights)
+
+    scale = _inverse_temperature(temperature)
+
+    def masked(planes):
+        return [_mask_top_k_top_p(planes[j] * scale[:, None], top_p, top_k)
+                for j in range(len(planes))]
+
+    return jax.lax.cond(
+        _needs_mask(top_p, top_k),
+        lambda: on(masked(logits), masked(proposal),
+                   jnp.ones_like(scale), [None] * (k + 1)),
+        lambda: on(logits, proposal, scale, tops))
+
+
+def verify_proposal(logits, drafts: jnp.ndarray, draft_lens: jnp.ndarray,
+                    proposal, temperature: jnp.ndarray,
+                    top_p: jnp.ndarray, top_k: jnp.ndarray,
+                    key: jax.Array) -> jnp.ndarray:
+    """``spec_verify``'s rule for a proposal that is a DISTRIBUTION (a
+    draft model, a prediction module): draft j, drawn from ``q_j``, is
     accepted with ``min(1, p_j(d_j) / q_j(d_j))``; the one replacement
     a row needs, at its first rejected offset ``a`` or at the bonus
-    offset, is drawn from ``norm(max(0, p_a - q_a))`` (``q`` is zero at
-    the bonus offset and where a row offered no draft, so there it is
-    ``p_a`` itself). One draw a row, at offset ``a`` alone."""
-    b, s, vocab = logits.shape
+    offset, is drawn from ``norm(max(0, p_a - q_a))`` (``q`` counts as
+    zero at the bonus offset and where the row offered no draft, so
+    there it is ``p_a`` itself). The output distribution is exactly
+    the target's (Leviathan et al.). A greedy row accepts a draft iff
+    it is the raw argmax and its replacement is the raw argmax at
+    ``a``: the stream non-speculative greedy decode gives.
+
+    Positions are PLANES: ``logits`` is a sequence of S dense
+    ``[B, vocab]`` float32 arrays (or one ``[S, B, vocab]``, S
+    outermost), never ``[B, S, vocab]``, whose minor tiles of S rows an
+    elementwise pass pays for several times over. The proposer hands
+    over what its head wrote, and both distributions are measured here
+    from logits, under the row's temperature (in the exponent) and,
+    where some row has one, its top-k/top-p (``sample_tokens``' mask on
+    target and proposal alike; the vocabulary is sorted only then). No
+    probabilities are written out. Per plane one pass for the argmax
+    (whose value is the max) and one for the log-sum-exp; ``p_j(d_j)``
+    and ``q_j(d_j)`` are one element a row; then ONE pass over the
+    planes selected per row by ``a`` forms the residual's log-weights,
+    adds the Gumbel noise and reduces to the replacement. The
+    point-mass rule's ``remove`` mask is not computed here.
+
+    Args:
+      logits:      S planes ``[B, vocab]`` float32, the target's raw
+                   logits at offsets 0 .. S-1
+      drafts:      [B, S-1] int32 draft tokens, -1 padded
+      draft_lens:  [B] int32 in [0, S-1]; 0 = a row without a draft
+      proposal:    S-1 planes ``[B, vocab]`` float32: the raw logits
+                   draft j was drawn from by ``draw_proposal`` under
+                   the row's own sampling parameters (a row without a
+                   draft: not read)
+      temperature, top_p, top_k: [B], as ``sample_tokens`` takes them
+      key:         PRNG key for the acceptance draws and the
+                   replacement
+
+    Returns [B, S] int32: row i's emitted tokens in its first
+    ``accepted_i + 1`` slots, -1 beyond.
+    """
+    k = len(proposal)
+    if len(logits) != k + 1 or k < 1:
+        raise ValueError(
+            f"verify_proposal: {len(logits)} target planes need "
+            f"{len(logits) - 1} proposal planes (at least one), got {k}")
+    in_draft = jnp.arange(k)[None, :] < draft_lens[:, None]  # [B, K]
     stochastic = temperature > 0
-    dsafe = jnp.clip(drafts, 0)
-    probs = sampling_probs(
-        logits.reshape(b * s, vocab), jnp.repeat(temperature, s),
-        jnp.repeat(top_p, s), jnp.repeat(top_k, s)).reshape(b, s, vocab)
-    q = jnp.where(in_draft[..., None], draft_probs, 0.0)
-    p_draft = jnp.take_along_axis(
-        probs[:, :-1], dsafe[..., None], axis=-1)[..., 0]
-    q_draft = jnp.take_along_axis(q, dsafe[..., None], axis=-1)[..., 0]
-    key_u, key_r = jax.random.split(key)
-    u = jax.random.uniform(key_u, (b, s - 1))
-    accept = jnp.where(stochastic[:, None], u * q_draft < p_draft,
-                       accept_greedy) & in_draft
-    a = jnp.cumprod(accept.astype(jnp.int32), axis=-1).sum(axis=-1)
-    at = a[:, None, None]
-    p_a = jnp.take_along_axis(probs, at, axis=1)[:, 0]
-    q_a = jnp.take_along_axis(
-        jnp.pad(q, ((0, 0), (0, 1), (0, 0))), at, axis=1)[:, 0]
-    residual = jnp.maximum(p_a - q_a, 0.0)
-    resampled = jax.random.categorical(
-        key_r, jnp.where(residual > 0, jnp.log(residual), NEG_INF),
-        axis=-1).astype(jnp.int32)
-    final_a = jnp.where(
-        stochastic, resampled,
-        jnp.take_along_axis(greedy_final, a[:, None], axis=1)[:, 0])
-    return accept, jnp.broadcast_to(final_a[:, None], (b, s))
+    tops = [jnp.argmax(logits[j], axis=-1).astype(jnp.int32)
+            for j in range(k + 1)]
+    accept_greedy = (drafts == jnp.stack(tops[:-1], axis=1)) & in_draft
+
+    def greedy_only():
+        # An all-greedy batch: an argmax a position, nothing else.
+        a = _accepted_length(accept_greedy)
+        return a, _by_offset(a, tops)
+
+    def draws(p_draft, q_draft, log_weights):
+        key_u, key_r = jax.random.split(key)
+        u = jax.random.uniform(key_u, drafts.shape)
+        accept = jnp.where(stochastic[:, None], u * q_draft < p_draft,
+                           accept_greedy) & in_draft
+        a = _accepted_length(accept)
+        resampled = jax.random.categorical(
+            key_r, log_weights(a), axis=-1).astype(jnp.int32)
+        return a, jnp.where(stochastic, resampled, _by_offset(a, tops))
+
+    a, final = jax.lax.cond(
+        jnp.any(stochastic),
+        lambda: _proposal_rule(logits, proposal, drafts, draft_lens,
+                               temperature, top_p, top_k, tops, draws),
+        greedy_only)
+    return _emitted(drafts, a, final[:, None])
 
 
 def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
                 draft_lens: jnp.ndarray, temperature: jnp.ndarray,
                 top_p: jnp.ndarray, top_k: jnp.ndarray,
-                key: jax.Array,
-                draft_probs: "jnp.ndarray | None" = None) -> jnp.ndarray:
+                key: jax.Array) -> jnp.ndarray:
     """Vectorized speculative-decoding acceptance rule.
 
     One verify forward pass scored S = K+1 positions per row: the
@@ -250,14 +436,14 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
     with invalid slots). ``logits[:, j]`` is the target model's
     distribution for the token at offset j past the committed length.
 
-    Acceptance (Leviathan et al. rejection sampling). Without
-    ``draft_probs`` the proposal is a deterministic point mass, the
-    n-gram draft (``q`` = one-hot at the draft: ``p/q`` is ``p(d)`` and
-    ``max(0, p - q)`` is ``p`` with the draft removed); with it, the
-    proposal is the distribution each draft was drawn from (a draft
-    model, a prediction module): accept with ``min(1, p(d)/q(d))``,
-    else draw from ``norm(max(0, p - q))`` (``_verify_proposal``).
-    Either way:
+    Acceptance (Leviathan et al. rejection sampling) for a proposal
+    that is a deterministic point mass, the n-gram draft (``q`` =
+    one-hot at the draft: ``p/q`` is ``p(d)`` and ``max(0, p - q)`` is
+    ``p`` with the draft removed, which is what the ``remove`` mask
+    below is for). A proposal that is a distribution (a draft model, a
+    prediction module) has its own function, ``verify_proposal``: it
+    wants a plane of logits a draft where this one wants a token id,
+    and the two share nothing over the vocabulary, only ``_emitted``.
       * greedy rows (temperature 0): draft j is accepted iff it equals
         the raw-logits argmax at offset j — the emitted stream is
         byte-identical to non-speculative greedy decode.
@@ -279,11 +465,6 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
       top_p:       [B] (1.0 => disabled)
       top_k:       [B] int32 (0 => disabled)
       key:         PRNG key for acceptance draws + residual samples
-      draft_probs: optional [B, S-1, vocab] float32, the distribution
-                   each stochastic row's draft was drawn from, under
-                   the row's own sampling parameters
-                   (``sampling_probs``); a greedy row's draft is its
-                   proposer's argmax and its entry is not read
 
     Returns [B, S] int32: row i's emitted tokens in its first
     ``accepted_i + 1`` slots, -1 beyond.
@@ -339,19 +520,6 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
                           greedy_final)
         return accept & in_draft, final
 
-    def with_proposal():
-        return _verify_proposal(
-            logits, drafts, in_draft, draft_probs, temperature, top_p,
-            top_k, key, accept_greedy, greedy_final)
-
     accept, final = jax.lax.cond(
-        jnp.any(stochastic),
-        with_stochastic if draft_probs is None else with_proposal,
-        greedy_only)
-    # Accepted prefix length: drafts accept left-to-right until the
-    # first rejection.
-    a = jnp.cumprod(accept.astype(jnp.int32), axis=-1).sum(axis=-1)
-    drafts_padded = jnp.pad(drafts, ((0, 0), (0, 1)))
-    return jnp.where(
-        pos < a[:, None], drafts_padded,
-        jnp.where(pos == a[:, None], final, -1)).astype(jnp.int32)
+        jnp.any(stochastic), with_stochastic, greedy_only)
+    return _emitted(drafts, _accepted_length(accept), final)

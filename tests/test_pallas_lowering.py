@@ -695,3 +695,59 @@ def test_the_drafting_burst_and_its_prefill_step_lower_for_tpu():
         sample_index_mode="last", next_tokens=i32(rows))
     assert "mtp_draft" in step.lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def _sampler_census(form, rows, vocab, one_chip):
+    from benchmarks.mtp_sampler_iteration import (
+        FORMS,
+        plane_census,
+        step_shapes,
+    )
+    text = jax.jit(FORMS[form][0]).lower(
+        *step_shapes(form, rows, vocab, one_chip)).compile().as_text()
+    return text, plane_census(text, rows, vocab)
+
+
+def test_the_drafting_iterations_sampler_reads_dense_planes(one_chip):
+    """The drafting iteration's sampler (``verify_proposal`` on the two
+    positions' logits, ``draw_proposal`` on the module's) at the GLM
+    cell's 160 rows and vocabulary of 154880, compiled for the
+    described chip: the only float32 array of ``B x 2 x V`` elements in
+    its text is the one the head wrote, positions OUTERMOST under dense
+    (8, 128) tiles, and nothing reshapes, transposes, copies, pads or
+    gathers one; outside the branches that sort the vocabulary at most
+    fourteen instructions touch a plane at all (twelve as written: an
+    argmax a position, the proposal's max, the three log-sum-exps in
+    one fusion, the last pass, the draw, the all-greedy draw, one
+    async copy that parks a plane in VMEM, four gathers of an element
+    a row). PR 43's form kept the
+    positions in a minor axis, under (2, 128) tiles, and spent 8.6 ms
+    an iteration there (PERF.md section 6, PR 44); the control below
+    shows this census sees that form."""
+    rows, vocab = VERIFY_CELL[0], 154880
+    text, census = _sampler_census("planes", rows, vocab, one_chip)
+    assert census["pair_arrays"], "the census found no logits at all"
+    for found in census["pair_arrays"]:
+        shape, opcode = found.split(" ")
+        assert shape == f"f32[2,{rows},{vocab}]{{2,1,0:T(8,128)}}", found
+        assert opcode in ("parameter", "get-tuple-element", "tuple",
+                          "bitcast"), found
+    assert f"f32[{rows},2,{vocab}]" not in text
+    passes = [p for name, found in census["plane_passes"].items()
+              if "sorts" not in name for p in found
+              if not p.startswith("conditional")]
+    assert 6 <= len(passes) <= 14, passes
+
+
+def test_that_census_sees_the_position_minor_form(one_chip):
+    """The control: PR 43's form (kept in
+    ``benchmarks/mtp_sampler_iteration.py``) holds ``[B, 2, V]`` under
+    (2, 128) tiles and relayouts of it; at a small vocabulary, which
+    shows the same."""
+    rows, vocab = VERIFY_CELL[0], 2048
+    _, census = _sampler_census("parent", rows, vocab, one_chip)
+    minor = [f for f in census["pair_arrays"]
+             if f.startswith(f"f32[{rows},2,{vocab}]")]
+    assert any("T(2,128)" in f for f in minor), census["pair_arrays"]
+    assert {f.split(" ")[1] for f in census["pair_arrays"]} & {
+        "reshape", "transpose", "copy", "pad", "gather"}

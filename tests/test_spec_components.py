@@ -182,21 +182,31 @@ def test_verify_mixed_batch_keeps_greedy_rows_exact():
     assert out[1].tolist()[:3] == [3, 5, 7]
 
 
-# ---- spec_verify with a proposal that is a distribution -------------------
+# ---- verify_proposal: a proposal that is a distribution -------------------
 
 
-def _verify_q(logits, drafts, lens, temps, q, seed=0, top_k=None):
-    from production_stack_tpu.ops.sampling import spec_verify
+def _planes(x):
+    """``[B, S, V]`` -> the S dense ``[B, V]`` planes the rule takes."""
+    x = jnp.asarray(x)
+    return tuple(x[:, j] for j in range(x.shape[1]))
+
+
+def _verify_q(logits, drafts, lens, temps, proposal, seed=0, top_k=None,
+              top_p=None):
+    """``proposal [B, S-1, V]``: the proposer's raw logits, which the
+    rule reads under the row's own temperature and mask."""
+    from production_stack_tpu.ops.sampling import verify_proposal
 
     b = logits.shape[0]
-    return np.asarray(spec_verify(
-        logits, jnp.asarray(drafts, jnp.int32),
-        jnp.asarray(lens, jnp.int32),
+    return np.asarray(verify_proposal(
+        _planes(logits), jnp.asarray(drafts, jnp.int32),
+        jnp.asarray(lens, jnp.int32), _planes(proposal),
         jnp.asarray(temps, jnp.float32),
-        jnp.ones((b,), jnp.float32),
+        jnp.ones((b,), jnp.float32) if top_p is None
+        else jnp.asarray(top_p, jnp.float32),
         jnp.zeros((b,), jnp.int32) if top_k is None
         else jnp.asarray(top_k, jnp.int32),
-        jax.random.PRNGKey(seed), draft_probs=jnp.asarray(q)))
+        jax.random.PRNGKey(seed)))
 
 
 @pytest.mark.parametrize("case,logits,drafts,lens,temps,want", [
@@ -215,31 +225,43 @@ def _verify_q(logits, drafts, lens, temps, q, seed=0, top_k=None):
 ])
 def test_the_one_hot_proposal_reads_as_the_point_mass_rule(
         case, logits, drafts, lens, temps, want):
-    """``draft_probs`` = one-hot at each draft is the prompt-lookup
-    form: the same rows come out as from the rule without the
-    argument, case for case of the tests above."""
+    """A proposal that is a point mass at each draft (logits 0 there
+    and -1e30 elsewhere) is the prompt-lookup form: the same rows come
+    out as from ``spec_verify``, case for case of the tests above."""
     logits = _point_logits(logits)
-    one_hot = np.zeros((1, 3, 16), np.float32)
+    point = np.full((1, 3, 16), -1e30, np.float32)
     for j, d in enumerate(drafts[0]):
-        one_hot[0, j, max(d, 0)] = 1.0
+        point[0, j, max(d, 0)] = 0.0
     assert _verify_q(logits, drafts, lens, temps,
-                     one_hot)[0].tolist() == want, case
+                     point)[0].tolist() == want, case
     assert _verify(logits, drafts, lens, temps)[0].tolist() == want
 
 
-def _proposal_case(n, temperature=0.8):
-    from production_stack_tpu.ops.sampling import sampling_probs
-    lp = jnp.asarray([0.5, 1.0, -1.0, 0.2, 2.0, 0.0])
-    lq = jnp.asarray([1.5, -1.0, 0.3, 0.2, 0.0, 1.0])
+LP = [0.5, 1.0, -1.0, 0.2, 2.0, 0.0]
+LQ = [1.5, -1.0, 0.3, 0.2, 0.0, 1.0]
+
+
+def _proposal_case(n, temperature=0.8, top_k=0):
+    """n rows of one target (``LP``, then ``LP / 2`` after an accepted
+    draft) and one proposer (``LQ``), the drafts drawn as the burst
+    draws them: ``(logits [n, 2, 6], drafts, temps, proposal [n, 6],
+    q, p, p2)``, the last three as probabilities."""
+    from production_stack_tpu.ops.sampling import (
+        _mask_top_k_top_p,
+        draw_proposal,
+    )
+    lp, lq = jnp.asarray(LP), jnp.asarray(LQ)
     temps = jnp.full((n,), temperature)
+    ones, ks = jnp.ones((n,)), jnp.full((n,), top_k, jnp.int32)
     logits = jnp.tile(jnp.stack([lp, lp * 0.5])[None], (n, 1, 1))
-    q = sampling_probs(jnp.tile(lq[None], (n, 1)), temps,
-                       jnp.ones((n,)), jnp.zeros((n,), jnp.int32))
-    drafts = jax.random.categorical(jax.random.PRNGKey(7),
-                                    jnp.log(q)).astype(jnp.int32)
+    proposal = jnp.tile(lq[None], (n, 1))
+    drafts = draw_proposal(proposal, temps, ones, ks,
+                           jax.random.PRNGKey(7))
+    q = jax.nn.softmax(_mask_top_k_top_p(
+        proposal[:1] / temperature, ones[:1], ks[:1]))[0]
     p = np.asarray(jax.nn.softmax(lp / temperature))
     p2 = np.asarray(jax.nn.softmax(lp * 0.5 / temperature))
-    return logits, drafts, temps, q, p, p2
+    return logits, drafts, temps, proposal, np.asarray(q), p, p2
 
 
 def test_a_sampled_proposal_leaves_the_targets_distribution():
@@ -249,13 +271,15 @@ def test_a_sampled_proposal_leaves_the_targets_distribution():
     an accepted draft as the second position's ``p``. 40000 rows, six
     tokens: sampling noise under 0.01."""
     n = 40000
-    logits, drafts, temps, q, p, p2 = _proposal_case(n)
+    logits, drafts, temps, proposal, q, p, p2 = _proposal_case(n)
+    drawn = np.bincount(np.asarray(drafts), minlength=6) / n
+    assert np.abs(drawn - q).max() < 0.01         # the drafts are q's
     out = _verify_q(logits, np.asarray(drafts)[:, None], np.ones(n),
-                    np.asarray(temps), q[:, None], seed=1)
+                    np.asarray(temps), proposal[:, None], seed=1)
     first = np.bincount(out[:, 0], minlength=6) / n
     assert np.abs(first - p).max() < 0.01
     accepted = out[:, 1] >= 0
-    overlap = 1 - 0.5 * np.abs(p - np.asarray(q[0])).sum()
+    overlap = 1 - 0.5 * np.abs(p - q).sum()
     assert abs(accepted.mean() - overlap) < 0.01
     assert (out[accepted, 0] == np.asarray(drafts)[accepted]).all()
     second = np.bincount(out[accepted, 1], minlength=6) / accepted.sum()
@@ -265,23 +289,24 @@ def test_a_sampled_proposal_leaves_the_targets_distribution():
 def test_always_accepting_would_fail_that_bound():
     """The control: the first token taken from ``q`` outright is 0.3
     from ``p`` in its worst cell, thirty times the bound."""
-    _, _, _, q, p, _ = _proposal_case(4)
-    assert np.abs(np.asarray(q[0]) - p).max() > 0.3
+    _, _, _, _, q, p, _ = _proposal_case(4)
+    assert np.abs(q - p).max() > 0.3
 
 
 def test_a_row_without_a_draft_and_a_greedy_row_beside_sampled_ones():
-    """``draft_lens`` 0 draws one token from ``p`` whatever ``q`` holds;
-    a greedy row accepts its draft iff it is the argmax."""
+    """``draft_lens`` 0 draws one token from ``p`` whatever the
+    proposal holds; a greedy row accepts its draft iff it is the
+    argmax."""
     n = 20000
-    logits, drafts, temps, q, p, _ = _proposal_case(n)
+    logits, drafts, temps, proposal, _, p, _ = _proposal_case(n)
     lens = np.ones(n, np.int32)
     lens[::2] = 0
     temps = np.asarray(temps).copy()
     temps[1] = temps[3] = 0.0
     drafts = np.asarray(drafts).copy()
     drafts[1], drafts[3] = 4, 2               # the argmax, and not
-    out = _verify_q(logits, drafts[:, None], lens, temps, q[:, None],
-                    seed=2)
+    out = _verify_q(logits, drafts[:, None], lens, temps,
+                    proposal[:, None], seed=2)
     assert (out[::2, 1] == -1).all()
     plain = np.bincount(out[::2, 0], minlength=6) / (n // 2)
     assert np.abs(plain - p).max() < 0.015
@@ -292,19 +317,143 @@ def test_a_row_without_a_draft_and_a_greedy_row_beside_sampled_ones():
 def test_top_k_masks_target_and_proposal_alike():
     """Under top-k 2 both distributions live on their own two largest
     tokens: nothing outside the target's two is ever emitted."""
-    from production_stack_tpu.ops.sampling import sampling_probs
     n = 4000
-    logits, _, temps, _, _, _ = _proposal_case(n)
-    top_k = np.full(n, 2, np.int32)
-    lq = jnp.asarray([1.5, -1.0, 0.3, 0.2, 0.0, 1.0])
-    q = sampling_probs(jnp.tile(lq[None], (n, 1)), temps, jnp.ones((n,)),
-                       jnp.asarray(top_k))
-    assert set(np.flatnonzero(np.asarray(q[0]))) == {0, 5}
-    drafts = jax.random.categorical(jax.random.PRNGKey(3),
-                                    jnp.log(q)).astype(jnp.int32)
+    logits, drafts, temps, proposal, q, _, _ = _proposal_case(n, top_k=2)
+    assert set(np.flatnonzero(q)) == {0, 5}
+    assert set(np.asarray(drafts).tolist()) == {0, 5}
     out = _verify_q(logits, np.asarray(drafts)[:, None], np.ones(n),
-                    np.asarray(temps), q[:, None], seed=4, top_k=top_k)
+                    np.asarray(temps), proposal[:, None], seed=4,
+                    top_k=np.full(n, 2, np.int32))
     assert set(out[:, 0].tolist()) <= {1, 4}      # the target's two
+
+
+def test_a_greedy_batch_drafts_and_verifies_by_the_argmax_alone():
+    """No stochastic row: the draft is the proposer's argmax and the
+    rule its greedy branch, whatever the key."""
+    from production_stack_tpu.ops.sampling import draw_proposal
+    n = 8
+    logits, _, _, proposal, _, _, _ = _proposal_case(n)
+    zeros = jnp.zeros((n,))
+    drafts = draw_proposal(proposal, zeros, jnp.ones((n,)),
+                           jnp.zeros((n,), jnp.int32),
+                           jax.random.PRNGKey(5))
+    assert np.asarray(drafts).tolist() == [0] * n  # LQ's argmax
+    out = _verify_q(logits, np.full((n, 1), 4), np.ones(n),
+                    np.zeros(n), proposal[:, None])
+    assert out.tolist() == [[4, 4]] * n            # LP's argmax, twice
+
+
+def _reference_rule(lp, lq, draft, has_draft, temperature, top_k, top_p):
+    """One row of the rule in float64 NumPy: ``(P(accept), the
+    replacement's weights after a rejection, the weights at the bonus
+    offset)`` for target logits ``lp [2, V]`` and proposal ``lq [V]``.
+    A greedy row (temperature 0): acceptance 0 or 1 and one-hot
+    weights at the raw argmax (what it commits)."""
+    def dist(x):
+        x = np.asarray(x, np.float64)
+        if temperature == 0:
+            return np.eye(len(x))[int(np.argmax(x))]
+        x = x / temperature
+        order = np.argsort(-x, kind="stable")
+        e = np.exp(x[order] - x[order][0])
+        sp = e / e.sum()
+        keep = np.arange(len(x)) < (top_k if top_k > 0 else len(x))
+        keep &= (np.cumsum(sp) - sp) < top_p
+        out = np.zeros(len(x))
+        out[order[keep]] = e[keep] / e[keep].sum()
+        return out
+
+    p0, p1 = dist(lp[0]), dist(lp[1])
+    if not has_draft:
+        return 0.0, p0, p1
+    if temperature == 0:
+        return float(draft == np.argmax(lp[0])), p0, p1
+    q = dist(lq)
+    residual = np.maximum(p0 - q, 0.0)
+    return (min(1.0, p0[draft] / q[draft]), residual / residual.sum(), p1)
+
+
+RULE_ROWS = [
+    # name, temperature, top_k, top_p, has a draft
+    ("stochastic", 0.7, 0, 1.0, True),
+    ("stochastic, hot", 1.3, 0, 1.0, True),
+    ("greedy", 0.0, 0, 1.0, True),
+    ("greedy, its draft off the argmax", 0.0, 0, 1.0, True),
+    ("draftless", 0.7, 0, 1.0, False),
+    ("top-k", 0.7, 20, 1.0, True),
+    ("top-p", 0.7, 0, 0.8, True),
+    ("top-k and top-p", 0.9, 50, 0.9, True),
+]
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["plain branch", "masked branch"])
+def test_the_rules_probabilities_against_a_float64_reference(masked):
+    """No draw in the comparison: ``_proposal_rule`` is the part of
+    ``verify_proposal`` that draws nothing, and hands what the draws
+    are made from to a function of the caller's. The rule accepts
+    where ``u q(d) < p(d)``, so ``p(d)`` and ``q(d)`` are its
+    acceptance probability ``min(1, p/q)``; the replacement is drawn
+    from the log-weights by Gumbel-max. Both against float64 NumPy at
+    a vocabulary of 1000, over the rows of ``RULE_ROWS``; the
+    top-k/top-p rows are left out of the plain batch (one such row
+    takes the whole batch through the sort). A greedy row's commits
+    are no draw: they are read off the whole rule."""
+    from production_stack_tpu.ops import sampling
+
+    rows = [r for r in RULE_ROWS if masked or (r[2] == 0 and r[3] == 1.0)]
+    rng = np.random.RandomState(11)
+    vocab, n = 1000, len(rows)
+    lp = (rng.randn(n, 2, vocab) * 2.0).astype(np.float32)
+    lq = (0.6 * lp[:, 0] + 1.6 * rng.randn(n, vocab)).astype(np.float32)
+    temps = np.asarray([r[1] for r in rows], np.float32)
+    top_k = np.asarray([r[2] for r in rows], np.int32)
+    top_p = np.asarray([r[3] for r in rows], np.float32)
+    lens = np.asarray([int(r[4]) for r in rows], np.int32)
+    # Each row's draft: a token its proposal gives real mass (the
+    # proposer's third largest), the target's argmax for the greedy row.
+    drafts = np.argsort(-lq, axis=-1)[:, 2].astype(np.int32)
+    for i, r in enumerate(rows):
+        if r[0] == "greedy":
+            drafts[i] = int(np.argmax(lp[i, 0]))
+    planes = _planes(lp)
+
+    def finish(p_draft, q_draft, log_weights):
+        offsets = jnp.arange(2)[:, None] * jnp.ones((n,), jnp.int32)
+        return (jnp.minimum(1.0, p_draft / q_draft),
+                jnp.stack([log_weights(a) for a in offsets]))
+
+    accept_p, log_w = (np.asarray(x, np.float64) for x in
+                       sampling._proposal_rule(
+        planes, (jnp.asarray(lq),), jnp.asarray(drafts)[:, None],
+        jnp.asarray(lens), jnp.asarray(temps), jnp.asarray(top_p),
+        jnp.asarray(top_k), [jnp.argmax(x, axis=-1) for x in planes],
+        finish))
+    out = _verify_q(jnp.asarray(lp), drafts[:, None], lens, temps,
+                    lq[:, None], seed=3, top_k=top_k, top_p=top_p)
+    for i, r in enumerate(rows):
+        want_accept, want_reject, want_bonus = _reference_rule(
+            lp[i], lq[i], int(drafts[i]), r[4], r[1], r[2], r[3])
+        if r[1] == 0:
+            first = int(np.argmax(want_reject))
+            assert out[i].tolist() == (
+                [first, int(np.argmax(want_bonus))] if want_accept
+                else [first, -1]), r[0]
+            continue
+        if r[4]:
+            assert accept_p[i, 0] == pytest.approx(want_accept,
+                                                   rel=2e-4), r[0]
+        else:
+            assert out[i, 1] == -1      # nothing to accept
+        # Offset 0 after a rejection (or with no draft), offset 1 after
+        # the acceptance: the weights, normalised here.
+        for offset, want in ((0, want_reject), (1, want_bonus)):
+            if offset == 1 and not r[4]:
+                continue        # a draftless row never reaches offset 1
+            w = np.exp(log_w[offset, i])
+            w = w / w.sum()
+            assert np.abs(w - want).max() < 2e-6, (r[0], offset)
+            assert (w[want == 0] < 1e-9).all(), (r[0], offset)
 
 
 # ---- config + feature gating ----------------------------------------------
