@@ -21,7 +21,12 @@ softmax's passes over the block's scores take the time in either), so
 the one form is all there is.
 
 The Pallas form of the decode step is ops/mla_attention_pallas.py;
-this module is the ground truth it is tested against. Neither is in
+this module is the ground truth it is tested against, and what that
+path still calls of it is ``absorb_queries`` before the kernel and
+``up_project_values`` after it: since PR 45 the kernel folds the
+burst's tail into its own running softmax and normalises, so
+``latent_tail_state`` serves this module's ``latent_paged_attention``
+alone (the prefill path and the tests' reference). Neither form is in
 ``ops/attention.py`` ``ATTENTION_IMPLS``: that registry lists the forms
 that read K/V planes and must have an int8 (``QuantKV``) parity test,
 and a latent plane has no quantized form (int8 pages are refused for
@@ -73,16 +78,6 @@ def latent_tail_state(q: jnp.ndarray, tail: jnp.ndarray,
     return (m, probs.sum(axis=-1), jnp.einsum(
         "bnts,bsr->bntr", probs.astype(lat.dtype), lat[..., :rank],
         preferred_element_type=jnp.float32))
-
-
-def merge_softmax_states(a, b):
-    """Two running softmax states (maximum, sum, weighted values) over
-    disjoint keys as one."""
-    (m_a, l_a, acc_a), (m_b, l_b, acc_b) = a, b
-    m = jnp.maximum(m_a, m_b)
-    keep_a, keep_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
-    return (m, l_a * keep_a + l_b * keep_b,
-            acc_a * keep_a[..., None] + acc_b * keep_b[..., None])
 
 
 def latent_paged_attention(q: jnp.ndarray, plane: jnp.ndarray,

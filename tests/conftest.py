@@ -122,3 +122,21 @@ def reset_singletons():
     yield
     _stop_singleton_threads(list(SingletonMeta._instances.values()))
     SingletonMeta._instances.clear()
+
+
+@pytest.fixture
+def latent_walk_at(monkeypatch):
+    """``at(fn, pages)``: the latent kernel's wrapper ``fn``
+    (ops/mla_attention_pallas.py) walking ``pages`` pages a link,
+    whatever its rule says of the shape; ``pages`` None is ``fn`` as it
+    stands. The rule is read at trace time, so the steered form is a
+    jit of its own and leaves no trace in ``fn``'s cache."""
+    def at(fn, pages):
+        if pages is None:
+            return fn
+        from production_stack_tpu.ops import mla_attention_pallas
+        monkeypatch.setattr(mla_attention_pallas, "latent_pages_per_chunk",
+                            lambda *shape: min(pages, shape[-1]))
+        return jax.jit(fn.__wrapped__,
+                       static_argnames=("scale", "interpret"))
+    return at

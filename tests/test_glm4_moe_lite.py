@@ -415,22 +415,31 @@ def _verify_case(lens, counts, t=2, dtype=jnp.float32, page=16, max_pages=8,
     return q, plane, table, lens, w_uk, w_uv, tail, positions
 
 
-@pytest.mark.parametrize("lens,counts", [
-    ((37, 20, 5), (0, 3, 6)),        # rows at their own tail counts
-    ((37, 0, 20, 0), (2, 0, 5, 1)),  # pad rows between live ones
-    ((16, 32, 128), (1, 1, 1)),      # lengths on a page's edge
-    ((128, 97, 3), (6, 0, 4)),       # the table's whole width
-    ((0, 0), (0, 2)),                # nothing in the pages
+@pytest.mark.parametrize("lens,counts,chunk,scale", [
+    ((37, 20, 5), (0, 3, 6), None, 24 ** -0.5),  # rows at their own counts
+    ((37, 0, 20, 0), (2, 0, 5, 1), None, 24 ** -0.5),  # pad rows between
+    ((16, 32, 128), (1, 1, 1), None, 24 ** -0.5),  # lengths on a page's edge
+    ((128, 97, 3), (6, 0, 4), None, 24 ** -0.5),  # the table's whole width
+    ((0, 0), (0, 2), None, 24 ** -0.5),          # nothing in the pages
+    # Links of two pages of 16: a row that ends on a link's edge, one
+    # page past it, a tail-only row between live rows, a pad-like row.
+    ((64, 80, 0, 65, 0), (0, 5, 3, 6, 0), 2, 24 ** -0.5),
+    ((48, 0, 49, 127), (1, 4, 0, 2), 3, 24 ** -0.5),  # three pages a link
+    # Seven pages a link: last links of 1, 4, 7, 7 and 1 pages run
+    # their products over 3, 6 or 7.
+    ((16, 60, 100, 112, 128), (0, 2, 6, 1, 3), 7, 24 ** -0.5),
+    ((37, 0, 64, 5), (2, 0, 6, 1), 2, 0.25),     # the scale in the query
 ])
-def test_the_verify_form_in_interpret_mode_equals_the_xla_form(lens, counts):
+def test_the_verify_form_in_interpret_mode_equals_the_xla_form(
+        lens, counts, chunk, scale, latent_walk_at):
     """Two positions a row over ONE walk of its pages (the 2 x 4 heads
-    are the kernel's rows), the causal cut between them in the tail:
-    slot s is position ``kv_lens + s``, so the first position sees
-    slots up to its own and the second one more."""
+    are the kernel's rows), the causal cut between them in the tail,
+    the walk's last link: slot s is position ``kv_lens + s``, so the
+    first position's band of rows sees slots up to its own and the
+    second's one more."""
     q, plane, table, lens, w_uk, w_uv, tail, pos = _verify_case(lens, counts)
-    scale = 24 ** -0.5
     with jax.default_matmul_precision("highest"):
-        got = latent_paged_verify_attention(
+        got = latent_walk_at(latent_paged_verify_attention, chunk)(
             q, plane, table, lens, w_uk, w_uv, scale, tail=tail,
             q_positions=pos, interpret=True)
         want = mla_attention.latent_paged_attention(
@@ -439,6 +448,24 @@ def test_the_verify_form_in_interpret_mode_equals_the_xla_form(lens, counts):
     assert np.abs(np.asarray(got - want)).max() < INTERPRET * max(
         1.0, np.abs(np.asarray(want)).max())
     assert np.isfinite(np.asarray(got)).all()
+
+
+def test_the_verify_form_at_three_positions_cuts_each_band_at_its_own():
+    """``T`` is the shape's: three positions a row are three bands of
+    the one walk, and a tail of 5 slots is padded to the sublane tile
+    with slots no position sees."""
+    q, plane, table, lens, w_uk, w_uv, tail, pos = _verify_case(
+        (37, 0, 64), (1, 0, 2), t=3, slots=5)
+    scale = 24 ** -0.5
+    with jax.default_matmul_precision("highest"):
+        got = latent_paged_verify_attention(
+            q, plane, table, lens, w_uk, w_uv, scale, tail=tail,
+            q_positions=pos, interpret=True)
+        want = mla_attention.latent_paged_attention(
+            q, plane, table, pos, lens, w_uk, w_uv, scale, tail=tail)
+    assert got.shape == want.shape == (3, 3, 4, 16)
+    assert np.abs(np.asarray(got - want)).max() < INTERPRET * max(
+        1.0, np.abs(np.asarray(want)).max())
 
 
 def test_the_second_position_sees_one_tail_slot_more_than_the_first():
@@ -456,11 +483,17 @@ def test_the_second_position_sees_one_tail_slot_more_than_the_first():
     assert float(jnp.abs(before[:, 1] - after[:, 1]).max()) > 1e-3
 
 
-def test_the_verify_form_walks_pages_of_128_in_bfloat16():
+@pytest.mark.parametrize("lens,counts,chunk", [
+    ((1400, 129, 0, 640), (5, 0, 2, 7), None),  # the rule: one link
+    ((1400, 129, 0, 640), (5, 0, 2, 7), 3),     # links of three pages
+    ((768, 769, 0, 1152), (0, 9, 3, 14), 3),    # on a link's edge, past it
+    ((100, 1500, 0, 640), (2, 0, 5, 7), 8),     # last links of 1, 4, 5 pages
+])
+def test_the_verify_form_walks_pages_of_128_in_bfloat16(lens, counts, chunk,
+                                                        latent_walk_at):
     q, plane, table, lens, w_uk, w_uv, tail, pos = _verify_case(
-        (1400, 129, 0, 640), (5, 0, 2, 7), dtype=jnp.bfloat16, page=128,
-        max_pages=12, slots=16)
-    got = latent_paged_verify_attention(
+        lens, counts, dtype=jnp.bfloat16, page=128, max_pages=12, slots=16)
+    got = latent_walk_at(latent_paged_verify_attention, chunk)(
         q, plane, table, lens, w_uk, w_uv, 24 ** -0.5, tail=tail,
         q_positions=pos, interpret=True)
     want = mla_attention.latent_paged_attention(

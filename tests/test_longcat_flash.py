@@ -18,6 +18,7 @@ in has to fail ``FLOAT32`` by ``CLEAR`` = 100 times.
 
 import dataclasses
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -437,7 +438,8 @@ def test_the_ranks_routed_parts_and_one_identity_term_add_up(ranks):
 # ---- the latent decode kernel ----------------------------------------------
 
 
-def _kernel_case(lens, steps=0, dtype=jnp.float32, page=16, max_pages=8):
+def _kernel_case(lens, steps=0, dtype=jnp.float32, page=16, max_pages=8,
+                 slots=4):
     keys = jax.random.split(jax.random.PRNGKey(6), 5)
     n, dn, dr, rank, dv = 4, 16, 8, 24, 16
     b = len(lens)
@@ -451,25 +453,37 @@ def _kernel_case(lens, steps=0, dtype=jnp.float32, page=16, max_pages=8):
     lens = jnp.asarray(lens, jnp.int32)
     tail = positions = None
     if steps:
-        tail = jax.random.normal(keys[4], (b, 4, 1, rank + dr), dtype)
+        tail = jax.random.normal(keys[4], (b, slots, 1, rank + dr), dtype)
         positions = lens + steps - 1    # the burst's step ``steps``
     return q, plane, table, lens, w_uk, w_uv, tail, positions
 
 
-@pytest.mark.parametrize("lens,steps", [
-    ((37, 20, 5), 0),          # no tail: an eager step
-    ((37, 0, 20, 0), 3),       # pad rows between live ones, a tail
-    ((1, 1), 1),               # rows of one token
-    ((16, 32, 128), 2),        # lengths on a page's edge
-    ((128, 97, 3), 4),         # the table's whole width, many chunks
-    ((0, 0), 2),               # nothing live but the tail
+# Pages of 16 under a table of 8: a link of 2 pages is 32 tokens.
+@pytest.mark.parametrize("lens,steps,chunk,scale", [
+    ((37, 20, 5), 0, None, 24 ** -0.5),   # no tail: an eager step
+    ((37, 0, 20, 0), 3, None, 24 ** -0.5),  # pad rows between live, a tail
+    ((1, 1), 1, None, 24 ** -0.5),        # rows of one token
+    ((16, 32, 128), 2, None, 24 ** -0.5),  # lengths on a page's edge
+    ((128, 97, 3), 4, None, 24 ** -0.5),  # the table's whole width
+    ((0, 0), 2, None, 24 ** -0.5),        # nothing live but the tail
+    # A row whose pages end on a link's edge (64 = two links of two
+    # pages), one page past it (80), one token past it (65), and a
+    # tail-only row and a pad-like row between them, slots partly seen.
+    ((64, 80, 0, 65, 32), 2, 2, 24 ** -0.5),
+    ((64, 0, 96, 17), 0, 2, 24 ** -0.5),  # the same edges with no tail
+    ((48, 0, 49, 127), 4, 3, 24 ** -0.5),  # three pages a link
+    # Seven pages a link: the last link's products run over 3, 6 or 7
+    # pages, whichever holds the row's last 1, 4, 7, 7 and 1 (of 8).
+    ((16, 60, 100, 112, 128), 2, 7, 24 ** -0.5),
+    ((33, 0, 81, 97), 0, 7, 24 ** -0.5),  # 3, 6 and 7 pages, no tail
+    ((37, 0, 64, 5), 3, 2, 0.25),         # a power of two: scaled in the query
+    ((37, 20, 5), 0, None, 0.125),        # the same without a tail
 ])
-def test_the_latent_kernel_in_interpret_mode_equals_the_xla_form(lens,
-                                                                 steps):
+def test_the_latent_kernel_in_interpret_mode_equals_the_xla_form(
+        lens, steps, chunk, scale, latent_walk_at):
     q, plane, table, lens, w_uk, w_uv, tail, pos = _kernel_case(lens, steps)
-    scale = 24 ** -0.5
     with jax.default_matmul_precision("highest"):
-        got = latent_paged_decode_attention(
+        got = latent_walk_at(latent_paged_decode_attention, chunk)(
             q, plane, table, lens, w_uk, w_uv, scale, tail=tail,
             q_positions=pos, interpret=True)
         want = mla_attention.latent_paged_attention(
@@ -483,25 +497,105 @@ def test_the_latent_kernel_in_interpret_mode_equals_the_xla_form(lens,
     assert np.isfinite(np.asarray(got)).all()
 
 
-def test_the_latent_kernel_walks_pages_of_128_in_several_chunks_in_bfloat16():
-    """bfloat16 pages of 128 as the cell keeps them, a table of 12
-    pages: a chunk is pages_per_chunk pages, so the longest row walks
-    several and hands its buffers to the next."""
+def test_the_latent_rule_sizes_a_link_for_the_one_plane():
+    """The cells' plane (576 wide, pages of 128, bfloat16) under their
+    table of 34 pages: twelve pages a link, three links at the most,
+    however wide the table (the links are a loop); a narrow table is
+    one link; the K/V kernel's rule is not this one."""
+    from production_stack_tpu.ops.mla_attention_pallas import (
+        LATENT_CHUNK_BYTES, latent_pages_per_chunk)
     from production_stack_tpu.ops.paged_attention_pallas import (
         pages_per_chunk)
+    page_bytes = 576 * 128 * 2
+    pages = latent_pages_per_chunk(576, 128, 2, 34)
+    assert pages == LATENT_CHUNK_BYTES // page_bytes == 12
+    assert latent_pages_per_chunk(576, 128, 2, 256) == pages
+    assert latent_pages_per_chunk(576, 128, 2, 2) == 2
+    assert latent_pages_per_chunk(32, 128, 2, 12) == 12
+    assert latent_pages_per_chunk(576, 128, 4, 34) == pages // 2
+    assert pages_per_chunk(1, 576, 128, 2, 34) == 3
+
+
+@pytest.mark.parametrize("lens,steps,chunk", [
+    ((1400, 129, 0, 640), 3, 3),    # links of three pages, a pad row
+    ((1400, 129, 0, 640), 3, None),  # the rule: the table is one link
+    ((768, 769, 0, 1152), 9, 3),    # on a link's edge, one token past it
+    ((100, 1500, 0, 640, 900), 5, 8),  # last links of 1, 4, 5 and 8 pages
+    ((384, 0, 1536), 0, 3),         # no tail
+])
+def test_the_latent_kernel_walks_pages_of_128_in_several_chunks_in_bfloat16(
+        lens, steps, chunk, latent_walk_at):
+    """bfloat16 pages of 128 as the cell keeps them, a table of 12
+    pages: at three pages a link the longest row walks several and
+    hands its buffers to the next; the tail of 16 slots is the last
+    link."""
     q, plane, table, lens, w_uk, w_uv, tail, pos = _kernel_case(
-        (1400, 129, 0, 640), 3, jnp.bfloat16, page=128, max_pages=12)
-    assert pages_per_chunk(1, 32, 128, 2, 12) == 12
-    got = latent_paged_decode_attention(
+        lens, steps, jnp.bfloat16, page=128, max_pages=12, slots=16)
+    got = latent_walk_at(latent_paged_decode_attention, chunk)(
         q, plane, table, lens, w_uk, w_uv, 24 ** -0.5, tail=tail,
         q_positions=pos, interpret=True)
     want = mla_attention.latent_paged_attention(
-        q[:, None], plane, table, pos[:, None], lens, w_uk, w_uv,
+        q[:, None], plane, table,
+        (lens - 1 if pos is None else pos)[:, None], lens, w_uk, w_uv,
         24 ** -0.5, tail=tail)[:, 0]
     assert got.dtype == want.dtype == jnp.bfloat16
-    assert np.abs(np.asarray(got, np.float32)
+    live = np.asarray((lens > 0) | (steps > 0))
+    got, want = (np.asarray(x, np.float32)[live] for x in (got, want))
+    assert np.abs(got - want).max() < 0.03 * np.abs(want).max()
+
+
+def test_a_power_of_two_scale_in_the_query_gives_the_same_bits():
+    """GLM's 1/16 goes into the query's block once a row, LongCat's
+    192 ** -0.5 stays on the float32 scores. A power of two commutes
+    with every rounding on the way (the absorption's, the products',
+    the float32 sums'), so the kernel at scale 1/16 gives, bit for
+    bit, what it gives at scale 1 on a query that was 1/16 of this
+    one from the start: the bfloat16 query is not rounded twice."""
+    q, plane, table, lens, w_uk, w_uv, tail, pos = _kernel_case(
+        (300, 129, 640), 2, jnp.bfloat16, page=128, max_pages=12, slots=16)
+    run = lambda scale, qq: latent_paged_decode_attention(  # noqa: E731
+        qq, plane, table, lens, w_uk, w_uv, scale, tail=tail,
+        q_positions=pos, interpret=True)
+    in_kernel = run(2.0 ** -4, q)
+    by_hand = run(1.0, q * jnp.asarray(2.0 ** -4, q.dtype))
+    assert in_kernel.dtype == jnp.bfloat16
+    assert bool((in_kernel == by_hand).all())
+    want = mla_attention.latent_paged_attention(
+        q[:, None], plane, table, pos[:, None], lens, w_uk, w_uv,
+        2.0 ** -4, tail=tail)[:, 0]
+    assert np.abs(np.asarray(in_kernel, np.float32)
                   - np.asarray(want, np.float32)).max() < 0.03 * np.abs(
         np.asarray(want, np.float32)).max()
+
+
+def test_the_walk_benchmark_parses_its_arguments_and_runs_tiny(capsys):
+    """``benchmarks/latent_walk_iteration.py``: by default the three
+    calls the two latent cells make at their shapes; ``--tiny`` is the
+    tests' widths, run here in interpret mode at the rule's link and at
+    two pages a link: the same calls, so the same sums."""
+    from benchmarks import latent_walk_iteration as bench
+    args = bench.parse_args([])
+    assert args.shapes == bench.SHAPES and list(args.shapes) == [
+        "longcat", "glm-verify", "glm-module"]
+    assert (args.rows, args.page, args.table_pages, args.calls) == (
+        160, 128, 34, 64)
+    assert args.chunks == [None]
+    assert bench.parse_args(["--chunks", "3,rule,7"]).chunks == [3, None, 7]
+    lens = np.asarray(bench.draw_lengths(jax.random.PRNGKey(0), 4096))
+    assert 256 <= lens.min() and lens.max() <= 4096
+    assert 1500 < lens.mean() < 1850
+    line = bench.main(["--tiny", "--interpret", "--calls", "2",
+                       "--repeats", "1", "--chunks", "rule,2"])
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == line
+    assert line["device"]["platform"] == "cpu"
+    assert set(line["ms_per_call"]) == {"tiny", "tiny-verify"}
+    for shape, by_chunk in line["ms_per_call"].items():
+        assert set(by_chunk) == {"rule", "2"}
+        assert len(by_chunk["2"]["ms"]) == 1
+        assert np.isfinite(by_chunk["rule"]["checksum"])
+        assert abs(by_chunk["rule"]["checksum"]
+                   - by_chunk["2"]["checksum"]) < 1e-4
+        assert 0 < line["kv_lens"][shape]["min"]
 
 
 def test_the_pallas_path_in_interpret_mode_equals_the_xla_path():

@@ -530,6 +530,78 @@ def test_latent_decode_kernel_with_a_tail_compiles_for_a_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_latent_decode_kernel_without_a_tail_compiles_for_a_v5e(one_chip):
+    """A single step (the harness's check requests decode a token at a
+    time after their prefill): the same kernel with no tail's link, no
+    tail block and no positions among its scalars."""
+    from production_stack_tpu.ops.mla_attention_pallas import (
+        latent_paged_decode_attention,
+    )
+    q, plane, table, lens, w_uk, w_uv, _, _ = _latent_decode_shapes(
+        sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: latent_paged_decode_attention(*a, 192 ** -0.5)).lower(
+        q, plane, table, lens, w_uk, w_uv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", ["longcat", "glm-verify", "glm-module"])
+def test_the_latent_call_hands_no_softmax_state_through_hbm(shape, one_chip):
+    """One sublayer's call of a deferred burst at each cell's shape
+    (``benchmarks/latent_walk_iteration.py``), compiled for the
+    described chip: the kernel's one result is the normalised weighted
+    latents in bfloat16, and no float32 array of ``rows x heads x
+    rank`` (the accumulator) or ``rows x heads x 128`` (the statistics'
+    tile) is made anywhere between the custom call and the
+    up-projection, as PR 44's form made eleven times a call (PERF.md
+    section 6, PR 45); nothing of the plane's size appears either."""
+    from benchmarks.latent_walk_iteration import (
+        PAGE,
+        ROWS,
+        SHAPES,
+        TABLE_PAGES,
+        array_census,
+        make_case,
+        sublayer,
+    )
+    from production_stack_tpu.ops import mla_attention_pallas
+    heads, positions, _, _, rank = SHAPES[shape][:5]
+    args, _ = make_case(SHAPES[shape], ROWS, PAGE, TABLE_PAGES, 1,
+                        jax.random.PRNGKey(0), as_shapes=one_chip)
+    text = jax.jit(sublayer(mla_attention_pallas, SHAPES[shape], False)
+                   ).lower(*args).compile().as_text()
+    plane = args[1].shape
+    census = array_census(text, ROWS, heads * positions, rank,
+                          plane[1] * plane[2] * plane[3])
+    assert census == {"float32_state": {}, "plane_sized": {}}
+    query_rows = -(-heads * positions // 16) * 16
+    call = [line for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(call) == 1
+    assert f"= bf16[{ROWS},{query_rows},{rank}]" in call[0]
+
+
+def test_that_census_sees_a_state_on_its_way_through_hbm():
+    """The control: lines as PR 44's compiled call had them."""
+    from benchmarks.latent_walk_iteration import array_census
+    text = "\n".join([
+        "  %call.1 = (f32[160,64,512]{2,1,0:T(8,128)}, f32[160,64,128]"
+        "{2,1,0:T(8,128)}) custom-call(%pad.0, %plane.1), "
+        "custom_call_target=\"tpu_custom_call\"",
+        "  %divide.1 = f32[160,64,512]{2,1,0:T(8,128)} divide(%a, %b)",
+        "  %slice.3 = f32[160,40,512]{2,1,0:T(8,128)} slice(%call.2)",
+        "  %plane.1 = bf16[1,100,576,128]{3,2,1,0} parameter(1)",
+        "  %copy.9 = bf16[1,100,576,128]{3,2,1,0} copy(%plane.1)",
+        "  %q.1 = bf16[160,64,576]{2,1,0} parameter(0)"])
+    assert array_census(text, 160, 64, 512, 100 * 576 * 128) == {
+        "float32_state": {"f32[160,64,512] custom-call": 1,
+                          "f32[160,64,128] custom-call": 1,
+                          "f32[160,64,512] divide": 1},
+        "plane_sized": {"bf16[1,100,576,128] copy": 1}}
+    assert array_census(text, 160, 40, 512, 1)["float32_state"] == {
+        "f32[160,40,512] slice": 1}
+
+
 def test_the_latent_decode_step_never_expands_cached_tokens_to_heads():
     """One decode step of the whole model at the published widths,
     lowered for the TPU in the form the cell's burst runs (the Pallas
