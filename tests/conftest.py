@@ -28,52 +28,96 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+# What the lane pays for is compiling tiny programs, not running them:
+# the float32 references run op by op (some 1600 compiles of 40 ms in
+# one case of a family's engine module), and a family's engine programs
+# are traced in many shapes. So the CPU backend compiles at its lowest
+# optimisation level, through its older emitters (the two together: an
+# eager op's compile 36 -> 15 ms, that case 101 -> 51 s, the whole lane
+# 1107 -> 784 s of wall, most of it theirs; PR 46's runs),
+# and without fused multiply-adds (``max_isa=AVX``). The third is what
+# keeps "the same arithmetic gives the same bits" true of two programs:
+# with the first two alone the five cases that compare a hybrid's
+# carried tails with its pool path to the bit read one unit in the last
+# place apart in 3-6 of 20 log-probabilities; at level 1, or with no
+# fused instruction to choose, they agree. Every comparison in the lane
+# is between programs compiled the same way, and nothing in it measures
+# the CPU's speed. The servers that rehearsal cases start inherit the
+# flags. A flag the installed XLA does not know ends the process, hence
+# the version: look at all three again when jax moves.
+if jax.__version_info__[:2] == (0, 9):
+    for _flag in ("--xla_backend_optimization_level=0",
+                  "--xla_cpu_use_fusion_emitters=false",
+                  "--xla_cpu_max_isa=AVX"):
+        if _flag.split("=")[0] not in os.environ["XLA_FLAGS"]:
+            os.environ["XLA_FLAGS"] += " " + _flag
+
 from production_stack_tpu.utils.compile_cache import (  # noqa: E402
     configure_compile_cache,
 )
 
-# The suite is dominated by recompiles of the same engine programs
-# every run; keep them in the persistent cache between sessions, the
-# many ~1 s ones included.
+# The suite is dominated by recompiles of the same programs: keep every
+# one in the persistent cache, the eager ops of 15 ms included (a
+# family's reference runs the same ops in its three modules, on
+# whichever of the six workers; writing an entry costs nothing that
+# shows, reading one back is a few ms), between sessions too.
 configure_compile_cache()
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-# Compile-heavy modules (engine builds, shard_map parity, multi-process
-# rigs) form the SLOW lane; everything else is the fast lane the common
-# dev loop runs (round-3 verdict: 206 tests / 24 min had no split).
-#   fast lane:  pytest -m "not slow"   (target <= 8 min)
-#   full suite: pytest                 (CI nightly / pre-merge)
-# Files can still mark themselves explicitly; this list saves each
-# slow module from repeating the boilerplate.
+# One lane. The driver's command (``-m 'not slow'``, six workers,
+# ``--dist loadfile``; docs/source/dev_guide/testing.md) is the one gate
+# a PR meets, so a module is in it unless this rule keeps it out:
+#   (a) it guards only a path no benchmark cell serves AND costs over
+#       60 s, or
+#   (b) it starts more than one ``jax.distributed`` process.
+# Seconds are a module's cases summed (PR 46: these four in a run of
+# ``-m slow`` alone, 100 s of wall on five workers; the lane's modules
+# below inside a six-worker run of the whole lane). A module that costs
+# under 40 s is in whatever it guards (``test_ring_attention``, 30 s,
+# came in so); ``pytest -m slow`` runs what is listed here.
 _SLOW_MODULES = {
-    "test_70b_lowering",
-    "test_abort",
-    "test_batch_e2e",
-    "test_deferred_kv",
-    "test_batched_prefill",
-    "test_cache_layout",
-    "test_context_parallel_serving",
-    "test_e2e_router_engine",
-    "test_embeddings",
-    "test_engine_server",
-    "test_guided_json",
-    "test_kv_offload",
-    "test_logit_bias",
-    "test_lora",
-    "test_min_tokens",
-    "test_model_parity",
-    "test_multihost",
-    "test_multistep_decode",
-    "test_pallas_attention",
-    "test_pallas_lowering",
-    "test_pipeline_parallel",
-    "test_quantization",
-    "test_real_checkpoint_sharded",
-    "test_ring_attention",
-    "test_score_rerank",
-    "test_spec_decode",
-    "test_tracing",
+    "test_context_parallel_serving": "67 s, (a): context-parallel serving",
+    "test_multihost": "20 s, (b): two jax.distributed processes",
+    "test_pipeline_parallel": "82 s, (a): pipeline stages",
+    "test_real_checkpoint_sharded": "22 s, (b): two jax.distributed "
+                                    "processes",
+}
+
+
+# ``--dist loadfile`` hands modules to the workers in the order they were
+# collected, and a long module handed out last is the lane's tail (the
+# alphabet ends on ``test_turn_phases`` and ``test_unified_step``, 66 and
+# 146 s). So the modules measured over 60 s are collected first, longest
+# first; the rest keep the alphabet. Seconds as above. A stale entry
+# costs balance and nothing else; a module that nears 300 s is split
+# (testing.md).
+_LONG_MODULES = {
+    "test_glm4_moe_lite_engine": 264,
+    "test_qwen3_next_deferred": 257,
+    "test_conv_tails_burst": 245,
+    "test_pallas_lowering": 205,
+    "test_chipbench_glm4_moe_lite_family": 177,
+    "test_longcat_flash": 169,
+    "test_lfm2_moe_engine": 164,
+    "test_glm4_moe_lite": 155,
+    "test_unified_step": 146,
+    "test_chipbench_lfm2_family": 144,
+    "test_pallas_attention": 142,
+    "test_longcat_flash_engine": 130,
+    "test_prefill_width": 113,
+    "test_chipbench_longcat_family": 106,
+    "test_qwen3_next_engine": 105,
+    "test_chipbench_rehearsal": 105,
+    "test_jamba_engine": 99,
+    "test_deferred_kv": 98,
+    "test_qwen3_next": 98,
+    "test_pallas_lowering_latent": 95,
+    "test_jamba": 71,
+    "test_lfm2_moe": 68,
+    "test_turn_phases": 66,
+    "test_chipbench_mixtral_family": 66,
+    "test_chip_smoke": 66,
 }
 
 
@@ -81,6 +125,7 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.module.__name__ in _SLOW_MODULES:
             item.add_marker(pytest.mark.slow)
+    items.sort(key=lambda item: -_LONG_MODULES.get(item.module.__name__, 0))
 
 
 def pytest_pyfunc_call(pyfuncitem):

@@ -16,7 +16,6 @@ tensorParallelSize (deployment-vllm-multi.yaml argv rendering).
 
 import jax
 import jax.numpy as jnp
-import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.engine.config import ModelConfig
@@ -38,7 +37,6 @@ def llama3_70b_config() -> ModelConfig:
     )
 
 
-@pytest.mark.slow
 def test_70b_tp8_serving_programs_lower():
     from production_stack_tpu.models import llama
     from production_stack_tpu.parallel.mesh import (
@@ -108,7 +106,6 @@ def test_70b_tp8_serving_programs_lower():
     run((b, 1))
 
 
-@pytest.mark.slow
 def test_70b_head_geometry_divides():
     m = llama3_70b_config()
     for tp in (2, 4, 8):

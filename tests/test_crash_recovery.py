@@ -9,9 +9,9 @@ request quarantine after repeated crashes, the step watchdog flipping
 /health, and the fleet manager's crash-loop containment (jittered
 exponential backoff, per-pool breaker, crash vs drain-exit).
 
-Fast lane: fake engines only (crash fakes run as subprocesses — the
-crash fault SIGKILLs its whole process). The real-engine parity tests
-build LLMEngines and ride the slow lane.
+The router's protocol is tested against fake engines (crash fakes run
+as subprocesses — the crash fault SIGKILLs its whole process); the
+real-engine parity tests build LLMEngines (12 s together, PR 46).
 """
 
 import asyncio
@@ -490,7 +490,7 @@ async def test_drain_exit_is_not_a_crash():
         await mgr.close()
 
 
-# ---- real-engine parity (slow lane) ---------------------------------------
+# ---- real-engine parity ---------------------------------------------------
 #
 # The fast tests above prove the router protocol against fakes; these
 # prove the engine side of the contract with the REAL model: the
@@ -638,7 +638,6 @@ async def _capture_interrupted(client, page_size=16):
     return full_text, rid, desc, delivered
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
 def test_resume_byte_identical_real_engine(cache_server_url, kv_dtype):
     """Kill-and-resume with the real engine: a fresh process restores
@@ -700,7 +699,6 @@ def test_resume_byte_identical_real_engine(cache_server_url, kv_dtype):
     asyncio.run(run())
 
 
-@pytest.mark.slow
 def test_resume_checkpoint_miss_recomputes_parity(cache_server_url):
     """Degraded-never-dropped: a replacement whose tier lost the pages
     (here: unreachable) recomputes from the token journal and still
@@ -748,7 +746,6 @@ def _free_port_url() -> str:
     return f"http://127.0.0.1:{port}"
 
 
-@pytest.mark.slow
 def test_resume_abort_releases_nothing_awaiting_kv(cache_server_url):
     """Regression: a resume parked in AWAITING_KV holds zero pages, so
     a client abort while it waits must release nothing and leave no

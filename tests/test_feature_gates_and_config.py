@@ -1,6 +1,7 @@
 """Feature gates + dynamic config hot-reload."""
 
 import json
+import time
 
 import pytest
 
@@ -66,7 +67,17 @@ def test_dynamic_config_watcher_applies_changes(tmp_path):
     }))
     watcher = DynamicConfigWatcher(str(config_path), poll_interval_s=3600)
     try:
-        watcher.check_and_apply()
+        # The watcher's own thread applies a file that is there at
+        # start-up, whenever it first gets the CPU: wait for that tick
+        # to end (then it sleeps its 3600 s), so that it neither takes
+        # a later change from under this thread's own call nor applies
+        # the first text over the last.
+        deadline = time.monotonic() + 30.0
+        while (watcher.get_current_config() is None
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        assert watcher.get_current_config() is not None
+        assert watcher.check_and_apply() is False
         eps = get_service_discovery().get_endpoint_info()
         assert [ep.url for ep in eps] == ["http://new:2"]
         assert eps[0].model_names == ["modelA"]

@@ -159,11 +159,20 @@ def test_wall_less_cpu_is_the_wait_for_the_interpreter():
     CPU.  Alone it is on a core nearly throughout.  Relative, because
     the operating system's scheduler keeps a thread off a core too where
     the box has fewer free cores than runnable threads, and the record
-    cannot tell that from the interpreter's lock; the best of three
-    where it matters, for the same reason."""
+    cannot tell that from the interpreter's lock; the best of as many
+    pairs as it takes, up to a deadline, for the same reason: on a box
+    whose every core is taken (six workers of the lane on eight cores)
+    a phase alone is off its core a third of the time in three
+    readings out of three often enough to be seen (PR 43), and one
+    quiet reading is all that "alone" asks."""
     rounds = _rounds_for(0.05)
-    beside = max(_off_cpu_share(rounds, True) for _ in range(3))
-    alone = min(_off_cpu_share(rounds, False) for _ in range(3))
+    beside, alone = 0.0, 1.0
+    deadline = time.monotonic() + 30.0
+    while time.monotonic() < deadline:
+        beside = max(beside, _off_cpu_share(rounds, True))
+        alone = min(alone, _off_cpu_share(rounds, False))
+        if beside >= 0.25 and alone < beside / 2:
+            break
     assert beside >= 0.25
     assert alone < beside / 2
 

@@ -78,28 +78,54 @@ def generate(engine, prompts, **sampling):
 PROMPTS = [prompt_of(n, seed=n) for n in (70, 20, 45, 33, 64, 12)]
 
 
+# An engine of one configuration is built once a module
+# (docs/source/dev_guide/testing.md): the cases that take
+# ``engine_config()`` or ``engine_config(draft=False)`` as they stand
+# share these two. A shared engine carries its prefix cache, its
+# counters and its sampler's key from case to case, so those cases
+# assert a counter's difference and never an unseeded stream; a case
+# that needs a cold prefix cache, preempts, leaves a request in flight
+# or swaps the tracer builds its own.
+
+
 @pytest.fixture(scope="module")
-def without_drafts():
-    """Greedy answers of 40 tokens with the module switched off."""
+def drafting():
+    return LLMEngine(engine_config())
+
+
+@pytest.fixture(scope="module")
+def draftless():
     engine = LLMEngine(engine_config(draft=False))
     assert engine.config.model.num_nextn_predict_layers == 0
     assert len(engine.runner.k_cache) == 3 + 1       # no entry for it
     assert "mtp_enorm" not in engine.runner.params
-    return [s.output_token_ids for s in generate(engine, PROMPTS)]
+    return engine
+
+
+def drafted_and_accepted(engine):
+    return (engine.metrics.spec_draft_tokens_total,
+            engine.metrics.spec_accepted_tokens_total)
+
+
+@pytest.fixture(scope="module")
+def without_drafts(draftless):
+    """Greedy answers of 40 tokens with the module switched off."""
+    return [s.output_token_ids for s in generate(draftless, PROMPTS)]
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
 def test_greedy_output_with_drafts_equals_the_output_without(
-        impl, without_drafts):
+        impl, without_drafts, drafting):
     """Six prompts over four rows, 40 tokens each, some 200 verify
     iterations: token for token what the same model gives with the
     module off, whatever was drafted; about one greedy draft in 16 is
     accepted, so both commits of a pair are among them."""
-    engine = LLMEngine(engine_config(model_config(attention_impl=impl)))
+    engine = drafting if impl == "xla" else LLMEngine(
+        engine_config(model_config(attention_impl=impl)))
+    before = drafted_and_accepted(engine)
     seqs = generate(engine, PROMPTS)
     assert [s.output_token_ids for s in seqs] == without_drafts
-    drafted = engine.metrics.spec_draft_tokens_total
-    accepted = engine.metrics.spec_accepted_tokens_total
+    drafted, accepted = np.subtract(drafted_and_accepted(engine), before)
     assert drafted > 100
     assert 0 < accepted < drafted / 4
     # Four latent planes (three layers and the module's) and the
@@ -206,12 +232,14 @@ def test_the_sampled_distribution_is_the_targets():
         assert 0.5 * np.abs(empirical - exact).sum() < 0.06, index
 
 
-def test_max_tokens_and_a_stop_token_cut_inside_an_accepted_pair():
+def test_max_tokens_and_a_stop_token_cut_inside_an_accepted_pair(drafting):
     """At temperature 1 nine drafts in ten are accepted. A budget that
     ends on a pair's first token drops the second; a stop token that is
     an accepted draft ends the row and the token after it is dropped:
     no output is longer than asked and none goes on past its stop."""
-    engine = LLMEngine(engine_config(decode_steps=4))
+    engine = drafting
+    assert engine.config.scheduler.decode_steps == 4
+    _, accepted_before = drafted_and_accepted(engine)
     prompts = [prompt_of(18 + i % 5, seed=i) for i in range(48)]
     for budget in (3, 4, 5):
         seqs = generate(engine, prompts[:16], temperature=1.0,
@@ -230,16 +258,17 @@ def test_max_tokens_and_a_stop_token_cut_inside_an_accepted_pair():
             assert not hits and len(s.output_token_ids) == 40
     # Stops fell on both commits of a pair: at even and at odd places.
     assert len({len(s.output_token_ids) % 2 for s in stopped}) == 2
-    assert engine.metrics.spec_accepted_tokens_total > 100
+    assert (engine.metrics.spec_accepted_tokens_total
+            - accepted_before) > 100
 
 
 def test_a_row_with_a_penalty_runs_draftless_beside_rows_that_draft(
-        without_drafts):
+        without_drafts, drafting, draftless):
     """One burst program: the penalised row commits one token an
     iteration by its own rule, the others draft. All four rows give
     what they give with the module off."""
     def run(draft):
-        engine = LLMEngine(engine_config(draft=draft))
+        engine = drafting if draft else draftless
         ids = [engine.add_request(p, SamplingParams(
             temperature=0.0, max_tokens=40, ignore_eos=True,
             repetition_penalty=1.0 if i else 1.3, presence_penalty=0.0
@@ -248,22 +277,24 @@ def test_a_row_with_a_penalty_runs_draftless_beside_rows_that_draft(
         finish(engine, seqs)
         return engine, [s.output_token_ids for s in seqs]
 
+    offered_before, _ = drafted_and_accepted(drafting)
     engine, drafted = run(True)
     _, plain = run(False)
     assert drafted == plain
     assert drafted[1:] == without_drafts[1:4]
     assert drafted[0] != without_drafts[0]           # the penalty bites
     # Three of four rows offered drafts: under the 39 iterations x 4.
-    offered = engine.metrics.spec_draft_tokens_total
+    offered = engine.metrics.spec_draft_tokens_total - offered_before
     assert 60 < offered <= 3 * 39
 
 
-def test_a_logit_bias_and_a_min_tokens_row_run_draftless_too():
+def test_a_logit_bias_and_a_min_tokens_row_run_draftless_too(
+        drafting, draftless):
     """The other rewrites of a row's logits: a bias, and stop tokens
     suppressed under ``min_tokens`` (the row drafts once it is past its
     minimum: a later burst's payload says so)."""
     def run(draft):
-        engine = LLMEngine(engine_config(draft=draft))
+        engine = drafting if draft else draftless
         params = [dict(logit_bias={5: 4.0}),
                   dict(min_tokens=6, ignore_eos=False,
                        stop_token_ids=list(range(8))), {}]
@@ -280,9 +311,10 @@ def test_a_logit_bias_and_a_min_tokens_row_run_draftless_too():
     assert not set(drafted[1][:5]) & set(range(8))
 
 
-def test_a_seeded_row_keeps_its_stream_beside_rows_that_draft():
+def test_a_seeded_row_keeps_its_stream_beside_rows_that_draft(
+        drafting, draftless):
     def run(draft):
-        engine = LLMEngine(engine_config(draft=draft))
+        engine = drafting if draft else draftless
         ids = [engine.add_request(p, SamplingParams(
             temperature=1.0, max_tokens=20, ignore_eos=True,
             seed=None if i else 1234)) for i, p in enumerate(PROMPTS[:3])]

@@ -507,7 +507,6 @@ def _run_to_finish(engine, sid):
     return seq
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
 def test_cold_start_restores_another_engines_kv(kv_dtype):
     """The tentpole acceptance: engine A computes a prompt's KV and
@@ -550,7 +549,6 @@ def test_cold_start_restores_another_engines_kv(kv_dtype):
         stop()
 
 
-@pytest.mark.slow
 def test_cold_start_miss_computes_without_waiting():
     """Shared tier up but empty: the probe answers a definitive miss
     and the sequence computes on the next admission pass — and the
@@ -571,7 +569,6 @@ def test_cold_start_miss_computes_without_waiting():
         stop()
 
 
-@pytest.mark.slow
 def test_cold_start_tier_down_degrades_immediately():
     """Remote tier unreachable: unlike a disagg handoff (which waits
     out handoff_timeout_s for pages that WERE shipped), a cold-start
@@ -589,7 +586,6 @@ def test_cold_start_tier_down_degrades_immediately():
     assert dec.offload.restored_pages == 0
 
 
-@pytest.mark.slow
 def test_abort_during_cold_start_probe_leaks_no_pages():
     """Regression guard: aborting a request while it is parked for the
     cold-start probe (and aborting one that restored and started

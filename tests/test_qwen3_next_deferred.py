@@ -56,27 +56,48 @@ def hybrid_engine(form, deferred, **scheduler):
         **scheduler))
 
 
+@pytest.fixture(scope="module")
+def hybrid_engines():
+    """``engines(form, deferred)``: ``hybrid_engine(form, deferred)``,
+    built once a module (docs/source/dev_guide/testing.md) for the
+    cases that run greedy requests to their end on it or only read it.
+    A hybrid takes no prefix hit and a recycled slot needs no clearing
+    (tests/test_qwen3_next_engine.py), so greedy tokens do not depend
+    on what ran before; a case that compares whole planes or pools
+    builds its own."""
+    built = {}
+
+    def engines(form, deferred):
+        if (form, deferred) not in built:
+            built[form, deferred] = hybrid_engine(form, deferred)
+        return built[form, deferred]
+
+    yield engines
+    built.clear()
+
+
 @forms
-def test_deferred_tokens_equal_the_eager_bursts(form):
+def test_deferred_tokens_equal_the_eager_bursts(form, hybrid_engines):
     """Four bursts of four steps, rows that cross a page boundary
     inside a burst, and a prompt of two chunks (45 of 32), whose state
     is carried between the chunks before the first burst reads it."""
     prompts = [prompt_of(n, seed=n) for n in (45, 20, 14, 33)]
     eager, deferred = (
         [s.output_token_ids
-         for s in greedy(hybrid_engine(form, d), prompts, max_tokens=15)]
+         for s in greedy(hybrid_engines(form, d), prompts, max_tokens=15)]
         for d in (False, True))
     assert deferred == eager
     assert all(len(t) == 15 for t in deferred)
 
 
 @forms
-def test_a_burst_leaves_the_planes_and_the_state_as_the_eager_one(form):
+def test_a_burst_leaves_the_planes_and_the_state_as_the_eager_one(
+        form, hybrid_engines):
     """One row stops on a token in the middle of a burst, one runs out
     of budget there: the flush takes what each emitted and no more, and
     a frozen row's pools are left as they were."""
     prompts = [prompt_of(19, seed=3), prompt_of(27, seed=4)]
-    free = greedy(hybrid_engine(form, False), prompts, max_tokens=8)
+    free = greedy(hybrid_engines(form, False), prompts, max_tokens=8)
     stop = free[0].output_token_ids[1]
 
     def run(deferred):
@@ -109,15 +130,16 @@ def test_a_burst_leaves_the_planes_and_the_state_as_the_eager_one(form):
 
 
 @forms
-def test_the_expert_counters_ride_the_deferred_burst(form):
-    engine = hybrid_engine(form, True)
+def test_the_expert_counters_ride_the_deferred_burst(
+        form, hybrid_engines, monkeypatch):
+    engine = hybrid_engines(form, True)
     read, seen = engine.runner.read_moe_stats, []
 
     def record():
         seen.append(read())
         return seen[-1]
 
-    engine.runner.read_moe_stats = record
+    monkeypatch.setattr(engine.runner, "read_moe_stats", record)
     greedy(engine, [prompt_of(20, seed=1), prompt_of(11, seed=2)],
            max_tokens=9)
     bursts = [s for s in seen if s]
@@ -165,13 +187,13 @@ def burst_scan(runner, deferred):
 
 @pytest.mark.parametrize("family", ["qwen3_next", "jamba", "lfm2_moe",
                                     "llama"])
-def test_no_page_plane_rides_the_deferred_scan(family):
+def test_no_page_plane_rides_the_deferred_scan(family, hybrid_engines):
     """The planes are constants of the scan and not its carry, which
     is what keeps XLA from copying them around the block loop; of a
     hybrid model's caches the state pools and the counters are
     carried."""
     if family == "qwen3_next":
-        runner = hybrid_engine("xla", True).runner
+        runner = hybrid_engines("xla", True).runner
     elif family in ("jamba", "lfm2_moe"):
         import importlib
         runner = LLMEngine(importlib.import_module(
@@ -233,11 +255,12 @@ def test_auto_resolves_deferred_writes_on_for_the_hybrid_cell(
     assert _resolve_deferred_kv(args, config) is False
 
 
-def test_version_names_the_write_mode_the_hybrid_is_served_with():
+def test_version_names_the_write_mode_the_hybrid_is_served_with(
+        hybrid_engines):
     from production_stack_tpu.engine.server import EngineServer
 
     async def kv_writes(deferred):
-        server = EngineServer(hybrid_engine("xla", deferred),
+        server = EngineServer(hybrid_engines("xla", deferred),
                               "tiny-qwen3-next")
         client = TestClient(TestServer(server.build_app()))
         await client.start_server()

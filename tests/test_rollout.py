@@ -16,6 +16,7 @@ Fast lane: fake engines only — no LLMEngine is ever built.
 
 import asyncio
 import json
+import os
 import socket
 import sys
 import time
@@ -58,12 +59,31 @@ from production_stack_tpu.router.stats.request_stats import (
 )
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+def _free_port_range(n: int) -> int:
+    """First of ``n`` consecutive ports, every one of which binds now.
+    The fleet manager hands its replicas the ports of a range in order
+    and asks nothing of them, so one free port and the nine after it
+    (as this read until PR 46) met whatever another worker's server had
+    been given meanwhile. Searched below the range the kernel hands out
+    on its own, so that no later ``bind(0)`` of any test lands inside,
+    and from a start of this process's own, so that two workers do not
+    probe the same ports."""
+    low, high = 20000, 32000
+    start = low + (os.getpid() * 64) % (high - low - n)
+    for base in list(range(start, high - n, n)) + list(range(low, start, n)):
+        held = []
+        try:
+            for port in range(base, base + n):
+                sock = socket.socket()
+                held.append(sock)
+                sock.bind(("127.0.0.1", port))
+            return base
+        except OSError:
+            continue
+        finally:
+            for sock in held:
+                sock.close()
+    raise RuntimeError(f"no {n} free ports in a row in [{low}, {high})")
 
 
 def _fake_pool_command(speed: float = 200.0, ckpt_every: int = 2):
@@ -337,7 +357,7 @@ async def _rollout_rig(tmp_path, pool: PoolSpec):
                   f"{site._server.sockets[0].getsockname()[1]}")
 
     config_path = tmp_path / "dyn.json"
-    base = _free_port()
+    base = _free_port_range(10)
     spec = FleetSpec(
         pools=[pool], port_start=base, port_end=base + 9,
         router_url=router_url, router_config_path=str(config_path),

@@ -327,9 +327,14 @@ async def test_a_profiler_slice_holds_the_turns_of_the_records(
 
 async def test_stopping_a_slice_does_not_hold_the_streams(monkeypatch):
     """Writing a trace takes seconds (here: a stop_trace that sleeps
-    2 s): POST /debug/profiler/stop waits for it off the event loop,
+    4 s): POST /debug/profiler/stop waits for it off the event loop,
     so a stream's tokens keep arriving, a second stop or a start
-    meanwhile gets 409, and the slice's span closes after it."""
+    meanwhile gets 409, and the slice's span closes after it. A stop
+    that held the loop would show as one gap of the whole 4 s; the
+    bound on a gap is 1.5 s and not a few token times because on a box
+    whose every core is taken a decode step that meets a new bucket's
+    compile was seen to take 0.43 s (PR 46: at a stop of 2 s and a
+    bound of 0.5 s the case failed at the floor, PR 44)."""
     import jax
     from aiohttp.test_utils import TestClient, TestServer
 
@@ -339,7 +344,7 @@ async def test_stopping_a_slice_does_not_hold_the_streams(monkeypatch):
     monkeypatch.setattr(jax.profiler, "start_trace",
                         lambda trace_dir, **kw: started.append(kw))
     monkeypatch.setattr(jax.profiler, "stop_trace",
-                        lambda: time.sleep(2.0))
+                        lambda: time.sleep(4.0))
     engine = _engine(max_model_len=1024, num_pages=80)
     engine.tracer = EngineTracer(ring_size=8)
     step = engine.step
@@ -378,13 +383,13 @@ async def test_stopping_a_slice_does_not_hold_the_streams(monkeypatch):
         assert (await client.post("/debug/profiler/start")).status == 409
         assert (await stop).status == 200
         t1 = time.perf_counter()
-        assert t1 - t0 >= 2.0
+        assert t1 - t0 >= 4.0
         assert not reader.done()  # the stream outlasted the stop
         during = [t for t in arrivals if t0 + 0.1 < t < t1 - 0.1]
         assert len(during) >= 20
-        assert max(b - a for a, b in zip(during, during[1:])) < 0.5
+        assert max(b - a for a, b in zip(during, during[1:])) < 1.5
         inside = [t for t in ticks if t0 <= t <= t1]
-        assert max(b - a for a, b in zip(inside, inside[1:])) < 0.5
+        assert max(b - a for a, b in zip(inside, inside[1:])) < 1.5
         span = list(engine.tracer._ring)[-1]
         assert span.seq_id.startswith("prof-")
         assert "profiler_stop" in [e["event"] for e in span.events]
