@@ -48,8 +48,8 @@ class ModelConfig:
 
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
-    # | lfm2_moe | longcat_flash | glm4_moe_lite (models/registry.py
-    # FAMILIES)
+    # | lfm2_moe | longcat_flash | glm4_moe_lite | granitemoehybrid
+    # (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -159,6 +159,27 @@ class ModelConfig:
     # configuration sets it to 0 where scheduler.draft_module is off:
     # the module is then neither made nor given pages.
     num_nextn_predict_layers: int = 0
+    # Granite-MoE-hybrid decoders (architecture == "granitemoehybrid",
+    # models/granitemoehybrid.py). ``layer_types`` lists every layer
+    # as "mamba" (a Mamba-2 mixer: mamba_n_heads heads of mamba_d_head
+    # channels, each with a state of mamba_d_state numbers a channel
+    # and one scalar decay a head; B and C shared by all heads, one
+    # group; mamba_d_inner = mamba_n_heads * mamba_d_head; prefill in
+    # the matrix form mamba_chunk_size tokens at a time) or "attention" (grouped
+    # queries over pages, no position term, scores scaled by
+    # attention_multiplier). Every layer's feed-forward is num_experts
+    # held experts of moe_intermediate_size under a softmax router
+    # beside a shared expert of shared_expert_intermediate_size added
+    # whole. The embedding is scaled by embedding_multiplier, each
+    # sublayer's output by residual_multiplier, and the logits are
+    # divided by logits_scaling.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -355,6 +376,107 @@ class ModelConfig:
                 shared_expert_intermediate_size=hf[
                     "shared_expert_intermediate_size"],
                 norm_topk_prob=hf.get("norm_topk_prob", True),
+                activation="silu",
+                dtype="bfloat16",
+            )
+        if "granitemoehybrid" in arch.replace("_", ""):
+            ep, rank = _expert_parallel_share(hf)
+            layer_types = tuple(hf["layer_types"])
+            other = sorted(set(layer_types) - {"mamba", "attention"})
+            heads = hf["mamba_n_heads"]
+            d_head = hf.get("mamba_d_head") or (
+                hf.get("mamba_expand", 2) * hf["hidden_size"] // heads)
+            groups = hf.get("mamba_n_groups", 1)
+            refused = [why for bad, why in (
+                (bool(other),
+                 f"layer_types entries {other}: a layer is served as "
+                 "'mamba' (the Mamba-2 mixer) or 'attention' (causal "
+                 "attention over the paged cache), and no other kind "
+                 "has a path"),
+                (len(layer_types) != hf["num_hidden_layers"],
+                 f"layer_types lists {len(layer_types)} layers and "
+                 f"num_hidden_layers says {hf['num_hidden_layers']}"),
+                (bool(hf.get("mamba_proj_bias", False)),
+                 "mamba_proj_bias true: the Mamba mixer's in and out "
+                 "projections are served without a bias"),
+                (not hf.get("mamba_conv_bias", True),
+                 "mamba_conv_bias false: the convolution is served "
+                 "with its bias"),
+                (hf.get("position_embedding_type", "nope") != "nope",
+                 f"position_embedding_type "
+                 f"{hf.get('position_embedding_type')!r}: the "
+                 "attention layers are served with no position term "
+                 "('nope'); the Mamba layers carry the order"),
+                (groups != 1,
+                 f"mamba_n_groups {groups}"
+                 + ("" if heads % groups == 0 else
+                    f", which does not divide mamba_n_heads {heads}")
+                 + ": B and C are served shared by all heads (one "
+                 "group)"),
+                (heads * d_head
+                 != hf.get("mamba_expand", 2) * hf["hidden_size"],
+                 f"mamba_n_heads {heads} x mamba_d_head {d_head} is "
+                 f"not mamba_expand {hf.get('mamba_expand', 2)} x "
+                 f"hidden_size {hf['hidden_size']}"),
+                (bool(hf.get("attention_bias", False)),
+                 "attention_bias true: the attention projections are "
+                 "served without a bias"),
+                (hf.get("hidden_act", "silu") != "silu",
+                 f"hidden_act {hf.get('hidden_act')!r}: the experts "
+                 "and the shared expert are SwiGLU"),
+                (hf.get("normalization_function",
+                        "rmsnorm") != "rmsnorm",
+                 f"normalization_function "
+                 f"{hf.get('normalization_function')!r}: every norm "
+                 "is served as an RMS norm"),
+                (not hf.get("shared_intermediate_size"),
+                 "shared_intermediate_size 0: every layer is served "
+                 "with its shared expert"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "Granite-MoE-hybrid config this engine does not "
+                    "serve: " + "; ".join(refused))
+            return cls(
+                name=name or hf.get("_name_or_path", "granitemoehybrid"),
+                architecture="granitemoehybrid",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf["intermediate_size"],
+                num_hidden_layers=hf["num_hidden_layers"],
+                num_attention_heads=hf["num_attention_heads"],
+                num_key_value_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim"),
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 131072),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           True),
+                layer_types=layer_types,
+                mamba_n_heads=heads,
+                mamba_d_head=d_head,
+                mamba_d_state=hf.get("mamba_d_state", 128),
+                mamba_d_conv=hf.get("mamba_d_conv", 4),
+                mamba_expand=hf.get("mamba_expand", 2),
+                mamba_chunk_size=hf.get("mamba_chunk_size", 256),
+                # The count this engine holds (the key counts what is
+                # held); the router's width is this times
+                # expert_parallel_size. The source has no key of its
+                # own for an expert's width: intermediate_size is it.
+                num_experts=hf["num_local_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                moe_intermediate_size=hf["intermediate_size"],
+                shared_expert_intermediate_size=hf[
+                    "shared_intermediate_size"],
+                embedding_multiplier=float(
+                    hf.get("embedding_multiplier", 1.0)),
+                attention_multiplier=float(
+                    hf.get("attention_multiplier", 1.0)),
+                residual_multiplier=float(
+                    hf.get("residual_multiplier", 1.0)),
+                logits_scaling=float(hf.get("logits_scaling", 1.0)),
                 activation="silu",
                 dtype="bfloat16",
             )
@@ -678,7 +800,8 @@ class ModelConfig:
                 f"architecture {arch!r} is none this engine serves "
                 "(config.json 'architectures', else 'model_type'): it "
                 "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba, LFM2-MoE, "
-                "LongCat-Flash and the "
+                "LongCat-Flash, GLM-4 MoE lite, Granite-MoE-hybrid and "
+                "the "
                 f"Llama shapes {sorted(_LLAMA_SHAPES)}, and reads no "
                 "other as one of them")
         qwen = "qwen2" in arch
@@ -1401,6 +1524,13 @@ INTERNAL_FIELDS = {
     "model.zero_expert_num",
     "model.num_nextn_predict_layers",
     "model.routed_scaling_factor",
+    "model.mamba_n_heads",
+    "model.mamba_d_head",
+    "model.mamba_chunk_size",
+    "model.embedding_multiplier",
+    "model.attention_multiplier",
+    "model.residual_multiplier",
+    "model.logits_scaling",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -1524,6 +1654,48 @@ def tiny_lfm2_moe_config(expert_parallel_size: int = 1,
         expert_parallel_rank=expert_parallel_rank,
         num_experts_per_tok=2,
         moe_intermediate_size=32,
+        dtype="float32",
+    )
+
+
+def tiny_granitemoehybrid_config(expert_parallel_size: int = 1,
+                                 expert_parallel_rank: int = 0
+                                 ) -> ModelConfig:
+    """A tiny Granite-MoE-hybrid (Mamba-2 mixers around one attention
+    layer that is neither first nor last, two query heads a KV head,
+    held experts of a wider softmax router beside an ungated shared
+    expert in every layer, the four multipliers at values that are not
+    1 and an attention multiplier that is not ``head_dim ** -0.5``) for
+    tests that run anywhere."""
+    return ModelConfig(
+        name="tiny-granitemoehybrid",
+        architecture="granitemoehybrid",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_hidden_layers=4,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        mamba_n_heads=4,
+        mamba_d_head=32,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_chunk_size=8,
+        num_experts=8 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=48,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.125,
+        residual_multiplier=0.22,
+        logits_scaling=4.0,
         dtype="float32",
     )
 

@@ -2283,6 +2283,11 @@ class EngineServer:
         deferred = config.scheduler.deferred_kv_writes
         conv_tails = ({"conv_tails": "burst" if deferred else "step"}
                       if config.model.family.conv_tail else {})
+        # What a sequence holds of the state pool, over all recurrent
+        # layers, for a family that keeps such a state.
+        state = ({"state_bytes_per_sequence":
+                  config.model.recurrent_state_bytes()}
+                 if config.model.has_recurrent_state else {})
         # What a page holds: K and V planes, or one latent a token an
         # entry stored once; and the bytes a committed token costs.
         kv = {"kv": ("latent" if config.model.has_latent_cache
@@ -2312,6 +2317,7 @@ class EngineServer:
                 if config.scheduler.speculative_k > 0 else
                 {"by": "none"}),
             **conv_tails,
+            **state,
             "family": config.model.architecture,
             **kv,
         })
@@ -2959,7 +2965,7 @@ def parse_args(argv=None):
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
                              "qwen3_next, jamba, lfm2_moe, longcat_flash, "
-                             "glm4_moe_lite; "
+                             "glm4_moe_lite, granitemoehybrid; "
                              "decode-steps "
                              "> 1, no pp/sp); /version "
                              "says which "

@@ -278,6 +278,13 @@ def load_weights(model_dir: str, config: ModelConfig,
             "fused, the prediction layer's body after the main "
             "layers') is not written yet: serve the architecture with "
             "--random-weights")
+    if config.architecture == "granitemoehybrid":
+        raise NotImplementedError(
+            "reading a Granite-MoE-hybrid checkpoint into this engine's "
+            "stacks (the Mamba-2 mixers' and the attention layers' "
+            "apart, the held experts' block with gate | up fused) is "
+            "not written yet: serve the architecture with "
+            "--random-weights")
     if config.architecture not in ("llama", "mistral", "qwen2"):
         raise NotImplementedError(
             f"no reader for a {config.architecture!r} checkpoint, and "

@@ -127,6 +127,22 @@ def _lfm2_moe_state(c) -> tuple:
     return (((c.conv_L_cache - 1, c.hidden_size), "model"),)
 
 
+def _granitemoehybrid_layers(c) -> tuple:
+    """A Mamba-2 mixer where ``layer_types`` says ``mamba``, attention
+    where it says ``attention``: the published list."""
+    return tuple(kind == "mamba" for kind in c.layer_types)
+
+
+def _granitemoehybrid_state(c) -> tuple:
+    """The Mamba-2 recurrence's ``h`` (float32), kept ``[d_state,
+    heads * d_head]`` so that the channels lie along the lanes and the
+    state elements along the sublanes (``ops/ssd.py`` says why), and
+    the tail of the convolution over ``x | B | C``."""
+    return (((c.mamba_d_state, c.mamba_d_inner), "float32"),
+            ((c.mamba_d_conv - 1, c.mamba_d_inner + 2 * c.mamba_d_state),
+             "model"))
+
+
 def _longcat_flash_pages(c) -> PageCache:
     """Two latent-attention sublayers a layer, each with its own
     cache: per token the compressed latent (``kv_lora_rank``) and the
@@ -187,6 +203,19 @@ FAMILIES: Dict[str, Family] = {
             "tensor parallelism": "the convolution's tail pool and the "
                                   "expert layer have no sharding rules",
             "weight quantization": "the convolution's fused projection "
+                                   "and the experts have no quantized "
+                                   "form",
+        }),
+    "granitemoehybrid": Family(
+        "granitemoehybrid", deferred_kv=True,
+        recurrent_layers=_granitemoehybrid_layers,
+        state=_granitemoehybrid_state,
+        conv_tail=True, counters=_EXPERT_COUNTERS,
+        refusals={
+            "tensor parallelism": "the state pools, the Mamba-2 mixer "
+                                  "and the expert layer have no "
+                                  "sharding rules",
+            "weight quantization": "the Mamba-2 mixer's projections "
                                    "and the experts have no quantized "
                                    "form",
         }),

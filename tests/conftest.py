@@ -97,6 +97,7 @@ _LONG_MODULES = {
     "test_qwen3_next_deferred": 257,
     "test_conv_tails_burst": 245,
     "test_pallas_lowering": 205,
+    "test_granitemoehybrid": 150,
     "test_chipbench_glm4_moe_lite_family": 177,
     "test_longcat_flash": 169,
     "test_lfm2_moe_engine": 164,
@@ -107,6 +108,8 @@ _LONG_MODULES = {
     "test_longcat_flash_engine": 130,
     "test_prefill_width": 113,
     "test_chipbench_longcat_family": 106,
+    "test_chipbench_granitemoehybrid_family": 105,
+    "test_granitemoehybrid_engine": 100,
     "test_qwen3_next_engine": 105,
     "test_chipbench_rehearsal": 105,
     "test_jamba_engine": 99,
