@@ -14,12 +14,12 @@ import threading
 import time
 import uuid
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.kv_cache import PagedCacheManager
 from production_stack_tpu.engine.model_runner import ModelRunner
-from production_stack_tpu.engine.scheduler import Scheduler
+from production_stack_tpu.engine.scheduler import Scheduler, StepPlan
 from production_stack_tpu.engine.sequence import (
     SamplingParams,
     Sequence,
@@ -43,6 +43,32 @@ class StepOutput:
     # (sampled_logprob, [(token_id, logprob), ...]) when the request
     # asked for logprobs; None otherwise.
     logprobs: Optional[tuple] = None
+
+
+@dataclass
+class CommittedRow:
+    """A decode row's tokens as the planner's half of the commit left
+    them (``Scheduler.commit_decode_tokens``): what the deferred half
+    makes the row's StepOutputs from. ``finished`` and
+    ``finish_reason`` are the row's as the commit decided them, not as
+    an abort between the two halves may have changed them since."""
+    seq: Sequence
+    tokens: List[int]
+    logprobs: Optional[list]
+    finished: bool
+    finish_reason: Optional[str]
+    now: float
+
+
+@dataclass
+class EnqueuedStep:
+    """One device program between LLMEngine.begin_step, which planned,
+    built and enqueued it, and finish_step, which waits for it."""
+    plan: StepPlan
+    handle: object  # the runner's: result() is the one blocking read
+    commit: Callable  # (plan, result): the planner's half of the commit
+    t0: float  # the turn's start, before the plan
+    enqueue_s: float  # inside the runner, up to the enqueue
 
 
 class LLMEngine:
@@ -91,6 +117,13 @@ class LLMEngine:
         # pipeline exists to shrink.
         self._in_flight = None
         self._idle_mark: Optional[float] = None
+        # The deferred half of the commits so far (take_owed): ready
+        # StepOutputs and CommittedRows, in the engine's order. The
+        # server loop takes them behind its next dispatch
+        # (docs/async_pipeline.md, "The served loop").
+        self._owed: list = []
+        # The program begin_step enqueued and finish_step has not read.
+        self._enqueued: Optional[EnqueuedStep] = None
         # Row/spec detail for the step about to be accounted, staged
         # by the execute helpers for the flight recorder (tracer set
         # only); drained by _account_step.
@@ -750,7 +783,11 @@ class LLMEngine:
         forever (a Mosaic refusal or an HBM OOM at first dispatch used
         to show up as a request that never ends). Waiting sequences
         the device never touched stay queued."""
-        outputs: List[StepOutput] = []
+        # What earlier turns committed still reaches its streams, and
+        # before the aborts.
+        self._enqueued = None
+        outputs = self.take_owed()
+        aborts: List[StepOutput] = []
         with self._lock:
             self._in_flight = None
             self.metrics.set_inflight_depth(0)
@@ -758,9 +795,9 @@ class LLMEngine:
                 s for s in self.scheduler.waiting if s.pages]
             for seq in touched:
                 self.scheduler.abort_sequence(seq)
-                outputs.append(self._delta(seq, None))
-        self._pop_finished(outputs)
-        return outputs
+                aborts.append(self._delta(seq, None))
+        self._pop_finished(aborts)
+        return outputs + aborts
 
     def _trace_finish(self, seq: Sequence) -> None:
         """Finalize ``seq``'s engine span (caller checked the tracer)."""
@@ -776,14 +813,35 @@ class LLMEngine:
             output_tokens=seq.num_generated)
 
     def has_work(self) -> bool:
-        # A dispatched-but-unread decode step is work: the loop must
-        # come back to reconcile it even if every row since finished.
+        # Outputs owed to the streams are work: the loop must come
+        # back to hand them over even if every row since finished.
+        return bool(self._owed) or self.more_to_run()
+
+    def more_to_run(self) -> bool:
+        """Whether a device program follows: rows to plan, or a
+        dispatched-but-unread decode step to reconcile."""
         return self._in_flight is not None or self.scheduler.has_work()
 
     # ---- engine step ------------------------------------------------------
 
     def step(self) -> List[StepOutput]:
         """Plan + execute one device program; returns per-seq deltas.
+
+        The two calls of a turn back to back, and at once what the
+        server loop takes behind its next dispatch: begin_step (plan,
+        build, enqueue), finish_step (wait, parse, commit what the
+        planner reads), take_owed (the outputs).
+        """
+        enqueued = self.begin_step()
+        if enqueued is not None:
+            self.finish_step(enqueued)
+        return self.take_owed()
+
+    def begin_step(self) -> Optional[EnqueuedStep]:
+        """Plan, build and enqueue one device program from the
+        committed state; returns what finish_step takes, or None where
+        nothing is left enqueued: no executable work, or the
+        overlapped pipeline's turn, which runs whole in here.
 
         ``scheduler.async_scheduling`` routes decode through the
         overlapped plan -> dispatch -> complete pipeline
@@ -798,10 +856,60 @@ class LLMEngine:
             self._checkpoint_tick()
         if (self.config.scheduler.async_scheduling
                 and self.runner.bridge is None):
-            return self._step_async()
-        return self._step_sync()
+            outputs = self._step_async()
+            self._owed.extend(outputs)  # a new list since _settle()
+            return None
+        t0 = time.perf_counter()
+        plan = self._plan_locked(self._owed)
+        if plan.empty:
+            return None
+        self._enqueued = self._enqueue(plan, t0)
+        return self._enqueued
 
-    def _plan_locked(self, outputs: List[StepOutput]):
+    def finish_step(self, enqueued: EnqueuedStep) -> None:
+        """Wait for the program begin_step enqueued, parse its result
+        and commit what the planner reads (tokens appended, finishes
+        decided, pages and state slots freed, ``running`` updated);
+        what only the streams read is owed (take_owed)."""
+        wait_s = self._finish(enqueued)
+        self._account_step(
+            host_s=(time.perf_counter() - enqueued.t0) - wait_s,
+            wait_s=wait_s, ahead=False)
+
+    def take_owed(self) -> List[StepOutput]:
+        """The deferred half of every commit since the last call: the
+        rows' StepOutputs, their inter-token metrics, the finished
+        sequences' retirement. The server loop calls it behind its
+        next dispatch, or at once where no program follows."""
+        if not self._owed:
+            return []
+        if self._tracer is not None:
+            self._tracer.phase("commit")
+        outputs = self._settle()
+        if outputs:
+            self.metrics.on_handover(behind=self._enqueued is not None)
+        return outputs
+
+    def _settle(self) -> List[StepOutput]:
+        owed, self._owed = self._owed, []
+        outputs: List[StepOutput] = []
+        for item in owed:
+            if isinstance(item, StepOutput):
+                outputs.append(item)
+                continue
+            seq_id, lps = item.seq.seq_id, item.logprobs
+            last = len(item.tokens) - 1
+            for k, token in enumerate(item.tokens):
+                outputs.append(StepOutput(
+                    seq_id, token, item.finished and k == last,
+                    item.finish_reason if k == last else None,
+                    lps[k] if lps else None))
+            self.metrics.on_decode_tokens(
+                item.seq, len(item.tokens), item.now)
+        self._pop_finished(outputs)
+        return outputs
+
+    def _plan_locked(self, outputs: list):
         if self._tracer is not None:
             self._tracer.phase("plan")
         with self._lock:
@@ -811,27 +919,43 @@ class LLMEngine:
             self.scheduler.newly_aborted.clear()
         return plan
 
-    def _step_sync(self) -> List[StepOutput]:
-        outputs: List[StepOutput] = []
-        t0 = time.perf_counter()
-        plan = self._plan_locked(outputs)
-        if plan.empty:
-            for out in outputs:
-                self.sequences.pop(out.seq_id, None)
-            return outputs
+    def _enqueue(self, plan: StepPlan, t0: float) -> EnqueuedStep:
+        td = time.perf_counter()
+        self._note_dispatch(td)
         if plan.prefill is not None and plan.decode is not None:
             # Mixed plan (scheduler._plan_mixed): one unified ragged
             # dispatch carries both sides (docs/unified_step.md).
-            wait_s = self._execute_unified(plan, outputs)
+            handle = self.runner.dispatch_unified(plan)
+            commit = self._commit_unified
         elif plan.prefill is not None:
-            wait_s = self._execute_prefill(plan, outputs)
+            handle = self.runner.dispatch_prefill(plan.prefill)
+            commit = self._commit_prefill
         else:
-            wait_s = self._execute_decode_sync(plan, outputs)
-        self._account_step(
-            host_s=(time.perf_counter() - t0) - wait_s,
-            wait_s=wait_s, ahead=False)
-        self._pop_finished(outputs)
-        return outputs
+            handle = self.runner.dispatch_decode_plan(plan.decode)
+            commit = self._commit_decode
+        return EnqueuedStep(plan, handle, commit, t0,
+                            time.perf_counter() - td)
+
+    def _finish(self, enqueued: EnqueuedStep) -> float:
+        """The enqueued program's result read and committed; returns
+        the seconds inside the runner (enqueue, wait, parse)."""
+        tw = time.perf_counter()
+        result = enqueued.handle.result()
+        tr = time.perf_counter()
+        self._enqueued = None
+        self._idle_mark = tr
+        if self._tracer is not None:
+            self._tracer.phase("commit")
+        enqueued.commit(enqueued.plan, result)
+        return enqueued.enqueue_s + (tr - tw)
+
+    def _execute_now(self, plan: StepPlan, outputs: list) -> float:
+        """One program run to its end with nothing deferred, for the
+        overlapped pipeline's synchronous turns; returns the seconds
+        inside the runner."""
+        wait_s = self._finish(self._enqueue(plan, time.perf_counter()))
+        outputs.extend(self._settle())
+        return wait_s
 
     def _account_step(self, host_s: float, wait_s: float, ahead: bool,
                       pipeline_break: bool = False, **extra) -> None:
@@ -862,30 +986,73 @@ class LLMEngine:
                 device_wait_ms=round(wait_s * 1e3, 3),
                 ahead=ahead, pipeline_break=pipeline_break, **note)
 
-    def _execute_prefill(self, plan, outputs) -> float:
-        td = time.perf_counter()
-        self._note_dispatch(td)
-        sampled, lp_rows = self.runner.run_prefill(plan.prefill)
-        tr = time.perf_counter()
-        self._idle_mark = tr
-        if self._tracer is not None:
-            self._tracer.phase("commit")
+    def _commit_prefill_chunks(self, chunks, sampled, lp_rows) -> None:
+        """Caller holds self._lock."""
+        for i, (chunk, token) in enumerate(zip(chunks, sampled)):
+            self.scheduler.on_prefill_executed(chunk, token)
+            if chunk.is_last_chunk:
+                if (chunk.seq.handoff_prefill
+                        and chunk.seq.state == SequenceState.RUNNING):
+                    # Disagg prefill role: ship KV + retire (unless
+                    # the first token already finished the request —
+                    # then there is nothing to decode and nothing
+                    # worth shipping).
+                    self._ship_handoff(chunk.seq)
+                self._owed.append(self._delta(
+                    chunk.seq, token,
+                    lp_rows[i] if lp_rows else None))
+
+    def _commit_decode_rows(self, rows, token_lists, lp_lists,
+                            spec_drafts, expected=None) -> tuple:
+        """The planner's half of a decode program's commit, row by
+        row (Scheduler.commit_decode_tokens); the rows' outputs are
+        owed. Returns (drafted, accepted, tokens kept, rows walked
+        token by token). Caller holds self._lock."""
+        now = time.time()
+        commit = self.scheduler.commit_decode_tokens
+        drafted = accepted = step_tokens = slow_rows = 0
+        for i, (seq, toks) in enumerate(zip(rows, token_lists)):
+            if seq is None:  # plan-ahead masked slot
+                continue
+            if expected is not None and (
+                    expected[i] is None
+                    or seq.total_len != expected[i]):
+                # Stale: the verify step this row was dispatched
+                # behind committed more than the one token the ahead
+                # plan assumed, so this sample came from incomplete
+                # context. Its KV write was identical either way
+                # (token_source is always the first committed token)
+                # — only the sample is dropped.
+                continue
+            if spec_drafts is not None:
+                # Device-level acceptance (each verify row emits
+                # accepted + 1 tokens), counted before any host-side
+                # stop truncation so the rate reflects the model, not
+                # request budgets.
+                drafted += len(spec_drafts[i])
+                accepted += len(toks) - 1
+                seq.spec_drafted_total += len(spec_drafts[i])
+                seq.spec_accepted_total += max(0, len(toks) - 1)
+            kept, slow = commit(seq, toks)
+            slow_rows += slow
+            if kept:
+                step_tokens += kept
+                done = seq.state != SequenceState.RUNNING
+                self._owed.append(CommittedRow(
+                    seq, toks if kept == len(toks) else toks[:kept],
+                    lp_lists[i] if lp_lists else None, done,
+                    seq.finish_reason.value if done else None, now))
+            if spec_drafts is not None:
+                self.scheduler.on_spec_executed(seq)
+        if spec_drafts is not None:
+            self.metrics.on_spec_step(drafted, accepted)
+        return drafted, accepted, step_tokens, slow_rows
+
+    def _commit_prefill(self, plan, result) -> None:
+        sampled, lp_rows = result
         with self._lock:
-            for i, (chunk, token) in enumerate(
-                    zip(plan.prefill.chunks, sampled)):
-                self.scheduler.on_prefill_executed(chunk, token)
-                if chunk.is_last_chunk:
-                    if (chunk.seq.handoff_prefill
-                            and chunk.seq.state
-                            == SequenceState.RUNNING):
-                        # Disagg prefill role: ship KV + retire
-                        # (unless the first token already finished
-                        # the request — then there is nothing to
-                        # decode and nothing worth shipping).
-                        self._ship_handoff(chunk.seq)
-                    outputs.append(self._delta(
-                        chunk.seq, token,
-                        lp_rows[i] if lp_rows else None))
+            self._commit_prefill_chunks(plan.prefill.chunks, sampled,
+                                        lp_rows)
             if self._tracer is not None:
                 self._step_note = {
                     "kind": "prefill",
@@ -897,50 +1064,18 @@ class LLMEngine:
         self._obs_note = ("prefill",
                           sum(len(c.chunk_tokens)
                               for c in plan.prefill.chunks))
-        return tr - td
 
-    def _execute_decode_sync(self, plan, outputs) -> float:
-        td = time.perf_counter()
-        self._note_dispatch(td)
-        token_lists, lp_lists = self.runner.run_decode(plan.decode)
-        tr = time.perf_counter()
-        self._idle_mark = tr
-        if self._tracer is not None:
-            self._tracer.phase("commit")
+    def _commit_decode(self, plan, result) -> None:
+        token_lists, lp_lists = result
         # The dispatch's result is on the host, so the expert layer's
         # counters can be read without waiting for the device.
         moe = self.runner.read_moe_stats()
         moe_note = self.metrics.on_moe_stats(moe) if moe else {}
-        now = time.time()
         spec_drafts = plan.decode.drafts
         with self._lock:
-            drafted = accepted = step_tokens = 0
-            for i, (seq, toks) in enumerate(
-                    zip(plan.decode.seqs, token_lists)):
-                if spec_drafts is not None:
-                    # Device-level acceptance (each verify row
-                    # emits accepted + 1 tokens), counted before
-                    # any host-side stop truncation so the rate
-                    # reflects the model, not request budgets.
-                    drafted += len(spec_drafts[i])
-                    accepted += len(toks) - 1
-                    seq.spec_drafted_total += len(spec_drafts[i])
-                    seq.spec_accepted_total += max(0, len(toks) - 1)
-                emitted = 0
-                for k, tok in enumerate(toks):
-                    if seq.state != SequenceState.RUNNING:
-                        break  # stop hit mid-window: drop the tail
-                    self.scheduler.append_decode_token(seq, tok)
-                    emitted += 1
-                    outputs.append(self._delta(
-                        seq, tok,
-                        lp_lists[i][k] if lp_lists else None))
-                step_tokens += emitted
-                self.metrics.on_decode_tokens(seq, emitted, now)
-                if spec_drafts is not None:
-                    self.scheduler.on_spec_executed(seq)
-            if spec_drafts is not None:
-                self.metrics.on_spec_step(drafted, accepted)
+            drafted, accepted, step_tokens, slow_rows = (
+                self._commit_decode_rows(plan.decode.seqs, token_lists,
+                                         lp_lists, spec_drafts))
             module_note = {}
             if moe and "drafts" in moe:
                 # A burst whose draft module proposed inside it: the
@@ -960,85 +1095,47 @@ class LLMEngine:
                     "attn_pages": self.runner.last_attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
+                    "commit_rows_slow": slow_rows,
                     **module_note,
                     **{k: round(v, 3) for k, v in moe_note.items()},
                 }
         self._obs_note = ("spec" if spec_drafts is not None
                           else "decode", step_tokens)
-        return tr - td
 
-    def _execute_unified(self, plan, outputs) -> float:
+    def _commit_unified(self, plan, result) -> None:
         """One unified ragged step (docs/unified_step.md): decode/
         draft rows and prefill chunk rows commit out of a single
         dispatch — decode rows through the spec-verify contract
         (1..span tokens each), prefill chunks through the ordinary
         chunked-prefill commit path, handoff shipping included."""
-        td = time.perf_counter()
-        self._note_dispatch(td)
-        (token_lists, lp_lists, prefill_toks,
-         prefill_lps) = self.runner.run_unified(plan)
-        tr = time.perf_counter()
-        self._idle_mark = tr
-        if self._tracer is not None:
-            self._tracer.phase("commit")
-        now = time.time()
+        token_lists, lp_lists, prefill_toks, prefill_lps = result
         seqs = plan.decode.seqs[: self.runner.decode_width]
         chunks = plan.prefill.chunks[: self.runner.prefill_width]
-        spec_drafts = plan.decode.drafts
+        pad_rows = self.runner.last_unified_rows - len(chunks) - len(seqs)
         self.metrics.on_ragged_step(
             prefill_rows=len(chunks), decode_rows=len(seqs),
-            pad_rows=(self.runner.last_unified_rows
-                      - len(chunks) - len(seqs)))
+            pad_rows=pad_rows)
         with self._lock:
-            drafted = accepted = step_tokens = 0
-            for i, (seq, toks) in enumerate(zip(seqs, token_lists)):
-                if spec_drafts is not None:
-                    drafted += len(spec_drafts[i])
-                    accepted += len(toks) - 1
-                    seq.spec_drafted_total += len(spec_drafts[i])
-                    seq.spec_accepted_total += max(0, len(toks) - 1)
-                emitted = 0
-                for k, tok in enumerate(toks):
-                    if seq.state != SequenceState.RUNNING:
-                        break  # stop hit mid-span: drop the tail
-                    self.scheduler.append_decode_token(seq, tok)
-                    emitted += 1
-                    outputs.append(self._delta(
-                        seq, tok,
-                        lp_lists[i][k] if lp_lists else None))
-                step_tokens += emitted
-                self.metrics.on_decode_tokens(seq, emitted, now)
-                if spec_drafts is not None:
-                    self.scheduler.on_spec_executed(seq)
-            if spec_drafts is not None:
-                self.metrics.on_spec_step(drafted, accepted)
-            for i, (chunk, token) in enumerate(
-                    zip(chunks, prefill_toks)):
-                self.scheduler.on_prefill_executed(chunk, token)
-                if chunk.is_last_chunk:
-                    if (chunk.seq.handoff_prefill
-                            and chunk.seq.state
-                            == SequenceState.RUNNING):
-                        self._ship_handoff(chunk.seq)
-                    outputs.append(self._delta(
-                        chunk.seq, token,
-                        prefill_lps[i] if prefill_lps else None))
+            drafted, accepted, step_tokens, slow_rows = (
+                self._commit_decode_rows(seqs, token_lists, lp_lists,
+                                         plan.decode.drafts))
+            self._commit_prefill_chunks(chunks, prefill_toks,
+                                        prefill_lps)
             if self._tracer is not None:
                 self._step_note = {
                     "kind": "unified",
                     "prefill_rows": len(chunks),
                     "decode_rows": len(seqs),
-                    "pad_rows": (self.runner.last_unified_rows
-                                 - len(chunks) - len(seqs)),
+                    "pad_rows": pad_rows,
                     "row_bucket": self.runner.last_unified_rows,
                     "window": plan.decode.window,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
+                    "commit_rows_slow": slow_rows,
                 }
         self._obs_note = ("unified",
                           step_tokens + sum(len(c.chunk_tokens)
                                             for c in chunks))
-        return tr - td
 
     # ---- overlapped async pipeline (docs/async_pipeline.md) ---------------
 
@@ -1109,10 +1206,7 @@ class LLMEngine:
             # Prefill (and the mixed ragged step) stays synchronous:
             # each chunk's commit feeds the next chunk's plan, so
             # these run as deliberate pipeline breaks.
-            if plan.decode is not None:
-                wait_s = self._execute_unified(plan, outputs)
-            else:
-                wait_s = self._execute_prefill(plan, outputs)
+            wait_s = self._execute_now(plan, outputs)
             self._account_step(
                 host_s=(time.perf_counter() - t0) - wait_s,
                 wait_s=wait_s, ahead=False, pipeline_break=True)
@@ -1139,7 +1233,7 @@ class LLMEngine:
             # work for window-1 of its steps, so it runs synchronously
             # rather than through the depth-1 pipeline (stacking both
             # overlaps would speculate window tokens ahead).
-            wait_s = self._execute_decode_sync(plan, outputs)
+            wait_s = self._execute_now(plan, outputs)
             self._account_step(
                 host_s=(time.perf_counter() - t0) - wait_s,
                 wait_s=wait_s, ahead=False)
@@ -1175,46 +1269,12 @@ class LLMEngine:
         wait_s = time.perf_counter() - tw
         if self._tracer is not None:
             self._tracer.phase("commit")
-        now = time.time()
-        outputs: List[StepOutput] = []
-        expected = handle.expected_lens
         spec_drafts = handle.drafts if handle.is_spec else None
         with self._lock:
-            drafted = accepted = step_tokens = 0
-            for i, (seq, toks) in enumerate(
-                    zip(handle.rows, token_lists)):
-                if seq is None:  # plan-ahead masked slot
-                    continue
-                if expected is not None and (
-                        expected[i] is None
-                        or seq.total_len != expected[i]):
-                    # Stale: the verify step this row was dispatched
-                    # behind committed more than the one token the
-                    # ahead plan assumed, so this sample came from
-                    # incomplete context. Its KV write was identical
-                    # either way (token_source is always the first
-                    # committed token) — only the sample is dropped.
-                    continue
-                if spec_drafts is not None:
-                    drafted += len(spec_drafts[i])
-                    accepted += len(toks) - 1
-                    seq.spec_drafted_total += len(spec_drafts[i])
-                    seq.spec_accepted_total += max(0, len(toks) - 1)
-                emitted = 0
-                for k, tok in enumerate(toks):
-                    if seq.state != SequenceState.RUNNING:
-                        break
-                    self.scheduler.append_decode_token(seq, tok)
-                    emitted += 1
-                    outputs.append(self._delta(
-                        seq, tok,
-                        lp_lists[i][k] if lp_lists else None))
-                step_tokens += emitted
-                self.metrics.on_decode_tokens(seq, emitted, now)
-                if spec_drafts is not None:
-                    self.scheduler.on_spec_executed(seq)
-            if spec_drafts is not None:
-                self.metrics.on_spec_step(drafted, accepted)
+            drafted, accepted, step_tokens, slow_rows = (
+                self._commit_decode_rows(
+                    handle.rows, token_lists, lp_lists, spec_drafts,
+                    expected=handle.expected_lens))
             if self._tracer is not None:
                 self._step_note = {
                     "kind": "spec" if handle.is_spec else "decode",
@@ -1224,11 +1284,11 @@ class LLMEngine:
                     "attn_pages": handle.attn_pages,
                     "spec_drafted": drafted,
                     "spec_accepted": accepted,
+                    "commit_rows_slow": slow_rows,
                 }
         self._obs_note = ("spec" if handle.is_spec else "decode",
                           step_tokens)
-        self._pop_finished(outputs)
-        return outputs, wait_s
+        return self._settle(), wait_s
 
     def _pop_finished(self, outputs: List[StepOutput]) -> None:
         for out in outputs:

@@ -24,14 +24,15 @@ class Histogram:
         self.total = 0.0
         self.n = 0
 
-    def observe(self, value: float) -> None:
-        self.total += value
-        self.n += 1
+    def observe(self, value: float, count: int = 1) -> None:
+        """``count`` observations of ``value``."""
+        self.total += value * count
+        self.n += count
         for i, b in enumerate(self.buckets):
             if value <= b:
-                self.counts[i] += 1
+                self.counts[i] += count
                 return
-        self.counts[-1] += 1
+        self.counts[-1] += count
 
     def render(self, name: str) -> List[str]:
         lines = [f"# TYPE {name} histogram"]
@@ -107,6 +108,12 @@ class EngineMetrics:
         self.pipeline_steps_total = 0
         self.pipeline_ahead_steps_total = 0
         self.async_inflight_depth = 0
+        # The served loop's hand-overs (docs/async_pipeline.md, "The
+        # served loop"): how many times a turn's outputs went to the
+        # streams behind the next program's dispatch, and how many at
+        # once because no program followed.
+        self.handovers_behind_total = 0
+        self.handovers_flushed_total = 0
         # Unified ragged step (docs/unified_step.md): the last mixed
         # dispatch's row occupancy split (gauges) plus cumulative row
         # totals so scrapers can derive the pad ratio
@@ -192,6 +199,21 @@ class EngineMetrics:
         with self._lock:
             self.device_idle_seconds_total += max(0.0, gap_s)
 
+    def handover_behind_share(self) -> float:
+        """Of the hand-overs so far, the share made behind a dispatch
+        (0.0 before the first)."""
+        total = self.handovers_behind_total + self.handovers_flushed_total
+        return self.handovers_behind_total / total if total else 0.0
+
+    def on_handover(self, behind: bool) -> None:
+        """One turn's outputs were handed to the streams: behind the
+        next program's dispatch, or at once."""
+        with self._lock:
+            if behind:
+                self.handovers_behind_total += 1
+            else:
+                self.handovers_flushed_total += 1
+
     def set_inflight_depth(self, depth: int) -> None:
         with self._lock:
             self.async_inflight_depth = depth
@@ -225,8 +247,7 @@ class EngineMetrics:
             return
         dt = max(0.0, now - prev) / n_tokens
         with self._lock:
-            for _ in range(n_tokens):
-                self.itl.observe(dt)
+            self.itl.observe(dt, n_tokens)
 
     def on_finished(self, seq) -> None:
         with self._lock:
@@ -308,6 +329,14 @@ class EngineMetrics:
                 "# TYPE vllm:engine_async_inflight_depth gauge",
                 ("vllm:engine_async_inflight_depth "
                  f"{self.async_inflight_depth}"),
+                "# TYPE vllm:engine_handovers_total counter",
+                ('vllm:engine_handovers_total{order="behind"} '
+                 f"{self.handovers_behind_total}"),
+                ('vllm:engine_handovers_total{order="flushed"} '
+                 f"{self.handovers_flushed_total}"),
+                "# TYPE vllm:engine_handover_behind_share gauge",
+                ("vllm:engine_handover_behind_share "
+                 f"{self.handover_behind_share()}"),
                 "# TYPE vllm:engine_step_prefill_rows gauge",
                 ("vllm:engine_step_prefill_rows "
                  f"{self.last_prefill_rows}"),

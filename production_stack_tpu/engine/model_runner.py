@@ -24,6 +24,7 @@ from production_stack_tpu.engine.perf_observatory import (
 )
 from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
 from production_stack_tpu.engine.sequence import (
+    STOP_SET_WIDTH,
     Sequence,
     decode_budget,
     draftless,
@@ -59,13 +60,6 @@ from production_stack_tpu.parallel.mesh import (
 from production_stack_tpu.utils.log import init_logger
 
 logger = init_logger(__name__)
-
-# Fixed per-row stop-set width for the decode burst: one compiled
-# shape regardless of batch composition (a data-dependent width would
-# recompile the fused K-step program mid-serving). Requests with more
-# stop ids than this still finish correctly — the host enforces the
-# full set; the burst merely speculates a little further.
-STOP_SET_WIDTH = 16
 
 # What attention_impl='auto' serves for the unified step on a TPU
 # besides the composed prefill kernel. False: the fused ragged kernel
@@ -235,6 +229,24 @@ def prefill_shape(rows: int, longest: int, batch_size: int,
                key=lambda s: (s[0] * s[1], -s[0]))
 
 
+class HostKeys:
+    """The sampling keys of every step program, made on the host: each
+    is the ``uint32[2]`` a threefry key is, word 0 the engine seed's
+    and word 1 a counter, so one seed gives one stream of keys and no
+    two of 2**32 consecutive keys are equal. A key is a numpy payload
+    entry like every other input: making it runs no device program and
+    waits for none."""
+
+    def __init__(self, seed: int):
+        self._seed = seed & 0xFFFFFFFF
+        self._drawn = 0
+
+    def next(self) -> np.ndarray:
+        key = np.array([self._seed, self._drawn & 0xFFFFFFFF], np.uint32)
+        self._drawn += 1
+        return key
+
+
 class DecodeStepHandle:
     """One dispatched-but-unread single-step decode program.
 
@@ -347,6 +359,23 @@ class SpecStepHandle:
             token_lists.append(row_t)
             lp_lists.append(row_l)
         return token_lists, lp_lists
+
+
+class StepHandle:
+    """One dispatched-but-unread prefill step, decode burst or unified
+    step. ``result()`` is its one blocking read, parsed by the function
+    its dispatcher left; a step no row of which samples (mid-prompt
+    chunks alone) has nothing to read and waits for nothing."""
+
+    def __init__(self, runner: "ModelRunner", sampled, parse):
+        self.runner = runner
+        self.sampled = sampled
+        self._parse = parse
+
+    def result(self):
+        if self.sampled is None:
+            return self._parse(None)
+        return self._parse(self.runner.read_back(self.sampled))
 
 
 class ModelRunner:
@@ -634,7 +663,7 @@ class ModelRunner:
         self._buckets = prefill_buckets(
             config.scheduler.prefill_chunk_size
         )
-        self._rng = jax.random.PRNGKey(config.seed + 1)
+        self._keys = HostKeys(config.seed + 1)
         # Reused host staging buffers for the single-step decode
         # payload (dispatch_decode): the per-step numpy allocation
         # shower is replaced by in-place fills + ONE fused
@@ -1937,26 +1966,10 @@ class ModelRunner:
             return (out,) + lp, k_cache, v_cache
         return out, k_cache, v_cache
 
-    def _next_rng(self) -> jax.Array:
-        # The split is an eager program of its own: its own turn phase.
-        tracer = self.tracer
-        back = tracer.phase("rng") if tracer is not None else None
-        self._rng, sub = jax.random.split(self._rng)
-        if back is not None:
-            tracer.phase(back)
-        return sub
-
-    def _host_rng(self) -> np.ndarray:
-        """The next key as a numpy payload entry. The read-back waits
-        for the split program, so it is inside the one rng phase (and
-        kept out of _next_rng, which the async dispatch path calls)."""
-        tracer = self.tracer
-        back = tracer.phase("rng") if tracer is not None else None
-        self._rng, sub = jax.random.split(self._rng)
-        key = np.asarray(sub)
-        if back is not None:
-            tracer.phase(back)
-        return key
+    def _next_rng(self) -> np.ndarray:
+        """The next sampling key (HostKeys): the one source for every
+        path, single host or many."""
+        return self._keys.next()
 
     def read_back(self, sampled):
         """A step's one blocking device_get; to the turn's phases the
@@ -2349,11 +2362,11 @@ class ModelRunner:
 
     # ---- prefill ----------------------------------------------------------
 
-    def run_sp_prefill(self, plan: PrefillPlan):
+    def dispatch_sp_prefill(self, plan: PrefillPlan) -> StepHandle:
         """Context-parallel whole-prompt prefill: ONE dispatch covers
         the entire prompt with the sequence sharded over 'sp'
-        (parallel/context_serving.py). Returns the sampled first
-        token."""
+        (parallel/context_serving.py). The handle's result is the
+        sampled first token."""
         if self.bridge is not None:
             raise NotImplementedError(
                 "context-parallel prefill over the multihost step "
@@ -2404,12 +2417,15 @@ class ModelRunner:
             penalties, seeding, bias, suppress, fsm,
             want_logprobs=want_lp,
         )
-        host = self.read_back(sampled)
-        if want_lp:
-            toks, slp, tids, tlps = host
-            return ([int(toks[0])],
-                    [self._lp_entry(seq, slp[0], tids[0], tlps[0])])
-        return [int(host[0])], None
+
+        def parse(host):
+            if want_lp:
+                toks, slp, tids, tlps = host
+                return ([int(toks[0])],
+                        [self._lp_entry(seq, slp[0], tids[0], tlps[0])])
+            return [int(host[0])], None
+
+        return StepHandle(self, sampled, parse)
 
     def _other_width_payloads(self, b: int, t: int) -> List[dict]:
         """The top bucket has two widths and a smoke request or a
@@ -2448,14 +2464,20 @@ class ModelRunner:
 
     def run_prefill(self, plan: PrefillPlan
                     ) -> Tuple[List[Optional[int]], Optional[list]]:
-        """Execute one batched prefill step (the next chunk of up to
-        ``prefill_batch_size`` distinct sequences, rows padded to the
-        fixed width). Returns (tokens, logprobs): one sampled token
+        """One prefill step run to its end: dispatch + immediate
+        readback."""
+        return self.dispatch_prefill(plan).result()
+
+    def dispatch_prefill(self, plan: PrefillPlan) -> StepHandle:
+        """Build and dispatch one batched prefill step (the next chunk
+        of up to ``prefill_batch_size`` distinct sequences, rows padded
+        to the fixed width) with no blocking host read on the path.
+        The handle's result is (tokens, logprobs): one sampled token
         per chunk — None for rows whose prompt is not yet fully
         prefilled — and, when any sampling row requested logprobs, a
         parallel list of per-row logprob entries (else None)."""
         if plan.sp:
-            return self.run_sp_prefill(plan)
+            return self.dispatch_sp_prefill(plan)
         if self.tracer is not None:
             self.tracer.phase("build")
         chunks = plan.chunks
@@ -2501,7 +2523,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": self._host_rng(),
+            "rng": self._next_rng(),
         }
         if self._hybrid:
             payload["state_slots"] = self._state_slot_rows(
@@ -2547,14 +2569,15 @@ class ModelRunner:
             loading.join()
             # Through _dispatch, so a multihost worker follows.
             self._dispatch(1, t, other)
-        host = None
-        out: List[Optional[int]] = []
-        lps: List[Optional[tuple]] = []
-        for i, chunk in enumerate(chunks):
-            if chunk.is_last_chunk:
-                if host is None:
-                    host = self.read_back(sampled)
-                if want_lp:
+
+        def parse(host):
+            out: List[Optional[int]] = []
+            lps: List[Optional[tuple]] = []
+            for i, chunk in enumerate(chunks):
+                if not chunk.is_last_chunk:
+                    out.append(None)
+                    lps.append(None)
+                elif want_lp:
                     out.append(int(host[0][i]))
                     lps.append(
                         self._lp_entry(chunk.seq, host[1][i],
@@ -2563,10 +2586,10 @@ class ModelRunner:
                 else:
                     out.append(int(host[i]))
                     lps.append(None)
-            else:
-                out.append(None)
-                lps.append(None)
-        return out, (lps if want_lp else None)
+            return out, (lps if want_lp else None)
+
+        sampling = any(c.is_last_chunk for c in chunks)
+        return StepHandle(self, sampled if sampling else None, parse)
 
     # ---- decode -----------------------------------------------------------
 
@@ -2648,7 +2671,6 @@ class ModelRunner:
                     if seq is not None else None for seq in rows)
         cached = self._decode_static_cache
         reuse = cached is not None and cached[0] == sig
-        stochastic = False
         for i, seq in enumerate(rows):
             if seq is None:
                 continue
@@ -2658,11 +2680,9 @@ class ModelRunner:
                                    else seq.prompt_token_ids[-1])
             st["positions"][i, 0] = seq.total_len - 1 + off
             st["kv_lens"][i] = seq.total_len + off
-            sp = seq.sampling
-            if sp.temperature > 0:
-                stochastic = True
             if reuse:
                 continue
+            sp = seq.sampling
             st["valid"][i, 0] = True
             st["temperature"][i] = sp.temperature
             st["top_p"][i] = sp.top_p
@@ -2688,23 +2708,19 @@ class ModelRunner:
         # backend device_put of a numpy array may be ZERO-copy, and
         # the cached device arrays must not alias a staging buffer
         # that later steps zero-reset and refill.
+        # The key rides the same transfer.
         devs = jax.device_put(tuple(
-            st[n] if n in dynamic else st[n].copy() for n in names))
-        payload = dict(zip(names, devs))
+            st[n] if n in dynamic else st[n].copy() for n in names)
+            + (self._next_rng(),))
+        payload = dict(zip(names + ("rng",), devs))
         if reuse:
             payload.update(cached[1])
         else:
             self._decode_static_cache = (sig, {
                 n: payload[n] for n in payload
-                if n not in ("tokens", "positions", "kv_lens")})
+                if n not in ("tokens", "positions", "kv_lens", "rng")})
         if token_source is not None:
             payload["tokens"] = token_source
-        # The rng key stays a device array (no host readback; the
-        # multihost numpy conversion is unreachable here). An
-        # all-greedy batch never consumes the key (temperature 0
-        # short-circuits sampling), so skip the per-step split — a
-        # real eager dispatch — and pass the stream head unadvanced.
-        payload["rng"] = self._next_rng() if stochastic else self._rng
         if not ahead:
             # Per-row optional inputs (penalties/seed/bias/suppress/
             # guided) for the sync single-step path. Plan-ahead
@@ -2726,23 +2742,36 @@ class ModelRunner:
 
     def run_decode(self, plan: DecodePlan
                    ) -> Tuple[List[List[int]], Optional[list]]:
-        """One decode dispatch over all running sequences (padded
-        batch); returns (token_lists, logprob_lists) — logprob_lists
-        is None unless a row requested logprobs. With a multi-step
-        window the burst program evaluates per-row budgets and stop
-        sets on device, so one dispatch + one device_get covers up to
-        ``window`` tokens per row even when rows finish mid-burst."""
+        """One decode program run to its end: dispatch + immediate
+        readback."""
+        return self.dispatch_decode_plan(plan).result()
+
+    def dispatch_decode_plan(self, plan: DecodePlan):
+        """Dispatch the one program ``plan`` asks for — a verify step,
+        a single step or a burst — and return its handle, whose result
+        is (token_lists, logprob_lists); logprob_lists is None unless a
+        row requested logprobs."""
         if plan.drafts is not None:
-            return self._run_spec_decode(plan)
-        seqs = plan.seqs[: self.decode_width]
-        b = self.decode_width
-        window = max(1, plan.window)
-        if window == 1 and self.bridge is None:
+            return self.dispatch_spec(plan)
+        if max(1, plan.window) == 1 and self.bridge is None:
             # Single-host single-step decode rides the async
             # pipeline's dispatch path (staged inputs, one fused
             # transfer, one fused device_get) even in sync mode, so
             # sync-vs-async parity is the same code path.
-            return self.dispatch_decode(seqs).result()
+            return self.dispatch_decode(plan.seqs[: self.decode_width])
+        return self.dispatch_burst(plan)
+
+    def dispatch_burst(self, plan: DecodePlan) -> StepHandle:
+        """Build and dispatch one decode burst over all running
+        sequences (padded batch) with no blocking host read on the
+        path. The burst program evaluates per-row budgets and stop
+        sets on device, so one dispatch + one device_get covers up to
+        ``window`` tokens per row even when rows finish mid-burst (a
+        slot reads -1 where a row committed nothing). Over the
+        multihost bridge a window of 1 comes this way too."""
+        seqs = plan.seqs[: self.decode_width]
+        b = self.decode_width
+        window = max(1, plan.window)
         stop_w = STOP_SET_WIDTH
         if self.tracer is not None:
             self.tracer.phase("build")
@@ -2786,7 +2815,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": self._host_rng(),
+            "rng": self._next_rng(),
         }
         if self._hybrid:
             payload["state_slots"] = self._state_slot_rows(seqs, b)
@@ -2823,35 +2852,39 @@ class ModelRunner:
         self._note_attn_pages(positions if self._deferred and window > 1
                               else kv_lens)
         sampled = self._dispatch(2, window, payload)
-        host = self.read_back(sampled)
-        if not want_lp:
+
+        def parse(host):
+            if not want_lp:
+                if window == 1:
+                    return [[int(host[i])]
+                            for i in range(len(seqs))], None
+                # A burst's slots: ``window``, or twice that where it
+                # drafts; -1 where a row committed nothing.
+                return [[int(tok) for tok in host[:, i] if tok >= 0]
+                        for i in range(len(seqs))], None
+            toks, slp, tids, tlps = host
             if window == 1:
-                return [[int(host[i])] for i in range(len(seqs))], None
-            # A burst's slots: ``window``, or twice that where it
-            # drafts; -1 where a row committed nothing.
-            return [[int(tok) for tok in host[:, i] if tok >= 0]
-                    for i in range(len(seqs))], None
-        toks, slp, tids, tlps = host
-        if window == 1:
-            return ([[int(toks[i])] for i in range(len(seqs))],
-                    [[self._lp_entry(seqs[i], slp[i], tids[i],
-                                     tlps[i])
-                      if seqs[i].sampling.logprobs else None]
-                     for i in range(len(seqs))])
-        token_lists, lp_lists = [], []
-        for i, seq in enumerate(seqs):
-            row_t, row_l = [], []
-            for k in range(toks.shape[0]):
-                if toks[k, i] < 0:
-                    continue
-                row_t.append(int(toks[k, i]))
-                row_l.append(
-                    self._lp_entry(seq, slp[k, i], tids[k, i],
-                                   tlps[k, i])
-                    if seq.sampling.logprobs else None)
-            token_lists.append(row_t)
-            lp_lists.append(row_l)
-        return token_lists, lp_lists
+                return ([[int(toks[i])] for i in range(len(seqs))],
+                        [[self._lp_entry(seqs[i], slp[i], tids[i],
+                                         tlps[i])
+                          if seqs[i].sampling.logprobs else None]
+                         for i in range(len(seqs))])
+            token_lists, lp_lists = [], []
+            for i, seq in enumerate(seqs):
+                row_t, row_l = [], []
+                for k in range(toks.shape[0]):
+                    if toks[k, i] < 0:
+                        continue
+                    row_t.append(int(toks[k, i]))
+                    row_l.append(
+                        self._lp_entry(seq, slp[k, i], tids[k, i],
+                                       tlps[k, i])
+                        if seq.sampling.logprobs else None)
+                token_lists.append(row_t)
+                lp_lists.append(row_l)
+            return token_lists, lp_lists
+
+        return StepHandle(self, sampled, parse)
 
     def dispatch_spec(self, plan: DecodePlan) -> SpecStepHandle:
         """Build and dispatch ONE speculative verify step with no
@@ -2914,7 +2947,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": self._host_rng(),
+            "rng": self._next_rng(),
             "drafts": drafts,
             "draft_lens": draft_lens,
         }
@@ -2934,16 +2967,17 @@ class ModelRunner:
             [list(plan.drafts[i]) for i in range(len(seqs))],
             sampled, want_lp)
 
-    def _run_spec_decode(self, plan: DecodePlan
-                         ) -> Tuple[List[List[int]], Optional[list]]:
-        """Synchronous verify step: dispatch + immediate readback."""
-        return self.dispatch_spec(plan).result()
-
     # ---- unified ragged step (docs/unified_step.md) -----------------------
 
     def run_unified(self, plan):
-        """Execute one genuinely mixed step: decode/draft rows and
-        prefill chunk rows in ONE fixed-shape [R, W] ragged program.
+        """One unified step run to its end: dispatch + immediate
+        readback."""
+        return self.dispatch_unified(plan).result()
+
+    def dispatch_unified(self, plan) -> StepHandle:
+        """Build and dispatch one genuinely mixed step: decode/draft
+        rows and prefill chunk rows in ONE fixed-shape [R, W] ragged
+        program, with no blocking host read on the path.
 
         Row layout (the per-row descriptor is the
         kv_lens/last_index/draft_lens triple — docs/unified_step.md):
@@ -2952,7 +2986,8 @@ class ModelRunner:
         (aligned with plan.prefill.chunks), pads only at the tail.
         R snaps to the closed ``unified_row_buckets`` lattice so the
         compiled shape depends on occupancy only through the (row
-        bucket, W bucket) pair, never on batch composition. Returns
+        bucket, W bucket) pair, never on batch composition. The
+        handle's result is
         (decode_token_lists, decode_lp_lists, prefill_tokens,
         prefill_lp_rows): decode rows commit 1..span tokens (the
         verify contract), prefill rows one sampled token for last
@@ -3037,7 +3072,7 @@ class ModelRunner:
             "temperature": temperature,
             "top_p": top_p,
             "top_k": top_k,
-            "rng": self._host_rng(),
+            "rng": self._next_rng(),
         }
         if lora_ids is not None:
             payload["lora_ids"] = lora_ids
@@ -3048,39 +3083,43 @@ class ModelRunner:
             payload["want_logprobs"] = True
 
         sampled = self._dispatch(KIND_UNIFIED, w, payload)
-        host = self.read_back(sampled)
-        if want_lp:
-            toks, slp, tids, tlps = host
-        else:
-            toks = host
-        token_lists, lp_lists = [], []
-        for i, seq in enumerate(seqs):
-            row_t, row_l = [], []
-            for j in range(s):
-                if toks[i, j] < 0:
-                    break
-                row_t.append(int(toks[i, j]))
-                if want_lp:
-                    row_l.append(
-                        self._lp_entry(seq, slp[i, j], tids[i, j],
-                                       tlps[i, j])
-                        if seq.sampling.logprobs else None)
-            token_lists.append(row_t)
-            lp_lists.append(row_l)
-        prefill_out, prefill_lps = [], []
-        for j, chunk in enumerate(chunks):
-            i = off + j
-            if not chunk.is_last_chunk:
-                prefill_out.append(None)
-                prefill_lps.append(None)
-                continue
-            prefill_out.append(int(toks[i, 0]))
-            prefill_lps.append(
-                self._lp_entry(chunk.seq, slp[i, 0], tids[i, 0],
-                               tlps[i, 0])
-                if want_lp and chunk.seq.sampling.logprobs else None)
-        return (token_lists, lp_lists if want_lp else None,
-                prefill_out, prefill_lps if want_lp else None)
+
+        def parse(host):
+            if want_lp:
+                toks, slp, tids, tlps = host
+            else:
+                toks = host
+            token_lists, lp_lists = [], []
+            for i, seq in enumerate(seqs):
+                row_t, row_l = [], []
+                for j in range(s):
+                    if toks[i, j] < 0:
+                        break
+                    row_t.append(int(toks[i, j]))
+                    if want_lp:
+                        row_l.append(
+                            self._lp_entry(seq, slp[i, j], tids[i, j],
+                                           tlps[i, j])
+                            if seq.sampling.logprobs else None)
+                token_lists.append(row_t)
+                lp_lists.append(row_l)
+            prefill_out, prefill_lps = [], []
+            for j, chunk in enumerate(chunks):
+                i = off + j
+                if not chunk.is_last_chunk:
+                    prefill_out.append(None)
+                    prefill_lps.append(None)
+                    continue
+                prefill_out.append(int(toks[i, 0]))
+                prefill_lps.append(
+                    self._lp_entry(chunk.seq, slp[i, 0], tids[i, 0],
+                                   tlps[i, 0])
+                    if want_lp and chunk.seq.sampling.logprobs
+                    else None)
+            return (token_lists, lp_lists if want_lp else None,
+                    prefill_out, prefill_lps if want_lp else None)
+
+        return StepHandle(self, sampled, parse)
 
     # ---- page-granular IO (offload tiers) ---------------------------------
 

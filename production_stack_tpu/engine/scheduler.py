@@ -30,6 +30,7 @@ from production_stack_tpu.engine.kv_cache import (
     PagedCacheManager,
 )
 from production_stack_tpu.engine.sequence import (
+    STOP_SET_WIDTH,
     FinishReason,
     Sequence,
     SequenceState,
@@ -875,6 +876,54 @@ class Scheduler:
             return False
         self._append_token(seq, token)
         return seq.state == SequenceState.RUNNING
+
+    def commit_decode_tokens(self, seq: Sequence, tokens: List[int]
+                             ) -> tuple:
+        """Commit what one decode program gave a running row; returns
+        (how many of ``tokens`` were kept, whether the row was walked
+        token by token). The end state is the one append_decode_token
+        reaches token by token: the kept tokens appended, the finish
+        decided, pages and state slot freed, ``running`` updated.
+
+        A row is appended in one go and its finish decided once,
+        from the place of its first stop id and its budgets, unless
+        something of it must be looked at every token, which the row
+        itself says: a guided automaton to advance, a ``min_tokens``
+        not yet passed (a stop id under the minimum does not end the
+        row), a stop set wider than the device's (the burst ran past
+        what the host ends at), logprobs (an entry a token)."""
+        sp = seq.sampling
+        if (seq.fsm_state is not None or sp.logprobs
+                or sp.min_tokens > seq.num_generated
+                or (not sp.ignore_eos
+                    and len(sp.stop_token_ids) > STOP_SET_WIDTH)):
+            kept = 0
+            for token in tokens:
+                if seq.state != SequenceState.RUNNING:
+                    break  # stop hit mid-window: drop the tail
+                self.append_decode_token(seq, token)
+                kept += 1
+            return kept, True
+        if not tokens or seq.state != SequenceState.RUNNING:
+            return 0, False
+        # The k-th token ends the row at the first k at which
+        # _append_token would: a stop id there, else a budget met
+        # (the first token is appended whatever the budget reads).
+        at_length = max(1, decode_budget(seq, self.config.max_model_len))
+        at_stop = len(tokens) + 1
+        if not sp.ignore_eos:
+            for stop in sp.stop_token_ids:
+                if stop in tokens:
+                    at_stop = min(at_stop, tokens.index(stop) + 1)
+        kept = min(len(tokens), at_stop, at_length)
+        seq.output_token_ids.extend(tokens[:kept])
+        if kept == at_stop:
+            self._finish(seq, FinishReason.STOP)
+            self.running.remove(seq)
+        elif kept == at_length:
+            self._finish(seq, FinishReason.LENGTH)
+            self.running.remove(seq)
+        return kept, False
 
     def _append_token(self, seq: Sequence, token: int) -> None:
         seq.output_token_ids.append(token)

@@ -8,6 +8,14 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 
+# Fixed per-row stop-set width for the decode burst: one compiled
+# shape regardless of batch composition (a data-dependent width would
+# recompile the fused K-step program mid-serving). Requests with more
+# stop ids than this still finish correctly — the host enforces the
+# full set; the burst merely speculates a little further.
+STOP_SET_WIDTH = 16
+
+
 @dataclass
 class SamplingParams:
     max_tokens: int = 128

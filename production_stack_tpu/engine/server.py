@@ -156,13 +156,14 @@ class AsyncEngine:
         self._started.wait()
         # Turn phases (engine/tracing.py TURN_PHASES): with a tracer
         # this thread is always in one named phase, and each pass that
-        # accounts a step closes one turn record after its outputs
-        # have been handed over.
+        # accounts a step closes one turn record.
         tracer = self.engine.tracer
         obs = getattr(self.engine.runner, "observatory", None)
         if tracer is not None:
             tracer.start_turns(
                 compiles=obs.compile_events_total() if obs else 0)
+        # Outputs handed over since the last record closed.
+        emitted = 0
         while True:
             # Drain submissions (non-blocking when engine has work).
             block = not self.engine.has_work()
@@ -225,16 +226,25 @@ class AsyncEngine:
                     logger.exception("autotune tick failed")
             if not self.engine.has_work():
                 continue
+            # The turn dispatches first (docs/async_pipeline.md, "The
+            # served loop"): plan, build and enqueue the next program
+            # from the committed state; behind it, while the device
+            # runs, what the turn before still owes its streams; then
+            # wait, parse and commit what the next plan reads.
             self._step_started = time.time()
             try:
-                outputs = self.engine.step()
+                enqueued = self.engine.begin_step()
+                handed = self._hand_over_owed(behind=enqueued is not None)
+                if enqueued is not None:
+                    self.engine.finish_step(enqueued)
             except Exception as e:
                 logger.exception("Engine step failed: %s", e)
                 self.consecutive_step_failures += 1
                 if tracer is not None:
                     tracer.phase("other")
                 # The sequences that step touched end here with a
-                # terminal 'abort' instead of being retried forever.
+                # terminal 'abort' instead of being retried forever,
+                # behind whatever earlier turns still owed them.
                 self._hand_over(self.engine.abort_after_step_failure())
                 # Interruptible backoff: a new submission or abort
                 # wakes the loop immediately instead of serving out
@@ -245,7 +255,7 @@ class AsyncEngine:
             finally:
                 self._step_started = None
             self.consecutive_step_failures = 0
-            if not outputs:
+            if enqueued is None and not handed:
                 # Planner produced no executable work (e.g. transient
                 # KV-cache starvation, or an async dispatch that owes
                 # nothing yet): don't busy-spin, but let new arrivals
@@ -254,14 +264,29 @@ class AsyncEngine:
                     tracer.phase("other")
                 self._wakeup.wait(0.002)
                 self._wakeup.clear()
+            if not self.engine.more_to_run():
+                # Nothing is owed to an idle engine: where no program
+                # follows, the turn's own outputs go now.
+                handed += self._hand_over_owed(behind=False)
+            emitted += handed
+            if tracer is not None and tracer.end_turn(
+                    emitted=emitted,
+                    compiles=obs.compile_events_total() if obs else 0):
+                emitted = 0
+
+    def _hand_over_owed(self, behind: bool) -> int:
+        """The deferred half of the engine's commits so far, made and
+        handed over, ``behind`` the dispatch of the next program or at
+        once; returns how many outputs that was."""
+        outputs = self.engine.take_owed()
+        if outputs:
+            tracer = self.engine.tracer
             if tracer is None:
                 self._hand_over(outputs)
-                continue
-            tracer.phase("emit")
-            self._hand_over(outputs, tracer.handoff_stamp())
-            tracer.end_turn(
-                emitted=len(outputs),
-                compiles=obs.compile_events_total() if obs else 0)
+            else:
+                tracer.phase("emit")
+                self._hand_over(outputs, tracer.handoff_stamp(behind))
+        return len(outputs)
 
     def _hand_over(self, outputs, stamp=None) -> None:
         """The loop thread's side: one cross-thread call for all of
@@ -3281,7 +3306,7 @@ def main(argv=None) -> None:
         if args.context_parallel_size > 1:
             # Fail at startup, not on the first long prompt: sp
             # prefill payloads are not mirrored over the step bridge
-            # yet (model_runner.run_sp_prefill), and a mid-serving
+            # yet (model_runner.dispatch_sp_prefill), and a mid-serving
             # NotImplementedError would wedge the worker hosts.
             raise ValueError(
                 "--context-parallel-size > 1 is not yet supported "

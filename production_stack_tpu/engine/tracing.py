@@ -22,9 +22,15 @@ Under the server loop a step record is one *turn* of that loop: the
 loop thread is always in exactly one of ``TURN_PHASES``, the record
 carries the milliseconds spent in each between ``t_start`` and
 ``t_end``, and turns are contiguous, so nothing the loop thread does
-falls between two records. Beside each phase's wall the record has the
-thread's CPU clock over it (``cpu``): a phase's wall less its CPU is
-the time the thread was kept off a core (the clock is the host's: where
+falls between two records. A turn runs from the end of one program's
+commit to the end of the next's, and the loop dispatches first
+(docs/async_pipeline.md, "The served loop"): the outputs of the turn
+before are made and handed over behind this turn's dispatch, so they
+are in this turn's ``commit`` and ``emit``, in its ``emitted`` and its
+``handoff_ms``, and ``handover`` says so (``behind``; ``flushed`` where
+they went at once because no program followed). Beside each phase's
+wall the record has the thread's CPU clock over it (``cpu``): a
+phase's wall less its CPU is the time the thread was kept off a core (the clock is the host's: where
 it advances in ticks of 10 ms, as on the v5e hosts, read sums over many
 turns, not one record). The interpreter has a second
 thread, the event loop, which turns the tokens into frames and writes
@@ -111,13 +117,12 @@ TURN_PHASES = (
     "idle",      # parked: no request, nothing in flight
     "admit",     # draining the submit queue into the scheduler
     "plan",      # scheduler.plan_step / plan_ahead, lock wait included
-    "build",     # host arrays for the program
-    "rng",       # splitting the sampling key: a small program of its own
+    "build",     # host arrays for the program, its sampling key among them
     "dispatch",  # until the jitted call returns
     "wait",      # blocked on the device's results
     "parse",     # device arrays to Python lists
-    "commit",    # scheduler and sequence updates under the lock
-    "emit",      # one call that hands the turn's outputs to the event loop
+    "commit",    # scheduler and sequence updates; the last turn's outputs
+    "emit",      # one call that hands a turn's outputs to the event loop
     "other",     # autotuner tick, back-off waits, the rest
 )
 
@@ -345,7 +350,18 @@ class EngineTracer:
         # it to its thread).
         self.front = FrontClock()
         self._turn_step = 0
+        # The record of the turn under way: made when the turn opens,
+        # so that a hand-over inside it has where to stamp, and
+        # ``_open`` once a step has been accounted into it.
+        self._record: Dict[str, Any] = {}
         self._open: Optional[Dict[str, Any]] = None
+        self._closed_kind: Optional[str] = None
+        # How the outputs of the turn before went ("behind" this
+        # turn's dispatch or "flushed" at once), for this turn's
+        # record, and how this turn's own went where they are gone
+        # already, for the next.
+        self._handover: Optional[str] = None
+        self._own_handover: Optional[str] = None
         self._marks: List[Any] = []
         self._compiles_seen = 0
         # perf_counter() + _unix0 is the records' clock: t_end - t_start
@@ -412,7 +428,12 @@ class EngineTracer:
         # step raised after its accounting) goes in as it is.
         if self._open is not None:
             self._steps.append(self._open)
-        self._open = record
+            self._record = {}
+        # Into the turn's record, beside what a hand-over has stamped
+        # there already (the event loop may be stamping it now: both
+        # sides only store keys of their own).
+        self._record.update(record)
+        self._open = self._record
 
     # -- turns of the server loop -------------------------------------------
 
@@ -425,6 +446,7 @@ class EngineTracer:
     def _open_turn(self) -> None:
         """A turn starts where the last ended, in ``other``."""
         self._turn_step = self._next_step
+        self._record = {"step": self._next_step}
         if self.annotate is not None:
             self._mark("turn")
             self._mark("other")
@@ -466,7 +488,10 @@ class EngineTracer:
         ``t_start``, ``t_end``, ``phases`` (ms, summing to the wall),
         ``cpu`` (the thread's CPU clock over the same phases),
         ``front`` (what the event loop's thread did meanwhile, where
-        one is bound), ``emitted`` and, where the compile ledger's
+        one is bound), ``emitted`` (the outputs handed over inside it),
+        ``handover`` (how the outputs of the turn before went:
+        ``behind`` this turn's dispatch or ``flushed`` at once; absent
+        where that turn had none) and, where the compile ledger's
         total ``compiles`` grew during it, ``compiles``; it goes into
         the ring and is returned. A turn without a step (nothing
         planned) goes on."""
@@ -474,6 +499,10 @@ class EngineTracer:
         if record is None:
             return None
         self._open = None
+        self._closed_kind = record.get("kind")
+        if self._handover is not None:
+            record["handover"] = self._handover
+        self._handover, self._own_handover = self._own_handover, None
         self._close_phase("other")
         while self._marks:
             self._marks.pop().__exit__(None, None, None)
@@ -499,7 +528,7 @@ class EngineTracer:
         # Parked without work is no part of a stall, nor its name.
         idle = phases.pop("idle", 0.0)
         wall = self._turn_t - t_start - idle
-        median = self._slow(self._walls, record, wall)
+        median = self._slow(self._walls, record.get("kind"), wall)
         if median is not None:
             name = max(phases, key=phases.get)
             logger.warning(
@@ -510,41 +539,57 @@ class EngineTracer:
         self._open_turn()
         return record
 
-    def handoff_stamp(self) -> Optional[Callable[[], None]]:
+    def handoff_stamp(self, behind: Optional[bool] = None
+                      ) -> Optional[Callable[[], None]]:
         """For the loop thread, as it enters ``emit``: what the event
-        loop is to call once it has delivered the turn's outputs, or
-        None where the turn accounted no step. The record is the one
-        end_turn() closes a moment later."""
-        if self._open is None:
+        loop is to call once it has delivered the outputs, or None
+        where no server loop keeps turns. The record is the one of the
+        turn under way, which end_turn() closes later; the outputs are
+        those of the step it has accounted, else of the turn before,
+        and ``behind`` says whether they go behind a dispatch or at
+        once (None: outputs of no turn, a refusal's)."""
+        if self._phase is None:
             return None
-        return functools.partial(self.on_handoff, self._open,
-                                 time.perf_counter())
+        own = self._open is not None
+        kind = self._open.get("kind") if own else self._closed_kind
+        if behind is not None:
+            order = "behind" if behind else "flushed"
+            if own:
+                self._own_handover = order
+            else:
+                self._handover = order
+        return functools.partial(self.on_handoff, self._record,
+                                 time.perf_counter(), kind)
 
-    def on_handoff(self, record: Dict[str, Any],
-                   emit_start: float) -> None:
+    def on_handoff(self, record: Dict[str, Any], emit_start: float,
+                   kind: Optional[str] = None) -> None:
         """Runs on the event loop as the last act of the call that
-        delivered the turn's outputs: ``handoff_ms`` is how long after
+        delivered a turn's outputs: ``handoff_ms`` is how long after
         the loop thread entered ``emit`` the event loop had put the
         last of them on its stream. The streams' consumers run after
-        it and are not in it. The loop thread may still be closing the
-        record: both sides only store keys of their own."""
+        it and are not in it. The loop thread may still be filling or
+        closing the record: both sides only store keys of their own.
+        ``kind`` is the kind of the turn whose outputs they are (the
+        record's own where not given), which is whose history a late
+        one is judged against."""
         taken = time.perf_counter() - emit_start
         record["handoff_ms"] = round(taken * 1e3, 3)
-        median = self._slow(self._handoffs, record, taken)
+        if kind is None:
+            kind = record.get("kind")
+        median = self._slow(self._handoffs, kind, taken)
         if median is not None:
             logger.warning(
                 "slow turn: %s handoff_ms %.1f against a median of "
                 "%.1f ms, the event loop was late: %s",
-                record.get("kind"), taken * 1e3, median * 1e3,
-                json.dumps(record))
+                kind, taken * 1e3, median * 1e3, json.dumps(record))
 
     @staticmethod
-    def _slow(history: Dict[str, deque], record: Dict[str, Any],
+    def _slow(history: Dict[str, deque], kind: Optional[str],
               seconds: float) -> Optional[float]:
         """The median ``seconds`` is slow against, else None; either
         way ``seconds`` joins its kind's history."""
         past = history.setdefault(
-            str(record.get("kind")), deque(maxlen=SLOW_TURN_HISTORY))
+            str(kind), deque(maxlen=SLOW_TURN_HISTORY))
         median = None
         if len(past) >= SLOW_TURN_MIN_HISTORY:
             median = statistics.median(past)

@@ -359,7 +359,7 @@ class MultihostStepBridge:
             template["draft_lens"] = np.zeros((b,), np.int32)
         if kind == KIND_DECODE and t > 1:
             # Decode bursts carry per-row lifecycle state
-            # (model_runner.run_decode); STOP_SET_WIDTH is fixed so
+            # (model_runner.dispatch_burst); STOP_SET_WIDTH is fixed so
             # this shape is derivable from the (kind, t) header alone.
             from production_stack_tpu.engine.model_runner import (
                 STOP_SET_WIDTH,

@@ -156,6 +156,10 @@ _ROUTER_UNSCRAPED = frozenset({
     # front the wall?"): an operator's rate, not a routing signal.
     "vllm:engine_front_cpu_seconds_total",
     "vllm:engine_loop_offcpu_seconds_total",
+    # The served loop's hand-overs, behind a dispatch or at once
+    # (docs/async_pipeline.md, "The served loop"): likewise.
+    "vllm:engine_handovers_total",
+    "vllm:engine_handover_behind_share",
 })
 
 

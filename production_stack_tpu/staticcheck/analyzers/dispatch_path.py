@@ -1,14 +1,19 @@
-"""Rule ``host-read``: no blocking host reads on the decode dispatch
-path.
+"""Rule ``host-read``: no blocking host reads on the dispatch path.
 
 The overlapped async pipeline (docs/async_pipeline.md) only hides
 host work if ``ModelRunner.dispatch_decode`` and everything it calls
 stays purely dispatching: building a payload, one fused host->device
-transfer, launching the jitted step. A single ``np.asarray(device
-array)``, ``jax.device_get`` or ``.block_until_ready()`` anywhere on
-that path silently re-serializes the pipeline — the step "works" but
-the overlap is gone, which no functional test notices. Inside the
-DISPATCH_PATH functions of engine/model_runner.py this flags:
+transfer, launching the jitted step. The served loop leans on the same
+property of every step program's enqueue side (``dispatch_burst``,
+``dispatch_prefill``, ``dispatch_spec``, ``dispatch_unified``): the
+turn before's outputs are made and handed over behind the dispatch,
+so a read-back that creeps in between two programs (as the sampling
+key's did until PR 48) leaves the device idle for its round trip. A
+single ``np.asarray(device array)``, ``jax.device_get`` or
+``.block_until_ready()`` anywhere on that path silently re-serializes
+the pipeline — the step "works" but the overlap is gone, which no
+functional test notices. Inside the DISPATCH_PATH functions of
+engine/model_runner.py this flags:
 
 - ``np.asarray(...)`` / ``np.array(...)`` — *unless* the argument is
   provably host-origin (see below): converting a Python list is a
@@ -68,11 +73,24 @@ from production_stack_tpu.staticcheck import (
 
 RUNNER = "production_stack_tpu/engine/model_runner.py"
 
-# Every function the async dispatch path runs through. run_decode /
-# result() are NOT here: they are the sync completion side and their
-# device_get is the one intended blocking read.
+# Every function a dispatch runs through: the async pipeline's
+# (dispatch_decode) and, since the served loop hands a turn's outputs
+# over behind the next program's dispatch (docs/async_pipeline.md,
+# "The served loop"), the enqueue side of every other step program.
+# result() / read_back() are NOT here: they are the completion side
+# and their device_get is the one intended blocking read.
 DISPATCH_PATH = {
     "dispatch_decode",
+    "dispatch_decode_plan",
+    "dispatch_burst",
+    "dispatch_prefill",
+    "dispatch_sp_prefill",
+    "dispatch_spec",
+    "dispatch_unified",
+    "_page_table_rows",
+    "_state_slot_rows",
+    "_note_attn_pages",
+    "_other_width_payloads",
     "_staging_set",
     "_dispatch",
     "execute_payload",

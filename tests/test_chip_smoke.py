@@ -148,7 +148,7 @@ def test_failed_step_ends_its_requests_and_flips_health():
     def refuse(plan):
         raise RuntimeError("Mosaic says no")
 
-    engine.runner.run_prefill = refuse
+    engine.runner.dispatch_prefill = refuse
     srv = engine_server.EngineServer(engine, "tiny-llama")
 
     async def run():

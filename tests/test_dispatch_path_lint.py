@@ -15,6 +15,8 @@ comparable. Waivers: ``# lint: allow-host-read`` on the call line.
 
 import pathlib
 
+import pytest
+
 from production_stack_tpu.staticcheck import Project, run_rules
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -58,5 +60,30 @@ def test_lint_catches_a_violation():
         "production_stack_tpu/engine/model_runner.py":
             "def dispatch_decode(self):\n"
             "    return jax.device_put(tuple(x))\n",
+    }))
+    assert not [f for f in clean if "blocking host read" in f.message]
+
+
+@pytest.mark.parametrize("enqueue", ["dispatch_burst", "dispatch_prefill"])
+def test_a_read_back_on_an_enqueue_function_is_a_finding(enqueue):
+    """The served loop hands the turn before's outputs over behind
+    these dispatches (docs/async_pipeline.md, "The served loop"): a
+    key read back from the device there, as until PR 48, is a round
+    trip between two programs."""
+    findings = _findings(Project.from_sources({
+        "production_stack_tpu/engine/model_runner.py":
+            f"def {enqueue}(self, plan):\n"
+            "    payload = {'rng': np.asarray(self._split())}\n"
+            "    sampled = self._dispatch(2, 4, payload)\n"
+            "    return StepHandle(self, sampled, parse)\n",
+    }))
+    blocking = [f for f in findings if "blocking host read" in f.message]
+    assert len(blocking) == 1 and enqueue in blocking[0].message
+    clean = _findings(Project.from_sources({
+        "production_stack_tpu/engine/model_runner.py":
+            f"def {enqueue}(self, plan):\n"
+            "    payload = {'rng': self._next_rng()}\n"
+            "    sampled = self._dispatch(2, 4, payload)\n"
+            "    return StepHandle(self, sampled, parse)\n",
     }))
     assert not [f for f in clean if "blocking host read" in f.message]

@@ -248,14 +248,14 @@ def test_the_all_pad_step_touches_nothing_live(family):
             (runner.k_cache, runner.v_cache))]
 
     def pad_steps():
-        before, key = cache(), np.asarray(runner._rng)
+        before, drawn = cache(), runner._keys._drawn
         counted = runner.num_narrow_prefill_steps
         for b in (2, 4):
             for payload in runner._other_width_payloads(b, CHUNK):
                 runner._load_step_program(payload).join()
                 runner._dispatch(1, CHUNK, payload)
         _trash_only(before, cache(), engine)
-        assert (np.asarray(runner._rng) == key).all()
+        assert runner._keys._drawn == drawn
         assert runner.num_narrow_prefill_steps == counted
         widths.append(runner.last_prefill_width)
 
