@@ -2319,6 +2319,16 @@ class EngineServer:
                      else "pair"),
               "kv_bytes_per_token":
                   config.cache.kv_bytes_per_token(config.model)}
+        # The tiles the routed experts' two grouped products take at
+        # this model's widths, and the grid steps a visit (ops/moe.py
+        # expert_tiles: static a shape, so stated once, not counted).
+        import jax.numpy as jnp
+        from production_stack_tpu.ops.moe import expert_layer_tiles
+        model = config.model
+        experts = ({"expert_tiles": expert_layer_tiles(
+            model.hidden_size, model.moe_intermediate_size,
+            jnp.dtype(model.jax_dtype).itemsize)}
+            if model.num_experts else {})
         return web.json_response({
             "version": __version__,
             "build_id": self.build_id,
@@ -2343,6 +2353,7 @@ class EngineServer:
                 {"by": "none"}),
             **conv_tails,
             **state,
+            **experts,
             "family": config.model.architecture,
             **kv,
         })

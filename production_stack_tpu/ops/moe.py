@@ -22,8 +22,10 @@ go through its three matrices as one group of a grouped matrix
 product, so FLOPs are tokens x held choices x one expert, and an
 expert nobody chose is never read. On a TPU the grouped product is the
 Pallas ``megablox`` kernel that ships with JAX (group sizes reach it
-through scalar prefetch; it visits only tiles that hold rows);
-elsewhere it is ``jax.lax.ragged_dot``.
+through scalar prefetch; it visits only tiles that hold rows) under
+tiles that follow each product's shape (``expert_tiles``: a k tile that
+divides k, a few grid steps a visit); elsewhere it is
+``jax.lax.ragged_dot``.
 """
 
 from __future__ import annotations
@@ -31,11 +33,80 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# m, k, n tiles of the grouped product. 128 rows a tile: a decode step
-# of 128 rows x 10 choices leaves ~2.5 rows an expert, so a tile is
-# mostly one expert's few rows and the product is bound by reading the
-# expert (6.3 MB at the published widths), not by the matrix unit.
-_TILING = (128, 1024, 512)
+# The grouped product's tiles follow the product's shape
+# (``expert_tiles``). 128 rows a tile: a decode step of 128 rows x 10
+# choices leaves ~2.5 rows an expert, so a tile is mostly one expert's
+# few rows and the product is bound by reading the expert (6.3 MB at
+# the published widths), not by the matrix unit.
+_TILE_ROWS = 128
+# The most one right-hand tile [tk, tn] may hold. For every (m tile,
+# expert) pair that holds rows the kernel walks tiles_n x tiles_k grid
+# steps, each fetching one such tile, and a step costs 0.11-0.3 us
+# beside its bytes (more where it splits k: the partial products are
+# summed over steps). Measured on a v5e at the five expert cells'
+# shapes (benchmarks/grouped_product_tiles.py, PERF.md section 6,
+# PR 49): 4 MiB is the fastest of 2, 3 and 4 on four shapes and 0.3%
+# behind 2 on the fifth; 6 MiB gains 2-3% more on two and leaves no
+# room in the scoped VMEM; 8 is refused.
+_RHS_TILE_BYTES = 4 << 20
+# A v5e's default scoped VMEM is 16 MiB and the kernel asks for no
+# more: two right-hand tiles, two left-hand tiles [128, tk], two
+# output tiles and the accumulator [128, tn] (float32) stay under
+# three quarters of it.
+_TILE_BUFFER_BYTES = 12 << 20
+# The constant tile every product had before the tiles followed its
+# shape, kept where no tile that divides k fits: the kernel then masks
+# the last k tile of both operands (in float32, on every such step:
+# 0.6 us a step, a quarter of the down product at k 1536).
+_CONSTANT_TK, _CONSTANT_TN = 1024, 512
+
+
+def grid_steps(tiles, k: int, n: int) -> int:
+    """Grid steps the kernel walks under ``tiles`` for one (m tile,
+    expert) pair that holds rows: one a (k tile, n tile)."""
+    _, tk, tn = tiles
+    return -(-k // tk) * -(-n // tn)
+
+
+def expert_tiles(k: int, n: int, itemsize: int):
+    """(tm, tk, tn) of the grouped product ``[m, k] x [E, k, n]`` whose
+    operands hold ``itemsize`` bytes an element.
+
+    ``tk`` is ``k`` or a divisor of it that is a multiple of 128, so
+    the kernel never builds its masked branch; ``tn`` is a divisor of
+    ``n`` that is a multiple of 128 (where ``n`` has none, the constant
+    tile's). Of the pairs that fit (``_RHS_TILE_BYTES``,
+    ``_TILE_BUFFER_BYTES``) the one of the fewest grid steps a visit,
+    and of those the one with the largest ``tk``: with ``k`` whole
+    nothing is summed over grid steps. Where no pair fits (a ``k`` over
+    what one tile holds that no multiple of 128 divides) the constant
+    tile stands."""
+    tm = _TILE_ROWS
+    tks = [d for d in range(k, 0, -1)
+           if k % d == 0 and (d == k or d % 128 == 0)]
+    tns = ([d for d in range(n, 0, -1) if n % d == 0 and d % 128 == 0]
+           or [min(_CONSTANT_TN, n)])
+    fitting = [
+        (tk, tn) for tk in tks for tn in tns
+        if tk * tn * itemsize <= _RHS_TILE_BYTES
+        and (2 * (tk * tn + tm * tk) * itemsize + 3 * tm * tn * 4
+             <= _TILE_BUFFER_BYTES)]
+    if not fitting:
+        return tm, min(_CONSTANT_TK, k), min(_CONSTANT_TN, n)
+    return min(((tm, tk, tn) for tk, tn in fitting),
+               key=lambda tiles: (grid_steps(tiles, k, n), -tiles[1]))
+
+
+def expert_layer_tiles(hidden: int, width: int, itemsize: int) -> dict:
+    """What one routed expert layer's two products run under, as
+    ``/version`` states it: the tiles of gate|up ``[H, 2F]`` and of
+    down ``[F, H]``, and the grid steps the kernel walks for an
+    (m tile, expert) pair that holds rows, over both."""
+    gate_up = expert_tiles(hidden, 2 * width, itemsize)
+    down = expert_tiles(width, hidden, itemsize)
+    return {"gate_up": list(gate_up), "down": list(down),
+            "steps_per_visit": grid_steps(gate_up, hidden, 2 * width)
+            + grid_steps(down, width, hidden)}
 
 
 def route(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
@@ -116,14 +187,12 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str):
                                  preferred_element_type=jnp.float32)
         return jnp.where(grouped, out, 0.0)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    n = rhs.shape[-1]
-    tm, tk, tn = _TILING
-    pad = (-m) % tm
+    tiling = expert_tiles(k, rhs.shape[-1], rhs.dtype.itemsize)
+    pad = (-m) % tiling[0]
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = gmm(lhs, rhs, group_sizes,
-              preferred_element_type=jnp.float32,
-              tiling=(tm, min(tk, k), min(tn, n)),
+              preferred_element_type=jnp.float32, tiling=tiling,
               interpret=impl == "pallas-interpret")
     return jnp.where(grouped, out[:m], 0.0)
 
@@ -142,7 +211,9 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
       first_expert: id of the first held expert
       valid:     [N] bool; a token that is not real chooses nothing
       impl:      "xla" (``ragged_dot``), "pallas" (the ``megablox``
-                 kernel, on a TPU) or "pallas-interpret"
+                 kernel, on a TPU, under ``expert_tiles(H, 2F)`` and
+                 ``expert_tiles(F, H)``: both follow the operands'
+                 shapes and dtype, nothing else) or "pallas-interpret"
 
     Returns (y [N, H] in x's dtype, load [E] int32: real tokens that
     chose each held expert).

@@ -286,6 +286,14 @@ def test_the_llama_familys_deferred_burst_is_untouched(layout, monkeypatch):
     ("llama", True, None)])
 def test_version_says_where_the_convolution_tails_are_kept(
         family, deferred, want):
+    body = version_of(family, deferred)
+    assert body["kv_writes"] == ("deferred" if deferred else "eager")
+    assert body.get("conv_tails") == want
+    assert ("conv_tails" in body) == (want is not None)
+
+
+def version_of(family, deferred=True) -> dict:
+    """``/version`` of a tiny engine of ``family``."""
     from production_stack_tpu.engine.server import EngineServer
     if family == "llama":
         from test_deferred_kv import _engine
@@ -303,7 +311,26 @@ def test_version_says_where_the_convolution_tails_are_kept(
         finally:
             await client.close()
 
-    body = asyncio.run(version())
-    assert body["kv_writes"] == ("deferred" if deferred else "eager")
-    assert body.get("conv_tails") == want
-    assert ("conv_tails" in body) == (want is not None)
+    return asyncio.run(version())
+
+
+@pytest.mark.parametrize("family,routed", [
+    ("qwen3_next", True), ("lfm2_moe", True), ("jamba", False),
+    ("llama", False)])
+def test_version_states_the_expert_products_tiles(family, routed):
+    """A family that serves routed experts says which tiles its two
+    grouped products take and how many grid steps a visit is; a dense
+    family says nothing of them."""
+    from production_stack_tpu.ops.moe import expert_tiles
+    body = version_of(family)
+    assert ("expert_tiles" in body) == routed
+    if routed:
+        model = HYBRIDS[family].engine_config().model
+        hidden, width = model.hidden_size, model.moe_intermediate_size
+        itemsize = jnp.dtype(model.jax_dtype).itemsize
+        tiles = body["expert_tiles"]
+        assert tiles["gate_up"] == list(
+            expert_tiles(hidden, 2 * width, itemsize))
+        assert tiles["down"] == list(expert_tiles(width, hidden, itemsize))
+        # Tiny widths: each product is one tile.
+        assert tiles["steps_per_visit"] == 2

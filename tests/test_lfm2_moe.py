@@ -2,7 +2,7 @@
 with its bias, the gated short convolution over a carried tail, the
 share of an expert-parallel group tied to the whole layer, the
 attention kernels at head size 64, and the grouped product at an expert
-width that is no multiple of its k tile (through the engine:
+width that is no multiple of 1024 (through the engine:
 tests/test_lfm2_moe_engine.py).
 
 Tiny widths, float32, seeded, on the CPU; the oracle is the family's
@@ -309,12 +309,29 @@ def test_the_attention_kernels_at_head_64_group_4_equal_xla(kernel):
                   - np.asarray(want, np.float32)).max() < 0.03
 
 
-def test_the_grouped_product_at_a_width_no_multiple_of_its_k_tile():
-    """Expert width 1792 = 14 x 128 is the contraction of ``w_down`` and
-    no multiple of the k tile 1024: the kernel in interpret mode equals
+def _no_fitting_divisor():
+    """An expert width over what one float32 tile holds beside the
+    narrowest ``tn`` and 8 past a multiple of 128: no divisor of it can
+    be the k tile, so the rule falls back to the constant tile and the
+    kernel masks the remainder."""
+    from production_stack_tpu.ops import moe
+    return moe._RHS_TILE_BYTES // (128 * 4) + 8
+
+
+@pytest.mark.parametrize("width", [1792, _no_fitting_divisor()], ids=[
+    "a_width_that_is_the_k_tile", "a_width_with_no_fitting_divisor"])
+def test_the_grouped_product_at(width):
+    """Expert width 1792 = 14 x 128 is the contraction of ``w_down``:
+    no multiple of 1024, and since the tiles follow the product's shape
+    one k tile (no remainder, nothing masked). A width without a
+    divisor that fits keeps the constant k tile 1024 and its masked
+    last tile. Either way the kernel in interpret mode equals
     ``ragged_dot`` and the experts one by one."""
+    from production_stack_tpu.ops.moe import expert_tiles
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
-    n, h, f, e = 24, 128, 1792, 2
+    n, h, f, e = 24, 128, width, 2
+    tk = expert_tiles(f, h, 4)[1]
+    assert (tk == f) if f == 1792 else (tk == 1024 and f % tk == 8)
     x = jax.random.normal(keys[0], (n, h), jnp.float32)
     w_gate_up = 0.05 * jax.random.normal(keys[1], (e, h, 2 * f), jnp.float32)
     w_down = 0.05 * jax.random.normal(keys[2], (e, f, h), jnp.float32)
