@@ -41,7 +41,8 @@ def main() -> None:
               "qwen3_next": cfg.tiny_qwen3_next_config(),
               "jamba": cfg.tiny_jamba_config(),
               "lfm2_moe": cfg.tiny_lfm2_moe_config(),
-              "longcat_flash": cfg.tiny_longcat_flash_config()}
+              "longcat_flash": cfg.tiny_longcat_flash_config(),
+              "granitemoehybrid": cfg.tiny_granitemoehybrid_config()}
     models["qwen2"].attention_bias = True
     for name, model in models.items():
         model.attention_impl, model.dtype = "pallas", "bfloat16"

@@ -49,7 +49,7 @@ class ModelConfig:
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
     # | lfm2_moe | longcat_flash | glm4_moe_lite | granitemoehybrid
-    # (models/registry.py FAMILIES)
+    # | exaone_moe (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -180,6 +180,15 @@ class ModelConfig:
     attention_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # EXAONE-MoE decoders (architecture == "exaone_moe",
+    # models/exaone_moe.py). ``layer_types`` lists every layer as
+    # "sliding_attention" (a query sees the sliding_window keys that
+    # end with its own; rotary; its K/V is a ring of sliding_window
+    # places a sequence in the state pool, never pages) or
+    # "full_attention" (the whole row over pages, no position term).
+    # Norms on each sublayer's output; the first num_dense_layers
+    # feed-forwards dense, the rest the expert block of glm4_moe_lite.
+    sliding_window: int = 0
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -379,6 +388,117 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "exaonemoe" in arch.replace("_", ""):
+            ep, rank = _expert_parallel_share(hf)
+            layers = hf["num_hidden_layers"]
+            layer_types = tuple(hf["layer_types"])
+            other = sorted(set(layer_types)
+                           - {"sliding_attention", "full_attention"})
+            window = hf.get("sliding_window")
+            dense = int(hf.get("first_k_dense_replace", 0))
+            mlp_types = hf.get("mlp_layer_types")
+            windows = hf.get("sliding_windows")
+            rope = hf.get("rope_parameters") or {}
+            refused = [why for bad, why in (
+                (bool(other),
+                 f"layer_types entries {other}: a layer is served as "
+                 "'sliding_attention' (a window's ring in the state "
+                 "pool) or 'full_attention' (causal attention over the "
+                 "paged cache), and no other kind has a path"),
+                (len(layer_types) != layers,
+                 f"layer_types lists {len(layer_types)} layers and "
+                 f"num_hidden_layers says {layers}"),
+                ("sliding_attention" in layer_types
+                 and not (isinstance(window, int) and window > 0),
+                 f"sliding_window {window!r} with sliding_attention "
+                 "layers: the ring holds a fixed number of places"),
+                (windows is not None and list(windows) != [
+                    window if kind == "sliding_attention" else 0
+                    for kind in layer_types],
+                 "sliding_windows does not say sliding_window where "
+                 "layer_types says sliding_attention and 0 elsewhere: "
+                 "one window serves every windowed layer"),
+                (not 0 <= dense <= layers,
+                 f"first_k_dense_replace {dense} of {layers} layers"),
+                (mlp_types is not None and list(mlp_types) != (
+                    ["dense"] * dense + ["sparse"] * (layers - dense)),
+                 "mlp_layer_types is not first_k_dense_replace "
+                 f"({dense}) 'dense' entries and 'sparse' after them: "
+                 "the dense feed-forwards are served first"),
+                (rope.get("rope_type", "default") != "default"
+                 or hf.get("rope_scaling") is not None,
+                 f"rope_parameters rope_type "
+                 f"{rope.get('rope_type')!r} / rope_scaling "
+                 f"{hf.get('rope_scaling')!r}: the windowed layers' "
+                 "rotary embedding is served unscaled ('default')"),
+                (int(hf.get("n_group", 1)) != 1
+                 or int(hf.get("topk_group", 1)) != 1,
+                 f"n_group {hf.get('n_group')} / topk_group "
+                 f"{hf.get('topk_group')}: the experts are chosen "
+                 "among all of them, with no group limit"),
+                (hf.get("scoring_func", "sigmoid") != "sigmoid",
+                 f"scoring_func {hf.get('scoring_func')!r}: the router "
+                 "scores each expert by a sigmoid"),
+                (not hf.get("norm_topk_prob", True),
+                 "norm_topk_prob false: the chosen experts' scores are "
+                 "divided by their sum"),
+                (int(hf.get("num_shared_experts", 1)) != 1,
+                 f"num_shared_experts {hf.get('num_shared_experts')}: "
+                 "one shared expert is added whole"),
+                (int(hf.get("num_nextn_predict_layers", 0)) > 1,
+                 f"num_nextn_predict_layers "
+                 f"{hf.get('num_nextn_predict_layers')}: at most one "
+                 "prediction layer is read, and it is not served (a "
+                 "draft over K/V pages and a ring has no burst)"),
+                (bool(hf.get("attention_bias", False)),
+                 "attention_bias true: the attention projections are "
+                 "served without a bias"),
+                (hf.get("hidden_act", "silu") != "silu",
+                 f"hidden_act {hf.get('hidden_act')!r}: the "
+                 "feed-forwards and the experts are SwiGLU"),
+            ) if bad]
+            if refused:
+                raise ValueError(
+                    "EXAONE-MoE config this engine does not serve: "
+                    + "; ".join(refused))
+            return cls(
+                name=name or hf.get("_name_or_path", "exaone-moe"),
+                architecture="exaone_moe",
+                vocab_size=hf["vocab_size"],
+                hidden_size=hf["hidden_size"],
+                intermediate_size=hf["intermediate_size"],
+                num_hidden_layers=layers,
+                num_attention_heads=hf["num_attention_heads"],
+                num_key_value_heads=hf["num_key_value_heads"],
+                head_dim=hf.get("head_dim"),
+                max_position_embeddings=hf.get(
+                    "max_position_embeddings", 262144),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+                rope_theta=float(rope.get(
+                    "rope_theta", hf.get("rope_theta", 1e6))),
+                tie_word_embeddings=hf.get("tie_word_embeddings",
+                                           False),
+                layer_types=layer_types,
+                sliding_window=int(window or 0),
+                num_dense_layers=dense,
+                # The count this engine holds; the router's width is
+                # this times expert_parallel_size.
+                num_experts=hf["num_experts"],
+                expert_parallel_size=ep,
+                expert_parallel_rank=rank,
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                shared_expert_intermediate_size=(
+                    hf["moe_intermediate_size"]
+                    * int(hf.get("num_shared_experts", 1))),
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                # The checkpoint's prediction layer is not served: the
+                # main model's logits do not depend on it.
+                num_nextn_predict_layers=0,
+                activation="silu",
+                dtype="bfloat16",
+            )
         if "granitemoehybrid" in arch.replace("_", ""):
             ep, rank = _expert_parallel_share(hf)
             layer_types = tuple(hf["layer_types"])
@@ -490,7 +610,9 @@ class ModelConfig:
                 (hf.get("sliding_window") is not None,
                  f"sliding_window {hf.get('sliding_window')}: the "
                  "attention layers are served as full causal attention "
-                 "over the paged cache"),
+                 "over the paged cache (the family that serves a "
+                 "window is exaone_moe, whose windowed layers keep a "
+                 "ring in the state pool: models/exaone_moe.py)"),
                 (bool(hf.get("mamba_proj_bias", False)),
                  "mamba_proj_bias: the Mamba mixer's in and out "
                  "projections are served without a bias"),
@@ -800,8 +922,8 @@ class ModelConfig:
                 f"architecture {arch!r} is none this engine serves "
                 "(config.json 'architectures', else 'model_type'): it "
                 "knows GPT-2, OPT, Mixtral, Qwen3-Next, Jamba, LFM2-MoE, "
-                "LongCat-Flash, GLM-4 MoE lite, Granite-MoE-hybrid and "
-                "the "
+                "LongCat-Flash, GLM-4 MoE lite, Granite-MoE-hybrid, "
+                "EXAONE-MoE and the "
                 f"Llama shapes {sorted(_LLAMA_SHAPES)}, and reads no "
                 "other as one of them")
         qwen = "qwen2" in arch
@@ -1253,6 +1375,14 @@ class EngineConfig:
                     f"{self.model.architecture} keeps a recurrent "
                     "state beside its pages; refused: " + "; ".join(
                         f"{feature} ({why})" for feature, why in refused))
+            window = self.model.sliding_window
+            if window and window % self.cache.page_size:
+                raise ValueError(
+                    f"{self.model.architecture}: sliding_window "
+                    f"{window} is not a whole number of pages of "
+                    f"{self.cache.page_size} tokens (--page-size): a "
+                    "sequence's ring is laid out as pages are and read "
+                    "by the paged kernels, which take whole pages")
             if self.cache.cache_layout == "auto":
                 self.cache = dataclasses.replace(
                     self.cache, cache_layout="per_layer")
@@ -1512,6 +1642,7 @@ INTERNAL_FIELDS = {
     "model.mamba_expand",
     "model.mamba_dt_rank",
     "model.layer_types",
+    "model.sliding_window",
     "model.conv_L_cache",
     "model.num_dense_layers",
     "model.kv_lora_rank",
@@ -1696,6 +1827,43 @@ def tiny_granitemoehybrid_config(expert_parallel_size: int = 1,
         attention_multiplier=0.125,
         residual_multiplier=0.22,
         logits_scaling=4.0,
+        dtype="float32",
+    )
+
+
+def tiny_exaone_moe_config(expert_parallel_size: int = 1,
+                           expert_parallel_rank: int = 0,
+                           sliding_window: int = 16) -> ModelConfig:
+    """A tiny EXAONE-MoE (windowed layers around one full layer that
+    is neither first nor last, a window of one tiny page, two query
+    heads a KV head, a leading dense layer, held experts of a wider
+    sigmoid router beside a shared expert, a scaling factor that is
+    not 1) for tests that run anywhere."""
+    return ModelConfig(
+        name="tiny-exaone-moe",
+        architecture="exaone_moe",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=4,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=16,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-5,
+        rope_theta=10000.0,
+        tie_word_embeddings=False,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention", "sliding_attention"),
+        sliding_window=sliding_window,
+        num_dense_layers=1,
+        num_experts=8 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=32,
+        routed_scaling_factor=2.5,
         dtype="float32",
     )
 

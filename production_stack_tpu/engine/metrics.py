@@ -161,6 +161,14 @@ class EngineMetrics:
                 / max(stats["choices"], 1.0),
         }
         self.moe_last = last
+        if stats.get("swa_queries"):
+            # A family with windowed layers: the ring places and own
+            # tokens a windowed layer's query had in sight, a mean over
+            # the dispatch's decode steps (the window's length on a row
+            # longer than the window, whatever the burst's step). For
+            # the step record alone.
+            return {**last, "swa_keys_mean":
+                    stats["swa_keys"] / stats["swa_queries"]}
         return last
 
     def on_spec_step(self, drafted: int, accepted: int) -> None:

@@ -45,6 +45,7 @@ from production_stack_tpu.ops.quant_kv import (
     quant_cache_struct,
     quant_cache_zeros,
 )
+from production_stack_tpu.ops.window_attention import write_to_ring
 from production_stack_tpu.ops.sampling import (
     apply_penalties,
     draw_proposal,
@@ -1509,12 +1510,19 @@ class ModelRunner:
         # tails, dense in the carry where the family's forward takes
         # them so; a k_cache that ends in its family's counters has
         # one entry more than there are entries.
-        conv = "conv" if m.family.conv_tail else "ride"
+        # A family whose state is a windowed layer's K and V rings
+        # carries a tail for each as for a plane ("ring"): the pools
+        # are read from outside the scan and written once, each tail
+        # token to its place ``position mod window`` of the row's slot.
+        ring = m.family.ring
+        state_v = ("ring" if ring else
+                   "conv" if m.family.conv_tail else "ride")
         second = "pages" if pages.planes == 2 else "ride"
-        k_kinds = tuple("ride" if state else "pages"
+        k_kinds = tuple(("ring" if ring else "ride") if state
+                        else "pages"
                         for state in m.cache_entry_is_state) + (
             "ride",) * bool(m.family.counters)
-        v_kinds = tuple(conv if state else second
+        v_kinds = tuple(state_v if state else second
                         for state in m.cache_entry_is_state)
         per_layer = isinstance(k_cache, tuple)
 
@@ -1525,7 +1533,7 @@ class ModelRunner:
                 cache = (None,) * m.num_hidden_layers
 
             def entry(c, kind):
-                if kind == "pages":
+                if kind in ("pages", "ring"):
                     return jnp.zeros(tail_shape, m.jax_dtype)
                 if kind == "conv":
                     held = c[state_slots]
@@ -1538,7 +1546,7 @@ class ModelRunner:
             # scan, everything else from the carry.
             if not per_layer:
                 return cache
-            return tuple(c if k == "pages" else s
+            return tuple(c if k in ("pages", "ring") else s
                          for c, s, k in zip(cache, carry, kinds))
 
         def flush_one(cache, carry, kinds, tail_pos, tail_valid):
@@ -1552,6 +1560,10 @@ class ModelRunner:
                 if kind == "pages":
                     return write_to_pages(c, s, page_table, tail_pos,
                                           tail_valid)
+                if kind == "ring":
+                    return write_to_ring(
+                        c, s, state_slots, tail_pos, tail_valid,
+                        kv_lens0 + jnp.sum(tail_valid, axis=1))
                 if kind == "conv":
                     return c.at[state_slots].set(jnp.stack(s, axis=1))
                 return s

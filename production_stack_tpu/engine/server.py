@@ -2329,6 +2329,12 @@ class EngineServer:
             model.hidden_size, model.moe_intermediate_size,
             jnp.dtype(model.jax_dtype).itemsize)}
             if model.num_experts else {})
+        # The kinds of layer of a family whose attention layers are not
+        # all alike, and the window of the windowed ones, whose K/V is a
+        # ring in the state pool and never pages.
+        window = ({"layer_types": list(model.layer_types),
+                   "sliding_window": model.sliding_window}
+                  if model.sliding_window else {})
         return web.json_response({
             "version": __version__,
             "build_id": self.build_id,
@@ -2354,6 +2360,7 @@ class EngineServer:
             **conv_tails,
             **state,
             **experts,
+            **window,
             "family": config.model.architecture,
             **kv,
         })
@@ -3001,8 +3008,8 @@ def parse_args(argv=None):
                              "flush per burst. 'auto' enables it "
                              "when eligible (llama, mistral, qwen2, "
                              "qwen3_next, jamba, lfm2_moe, longcat_flash, "
-                             "glm4_moe_lite, granitemoehybrid; "
-                             "decode-steps "
+                             "glm4_moe_lite, granitemoehybrid, "
+                             "exaone_moe; decode-steps "
                              "> 1, no pp/sp); /version "
                              "says which "
                              "is served (kv_writes)")

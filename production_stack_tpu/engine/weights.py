@@ -285,6 +285,12 @@ def load_weights(model_dir: str, config: ModelConfig,
             "apart, the held experts' block with gate | up fused) is "
             "not written yet: serve the architecture with "
             "--random-weights")
+    if config.architecture == "exaone_moe":
+        raise NotImplementedError(
+            "reading an EXAONE-MoE checkpoint into this engine's "
+            "stacks (gate | up fused, the held experts' block, the "
+            "prediction layer left out) is not written yet: serve the "
+            "architecture with --random-weights")
     if config.architecture not in ("llama", "mistral", "qwen2"):
         raise NotImplementedError(
             f"no reader for a {config.architecture!r} checkpoint, and "

@@ -163,9 +163,10 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
-def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla"):
+def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla",
+                 room=None):
     """u [B, T, H] normalised -> (F(u) [B, T, H], load [E]: real tokens
-    that chose each held expert)."""
+    that chose each held expert). ``room`` is ``held_experts``'."""
     c = config
     b, t, h = u.shape
     flat = u.reshape(b * t, h)
@@ -175,7 +176,7 @@ def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla"):
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
         c.expert_parallel_rank * c.num_experts, valid=valid.reshape(b * t),
-        impl=moe_impl)
+        impl=moe_impl, room=room)
     with jax.named_scope("shared_expert"):
         y = y + swiglu(flat, lp["shared_gate_up"], lp["shared_down"])
     return y.reshape(b, t, h), load

@@ -8,14 +8,26 @@ rest here too, so that the engine's configuration, the cache builder
 and the runner ask the family and name no model:
 
 - ``recurrent_layers(config)``: per layer, True where the layer keeps a
-  recurrent state a sequence (in a slot of the state pool,
-  ``engine/kv_cache.py``) and no pages;
+  state of fixed size a sequence (in a slot of the state pool,
+  ``engine/kv_cache.py``: a recurrence's state, a convolution's tail,
+  a window's K/V) and no pages;
 - ``state(config)``: one sequence's state in one such layer as one
   or two ``(shape, dtype name)`` entries (``"model"`` is the model's
   own dtype). The last is kept in the layer's ``v_cache`` entry; the
-  one before it, a recurrence's own state, in its ``k_cache`` entry,
-  which is ``None`` for a family that declares one entry: no pool is
-  made, read or written for it (``state_pools``);
+  one before it (a recurrence's own state, or a windowed attention
+  layer's K where the last is its V: ``ring``) in its ``k_cache``
+  entry, which is ``None`` for a family that declares one entry: no
+  pool is made, read or written for it (``state_pools``);
+- ``ring``: the two entries are no recurrence and no tail but a
+  windowed attention layer's K and V, a ring of ``window`` places a
+  sequence each (``[kv_heads, head_dim, window]``). Their pools are
+  laid out as the paged planes are, the slots where a plane has its
+  pages (``[kv_heads, slots, head_dim, window]``), so that the paged
+  writers and kernels serve a slot as a page whose table has one entry
+  (``ops/window_attention.py``). The forward takes ``kv_tail`` for
+  these layers too: in a deferred-write burst a ring is read and not
+  written, the layer's K/V rides a tail as a paged layer's does, and
+  the runner flushes it to the ring's places once a burst;
 - ``conv_tail``: the last of those is the tail of a short causal
   convolution, ``[K-1, channels]``, and the forward takes
   ``conv_tail``: in a deferred-write burst the runner gathers each
@@ -74,6 +86,7 @@ class Family:
     recurrent_layers: Optional[Callable] = None
     state: Optional[Callable] = None
     conv_tail: bool = False
+    ring: bool = False
     counters: Tuple[str, ...] = ()
     refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
     page_cache: Optional[Callable] = None
@@ -141,6 +154,21 @@ def _granitemoehybrid_state(c) -> tuple:
     return (((c.mamba_d_state, c.mamba_d_inner), "float32"),
             ((c.mamba_d_conv - 1, c.mamba_d_inner + 2 * c.mamba_d_state),
              "model"))
+
+
+def _exaone_moe_layers(c) -> tuple:
+    """A window's ring where ``layer_types`` says
+    ``sliding_attention``, pages where it says ``full_attention``:
+    the published list."""
+    return tuple(kind == "sliding_attention" for kind in c.layer_types)
+
+
+def _exaone_moe_state(c) -> tuple:
+    """A windowed layer's K ring and V ring: ``sliding_window``
+    places a sequence, each what one page of that many tokens
+    holds."""
+    ring = ((c.num_key_value_heads, c.head_dim, c.sliding_window), "model")
+    return (ring, ring)
 
 
 def _longcat_flash_pages(c) -> PageCache:
@@ -217,6 +245,17 @@ FAMILIES: Dict[str, Family] = {
                                   "sharding rules",
             "weight quantization": "the Mamba-2 mixer's projections "
                                    "and the experts have no quantized "
+                                   "form",
+        }),
+    "exaone_moe": Family(
+        "exaone_moe", deferred_kv=True,
+        recurrent_layers=_exaone_moe_layers, state=_exaone_moe_state,
+        ring=True,
+        counters=_EXPERT_COUNTERS + ("swa_keys", "swa_queries"),
+        refusals={
+            "tensor parallelism": "the rings' pools and the expert "
+                                  "layer have no sharding rules",
+            "weight quantization": "the experts have no quantized "
                                    "form",
         }),
     "longcat_flash": Family(
@@ -308,8 +347,9 @@ def init_hybrid_cache(config, num_pages: int, page_size: int,
     """The per-entry cache tuples of a family that declares its cache
     here: page buffers for the paged entries (the second plane ``None``
     where the family declares one), the state pools it declares
-    (``num_state_slots`` + the trash slot 0; ``None`` where it declares
-    none) for the recurrent layers, and after the entries the family's
+    (``num_state_slots`` + the trash slot 0, the leading axis but of a
+    ring's pool, whose slots lie where a plane's pages do; ``None``
+    where it declares none) for the recurrent layers, and after the entries the family's
     counters, if it keeps any, as one more ``k_cache`` entry."""
     import jax.numpy as jnp
 
@@ -320,9 +360,12 @@ def init_hybrid_cache(config, num_pages: int, page_size: int,
         if entry is None:
             return None
         shape, dtype = entry
+        shape = tuple(shape)
+        # A ring's pool is a paged plane whose pages are the slots.
+        shape = (shape[:1] + (num_state_slots + 1,) + shape[1:]
+                 if fam.ring else (num_state_slots + 1,) + shape)
         return jnp.zeros(
-            (num_state_slots + 1,) + tuple(shape),
-            model_dtype if dtype == "model" else jnp.dtype(dtype))
+            shape, model_dtype if dtype == "model" else jnp.dtype(dtype))
 
     pages = page_cache(config)
     page_shape = (pages.heads, num_pages, pages.width, page_size)

@@ -165,6 +165,55 @@ def write_to_pages(cache: jnp.ndarray, new_kv: jnp.ndarray,
     return cache.at[layer, :, flat_pages, :, flat_offsets].set(flat_kv)
 
 
+def write_chunk_to_pages(cache: jnp.ndarray, new_kv: jnp.ndarray,
+                         page_table: jnp.ndarray, positions: jnp.ndarray,
+                         valid: jnp.ndarray) -> jnp.ndarray:
+    """``write_to_pages`` for prefill chunks, a page at a time.
+
+    A row's real tokens are its first ``sum(valid)`` and sit at
+    contiguous positions from ``positions[:, 0]`` on (a prefill step's
+    rows). Each page a row's chunk can touch, ``ceil(T / page_size) +
+    1`` of them wherever the chunk starts, is read, gets the chunk's
+    tokens in their lanes, and is written back by a
+    ``dynamic_update_slice`` in the plane's own layout. The scatter of
+    ``write_to_pages`` is compiled on ``[pages x page_size, kv, d]``
+    and so copies the whole plane there and back every step (PERF.md
+    section 7 (47): 34.7 ms of a 141 ms prefill step over four planes
+    of 1.2e9 B); the pages of 8 rows x 256 tokens are 6 MB.
+
+    cache [kv, pages, d, page_size] (one layer's plane; no int8
+    form), new_kv [B, T, kv, d], positions/valid [B, T]. A page with
+    none of the row's tokens is written back as it was read.
+    """
+    if isinstance(cache, QuantKV) or cache.ndim != 4:
+        raise ValueError("write_chunk_to_pages writes one layer's plain "
+                         "[kv, pages, d, page_size] plane")
+    num_kv, _, head_dim, page_size = cache.shape
+    b, t = positions.shape
+    start = positions[:, 0]
+    count = jnp.sum(valid, axis=1).astype(start.dtype)
+    # [B, kv, d, page_size + T + page_size]: a page's lanes are a
+    # window of the row's chunk, wherever the chunk starts.
+    chunk = jnp.pad(new_kv.transpose(0, 2, 3, 1),
+                    ((0, 0),) * 3 + ((page_size, page_size),))
+    lane = jnp.arange(page_size, dtype=start.dtype)
+    last = page_table.shape[1] - 1
+    for row in range(b):
+        for j in range(-(-t // page_size) + 1):
+            logical = start[row] // page_size + j
+            first = logical * page_size - start[row]   # lane 0's token
+            token = first + lane
+            take = (token >= 0) & (token < count[row])
+            page = jnp.where(jnp.any(take),
+                             page_table[row, jnp.minimum(logical, last)], 0)
+            new = jax.lax.dynamic_slice_in_dim(
+                chunk[row], first + page_size, page_size, axis=-1)
+            old = jax.lax.dynamic_slice_in_dim(cache, page, 1, axis=1)
+            cache = jax.lax.dynamic_update_slice_in_dim(
+                cache, jnp.where(take, new[:, None], old), page, axis=1)
+    return cache
+
+
 def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
                   slot: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
     """One decode token into its burst-tail slot (deferred KV write).

@@ -200,7 +200,7 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str):
 def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
                  w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
                  first_expert: int, valid: jnp.ndarray = None,
-                 impl: str = "xla"):
+                 impl: str = "xla", room: "int | None" = None):
     """The held experts' part of the routed sum.
 
     Args:
@@ -214,6 +214,12 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
                  kernel, on a TPU, under ``expert_tiles(H, 2F)`` and
                  ``expert_tiles(F, H)``: both follow the operands'
                  shapes and dtype, nothing else) or "pallas-interpret"
+      room:      static, under N * k: where few of the published
+                 experts are held, the rows the held choices are
+                 expected to fit in. A call whose held choices do fit
+                 (seen at run time) gathers, multiplies and sums those
+                 rows alone; one whose do not takes all N * k, as a
+                 call without ``room`` does. Exact either way
 
     Returns (y [N, H] in x's dtype, load [E] int32: real tokens that
     chose each held expert).
@@ -231,18 +237,39 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
         key = jnp.where(held, local, e).reshape(-1)
         order = jnp.argsort(key, stable=True)
         load = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
-        token = order // top_k
-        rows = x[token]                                   # [N*k, H]
-        hidden = _grouped_dot(rows, w_gate_up, load, impl)
-        act = (jax.nn.silu(hidden[:, :f]) * hidden[:, f:]).astype(x.dtype)
-        out = _grouped_dot(act, w_down, load, impl)       # [N*k, H] f32
-        out = (out * weights.reshape(-1)[order][:, None]).astype(x.dtype)
-        # Back to (token, choice) order, then the sum over choices.
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        y = jnp.sum(out[inverse].reshape(n, top_k, -1), axis=1,
-                    dtype=jnp.float32)
-        return y.astype(x.dtype), load
+
+        def through(order):
+            """The sorted rows ``order`` through their experts,
+            weighted: [len(order), H] in x's dtype, zero past the
+            groups' total."""
+            hidden = _grouped_dot(x[order // top_k], w_gate_up, load, impl)
+            act = (jax.nn.silu(hidden[:, :f])
+                   * hidden[:, f:]).astype(x.dtype)
+            out = _grouped_dot(act, w_down, load, impl)   # f32
+            return (out * weights.reshape(-1)[order][:, None]).astype(
+                x.dtype)
+
+        def every_row():
+            out = through(order)
+            # Back to (token, choice) order, then the sum over choices.
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+            return jnp.sum(out[inverse].reshape(n, top_k, -1), axis=1,
+                           dtype=jnp.float32).astype(x.dtype)
+
+        def held_rows():
+            # Each token's sum as one product with the 0/1 matrix of
+            # which row is whose: exact in float32, and no scatter.
+            first = order[:room]
+            whose = (first // top_k)[None, :] == jnp.arange(n)[:, None]
+            return jnp.dot(whose.astype(x.dtype), through(first),
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+
+        if room is None or room >= n * top_k:
+            return every_row(), load
+        return jax.lax.cond(jnp.sum(load) <= room, held_rows,
+                            every_row), load
 
 
 def count_step(stats: jnp.ndarray, top_k: int, load: jnp.ndarray,

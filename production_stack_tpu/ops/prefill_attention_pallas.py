@@ -74,7 +74,7 @@ _PAGES_PER_CHUNK = 2
 
 
 def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
-                    layer_ref, q_ref,
+                    layer_ref, first_key_ref, q_ref,
                     k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
                     m_ref, l_ref, acc_ref,
                     k_scratch, v_scratch, ks_scratch, vs_scratch,
@@ -82,7 +82,10 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
                     page_size: int, pages_per_chunk: int,
                     chunk: int, head_dim: int, head_dim_pad: int,
                     rows_pad: int, max_pages: int,
-                    has_layer: bool, quantized: bool):
+                    has_layer: bool, quantized: bool,
+                    window: "int | None"):
+    # first_key_ref is None but under ``window``: the first position
+    # of the row's table that holds a key at all.
     # ks_hbm/vs_hbm carry the per-slot f32 dequant scales of an int8
     # cache (ops/quant_kv.py), pre-reshaped by the wrapper to
     # [.., pages, 1, page_size]; None for a full-precision cache.
@@ -126,6 +129,16 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
         jnp.int32, (rows_pad, chunk_tokens), 0
     ) % chunk  # [rows_pad, C*P]
 
+    def mask_fn(token_pos):
+        # Causal over the chunk's own tokens plus everything cached
+        # before it — exactly the ragged mixed-length contract: each
+        # row masks independently off its scalar-prefetched start.
+        in_sight = (token_pos <= q_pos) & (token_pos < kv_len)
+        if window is not None:
+            in_sight = (in_sight & (token_pos > q_pos - window)
+                        & (token_pos >= first_key_ref[b]))
+        return in_sight
+
     run_page_walk(
         q=q, kv_len=kv_len, num_chunks=num_chunks,
         max_chunks=max_chunks, chunk_tokens=chunk_tokens,
@@ -133,11 +146,7 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
         k_scratch=k_scratch, v_scratch=v_scratch,
         ks_scratch=ks_scratch, vs_scratch=vs_scratch,
         m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref,
-        # Causal over the chunk's own tokens plus everything cached
-        # before it — exactly the ragged mixed-length contract: each
-        # row masks independently off its scalar-prefetched start.
-        mask_fn=lambda token_pos: ((token_pos <= q_pos)
-                                   & (token_pos < kv_len)),
+        mask_fn=mask_fn,
         quantized=quantized,
     )
 
@@ -145,13 +154,15 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
     o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                             v_cache_layer: jnp.ndarray,
                             page_table: jnp.ndarray,
                             q_positions: jnp.ndarray,
                             kv_lens: jnp.ndarray,
                             layer: "jnp.ndarray | int | None" = None,
+                            window: "int | None" = None,
+                            first_key: "jnp.ndarray | None" = None,
                             interpret: bool = False) -> jnp.ndarray:
     """Chunked-prefill attention against a sequence's cached pages.
 
@@ -167,6 +178,12 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                    start_i + arange(T)), the engine's chunked-prefill
                    shape — only row starts reach the kernel (SMEM)
       kv_lens:     [B] int32 valid cached tokens (incl. this chunk)
+      window:      static; with it a key is in sight iff it is also
+                   under ``window`` positions behind the query and at
+                   or after ``first_key`` [B] int32 (a table whose
+                   first positions hold no key: a step's own plane
+                   over a ring, ops/window_attention.py). Without it
+                   nothing of the kernel or its operands changes
       interpret:   run in interpreter mode (CPU testing)
 
     Returns [B, T, num_q_heads, head_dim] for the 4D per-layer cache
@@ -209,8 +226,15 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
         _prefill_kernel, page_size=page_size, pages_per_chunk=c,
         chunk=t, head_dim=head_dim, head_dim_pad=d_pad,
         rows_pad=rows_pad, max_pages=max_pages,
-        has_layer=has_layer, quantized=quantized,
+        has_layer=has_layer, quantized=quantized, window=window,
     )
+    if (window is None) != (first_key is None):
+        raise ValueError(
+            "a window and the first key that exists go together "
+            f"(window {window!r}, first_key given: "
+            f"{first_key is not None})")
+    windowed = [] if window is None else [first_key]
+    n_prefetch = 4 + len(windowed)
     n_cache_in = 4 if quantized else 2
     # Stacked-form pass-through cache outputs exist only for the
     # input/output aliasing (see paged_decode_attention); the kernel
@@ -218,7 +242,11 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
     # None for the quant-only refs) before the canonical signature.
     n_pass = n_cache_in if has_layer else 0
 
-    def kernel(pt, kl, qs, la, q_ref, *refs):
+    def kernel(pt, kl, qs, la, *refs):
+        fk = None
+        if window is not None:
+            fk, *refs = refs
+        q_ref, *refs = refs
         cache_in = refs[:n_cache_in]
         o_ref = refs[n_cache_in]
         scratch = refs[n_cache_in + 1 + n_pass:]
@@ -229,7 +257,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
             k, v = cache_in
             ks = vs = ks_s = vs_s = ssem = None
             (m, l, acc, k_s, v_s, sem) = scratch
-        base_kernel(pt, kl, qs, la, q_ref, k, v, ks, vs, o_ref,
+        base_kernel(pt, kl, qs, la, fk, q_ref, k, v, ks, vs, o_ref,
                     m, l, acc, k_s, v_s, ks_s, vs_s, sem, ssem)
 
     hbm = hbm_block_spec()
@@ -243,18 +271,19 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
     scratch_shapes += dma_semaphore_shapes(c, quantized)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # page_table, kv_lens, q_start, layer
+        # page_table, kv_lens, q_start, layer (and a window's first key)
+        num_scalar_prefetch=n_prefetch,
         grid=(b, num_kv_heads),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, rows_pad, d_pad),
-                lambda bi, hi, pt, kl, qs, la: (bi, hi, 0, 0),
+                lambda bi, hi, *_: (bi, hi, 0, 0),
             ),
         ] + [hbm] * n_cache_in,
         out_specs=[
             pl.BlockSpec(
                 (1, 1, rows_pad, d_pad),
-                lambda bi, hi, pt, kl, qs, la: (bi, hi, 0, 0),
+                lambda bi, hi, *_: (bi, hi, 0, 0),
             ),
         ] + [hbm] * n_pass,
         scratch_shapes=scratch_shapes,
@@ -262,14 +291,14 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
 
     out_shape = [jax.ShapeDtypeStruct(
         (b, num_kv_heads, rows_pad, d_pad), q.dtype)]
-    operands = [page_table, kv_lens, q_start, layer_arr, qg,
+    operands = [page_table, kv_lens, q_start, layer_arr, *windowed, qg,
                 k_data, v_data]
     if quantized:
         operands += [k_scale, v_scale]
     if has_layer:
         out_shape += passthrough_out_shapes(
             k_data, v_data, k_scale, v_scale, quantized)
-    aliases = cache_alias_map(4, n_cache_in, has_layer)
+    aliases = cache_alias_map(n_prefetch, n_cache_in, has_layer)
     res = pl.pallas_call(
         kernel,
         out_shape=out_shape,

@@ -98,6 +98,9 @@ _LONG_MODULES = {
     "test_conv_tails_burst": 245,
     "test_pallas_lowering": 205,
     "test_granitemoehybrid": 150,
+    "test_exaone_moe": 140,
+    "test_exaone_moe_engine": 132,
+    "test_chipbench_exaone_moe_family": 120,
     "test_chipbench_glm4_moe_lite_family": 177,
     "test_longcat_flash": 169,
     "test_lfm2_moe_engine": 164,
