@@ -5,7 +5,9 @@ accounting: a free-list allocator plus a refcounted hash-based prefix
 cache (the TPU analogue of vLLM's prefix caching +
 ``--enable-prefix-caching``, which the reference chart passes through at
 helm/templates/deployment-vllm-multi.yaml:76-79). Page 0 is reserved as
-the trash page that padded writes land on (ops/attention.write_to_pages).
+the trash page: padded writes land on it (ops/attention.write_to_pages),
+and the run writer reads it and writes it back where a row's run does not
+reach a page (ops/attention.write_run_to_pages).
 
 Capacity metrics feed the engine's ``/metrics``:
 ``vllm:gpu_cache_usage_perc`` and ``vllm:gpu_prefix_cache_hit_rate``

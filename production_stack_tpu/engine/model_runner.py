@@ -38,6 +38,7 @@ from production_stack_tpu.models.registry import (
 from production_stack_tpu.ops.attention import (
     block_pages,
     gathered_blocks,
+    write_run_to_pages,
     write_to_pages,
 )
 from production_stack_tpu.ops.quant_kv import (
@@ -1495,8 +1496,8 @@ class ModelRunner:
         outside the scan, everything else from the carry);
         ``flush(k_carry, v_carry, count)`` gives the two caches with
         each row's first ``count [B]`` tail slots written to its pages
-        (slot s at position ``kv_lens0 + s``) and the convolution
-        tails scattered back."""
+        (slot s at position ``kv_lens0 + s``: a run, written page-wise
+        and in place) and the convolution tails scattered back."""
         m = self.config.model
         b = kv_lens0.shape[0]
         pages = m.page_cache
@@ -1549,39 +1550,56 @@ class ModelRunner:
             return tuple(c if k in ("pages", "ring") else s
                          for c, s, k in zip(cache, carry, kinds))
 
-        def flush_one(cache, carry, kinds, tail_pos, tail_valid):
-            if not per_layer:
+        def flush(k_carry, v_carry, count):
+            # A row's tail is a run of its own count at the row's
+            # length (a row that stopped inside the burst holds the
+            # tail it stopped with; a padded row has none): the planes
+            # of both caches, which share the one page table, take
+            # theirs page-wise and in place in one pass
+            # (ops/attention.write_run_to_pages); int8 pages and the
+            # stacked cache by one scatter a layer, a ring and a
+            # convolution's tails by one scatter each; the other
+            # entries are the carry's last.
+            tail_pos = kv_lens0[:, None] + jnp.arange(slots)[None, :]
+            tail_valid = jnp.arange(slots)[None, :] < count[:, None]
+            sides = ((k_cache, k_carry, k_kinds),
+                     (v_cache, v_carry, v_kinds))
+
+            def scatter(cache, tail, layer=None):
+                return write_to_pages(cache, tail, page_table, tail_pos,
+                                      tail_valid, layer=layer)
+
+            def stacked(cache, carry):
                 for l, tail in enumerate(carry):
-                    cache = write_to_pages(cache, tail, page_table,
-                                           tail_pos, tail_valid, layer=l)
+                    cache = scatter(cache, tail, l)
                 return cache
 
-            def entry(c, s, kind):
+            def entry(written, c, s, kind):
                 if kind == "pages":
-                    return write_to_pages(c, s, page_table, tail_pos,
-                                          tail_valid)
+                    return next(written)
                 if kind == "ring":
                     return write_to_ring(
                         c, s, state_slots, tail_pos, tail_valid,
-                        kv_lens0 + jnp.sum(tail_valid, axis=1))
+                        kv_lens0 + count)
                 if kind == "conv":
                     return c.at[state_slots].set(jnp.stack(s, axis=1))
                 return s
-            return tuple(entry(c, s, k)
-                         for c, s, k in zip(cache, carry, kinds))
 
-        def flush(k_carry, v_carry, count):
-            # One batched scatter per paged layer and per convolution
-            # tail for the whole burst, by each row's own count (a row
-            # that stopped inside it holds the tail it stopped with;
-            # padded rows write to the trash slot); the other entries
-            # are the carry's last.
-            tail_pos = kv_lens0[:, None] + jnp.arange(slots)[None, :]
-            tail_valid = jnp.arange(slots)[None, :] < count[:, None]
-            return (flush_one(k_cache, k_carry, k_kinds, tail_pos,
-                              tail_valid),
-                    flush_one(v_cache, v_carry, v_kinds, tail_pos,
-                              tail_valid))
+            with jax.named_scope("kv_flush"):
+                if not per_layer:
+                    return (stacked(k_cache, k_carry),
+                            stacked(v_cache, v_carry))
+                paged = [(c, s) for side in sides
+                         for c, s, kind in zip(*side) if kind == "pages"]
+                if any(isinstance(c, QuantKV) for c, _ in paged):
+                    written = iter([scatter(c, s) for c, s in paged])
+                else:
+                    written = iter(write_run_to_pages(
+                        *map(tuple, zip(*paged)), page_table, kv_lens0,
+                        count))
+                return tuple(
+                    tuple(entry(written, c, s, kind)
+                          for c, s, kind in zip(*side)) for side in sides)
 
         return (k_kinds, v_kinds, carried(k_cache, k_kinds),
                 carried(v_cache, v_kinds), served, flush)
@@ -1602,8 +1620,11 @@ class ModelRunner:
         selects — ops/attention.write_to_tail) and attention covers
         pages + tail positionally (paged_attention k_tail/v_tail);
         the page planes stay READ-ONLY through the scan (loop
-        invariants, not carry) and the tails flush to the pages with
-        one write_to_pages per layer at burst end. A decode ablation
+        invariants, not carry) and the tails flush to the pages once
+        at burst end, every plane's page-wise and in place in one
+        pass that copies no plane (ops/attention.write_run_to_pages;
+        int8 pages and the stacked cache by one write_to_pages a
+        layer). A decode ablation
         (builder-captured 2026-07-31, not measured by the driver) put
         the per-step scatters at ~5.1 of 11.1 ms for ~1 MB of writes;
         on the hybrid cell the eager burst copied both planes of each

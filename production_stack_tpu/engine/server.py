@@ -2303,7 +2303,8 @@ class EngineServer:
         # ("burst") or each step goes to the slot pool ("step").
         import jax
         devices = jax.devices()
-        obs = getattr(self.engine.runner, "observatory", None)
+        runner = self.engine.runner
+        obs = getattr(runner, "observatory", None)
         config = self.engine.config
         deferred = config.scheduler.deferred_kv_writes
         conv_tails = ({"conv_tails": "burst" if deferred else "step"}
@@ -2344,6 +2345,15 @@ class EngineServer:
             "attention_impl": (obs.attention_impls()
                                if obs is not None else {}),
             "kv_writes": "deferred" if deferred else "eager",
+            # How a run of tokens a row (a prefill chunk, a burst's
+            # tail) goes to its pages: page by page in the plane's own
+            # layout ("in_place": per-layer plain planes,
+            # ops/attention.write_run_to_pages), or by the scatter one
+            # token takes everywhere ("scatter": the stacked cache,
+            # int8 pages).
+            "page_writes": (
+                "in_place" if runner.cache_layout == "per_layer"
+                and not runner.kv_quantized else "scatter"),
             # What proposes drafts: the model's own prediction module
             # inside the burst ("module", with its depth and the most
             # tokens an iteration commits), the prompt-lookup proposer

@@ -62,15 +62,11 @@ import jax.numpy as jnp
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.models.glm4_moe_lite import expert_block
 from production_stack_tpu.models.llama import (
-    dispatch_attention,
     hybrid_attention,
     hybrid_kernel_impl,
     rms_norm,
 )
-from production_stack_tpu.ops.attention import (
-    write_chunk_to_pages,
-    write_to_tail,
-)
+from production_stack_tpu.ops.attention import write_to_tail
 from production_stack_tpu.ops.moe import count_step, swiglu
 from production_stack_tpu.ops.rope import apply_rope
 from production_stack_tpu.ops.window_attention import (
@@ -216,19 +212,6 @@ def _attention(config, lp, x, windowed, positions, page_table, kv_lens,
         attn, kc, vc, keys = _windowed(
             config, q, k, v, k_cache[layer], v_cache[layer], slots,
             positions, kv_lens, valid, tails)
-        k_cache = k_cache[:layer] + (kc,) + k_cache[layer + 1:]
-        v_cache = v_cache[:layer] + (vc,) + v_cache[layer + 1:]
-    elif kv_tail is None and t > 1:
-        # A chunk goes to its pages a page at a time: the scatter of
-        # ``cached_attention`` copied each plane there and back, a
-        # quarter of this family's prefill step (PERF.md section 6).
-        with jax.named_scope("full_attn"):
-            kc, vc = (write_chunk_to_pages(cache[layer], new, page_table,
-                                           positions, valid)
-                      for cache, new in ((k_cache, k), (v_cache, v)))
-            attn, kc, vc = dispatch_attention(
-                config, q, kc, vc, page_table, positions, kv_lens,
-                layer=None)
         k_cache = k_cache[:layer] + (kc,) + k_cache[layer + 1:]
         v_cache = v_cache[:layer] + (vc,) + v_cache[layer + 1:]
     else:

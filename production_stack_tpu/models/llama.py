@@ -21,9 +21,11 @@ import jax.numpy as jnp
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.ops.attention import (
     paged_attention,
+    write_run_to_pages,
     write_to_pages,
     write_to_tail,
 )
+from production_stack_tpu.ops.quant_kv import QuantKV
 from production_stack_tpu.ops.rope import apply_rope
 
 Params = Dict[str, jnp.ndarray]
@@ -123,8 +125,11 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
                  stacked cache with the layer index through SMEM.
       per_layer: tuples of L [kv, pages, d, page_size] buffers; this
                  layer's buffer is updated and the tuple rebuilt, so
-                 each scatter/kernel operand is ONE layer's buffer and
-                 jit donation aliases the L buffers 1:1.
+                 each write's/kernel's operand is ONE layer's buffer
+                 and jit donation aliases the L buffers 1:1. A step's
+                 rows are runs (``positions[:, 0]`` on, the first
+                 ``sum(valid)`` places) and go to their pages in place
+                 (ops/attention.write_run_to_pages).
 
     Returns ``(attn, k_cache, v_cache)``; callers must thread the
     returned caches so the buffer chain stays linear (see
@@ -132,8 +137,18 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
     """
     if isinstance(k_cache, (list, tuple)):
         kc, vc = k_cache[layer], v_cache[layer]
-        kc = write_to_pages(kc, k, page_table, positions, valid)
-        vc = write_to_pages(vc, v, page_table, positions, valid)
+        with jax.named_scope("kv_write"):
+            if positions.shape[1] > 1 and not isinstance(kc, QuantKV):
+                # A step of more than a token a row is a step of runs
+                # (a chunk, a token and its drafts): page-wise, both
+                # planes under the one table. One eager token a row
+                # and int8 pages keep the scatter.
+                kc, vc = write_run_to_pages(
+                    (kc, vc), (k, v), page_table, positions[:, 0],
+                    jnp.sum(valid, axis=1))
+            else:
+                kc = write_to_pages(kc, k, page_table, positions, valid)
+                vc = write_to_pages(vc, v, page_table, positions, valid)
         attn, kc, vc = dispatch_attention(
             config, q, kc, vc, page_table, positions, kv_lens,
             layer=None)

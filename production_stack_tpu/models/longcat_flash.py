@@ -55,7 +55,11 @@ import jax.numpy as jnp
 
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.models.llama import hybrid_kernel_impl, rms_norm
-from production_stack_tpu.ops.attention import write_to_pages, write_to_tail
+from production_stack_tpu.ops.attention import (
+    write_run_to_pages,
+    write_to_pages,
+    write_to_tail,
+)
 from production_stack_tpu.ops.mla_attention import latent_paged_attention
 from production_stack_tpu.ops.moe import (
     count_step,
@@ -176,6 +180,12 @@ def mla(config: ModelConfig, lp, x, positions, page_table, kv_lens,
                 tail = write_to_tail(
                     tail, latent if t == 1 else latent[:, j:j + 1],
                     positions[:, j] - kv_lens, valid[:, j])
+        elif t > 1:
+            # A chunk's rows are runs: page-wise, in place.
+            with jax.named_scope("kv_write"):
+                plane = write_run_to_pages(
+                    plane, latent, page_table, positions[:, 0],
+                    jnp.sum(valid, axis=1))
         else:
             plane = write_to_pages(plane, latent, page_table, positions,
                                    valid)

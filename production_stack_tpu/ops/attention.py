@@ -98,10 +98,18 @@ def write_to_pages(cache: jnp.ndarray, new_kv: jnp.ndarray,
                    page_table: jnp.ndarray, positions: jnp.ndarray,
                    valid: jnp.ndarray,
                    layer: "int | None" = None) -> jnp.ndarray:
-    """Scatter new KV entries into their pages.
+    """Scatter new KV entries into their pages, a token at a time.
 
     Page 0 is the engine's trash page (the allocator never hands it out),
     so padded slots write there harmlessly instead of needing predication.
+
+    The scatter's index lies in the plane's minor dimension, so a TPU
+    compiles it on ``[pages x page_size, kv, d]`` and copies the whole
+    plane to that layout and back at every call. It serves what is no
+    run of tokens into a plain plane: one eager decode token a row, int8
+    pages, the stacked cache, a ring (ops/window_attention.py). A run
+    (a prefill chunk, a deferred burst's tail at its flush) into a
+    layer's plain plane goes through ``write_run_to_pages``.
 
     With ``layer`` (a static int), ``cache`` is the full stacked
     [L, kv_heads, num_pages, head_dim, page_size] cache and the scatter
@@ -165,53 +173,95 @@ def write_to_pages(cache: jnp.ndarray, new_kv: jnp.ndarray,
     return cache.at[layer, :, flat_pages, :, flat_offsets].set(flat_kv)
 
 
-def write_chunk_to_pages(cache: jnp.ndarray, new_kv: jnp.ndarray,
-                         page_table: jnp.ndarray, positions: jnp.ndarray,
-                         valid: jnp.ndarray) -> jnp.ndarray:
-    """``write_to_pages`` for prefill chunks, a page at a time.
+def write_run_to_pages(cache, new_kv, page_table: jnp.ndarray,
+                       start: jnp.ndarray, count: jnp.ndarray):
+    """A run of tokens a row into its pages, a page at a time, in the
+    plane's own layout.
 
-    A row's real tokens are its first ``sum(valid)`` and sit at
-    contiguous positions from ``positions[:, 0]`` on (a prefill step's
-    rows). Each page a row's chunk can touch, ``ceil(T / page_size) +
-    1`` of them wherever the chunk starts, is read, gets the chunk's
-    tokens in their lanes, and is written back by a
-    ``dynamic_update_slice`` in the plane's own layout. The scatter of
-    ``write_to_pages`` is compiled on ``[pages x page_size, kv, d]``
-    and so copies the whole plane there and back every step (PERF.md
-    section 7 (47): 34.7 ms of a 141 ms prefill step over four planes
-    of 1.2e9 B); the pages of 8 rows x 256 tokens are 6 MB.
+    Row ``r``'s real tokens are the first ``count[r]`` of ``new_kv[r]``
+    and sit at the contiguous positions ``start[r] + s``: a prefill
+    chunk (``start = positions[:, 0]``, ``count = sum(valid)``) or a
+    deferred burst's tail at its flush (``start`` the row's length
+    before the burst, ``count`` the tokens it emitted). Each of the
+    pages a row's run can touch wherever it starts (``ceil(T /
+    page_size) + 1``; one fewer where T is one over a whole number of
+    pages) is read, takes the run's tokens on their lanes under a
+    select and goes back whole, by its page index alone: all rows'
+    pages in one gather and one scatter whose index is the plane's
+    second-major dimension, which the compiler does on ``[kv, pages,
+    d, page_size]`` as it lies and, the plane donated, in place. The
+    program's text does not grow with the rows. A page that gets none
+    of the row's tokens (every page of a pad row) is the trash page 0,
+    written back as it was read; no two rows own a page, so only page
+    0 is written twice. The result is the scatter's of
+    ``write_to_pages``, bit for bit, outside page 0.
 
-    cache [kv, pages, d, page_size] (one layer's plane; no int8
-    form), new_kv [B, T, kv, d], positions/valid [B, T]. A page with
-    none of the row's tokens is written back as it was read.
+    ``write_to_pages``' scatter has its index in the plane's minor
+    dimension; a TPU compiles it on ``[pages x page_size, kv, d]`` and
+    copies the whole plane to that layout and back at every call
+    (PERF.md section 6, PR 52). Who calls which: the run writer serves
+    ``models/llama.py`` ``cached_attention``'s per-layer branch at
+    more than a token a row (every prefill step of llama, lfm2_moe,
+    jamba, granitemoehybrid, qwen3_next and exaone_moe's full layers),
+    the latent plane's chunk in ``models/longcat_flash.py`` ``mla``
+    (LongCat, GLM) and the flush of both deferred bursts
+    (``engine/model_runner.py`` ``_burst_tails``); the scatter keeps
+    what is not a run into a plain plane: the stacked ``[L, ...]``
+    cache (``layer=``: pipeline and context serving), int8 pages
+    (``QuantKV`` writes a scale beside the data), one eager token a
+    row, and the ring's ``positions % window``
+    (ops/window_attention.py ``write_to_ring``).
+
+    Why no loop over the rows with a ``dynamic_update_slice`` a page,
+    which alone on the chip took half this form's time (PERF.md
+    section 6, PR 52): the compiler is free to give the planes such a
+    loop carries the layout of its small update, pages before heads.
+    At two KV heads it did, and copied all 72 planes of that cell's
+    burst; held to the row-major layout by a layout constraint it
+    copied none in one spelling of the body and twenty-two planes of
+    another cell's burst INSIDE the loop in the next.
+
+    cache [kv, pages, d, page_size] (one layer's plain plane, or the
+    latent's [1, pages, 576, page_size]) and new_kv [B, T, kv, d], or
+    a tuple of planes and a tuple of runs under the one table (a
+    layer's K and V, a flush's every plane), whose page indices and
+    lane masks are then worked out once; page_table [B, max_pages];
+    start, count [B].
     """
-    if isinstance(cache, QuantKV) or cache.ndim != 4:
-        raise ValueError("write_chunk_to_pages writes one layer's plain "
-                         "[kv, pages, d, page_size] plane")
-    num_kv, _, head_dim, page_size = cache.shape
-    b, t = positions.shape
-    start = positions[:, 0]
-    count = jnp.sum(valid, axis=1).astype(start.dtype)
-    # [B, kv, d, page_size + T + page_size]: a page's lanes are a
-    # window of the row's chunk, wherever the chunk starts.
-    chunk = jnp.pad(new_kv.transpose(0, 2, 3, 1),
-                    ((0, 0),) * 3 + ((page_size, page_size),))
-    lane = jnp.arange(page_size, dtype=start.dtype)
-    last = page_table.shape[1] - 1
-    for row in range(b):
-        for j in range(-(-t // page_size) + 1):
-            logical = start[row] // page_size + j
-            first = logical * page_size - start[row]   # lane 0's token
-            token = first + lane
-            take = (token >= 0) & (token < count[row])
-            page = jnp.where(jnp.any(take),
-                             page_table[row, jnp.minimum(logical, last)], 0)
-            new = jax.lax.dynamic_slice_in_dim(
-                chunk[row], first + page_size, page_size, axis=-1)
-            old = jax.lax.dynamic_slice_in_dim(cache, page, 1, axis=1)
-            cache = jax.lax.dynamic_update_slice_in_dim(
-                cache, jnp.where(take, new[:, None], old), page, axis=1)
-    return cache
+    planes, runs = ((cache, new_kv) if isinstance(cache, tuple)
+                    else ((cache,), (new_kv,)))
+    if any(isinstance(c, QuantKV) or c.ndim != 4 for c in planes):
+        raise ValueError("write_run_to_pages writes plain [kv, pages, d, "
+                         "page_size] planes, a layer's each: int8 pages "
+                         "and the stacked [L, ...] cache keep "
+                         "write_to_pages")
+    page_size = planes[0].shape[-1]
+    b, t = runs[0].shape[:2]
+    p = (t + page_size - 2) // page_size + 1
+    # Lane l of a row's j-th page holds the run's token
+    # j * page_size + l - start % page_size, where that is one.
+    token = (jnp.arange(p * page_size, dtype=start.dtype)[None]
+             - (start % page_size)[:, None])  # [B, P * page_size]
+    take = (token >= 0) & (token < count[:, None])
+    logical = start[:, None] // page_size + jnp.arange(p, dtype=start.dtype)
+    pages = jnp.where(
+        jnp.any(take.reshape(b, p, page_size), axis=-1),
+        jnp.take_along_axis(
+            page_table, jnp.minimum(logical, page_table.shape[1] - 1),
+            axis=1), 0).reshape(b * p)
+    source = jnp.clip(token, 0, t - 1)[:, :, None, None]
+    take = take.reshape(1, b * p, 1, page_size)
+    written = []
+    for plane, run in zip(planes, runs):
+        heads, _, width, _ = plane.shape
+        # The shift to the pages' lanes is a gather of whole tokens
+        # (rows of heads x width), then the pages' token-minor order.
+        new = jnp.take_along_axis(run, source, axis=1).reshape(
+            b, p, page_size, heads, width).transpose(
+            3, 0, 1, 4, 2).reshape(heads, b * p, width, page_size)
+        written.append(plane.at[:, pages].set(
+            jnp.where(take, new, plane[:, pages])))
+    return tuple(written) if isinstance(cache, tuple) else written[0]
 
 
 def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
@@ -222,8 +272,10 @@ def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
     the driver) put the per-step paged scatters at ~5.1 of 11.1 ms —
     for ~1 MB of writes. Deferred mode appends each step's K/V to a small
     dense [B, S, kv, d] tail instead (a one-hot select over S<=32
-    slots — no scatter), and flushes the whole tail to the pages with
-    ONE write_to_pages call per layer at burst end.
+    slots — no scatter), and the runner flushes the tails to the pages
+    once at burst end: every plane's tail page-wise and in place in one
+    pass (``write_run_to_pages``; int8 pages and the stacked cache by
+    one ``write_to_pages`` a layer).
 
     Args:
       tail:   [B, S, kv_heads, head_dim]

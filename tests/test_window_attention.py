@@ -151,39 +151,6 @@ def test_a_burst_crosses_the_rings_edge_and_its_flush_wraps(sequences):
                                   keys[row, p])
 
 
-@pytest.mark.parametrize("chunk,page", [(16, 16), (32, 16), (24, 16),
-                                        (8, 16), (256, 128)])
-def test_a_chunk_written_a_page_at_a_time_equals_the_scatter(chunk, page):
-    """``write_chunk_to_pages`` against ``write_to_pages``, bit for
-    bit: rows that start on a page's edge and inside a page, a row
-    that ends inside the chunk, a row with one token, a padded row,
-    and a row whose chunk ends on its table's last page."""
-    from production_stack_tpu.ops.attention import (
-        write_chunk_to_pages,
-        write_to_pages,
-    )
-    rng = np.random.default_rng(chunk + page)
-    pages_a_row = 2 * (-(-chunk // page)) + 2
-    cache = jnp.asarray(rng.standard_normal(
-        (KV, 1 + 6 * pages_a_row, 8, page)).astype(np.float32))
-    table = jnp.asarray(1 + rng.permutation(6 * pages_a_row).reshape(
-        6, pages_a_row), jnp.int32)
-    start = np.asarray([0, page, page + 3, 2 * page - 1, 0,
-                        pages_a_row * page - chunk])
-    count = np.asarray([chunk, chunk, chunk, max(chunk - 5, 1), 0, chunk])
-    count[1] = 1
-    valid = np.arange(chunk)[None] < count[:, None]
-    at = np.where(valid, start[:, None] + np.arange(chunk)[None], 0)
-    new = jnp.asarray(rng.standard_normal(
-        (6, chunk, KV, 8)).astype(np.float32))
-    args = (new, table, jnp.asarray(at, jnp.int32), jnp.asarray(valid))
-    want = np.asarray(write_to_pages(cache, *args))
-    got = np.asarray(write_chunk_to_pages(cache, *args))
-    # Page 0 is the trash page: anything may land there.
-    assert np.array_equal(got[:, 1:], want[:, 1:])
-    assert not np.array_equal(want[:, 1:], np.asarray(cache)[:, 1:])
-
-
 def test_the_prefill_kernels_window_comes_with_its_first_key():
     from production_stack_tpu.ops.prefill_attention_pallas import (
         paged_prefill_attention,
