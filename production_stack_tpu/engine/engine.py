@@ -73,7 +73,8 @@ class EnqueuedStep:
 
 class LLMEngine:
     def __init__(self, config: EngineConfig, mesh=None, params=None,
-                 tokenizer: Optional[BaseTokenizer] = None):
+                 tokenizer: Optional[BaseTokenizer] = None,
+                 startup=None):
         self.config = config
         self.tokenizer = tokenizer or get_tokenizer(None)
         self.cache_manager = PagedCacheManager(config.cache)
@@ -97,7 +98,10 @@ class LLMEngine:
             sp_threshold=sp_threshold,
             guided_advance=self._guided_advance,
         )
-        self.runner = ModelRunner(config, mesh=mesh, params=params)
+        # ``startup``: the server's timeline of the start
+        # (engine/tracing.py StartupTimeline), the runner's to fill.
+        self.runner = ModelRunner(config, mesh=mesh, params=params,
+                                  startup=startup)
         if self.guided_fsm is not None:
             self.runner.set_guided_tables(self.guided_fsm)
         self.sequences: Dict[str, Sequence] = {}

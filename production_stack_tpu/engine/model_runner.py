@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -21,8 +22,13 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.perf_observatory import (
     InstrumentedJit,
     PerfObservatory,
+    add_load_splits,
+    listen_for_loads,
+    rounded_split,
+    take_load_split,
 )
 from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
+from production_stack_tpu.engine.tracing import StartupTimeline
 from production_stack_tpu.engine.sequence import (
     STOP_SET_WIDTH,
     Sequence,
@@ -382,9 +388,19 @@ class StepHandle:
 
 class ModelRunner:
     def __init__(self, config: EngineConfig, mesh=None,
-                 params=None):
+                 params=None,
+                 startup: Optional[StartupTimeline] = None):
         self.config = config
         self.mesh = mesh
+        # The start's spans (engine/tracing.py STARTUP_SPANS): the
+        # server's, from its main(), or this runner's own, which
+        # nobody reads. The weights and the cache below say where
+        # they begin, each probe is its own span (_lowering_error);
+        # the rest is ``boot.engine``.
+        self.startup = startup if startup is not None \
+            else StartupTimeline()
+        ModelRunner._probing = self.startup
+        listen_for_loads()
         model_config = config.model
         # int8 paged KV (docs/kv_quantization.md): pages stored as
         # QuantKV pytrees (int8 data + per-slot f32 scales); the write
@@ -536,33 +552,14 @@ class ModelRunner:
         if self._drafts:
             self._draft = get_draft(model_config)
 
-        if params is None and model_config.quantization == "int8":
-            # Direct int8 init: full-precision init + quantize peaks
-            # at 3x the serving footprint on device and OOMs the 8B
-            # config on a 16 GB chip (see init_random_quantized).
-            from production_stack_tpu.engine.quantization import (
-                init_random_quantized,
-            )
-            logger.info("Initializing random int8 weights for %s",
-                        model_config.name)
-            params = init_random_quantized(
-                self._init_fn, model_config, config.seed)
-        elif params is None:
-            logger.info("Initializing random weights for %s",
-                        model_config.name)
-            params = self._init_fn(
-                model_config, jax.random.PRNGKey(config.seed)
-            )
-        elif model_config.quantization == "int8":
-            from production_stack_tpu.engine.quantization import (
-                has_quantized_leaves,
-                quantize_params,
-            )
-            if not has_quantized_leaves(params):
-                logger.info("Quantizing projection weights to int8 "
-                            "(weight-only)")
-                params = quantize_params(params, model_config)
-        self.params = shard_params(params, model_config, mesh)
+        with self.startup.within("boot.weights") as span:
+            self.params = self._place_params(params, model_config, mesh)
+            # Until the arrays are there: the cache's planes below
+            # would wait for them anyway.
+            jax.block_until_ready(self.params)
+            _leaves = jax.tree_util.tree_leaves(self.params)
+            span["params_bytes"] = sum(int(getattr(x, "nbytes", 0))
+                                       for x in _leaves)
 
         # Device performance observatory (engine/perf_observatory.py):
         # exact param-tree sizes (array metadata only — no host
@@ -570,7 +567,6 @@ class ModelRunner:
         # the resolved attention impls so the silent XLA fallback is
         # an alarmable gauge rather than a log line. Set to None to
         # disable every hook (the parity tests pin that path).
-        _leaves = jax.tree_util.tree_leaves(self.params)
         try:
             _device_kind = getattr(jax.devices()[0], "device_kind", "")
         except Exception:
@@ -579,8 +575,7 @@ class ModelRunner:
             config,
             param_count=sum(int(getattr(x, "size", 0))
                             for x in _leaves),
-            params_bytes=sum(int(getattr(x, "nbytes", 0))
-                             for x in _leaves),
+            params_bytes=span["params_bytes"],
             device_kind=_device_kind)
         self.observatory.set_attention_impl(
             "decode", model_config.attention_impl_decode
@@ -589,6 +584,10 @@ class ModelRunner:
             "prefill", model_config.attention_impl_prefill
             or model_config.attention_impl)
 
+        hbm = self.observatory.hbm_bytes()
+        resume = self.startup.enter(
+            "boot.cache", bytes=hbm["kv_pages"] + hbm["kv_scales"]
+            + hbm.get("recurrent_state", 0))
         # Head-major paged cache: [L, kv_heads, pages, d, page_size].
         # The kv axis is major so TP shards a leading axis; pages are
         # token-minor so the Pallas kernels DMA (d, 128)-tile-aligned
@@ -643,6 +642,8 @@ class ModelRunner:
             raise ValueError(
                 "cache.cache_layout must be 'auto', 'stacked' or "
                 f"'per_layer' (got {self.cache_layout!r})")
+        jax.block_until_ready((self.k_cache, self.v_cache))
+        self.startup.enter(resume)
 
         self.max_pages_per_seq = config.scheduler.max_pages_per_seq(
             config.cache.page_size
@@ -864,6 +865,37 @@ class ModelRunner:
                 donate_argnums=(1, 2),  # k_cache, v_cache
             ), self)
 
+    def _place_params(self, params, model_config, mesh):
+        """The parameter tree on the device: random where none was
+        read, quantised where asked, sharded over ``mesh``."""
+        if params is None and model_config.quantization == "int8":
+            # Direct int8 init: full-precision init + quantize peaks
+            # at 3x the serving footprint on device and OOMs the 8B
+            # config on a 16 GB chip (see init_random_quantized).
+            from production_stack_tpu.engine.quantization import (
+                init_random_quantized,
+            )
+            logger.info("Initializing random int8 weights for %s",
+                        model_config.name)
+            params = init_random_quantized(
+                self._init_fn, model_config, self.config.seed)
+        elif params is None:
+            logger.info("Initializing random weights for %s",
+                        model_config.name)
+            params = self._init_fn(
+                model_config, jax.random.PRNGKey(self.config.seed)
+            )
+        elif model_config.quantization == "int8":
+            from production_stack_tpu.engine.quantization import (
+                has_quantized_leaves,
+                quantize_params,
+            )
+            if not has_quantized_leaves(params):
+                logger.info("Quantizing projection weights to int8 "
+                            "(weight-only)")
+                params = quantize_params(params, model_config)
+        return shard_params(params, model_config, mesh)
+
     def _probe_cache_struct(self, model_config, config):
         """Shared probe boilerplate: the exact serving cache struct
         (per_layer slice vs stacked + SMEM layer scalar, QuantKV when
@@ -1049,6 +1081,14 @@ class ModelRunner:
             return with_impl("xla")
         return base_model, prefill_impl
 
+    # The start's timeline that the probes under way belong to (the
+    # runner being built: __init__ sets it). A class attribute and not
+    # an argument, so that ``_lowering_error`` is called from the very
+    # frames it always was: one method's frame between the caller and
+    # it made every probe's tracing and lowering half again as slow on
+    # the v5e hosts (15 s a start; PERF.md section 6, PR 53).
+    _probing: Optional[StartupTimeline] = None
+
     @staticmethod
     def _lowering_error(fn, *args, **kwargs) -> Optional[str]:
         """Compile ``fn`` for the backend in use at ``args``' shapes;
@@ -1056,12 +1096,23 @@ class ModelRunner:
         Python lowering rules: Mosaic's machine-code pass and the
         scoped-VMEM budget refuse kernels those rules accept, and a
         refusal has to be a start-up fact rather than the first
-        request's surprise."""
-        try:
-            jax.jit(fn).lower(*args, **kwargs).compile()
-            return None
-        except Exception as e:  # noqa: BLE001 — any compile failure
-            return repr(e)[:400]
+        request's surprise. One ``boot.probe`` span of the start: the
+        kernel, the shape of its first operand, what the load was made
+        of (perf_observatory.take_load_split: its executable can come
+        from the compile cache like any other) and whether the kernel
+        is ``served`` or the case ``degraded``."""
+        timeline = ModelRunner._probing or StartupTimeline()
+        with timeline.probe(kernel=getattr(fn, "func", fn).__name__,
+                            shape=list(args[0].shape)) as span:
+            since = time.perf_counter()
+            try:
+                jax.jit(fn).lower(*args, **kwargs).compile()
+                err = None
+            except Exception as e:  # noqa: BLE001 — any compile failure
+                err = repr(e)[:400]
+            span.update(rounded_split(take_load_split(since)),
+                        result="served" if err is None else "degraded")
+        return err
 
     def _resolve_pallas_impls(self, model_config, config,
                               auto_impl: bool = False) -> None:
@@ -2152,7 +2203,12 @@ class ModelRunner:
         donates them), compiled or read from the compile cache on a
         thread. The jitted step keeps the executable with its
         lowering, so the dispatch of that shape that follows finds it
-        and is counted by ``/debug/compiles`` as any first call is."""
+        and is counted by ``/debug/compiles`` as any first call is:
+        its record takes what this thread heard of the lowering and
+        what the compiling thread heard (observatory.load_ahead), so
+        the program's load is its own and not the step's that loads
+        beside it."""
+        since = time.perf_counter()
         args = tuple(_as_device(payload[name]) for name in (
             "tokens", "positions", "page_table", "kv_lens", "valid",
             "last_index", "temperature", "top_p", "top_k", "rng"))
@@ -2167,12 +2223,21 @@ class ModelRunner:
             None if lora_ids is None else _as_device(lora_ids),
             None, None, None, None, None,
             sample_index_mode="last", want_logprobs=False, **state)
+        obs = self.observatory
+        lowering = take_load_split(since)
+        lowered_s = time.perf_counter() - since
 
         def compile_it():
+            since = time.perf_counter()
             try:
                 lowered.compile()
             except Exception:  # noqa: BLE001 — the dispatch raises it
                 logger.exception("prefill program failed to compile")
+            if obs is not None:
+                obs.load_ahead(
+                    "step", payload["tokens"].shape,
+                    lowered_s + time.perf_counter() - since,
+                    add_load_splits(lowering, take_load_split(since)))
 
         thread = threading.Thread(target=compile_it, daemon=True,
                                   name="prefill-width-compile")

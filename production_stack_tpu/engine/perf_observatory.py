@@ -6,9 +6,12 @@ and allocation-free on the committed-token path:
 - **compile ledger** — every jitted step program is wrapped in
   :class:`InstrumentedJit`; a growth of the executable cache between
   two calls is a compile event, recorded with its kind, wall time and
-  the ``(rows, W)`` shape key that triggered it. A recompile storm
-  shows up on the dashboard within one scrape instead of only in a
-  slow test.
+  the ``(rows, W)`` shape key that triggered it, and with what the
+  load was made of as ``jax.monitoring`` published it meanwhile
+  (:func:`listen_for_loads`): Python tracing, lowering, the backend's
+  compile, a read from the persistent cache inside it, and whether
+  that cache had the executable. A recompile storm shows up on the
+  dashboard within one scrape instead of only in a slow test.
 - **HBM memory ledger** — an always-available analytic breakdown of
   device bytes from the engine config (weights from the actual param
   tree, KV pages + int8 scale tensors from the page math, step
@@ -31,6 +34,7 @@ from __future__ import annotations
 
 import collections
 import statistics
+import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -63,6 +67,138 @@ def resolve_peak_flops(device_kind: Optional[str],
     return 0.0
 
 
+# ---- what a program load is made of ----------------------------------------
+
+# The seconds of a load by part, as a compile record, a ``boot.probe``
+# span and ``compile_report()["parts"]`` carry them. ``cache_read_s``
+# is inside ``backend_s``: jax asks the persistent cache from within
+# the stage it times as the backend's compile.
+LOAD_PARTS = ("trace_s", "lower_s", "backend_s", "cache_read_s")
+
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class _Heard(threading.local):
+    """What this thread has heard since it last took: ``[end, what,
+    value]`` on ``perf_counter``'s clock, and the profiler events of
+    the stages under way, the outermost first."""
+
+    def __init__(self):
+        self.entries: List[list] = []
+        self.marks: List[Any] = []
+
+
+_heard = _Heard()
+_annotate: Any = None  # jax.profiler.TraceAnnotation once listening
+
+
+def _hear_stage_start(event: str, value: float, **kwargs: Any) -> None:
+    """jax says a stage begins (the scalar that precedes a duration):
+    it is a profiler event from here to its duration,
+    ``engine.load.trace|lower|backend``, on the thread that runs it."""
+    part = _STAGES.get(event)
+    if part is not None:
+        mark = _annotate("engine.load." + part[:-2],
+                         fun=str(kwargs.get("fun_name")))
+        mark.__enter__()
+        _heard.marks.append(mark)
+
+
+def _hear_duration(event: str, duration: float, **kwargs: Any) -> None:
+    heard = _heard
+    part = _STAGES.get(event)
+    if part is not None:
+        if heard.marks:
+            heard.marks.pop().__exit__(None, None, None)
+        if heard.marks:
+            # A stage inside another on this thread (a jitted helper
+            # traced inside the step's trace or inside a kernel's
+            # lowering, hundreds a kernel) is in the outer's seconds:
+            # the parts of a load stay disjoint.
+            return
+    elif event == _CACHE_READ and len(heard.marks) <= 1:
+        part = "cache_read_s"
+    else:
+        return
+    heard.entries.append([time.perf_counter(), part, duration])
+    if len(heard.entries) > 256:  # a thread that loads and never takes
+        del heard.entries[:128]
+
+
+def _hear_event(event: str, **kwargs: Any) -> None:
+    # Asked of the cache from inside the outermost backend stage: a
+    # load nested in another's stage is the outer's.
+    if ((event == _CACHE_ASKED or event == _CACHE_HIT)
+            and len(_heard.marks) <= 1):
+        _heard.entries.append([time.perf_counter(), event, 1])
+
+
+_listening = False
+
+
+def listen_for_loads() -> None:
+    """Registers the listeners, once a process however many runners it
+    builds, before the first compile. They run only when jax traces,
+    lowers, compiles or reads its cache: a call that finds its
+    executable hears nothing and allocates nothing."""
+    global _listening, _annotate
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring, profiler
+    _annotate = profiler.TraceAnnotation
+    monitoring.register_scalar_listener(_hear_stage_start)
+    monitoring.register_event_duration_secs_listener(_hear_duration)
+    monitoring.register_event_listener(_hear_event)
+
+
+def take_load_split(since: float) -> Dict[str, Any]:
+    """What the calling thread heard since ``since`` (``perf_counter``)
+    as a record's split: the seconds by part and ``cache``, ``hit``
+    where the persistent cache gave every executable that was asked of
+    it, ``miss`` where the backend compiled one it was asked for,
+    ``none`` where it was asked nothing (all was in the process, or
+    there is no cache). What was heard before ``since`` belongs to
+    nobody who will ask, and goes too."""
+    split: Dict[str, Any] = dict.fromkeys(LOAD_PARTS, 0.0)
+    asked = hits = 0
+    for end, what, value in _heard.entries:
+        if end < since:
+            continue
+        if what == _CACHE_ASKED:
+            asked += 1
+        elif what == _CACHE_HIT:
+            hits += 1
+        else:
+            split[what] += value
+    _heard.entries.clear()
+    split["cache"] = ("none" if not asked
+                      else "hit" if hits >= asked else "miss")
+    return split
+
+
+def add_load_splits(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
+    """One load heard on two threads (lowered on one, compiled on
+    another) as one split."""
+    out: Dict[str, Any] = {p: a[p] + b[p] for p in LOAD_PARTS}
+    caches = {a["cache"], b["cache"]} - {"none"}
+    out["cache"] = ("none" if not caches
+                    else "miss" if "miss" in caches else "hit")
+    return out
+
+
+def rounded_split(split: Dict[str, Any]) -> Dict[str, Any]:
+    return {**{p: round(split[p], 6) for p in LOAD_PARTS},
+            "cache": split["cache"]}
+
+
 class PerfObservatory:
     """Host-side device-performance ledgers for one model runner.
 
@@ -88,14 +224,18 @@ class PerfObservatory:
         # ---- compile ledger ------------------------------------------
         self._compile_events: Dict[str, int] = {}
         self._compile_seconds: Dict[str, float] = {}
-        self._cache_sizes: Dict[str, int] = {}
+        self._compile_parts: Dict[str, Dict[str, float]] = {}
+        self._cache_results = {"hit": 0, "miss": 0}
         self._jits: Dict[str, Any] = {}
         self._compile_ring: Deque[Dict[str, Any]] = collections.deque(
             maxlen=compile_ring_size)
+        # (kind, key) -> (seconds, split) of a program brought up
+        # ahead of its first call (load_ahead), until that call's
+        # record takes them.
+        self._ahead: Dict[tuple, tuple] = {}
 
         # ---- step / MFU ledger ---------------------------------------
         self._device_seconds: Dict[str, float] = {}
-        self._tokens: Dict[str, int] = {}
         self.device_seconds_total = 0.0
         self.tokens_total = 0
         # Bounded per-kind ring of recent step durations; its medians
@@ -115,22 +255,40 @@ class PerfObservatory:
         live executable-cache-size reads."""
         self._compile_events.setdefault(kind, 0)
         self._compile_seconds.setdefault(kind, 0.0)
-        self._cache_sizes.setdefault(kind, 0)
+        self._compile_parts.setdefault(
+            kind, dict.fromkeys(LOAD_PARTS, 0.0))
         self._jits[kind] = fn
+
+    def load_ahead(self, kind: str, key: Tuple[int, ...],
+                   seconds: float, split: Dict[str, Any]) -> None:
+        """A program of ``kind`` and shape ``key`` was lowered and
+        compiled before its first call (the half-width prefill
+        program, model_runner._load_step_program): that call's record
+        is the program's, and takes these seconds and this split."""
+        self._ahead[(kind, tuple(key))] = (float(seconds), split)
 
     def on_compile(self, kind: str,
                    key: Optional[Tuple[int, ...]],
-                   seconds: float, cache_size: int) -> None:
+                   seconds: float, split: Dict[str, Any]) -> None:
+        ahead = self._ahead.pop((kind, key), None)
+        if ahead is not None:
+            seconds += ahead[0]
+            split = add_load_splits(ahead[1], split)
         self._compile_events[kind] = self._compile_events.get(kind, 0) + 1
         self._compile_seconds[kind] = (
             self._compile_seconds.get(kind, 0.0) + float(seconds))
-        self._cache_sizes[kind] = int(cache_size)
+        parts = self._compile_parts.setdefault(
+            kind, dict.fromkeys(LOAD_PARTS, 0.0))
+        for part in LOAD_PARTS:
+            parts[part] += split[part]
+        if split["cache"] != "none":
+            self._cache_results[split["cache"]] += 1
         self._compile_ring.append({
             "kind": kind,
             "key": list(key) if key is not None else None,
             "seconds": round(float(seconds), 6),
-            "cache_size": int(cache_size),
             "ts": time.time(),
+            **rounded_split(split),
         })
 
     def compile_events_by_kind(self) -> Dict[str, int]:
@@ -144,21 +302,24 @@ class PerfObservatory:
             return self._compile_events.get(kind, 0)
         return sum(self._compile_events.values())
 
+    def compile_parts_by_kind(self) -> Dict[str, Dict[str, float]]:
+        """The compile seconds of each kind by part (LOAD_PARTS)."""
+        return {kind: dict(parts)
+                for kind, parts in self._compile_parts.items()}
+
+    def cache_results(self) -> Dict[str, int]:
+        """Program loads the persistent cache answered (``hit``) and
+        loads the backend compiled though it was asked (``miss``)."""
+        return dict(self._cache_results)
+
     def executable_cache_sizes(self) -> Dict[str, int]:
         """Live per-kind executable-cache sizes, read from the jit
-        handles where the runtime exposes ``_cache_size`` and falling
-        back to the last compile-time observation otherwise."""
+        handles (0 where the runtime exposes no ``_cache_size``, and
+        then InstrumentedJit counts no compile either)."""
         sizes: Dict[str, int] = {}
-        for kind, tracked in self._cache_sizes.items():
-            fn = self._jits.get(kind)
+        for kind, fn in self._jits.items():
             size_fn = getattr(fn, "_cache_size", None)
-            if callable(size_fn):
-                try:
-                    sizes[kind] = int(size_fn())
-                    continue
-                except Exception:
-                    pass
-            sizes[kind] = tracked
+            sizes[kind] = int(size_fn()) if callable(size_fn) else 0
         return sizes
 
     def recent_compiles(self, limit: int = 32) -> List[Dict[str, Any]]:
@@ -172,6 +333,9 @@ class PerfObservatory:
             "events": self.compile_events_by_kind(),
             "seconds": {k: round(v, 6)
                         for k, v in self._compile_seconds.items()},
+            "parts": {kind: {p: round(v, 6) for p, v in parts.items()}
+                      for kind, parts in self._compile_parts.items()},
+            "cache": dict(self._cache_results),
             "executable_cache_sizes": self.executable_cache_sizes(),
             "recent": self.recent_compiles(limit),
         }
@@ -247,7 +411,6 @@ class PerfObservatory:
     def on_step(self, kind: str, device_s: float, tokens: int) -> None:
         self._device_seconds[kind] = (
             self._device_seconds.get(kind, 0.0) + float(device_s))
-        self._tokens[kind] = self._tokens.get(kind, 0) + int(tokens)
         self.device_seconds_total += float(device_s)
         self.tokens_total += int(tokens)
         ring = self._step_durations.get(kind)
@@ -268,9 +431,6 @@ class PerfObservatory:
             if ring:
                 out[kind] = statistics.median(ring)
         return out
-
-    def tokens_by_kind(self) -> Dict[str, int]:
-        return dict(self._tokens)
 
     def mfu(self) -> float:
         """Useful-token MFU: committed/processed tokens (prefill chunk
@@ -293,13 +453,30 @@ class PerfObservatory:
         return dict(self._attention_impls)
 
 
+def program_key(args: tuple, kwargs: dict) -> Optional[Tuple[int, ...]]:
+    """The ``(rows, W)`` that names the step program a call compiled.
+    ``args[3]`` is the tokens block of every step program: ``[rows,
+    W]`` for a prefill, verify or unified step, ``[rows]`` for a
+    single decode step (W = 1), and ``[rows, 1]`` for a burst, plain or
+    drafting, whose program is one a ``num_steps``: its W is the steps
+    it runs."""
+    if len(args) <= 3 or not hasattr(args[3], "shape"):
+        return None
+    shape = tuple(int(d) for d in args[3].shape)
+    steps = kwargs.get("num_steps")
+    if steps is not None:
+        return (shape[0], int(steps))
+    return shape if len(shape) > 1 else shape + (1,)
+
+
 class InstrumentedJit:
     """Transparent wrapper around one jitted step program.
 
     Detects compile events as growth of the executable cache between
     two calls (compilation is synchronous inside ``__call__`` even
     under async dispatch, so the wall-clock delta on a growing call is
-    trace+compile time). The owner's ``observatory`` attribute is
+    trace+compile time, and what the thread heard of jax's stages
+    meanwhile is its split). The owner's ``observatory`` attribute is
     looked up at call time: set it to ``None`` and every call is a
     plain passthrough — the parity tests pin that path.
 
@@ -325,13 +502,8 @@ class InstrumentedJit:
         out = self.fn(*args, **kwargs)
         after = size_fn()
         if after != before:
-            key: Optional[Tuple[int, ...]] = None
-            # args[3] is the tokens block for every step program —
-            # its (rows, W) shape is the bucket key that compiled.
-            if len(args) > 3 and hasattr(args[3], "shape"):
-                key = tuple(int(d) for d in args[3].shape)
-            obs.on_compile(self.kind, key,
-                           time.perf_counter() - t0, after)
+            obs.on_compile(self.kind, program_key(args, kwargs),
+                           time.perf_counter() - t0, take_load_split(t0))
         return out
 
     def _cache_size(self) -> int:

@@ -49,6 +49,8 @@ from production_stack_tpu.kvecon.summary import (
     routable_text,
 )
 from production_stack_tpu.engine.engine import LLMEngine
+from production_stack_tpu.engine.perf_observatory import LOAD_PARTS
+from production_stack_tpu.engine.tracing import StartupTimeline
 from production_stack_tpu.qos import (
     parse_priority,
     Priority,
@@ -615,6 +617,11 @@ class EngineServer:
                  build_id: str = ""):
         self.async_engine = AsyncEngine(engine)
         self.engine = engine
+        # The start's spans (engine/tracing.py StartupTimeline), the
+        # runner's: main()'s where the process began there. /version
+        # and /metrics read it; build_app's on_startup closes it.
+        self.startup = (getattr(getattr(engine, "runner", None),
+                                "startup", None) or StartupTimeline())
         self.model_name = served_model_name
         self.tokenizer = engine.tokenizer
         self.pooling = pooling
@@ -2267,9 +2274,10 @@ class EngineServer:
     async def debug_compiles(self, request: web.Request):
         """GET /debug/compiles[?limit=N]: the device performance
         observatory's compile ledger — per-kind event/seconds
-        counters, live executable-cache sizes and the bounded ring
-        of recent compiles with their (rows, W) shape keys
-        (docs/observability.md)."""
+        counters and the seconds by part of a load, how the
+        persistent cache answered, live executable-cache sizes and
+        the bounded ring of recent compiles with their (rows, W)
+        shape keys and each one's split (docs/observability.md)."""
         obs = getattr(self.engine.runner, "observatory", None)
         if obs is None:
             return web.json_response(
@@ -2373,6 +2381,11 @@ class EngineServer:
             **window,
             "family": config.model.architecture,
             **kv,
+            # The start by span, from the process's first instant to
+            # the listener, on the unix clock (engine/tracing.py
+            # STARTUP_SPANS; docs/observability.md, "Why is a start
+            # slow?").
+            "startup": self.startup.to_dict(),
         })
 
     async def kv_summary_handler(self, request: web.Request):
@@ -2545,6 +2558,31 @@ class EngineServer:
                 lines.append(
                     "vllm:engine_compile_seconds_total{kind=\""
                     f"{kind}\"}} {float(secs)}")
+            # What the loads were made of, the probes' among them (a
+            # ``backend`` second may be a ``cache_read`` second too:
+            # jax reads its cache inside the stage it times as the
+            # backend's), and how the persistent cache answered.
+            probes = [s for s in self.startup.spans
+                      if s["name"] == "boot.probe"]
+            parts = obs.compile_parts_by_kind().values()
+            lines.append("# TYPE vllm:engine_compile_part_seconds_total "
+                         "counter")
+            for part in LOAD_PARTS:
+                lines.append(
+                    "vllm:engine_compile_part_seconds_total{part=\""
+                    f"{part[:-2]}\"}} "
+                    f"{sum(p[part] for p in (*parts, *probes))}")
+            lines.append("# TYPE vllm:engine_compile_cache_total "
+                         "counter")
+            for result, count in obs.cache_results().items():
+                count += sum(s["cache"] == result for s in probes)
+                lines.append(
+                    "vllm:engine_compile_cache_total{result=\""
+                    f"{result}\"}} {float(count)}")
+            lines.append("# TYPE vllm:engine_startup_seconds gauge")
+            for span, secs in self.startup.seconds_by_span().items():
+                lines.append("vllm:engine_startup_seconds{span=\""
+                             f"{span}\"}} {secs}")
             lines.append("# TYPE vllm:engine_executable_cache_size "
                          "gauge")
             for kind, size in sorted(
@@ -2676,6 +2714,7 @@ class EngineServer:
 
         async def on_startup(app):
             self.async_engine.start(asyncio.get_event_loop())
+            self.startup.ready()
 
         app.on_startup.append(on_startup)
         return app
@@ -2775,7 +2814,12 @@ def _resolve_unified_step(args, model_config=None) -> bool:
         engine_role=getattr(args, "engine_role", "both"))
 
 
-def build_engine_from_args(args) -> tuple[LLMEngine, str]:
+def build_engine_from_args(args, startup=None) -> tuple[LLMEngine, str]:
+    """``startup``: main()'s timeline of the start (engine/tracing.py
+    StartupTimeline); a checkpoint's read and the runner's probes,
+    weights and cache are spans of it, the rest ``boot.engine``."""
+    if startup is None:
+        startup = StartupTimeline()
     mesh = None
     if args.model in ("tiny-llama", "tiny-opt"):
         model_config = tiny_model_config(args.model.split("-")[1])
@@ -2809,9 +2853,11 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
         model_config = load_model_config(args.model)
         if args.dtype:
             model_config.dtype = args.dtype
-        params = (None if args.random_weights
-                  else load_weights(args.model, model_config))
-        tokenizer = get_tokenizer(args.tokenizer or args.model)
+        with startup.within("boot.weights"):
+            params = (None if args.random_weights
+                      else load_weights(args.model, model_config))
+        with startup.within("boot.tokenizer"):
+            tokenizer = get_tokenizer(args.tokenizer or args.model)
         served_name = args.served_model_name or args.model
     model_config.quantization = args.quantization
     model_config.attention_impl = args.attention_impl
@@ -2910,7 +2956,7 @@ def build_engine_from_args(args) -> tuple[LLMEngine, str]:
         step_watchdog_s=args.step_watchdog_s,
     )
     engine = LLMEngine(config, mesh=mesh, params=params,
-                       tokenizer=tokenizer)
+                       tokenizer=tokenizer, startup=startup)
     for module in args.lora_modules or []:
         name, _, path = module.partition("=")
         if not path:
@@ -3275,24 +3321,28 @@ def _load_chat_template(args) -> Optional[str]:
     return source
 
 
-def _claim_devices(args) -> None:
+def _claim_devices(args, startup) -> None:
     """Initialize the JAX backend now, so a device that cannot serve
     what was asked is a start-up error with a reason. A chip belongs
     to one process at a time and nothing here pins a process to a
     device: every engine process claims every chip of its host, so a
     second engine on the same host (or a parent that already touched
-    JAX) cannot start (README "One process per chip")."""
+    JAX) cannot start (README "One process per chip"). The start's
+    ``boot.claim_devices`` span (``startup``)."""
     import os
 
     import jax
-    try:
-        devices = jax.devices()
-    except RuntimeError as e:
-        raise SystemExit(
-            "tpu-engine: cannot claim the accelerator: " + str(e)
-            + "\nA chip belongs to one process at a time — is another "
-            "engine, benchmark or Python session holding it?") from e
-    platform = devices[0].platform
+    with startup.within("boot.claim_devices") as span:
+        try:
+            devices = jax.devices()
+        except RuntimeError as e:
+            raise SystemExit(
+                "tpu-engine: cannot claim the accelerator: " + str(e)
+                + "\nA chip belongs to one process at a time — is "
+                "another engine, benchmark or Python session holding "
+                "it?") from e
+        platform = devices[0].platform
+        span.update(platform=platform, devices=len(devices))
     logger.info("Devices: %d x %s (%s)", len(devices),
                 devices[0].device_kind, platform)
     if platform == "cpu" and "cpu" not in os.environ.get(
@@ -3312,6 +3362,10 @@ def _claim_devices(args) -> None:
 
 
 def main(argv=None) -> None:
+    # The start's timeline, from the process's first instant: what
+    # follows until the listener is in one of its spans.
+    import jax
+    startup = StartupTimeline(annotate=jax.profiler.TraceAnnotation)
     args = parse_args(argv)
     # Persistent executable cache: a restarted pod (weight PVC + this
     # cache) resumes serving without the cold-compile wait.
@@ -3342,7 +3396,7 @@ def main(argv=None) -> None:
             )
         init_distributed(args.coordinator_address, args.num_processes,
                          args.process_id)
-        engine, served_name = build_engine_from_args(args)
+        engine, served_name = build_engine_from_args(args, startup)
         # Size the liveness ledger from the discovered topology so a
         # dead host's missing acks name one slice on /metrics.
         from production_stack_tpu.parallel.topology import (
@@ -3383,14 +3437,15 @@ def main(argv=None) -> None:
         logger.info("tpu-engine %s (multihost coordinator) serving %s "
                     "on %s:%d", __version__, served_name, args.host,
                     args.port)
+        startup.enter("boot.listen")
         try:
             web.run_app(server.build_app(), host=args.host,
                         port=args.port, print=None)
         finally:
             bridge.shutdown()
         return
-    _claim_devices(args)
-    engine, served_name = build_engine_from_args(args)
+    _claim_devices(args, startup)
+    engine, served_name = build_engine_from_args(args, startup)
     server = EngineServer(engine, served_name, pooling=args.pooling,
                           profile_dir=args.profile_dir,
                           chat_template=_load_chat_template(args),
@@ -3398,6 +3453,7 @@ def main(argv=None) -> None:
                           build_id=args.build_id)
     logger.info("tpu-engine %s serving %s on %s:%d",
                 __version__, served_name, args.host, args.port)
+    startup.enter("boot.listen")
     web.run_app(server.build_app(), host=args.host, port=args.port,
                 print=None)
 

@@ -46,6 +46,13 @@ event loop's thread, so a profiler slice shows what both threads of
 the host did while the device idled. A turn far slower than its
 kind's recent median logs one ``slow turn`` WARNING.
 
+Before the first turn there is the start (``StartupTimeline``): the
+thread that builds the engine is always in exactly one ``boot.*`` span
+of ``STARTUP_SPANS``, from the instant the kernel started the process
+to the HTTP listener, on the unix clock; ``/version`` carries it
+(``startup``) and ``/metrics`` its sums
+(docs/observability.md, "Why is a start slow?").
+
 Concurrency: the engine's device loop, the asyncio handlers, and the
 drain path all touch the tracer. Every mutation is a GIL-atomic dict
 or ``deque(maxlen=...)`` operation — no lock is taken on the step or
@@ -68,8 +75,10 @@ loop's thread reads no clock: an addition a wake and ``is None`` checks.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
+import os
 import statistics
 import threading
 import time
@@ -80,6 +89,10 @@ from typing import Any, Callable, Dict, List, Optional
 from production_stack_tpu.utils.log import init_logger
 
 logger = init_logger(__name__)
+
+# The start of the process where the kernel does not say it
+# (process_start_unix): this module is imported on the way to main().
+_IMPORTED_UNIX = time.time()
 
 # The closed vocabulary of engine span event names. The staticcheck
 # ``span-contract`` rule holds this tuple, every string literal passed
@@ -126,6 +139,28 @@ TURN_PHASES = (
     "other",     # autotuner tick, back-off waits, the rest
 )
 
+# The closed vocabulary of a start's spans (StartupTimeline). ``boot``
+# is the whole; ``boot.probe`` is one probed case inside
+# ``boot.probes``; every other is a child of ``boot``, and the thread
+# that builds the engine is in exactly one of them at any instant, so
+# they tile ``boot`` from the process's start to the listener. The
+# profiler annotation of a span is ``engine.<name>``. tests/
+# test_startup_timeline.py holds this tuple, every literal passed to
+# ``*.enter(...)`` / ``*.within(...)`` across the package and the
+# table in docs/observability.md in agreement.
+STARTUP_SPANS = (
+    "boot",                # the process's start -> the listener is up
+    "boot.imports",        # the process's start -> main() entered
+    "boot.claim_devices",  # the backend's initialisation
+    "boot.probes",         # the kernels' lowering probes, all of them
+    "boot.probe",          # one probed case
+    "boot.weights",        # init or checkpoint read, quantise, shard
+    "boot.cache",          # the page planes and the state pool
+    "boot.tokenizer",      # a model directory's tokenizer, its imports
+    "boot.engine",         # the rest: arguments, scheduler, the tracer
+    "boot.listen",         # web.run_app -> on_startup
+)
+
 # Phases in which the loop thread is off the CPU because that is what
 # the phase is: blocked on the device, or parked with nothing to serve.
 # In every other phase the thread has work, and wall less CPU there is
@@ -147,6 +182,147 @@ def _ms(a: Optional[float], b: Optional[float]) -> Optional[float]:
     if a is None or b is None:
         return None
     return round((b - a) * 1e3, 2)
+
+
+def process_start_unix() -> Optional[float]:
+    """When the kernel started this process, on the unix clock, to the
+    hundredth of a second that ``/proc`` keeps: the age of the process
+    is the machine's uptime less the start time of ``/proc/self/stat``
+    (field 22, in clock ticks since the machine came up). None where
+    that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # After the command's closing bracket (a command may hold
+            # spaces): field 3 is the first there, field 22 the 20th.
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+    return time.time() - age if age >= 0 else None
+
+
+class StartupTimeline:
+    """A start's spans, in memory: ``name``, ``parent``, ``t_start``
+    (unix), ``seconds`` (None while open) and small fields.
+
+    ``boot`` opens at the process's start and ``boot.imports`` fills it
+    up to this object's creation (the top of ``server.main()``). From
+    there the creating thread is in ``boot.engine`` unless it has said
+    otherwise: ``enter(name)`` closes the span it is in and opens
+    ``name`` at the same clock read, ``within(name)`` does so for a
+    block and goes back, so the children of ``boot`` are contiguous and
+    ``boot`` has no time of its own. ``probe()`` is one ``boot.probe``
+    inside ``boot.probes``; ``ready()`` closes the last child and
+    ``boot``. One thread writes; ``/version`` and ``/metrics`` read
+    after ``ready()``. A runner built with no timeline handed to it
+    makes its own, which nobody reads: a few dozen clock reads.
+    """
+
+    def __init__(self, annotate: Optional[Callable[..., Any]] = None,
+                 clock: Callable[[], float] = time.time,
+                 process_start: Optional[float] = None):
+        self.annotate = annotate
+        self._clock = clock
+        now = clock()
+        if process_start is None:
+            process_start = process_start_unix() or _IMPORTED_UNIX
+        self.process_start_unix = min(process_start, now)
+        self.ready_unix: Optional[float] = None
+        self.spans: List[Dict[str, Any]] = []
+        self._marks: List[Any] = []
+        self._boot = self._open("boot", self.process_start_unix)
+        self._close(self._open("boot.imports", self.process_start_unix),
+                    now)
+        self._current = self._open("boot.engine", now, live=True)
+
+    def _open(self, name: str, now: float, live: bool = False,
+              **fields: Any) -> Dict[str, Any]:
+        """``live``: the span opens now and not in the past, so it can
+        be a profiler event too."""
+        parent = {"boot": None, "boot.probe": "boot.probes"}.get(
+            name, "boot")
+        span = {"name": name, "parent": parent, "t_start": now,
+                "seconds": None, **fields}
+        self.spans.append(span)
+        if live and self.annotate is not None:
+            mark = self.annotate("engine." + name)
+            mark.__enter__()
+            self._marks.append(mark)
+        return span
+
+    def _close(self, span: Dict[str, Any], now: float,
+               live: bool = False) -> None:
+        span["seconds"] = now - span["t_start"]
+        if live and self._marks:
+            self._marks.pop().__exit__(None, None, None)
+
+    def enter(self, name: str, **fields: Any) -> str:
+        """The building thread goes over to ``name`` (a child of
+        ``boot``); returns the span's name it was in. After
+        ``ready()`` a start has no more spans."""
+        old = self._current["name"]
+        if self.ready_unix is None:
+            now = self._clock()
+            self._close(self._current, now, live=True)
+            self._current = self._open(name, now, live=True, **fields)
+        return old
+
+    @contextlib.contextmanager
+    def within(self, name: str, **fields: Any):
+        """``name`` for a block, then back to the span before; yields
+        the span, for the fields that are known at its end."""
+        old = self.enter(name, **fields)
+        try:
+            yield self._current
+        finally:
+            self.enter(old)
+
+    @contextlib.contextmanager
+    def probe(self, **fields: Any):
+        """One ``boot.probe``: inside ``boot.probes``, which it enters
+        for its own length where the thread is elsewhere."""
+        with (contextlib.nullcontext()
+              if self._current["name"] == "boot.probes"
+              else self.within("boot.probes")):
+            span = self._open("boot.probe", self._clock(), live=True,
+                              **fields)
+            try:
+                yield span
+            finally:
+                self._close(span, self._clock(), live=True)
+
+    def ready(self) -> None:
+        """The listener is up: the last child and ``boot`` end here."""
+        if self.ready_unix is not None:
+            return
+        now = self.ready_unix = self._clock()
+        self._close(self._current, now, live=True)
+        self._close(self._boot, now)
+        self._boot.update(process_start_unix=self.process_start_unix,
+                          ready_unix=now)
+
+    def seconds_by_span(self) -> Dict[str, float]:
+        """Closed seconds by name, ``boot`` and its children (a name
+        entered more than once is one sum)."""
+        out: Dict[str, float] = {}
+        for span in self.spans:
+            if span["seconds"] is not None and span["parent"] in (
+                    None, "boot"):
+                out[span["name"]] = (out.get(span["name"], 0.0)
+                                     + span["seconds"])
+        return out
+
+    def to_dict(self) -> Dict[str, Any]:
+        """``/version``'s ``startup``: the spans so far."""
+        def rounded(span):
+            return {k: round(v, 6) if isinstance(v, float) else v
+                    for k, v in span.items()}
+        return {"process_start_unix": round(self.process_start_unix, 6),
+                "ready_unix": (None if self.ready_unix is None
+                               else round(self.ready_unix, 6)),
+                "spans": [rounded(s) for s in self.spans]}
 
 
 class EngineSpan:

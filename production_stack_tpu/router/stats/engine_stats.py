@@ -140,6 +140,12 @@ _ROUTER_UNSCRAPED = frozenset({
     # Prefill steps run at the half width (engine model runner): how
     # often a step is at most half full; an operator's rate.
     "vllm:engine_prefill_narrow_steps_total",
+    # Why a start was slow (docs/observability.md): the start by
+    # span, the loads by part, the compile cache's answers. An
+    # operator's question of one pod, not a routing signal.
+    "vllm:engine_startup_seconds",
+    "vllm:engine_compile_part_seconds_total",
+    "vllm:engine_compile_cache_total",
     # Autotune decision counts are an operator/dashboard rate, not a
     # routing signal — cluster Prometheus reads them directly.
     "vllm:autotune_decisions_total",

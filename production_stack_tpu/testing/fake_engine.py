@@ -86,7 +86,10 @@ from aiohttp import web
 # Stdlib-only by design (no JAX, no engine imports beyond it): the fake
 # reuses the real engine's tracer so router-side stitching tests see
 # genuine {"span": "engine_request"} lines without a TPU.
-from production_stack_tpu.engine.tracing import EngineTracer
+from production_stack_tpu.engine.tracing import (
+    EngineTracer,
+    StartupTimeline,
+)
 from production_stack_tpu.version import __version__
 from production_stack_tpu.kvecon.summary import (
     chain_text,
@@ -234,6 +237,11 @@ class FakeEngineState:
         # engine-span lines and serve /debug/trace/{id} as the real
         # server. None disables tracing entirely.
         self.tracer: Optional[EngineTracer] = None
+        # The start's spans (engine/tracing.py StartupTimeline): the
+        # fake has no device to claim and nothing to probe, so its
+        # start is ``boot.imports``, ``boot.engine`` and
+        # ``boot.listen``.
+        self.startup = StartupTimeline()
         # Cluster KV economy (docs/kv_economy.md): capped LRU hot set
         # of text-domain prefix chain hashes — the fake's stand-in for
         # "which prefixes have live KV here". The CAP matters: a fake
@@ -1247,19 +1255,30 @@ async def debug_compiles(request: web.Request) -> web.Response:
         return web.json_response(
             {"error": {"message": "limit must be an integer"}},
             status=400)
+    def split(trace_s, lower_s, backend_s, cache_read_s, cache):
+        return {"trace_s": trace_s, "lower_s": lower_s,
+                "backend_s": backend_s, "cache_read_s": cache_read_s,
+                "cache": cache}
+
     recent = [
-        {"kind": "step", "key": [4, 16], "seconds": 0.4,
-         "cache_size": 1, "ts": 0.0},
-        {"kind": "step", "key": [4, 32], "seconds": 0.45,
-         "cache_size": 2, "ts": 1.0},
-        {"kind": "step", "key": [8, 32], "seconds": 0.4,
-         "cache_size": 3, "ts": 2.0},
-        {"kind": "unified", "key": [12, 32], "seconds": 0.5,
-         "cache_size": 1, "ts": 3.0},
+        {"kind": "step", "key": [4, 16], "seconds": 0.4, "ts": 0.0,
+         **split(0.1, 0.1, 0.15, 0.05, "hit")},
+        {"kind": "step", "key": [4, 32], "seconds": 0.45, "ts": 1.0,
+         **split(0.1, 0.1, 0.2, 0.0, "miss")},
+        {"kind": "step", "key": [8, 32], "seconds": 0.4, "ts": 2.0,
+         **split(0.1, 0.1, 0.15, 0.05, "hit")},
+        {"kind": "unified", "key": [12, 32], "seconds": 0.5, "ts": 3.0,
+         **split(0.15, 0.1, 0.2, 0.1, "hit")},
     ]
     return web.json_response({
         "events": {"step": 3, "unified": 1},
         "seconds": {"step": 1.25, "unified": 0.5},
+        "parts": {kind: {part: round(sum(r[part] for r in recent
+                                         if r["kind"] == kind), 6)
+                         for part in ("trace_s", "lower_s", "backend_s",
+                                      "cache_read_s")}
+                  for kind in ("step", "unified")},
+        "cache": {"hit": 3, "miss": 1},
         "executable_cache_sizes": {"step": 3, "unified": 1},
         "recent": recent[-limit:] if limit >= 0 else recent,
     })
@@ -1268,11 +1287,13 @@ async def debug_compiles(request: web.Request) -> web.Response:
 async def version(request: web.Request) -> web.Response:
     """GET /version: the identity fields of the real server's reply
     (the package version — the fake IS this package — and the
-    deployed build id for rollout membership checks). The real server
+    deployed build id for rollout membership checks) and the start's
+    spans, from the same class as the real server's. The real server
     also names its device and attention impls; the fake has none."""
     state: FakeEngineState = request.app["state"]
     return web.json_response({"version": __version__,
-                              "build_id": state.build_id})
+                              "build_id": state.build_id,
+                              "startup": state.startup.to_dict()})
 
 
 async def debug_steps(request: web.Request) -> web.Response:
@@ -1359,6 +1380,12 @@ def build_fake_engine(model: str = "fake/model", speed: float = 100.0,
     app.router.add_post("/fault", set_fault)
     app.router.add_post("/drain", drain)
     app.router.add_post("/gauges", set_gauges)
+
+    async def on_startup(app):
+        state.startup.ready()
+
+    state.startup.enter("boot.listen")
+    app.on_startup.append(on_startup)
     return app
 
 

@@ -98,7 +98,7 @@ def test_compile_ledger_first_run_then_stable():
     for entry in obs.recent_compiles():
         assert entry["kind"] == "step"
         assert entry["seconds"] >= 0
-        assert entry["cache_size"] >= 1
+        assert entry["cache"] in ("hit", "miss", "none")
         assert isinstance(entry["key"], list)
     # Same shapes again: a warm engine must not compile.
     _run(engine, range(30, 40))

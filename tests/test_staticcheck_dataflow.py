@@ -631,9 +631,11 @@ def test_fake_engine_serves_version_like_the_real_server():
             # The identity fields of EngineServer.version: the build
             # identity rides along so rollouts can verify a canary's
             # revision (docs/fleet.md); empty when no --build-id was
-            # given. (The real server also names its device.)
-            assert await resp.json() == {"version": __version__,
-                                         "build_id": ""}
+            # given. (The real server also names its device.) And the
+            # start's spans, from the real server's class.
+            reply = await resp.json()
+            assert reply.pop("startup")["spans"][0]["name"] == "boot"
+            assert reply == {"version": __version__, "build_id": ""}
         finally:
             await client.close()
 
