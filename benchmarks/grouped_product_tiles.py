@@ -1,7 +1,8 @@
 """The routed experts alone (``ops/moe.py`` ``held_experts``: the sort,
-the gather, the two grouped products and the sum) at the five expert
-cells' shapes and loads, many calls inside ONE program, under several
-choices of the ``megablox`` kernel's tiles.
+the gather, the two grouped products and the sum) at the expert cells'
+burst and prefill shapes and loads, many calls inside ONE program,
+under several choices of the ``megablox`` kernel's tiles and of the
+rows that go around it.
 
     chiprun -- python3 benchmarks/grouped_product_tiles.py \
         --tiles parent,2,3,4 --out chiprun_out/pr49/tiles.json
@@ -11,11 +12,17 @@ choices of the ``megablox`` kernel's tiles.
         --tiny --interpret --calls 2 --repeats 1          # a rehearsal
     JAX_PLATFORMS=cpu python3 benchmarks/grouped_product_tiles.py \
         --compile-for-v5e --tiles parent,2,4              # Mosaic's verdict
+    chiprun -- python3 benchmarks/grouped_product_tiles.py \
+        --tiles rule --around every,rule --margin 1.25,1.5,2 \
+        --out chiprun_out/pr54/around.json    # the whole call, by its rows
 
 ``SHAPES`` are the calls the cells' decode bursts make
 (chipbench/configs, chipbench/workloads): the rows of a full burst
 (GLM's verify form is 160 rows x 2 positions), the choices a row, the
-router's width, the experts held, hidden and expert width. A row's
+router's width, the experts held, hidden and expert width; a name that
+ends in ``-prefill`` is the call of that cell's widest prefill step
+(``prefill-batch-size`` x ``prefill-chunk-size`` token places), run
+``--calls`` / 8 times a program. A row's
 choices are the ``top_k`` largest of Gumbel noise plus ``--skew`` times
 a fixed normal draw an expert, and every call shifts the ids by one, so
 the calls of a program do not hit the same experts; ``--fill`` is the
@@ -30,6 +37,17 @@ is refused there at no chip time), then the first command above with
 ``--shapes NAME``; the rule's choice is the ``rule`` entry of
 ``--tiles`` and the budget it was set by is ``ops/moe.py``
 ``_RHS_TILE_BYTES``.
+
+``--around`` entries say which rows go around the two products
+(``ops/moe.py`` ``expert_room``): ``rule`` (the module as it stands:
+the room its shapes give) and ``every`` (all rows x choices, the call
+without the router's width). ``--margin`` runs ``rule`` at those values
+of ``_ROOM_MARGIN``; the line gives the ``room`` each ran under and, of
+the calls' draws, the held pairs (``held_pairs_mean``, ``_max``: a call
+whose held pairs pass the room takes one more chunk). PR 54's table
+(PERF.md section 6) also timed a second way back to tokens, a gather
+of each (token, choice)'s row by its place in the order, which lost at
+every cell's shape and is not in the module.
 
 ``--tiles`` entries: ``rule`` (the module as it stands), ``parent``
 (the constant tile before PR 49: 128, min(1024, k), min(512, n)), a
@@ -50,6 +68,7 @@ nothing of the chip.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import pathlib
@@ -65,6 +84,12 @@ SHAPES = {
     "glm": (320, 4, 64, 64, 2048, 1536),
     "lfm2": (256, 4, 32, 8, 2048, 1792),
     "granite": (128, 10, 72, 18, 4096, 768),
+    "k-exaone": (128, 8, 128, 8, 6144, 2048),
+    "qwen3-next-prefill": (2048, 10, 512, 128, 2048, 512),
+    "longcat-prefill": (2048, 12, 768, 16, 6144, 2048),
+    "lfm2-prefill": (2048, 4, 32, 8, 2048, 1792),
+    "granite-prefill": (1024, 10, 72, 18, 4096, 768),
+    "k-exaone-prefill": (2048, 8, 128, 8, 6144, 2048),
 }
 TINY = {"tiny": (8, 2, 8, 4, 128, 256), "tiny-odd": (8, 2, 4, 4, 128, 160)}
 HBM_BYTES_PER_S = 819e9          # chipbench/peaks.json, TPU v5 lite
@@ -120,7 +145,9 @@ def load_of(ids, valid, shape, calls: int) -> dict:
                       for i in range(calls)])
     return {"tokens_per_expert_mean": float(loads.mean()),
             "tokens_per_expert_max": float(loads.max(axis=1).mean()),
-            "experts_hit": float((loads > 0).sum(axis=1).mean())}
+            "experts_hit": float((loads > 0).sum(axis=1).mean()),
+            "held_pairs_mean": float(loads.sum(axis=1).mean()),
+            "held_pairs_max": int(loads.sum(axis=1).max())}
 
 
 def make_case(key, shape, fill, skew, dtype, as_shapes=None):
@@ -148,17 +175,20 @@ def make_case(key, shape, fill, skew, dtype, as_shapes=None):
             drawn["w_down"])
 
 
-def one_call(moe, shape, part: str, impl: str):
+def one_call(moe, shape, part: str, impl: str, around: str = "rule"):
     """x, ids (already shifted) -> one row a row of x: the routed
-    experts as the models call them, or one product alone on the rows
-    as ``held_experts`` would hand them to it."""
+    experts as the models call them (``around`` says with which rows:
+    the module's text), or one product alone on the rows as
+    ``held_experts`` would hand them to it."""
     import jax.numpy as jnp
     rows, top_k, router_width, held, hidden, width = shape
 
     def call(x, weights, ids, valid, w_gate_up, w_down):
         if part == "experts":
-            return moe.held_experts(x, weights, ids, w_gate_up, w_down, 0,
-                                    valid=valid, impl=impl)[0]
+            return moe.held_experts(
+                x, weights, ids, w_gate_up, w_down, 0, valid=valid,
+                impl=impl, router_width=(None if around == "every"
+                                         else router_width))[0]
         load = jnp.zeros((held + 1,), jnp.int32).at[
             jnp.where((ids < held) & valid[:, None], ids, held).reshape(-1)
         ].add(1)[:held]
@@ -205,6 +235,11 @@ def parse_args(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="the tests' widths in float32")
     ap.add_argument("--tiles", default="parent,rule")
+    ap.add_argument("--around", default="rule",
+                    help="comma-separated: rule, every")
+    ap.add_argument("--margin", default=None,
+                    help="comma-separated values of ops/moe.py "
+                         "_ROOM_MARGIN (the module's)")
     ap.add_argument("--part", default="experts",
                     choices=("experts", "gate_up", "down"))
     ap.add_argument("--fill", type=float, default=1.0,
@@ -233,6 +268,9 @@ def parse_args(argv=None):
              else [item.split("=")[0] for item in args.shape])
     args.shapes = {name: known[name] for name in names}
     args.tiles = args.tiles.split(",")
+    args.around = args.around.split(",")
+    args.margin = ([float(m) for m in args.margin.split(",")]
+                   if args.margin else [None])
     return args
 
 
@@ -249,38 +287,52 @@ def measure(moe, args) -> dict:
                        "kind": device.device_kind},
             "shapes": {}}
     chip = described_v5e() if args.compile_for_v5e else None
-    kept = moe.expert_tiles
+    kept = moe.expert_tiles, moe._ROOM_MARGIN
     for name, shape in args.shapes.items():
         rows, top_k, router_width, held, hidden, width = shape
+        calls = (max(1, args.calls // 8) if name.endswith("-prefill")
+                 else args.calls)
         case = make_case(jax.random.PRNGKey(args.seed), shape, args.fill,
                          args.skew, dtype, as_shapes=chip)
         expert_bytes = {"experts": 3 * hidden * width,
                         "gate_up": 2 * hidden * width,
                         "down": hidden * width}[args.part] * itemsize
-        entry = line["shapes"][name] = {"shape": list(shape), "tiles": {}}
+        entry = line["shapes"][name] = {"shape": list(shape),
+                                        "calls": calls, "tiles": {}}
         if chip is None:
-            entry.update(load_of(case[2], case[3], shape, args.calls))
+            entry.update(load_of(case[2], case[3], shape, calls))
             entry["bytes_ms"] = round(
                 1e3 * entry["experts_hit"] * expert_bytes / HBM_BYTES_PER_S,
                 4)
-        for spec in args.tiles:
+        for spec, margin, around in itertools.product(
+                args.tiles, args.margin, args.around):
+            if around == "every" and margin != args.margin[0]:
+                continue        # every row: no room, so no margin
             rule = tile_rule(moe, spec, hidden, width)
             gate_up = rule(hidden, 2 * width, itemsize)
             down = rule(width, hidden, itemsize)
-            found = entry["tiles"][spec] = {
+            key = spec + (f":{around}" if around != "rule" else "") + (
+                f"@{margin}" if margin is not None and around != "every"
+                else "")
+            found = entry["tiles"][key] = {
                 "gate_up": list(gate_up), "down": list(down),
                 "steps_per_visit":
                     moe.grid_steps(gate_up, hidden, 2 * width)
                     + moe.grid_steps(down, width, hidden)}
             moe.expert_tiles = rule
+            if margin is not None:
+                moe._ROOM_MARGIN = margin
+            found["room"] = (None if around == "every" else moe.expert_room(
+                rows, top_k, held, router_width))
             try:
                 if chip is not None:
-                    jax.jit(one_call(moe, shape, args.part, "pallas")).lower(
-                        *case).compile()
+                    jax.jit(one_call(moe, shape, args.part, "pallas",
+                                     around)).lower(*case).compile()
                     found["compiles"] = True
                     continue
-                program = many_calls(one_call(moe, shape, args.part, impl),
-                                     args.calls, router_width)
+                program = many_calls(
+                    one_call(moe, shape, args.part, impl, around), calls,
+                    router_width)
                 times = []
                 for _ in range(args.repeats + 1):   # the first compiles
                     start = time.perf_counter()
@@ -290,9 +342,9 @@ def measure(moe, args) -> dict:
                 found["error"] = str(e).strip().splitlines()[-1][:300]
                 continue
             finally:
-                moe.expert_tiles = kept
+                moe.expert_tiles, moe._ROOM_MARGIN = kept
             found.update(
-                ms=[round(1e3 * t / args.calls, 4) for t in times[1:]],
+                ms=[round(1e3 * t / calls, 4) for t in times[1:]],
                 compile_and_first_s=round(times[0], 2),
                 checksum=float(total))
     return line

@@ -71,6 +71,9 @@ class EngineMetrics:
             "moe_experts_hit": 0.0,
             "moe_zero_choice_share": 0.0,
         }
+        # Expert layer-steps of decode bursts whose held choices took
+        # more than one chunk of their room (ops/moe.py expert_room).
+        self.moe_room_overflow_steps_total = 0
         self.ttft = Histogram(_TTFT_BUCKETS)
         self.itl = Histogram(_ITL_BUCKETS)
         self.e2e = Histogram(_E2E_BUCKETS)
@@ -161,6 +164,12 @@ class EngineMetrics:
                 / max(stats["choices"], 1.0),
         }
         self.moe_last = last
+        overflows = int(stats.get("room_overflows", 0.0))
+        self.moe_room_overflow_steps_total += overflows
+        # For the step record alone: the dispatch's expert layer-steps
+        # and those of them that passed their room.
+        last = {**last, "moe_layer_steps": int(steps),
+                "moe_room_overflows": overflows}
         if stats.get("swa_queries"):
             # A family with windowed layers: the ring places and own
             # tokens a windowed layer's query had in sight, a mean over
@@ -317,6 +326,9 @@ class EngineMetrics:
                  "counter"),
                 ("vllm:spec_decode_num_accepted_tokens_total "
                  f"{self.spec_accepted_tokens_total}"),
+                "# TYPE vllm:moe_room_overflow_steps_total counter",
+                ("vllm:moe_room_overflow_steps_total "
+                 f"{self.moe_room_overflow_steps_total}"),
                 "# TYPE vllm:engine_step_host_seconds_total counter",
                 ("vllm:engine_step_host_seconds_total "
                  f"{self.step_host_seconds_total}"),

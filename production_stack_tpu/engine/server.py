@@ -2330,13 +2330,32 @@ class EngineServer:
                   config.cache.kv_bytes_per_token(config.model)}
         # The tiles the routed experts' two grouped products take at
         # this model's widths, and the grid steps a visit (ops/moe.py
-        # expert_tiles: static a shape, so stated once, not counted).
+        # expert_tiles: static a shape, so stated once, not counted);
+        # and the rows that go around and through those products a
+        # chunk (expert_room: null where every row goes through), for
+        # the burst and for each shape a prefill step is compiled at.
         import jax.numpy as jnp
-        from production_stack_tpu.ops.moe import expert_layer_tiles
+        from production_stack_tpu.engine.model_runner import prefill_shapes
+        from production_stack_tpu.ops.moe import (
+            expert_layer_tiles,
+            expert_room,
+        )
         model = config.model
-        experts = ({"expert_tiles": expert_layer_tiles(
-            model.hidden_size, model.moe_intermediate_size,
-            jnp.dtype(model.jax_dtype).itemsize)}
+
+        def room(rows: int, tokens: int):
+            return expert_room(rows * tokens, model.num_experts_per_tok,
+                               model.num_experts, model.router_width)
+        experts = ({
+            "expert_tiles": expert_layer_tiles(
+                model.hidden_size, model.moe_intermediate_size,
+                jnp.dtype(model.jax_dtype).itemsize),
+            "expert_room": {
+                "burst": room(runner.decode_width,
+                              2 if config.scheduler.draft_module else 1),
+                "prefill": {f"{rows}x{tokens}": room(rows, tokens)
+                            for rows, tokens in prefill_shapes(
+                                runner.prefill_width,
+                                config.scheduler.prefill_chunk_size)}}}
             if model.num_experts else {})
         # The kinds of layer of a family whose attention layers are not
         # all alike, and the window of the windowed ones, whose K/V is a

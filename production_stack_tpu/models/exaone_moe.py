@@ -32,7 +32,7 @@ sequence owns (slot 0 is the trash slot of padded rows). Nothing of a
 ring is read that the row did not write: a place is in sight only
 while the row's length says it holds one of the row's tokens. After
 the layers ``k_cache`` carries the family's counters:
-``count_step``'s five over the expert layers' decode steps, then
+``count_step``'s six over the expert layers' decode steps, then
 ``swa_keys`` and ``swa_queries``: over the windowed layers' decode
 steps, the ring places and own tokens the attention's own mask let
 the real rows' queries see, and those queries. With ``kv_tail`` (a
@@ -248,14 +248,6 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
     impl = hybrid_kernel_impl(c)
     eps = c.rms_norm_eps
     real = jnp.sum(valid).astype(jnp.float32)
-    # Of a prefill step's choices the share that falls on held experts
-    # is ``num_experts / router_width`` (a sixteenth as published);
-    # twice that many rows, in whole tiles, is room for them
-    # (ops/moe.py ``held_experts``).
-    room = None
-    if t > 1:
-        room = -(-2 * b * t * c.num_experts_per_tok * c.num_experts
-                 // (c.router_width * 128)) * 128
 
     x = params["embed"][tokens]
     for layer, windowed in enumerate(c.layer_is_linear):
@@ -265,7 +257,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
             c, lp, x, windowed, positions, page_table, kv_lens, valid,
             state_slots, k_cache, v_cache, layer, kv_tail)
         if windowed and t == 1:
-            stats = stats.at[5:7].add(jnp.stack([
+            stats = stats.at[-2:].add(jnp.stack([
                 jnp.sum(jnp.where(valid, keys, 0)).astype(jnp.float32),
                 real]))
         x = x + rms_norm(mixed, lp["post_attn_norm"], eps)
@@ -276,12 +268,10 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         else:
             rp = {k: params[f"{k}_{layer}"] for k in ROUTED}
             rp.update({k: params[f"e_{k}_{layer}"] for k in EXPERTS})
-            y, load = expert_block(c, rp, x, valid, impl, room)
+            y, load = expert_block(c, rp, x, valid, impl)
             if t == 1:
-                stats = jnp.concatenate([
-                    count_step(stats[:5], c.num_experts_per_tok, load,
-                               valid),
-                    stats[5:]])
+                stats = count_step(stats, c.num_experts_per_tok, load,
+                                   valid, c.router_width)
         x = x + rms_norm(y, lp["post_ffn_norm"], eps)
 
     x = rms_norm(x, params["final_norm"], eps)

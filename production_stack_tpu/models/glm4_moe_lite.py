@@ -36,7 +36,7 @@ what each iteration committed (engine/model_runner.py).
 Cache contract (``models/registry.py``): ``k_cache`` is one latent
 plane a main layer (entries ``0 .. L-1``), then one for the module
 where ``num_nextn_predict_layers`` is 1 (entry ``L``), then the
-family's counters: ``count_step``'s five over the expert layers of a
+family's counters: ``count_step``'s six over the expert layers of a
 burst iteration (the module's among them) and the burst's drafts
 offered and accepted. Every ``v_cache`` entry is ``None``. ``forward``
 leaves the module's entry as it is; ``draft`` leaves the main ones.
@@ -163,10 +163,9 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
-def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla",
-                 room=None):
+def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla"):
     """u [B, T, H] normalised -> (F(u) [B, T, H], load [E]: real tokens
-    that chose each held expert). ``room`` is ``held_experts``'."""
+    that chose each held expert)."""
     c = config
     b, t, h = u.shape
     flat = u.reshape(b * t, h)
@@ -176,7 +175,7 @@ def expert_block(config: ModelConfig, lp, u, valid, moe_impl="xla",
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
         c.expert_parallel_rank * c.num_experts, valid=valid.reshape(b * t),
-        impl=moe_impl, room=room)
+        impl=moe_impl, router_width=c.router_width)
     with jax.named_scope("shared_expert"):
         y = y + swiglu(flat, lp["shared_gate_up"], lp["shared_down"])
     return y.reshape(b, t, h), load
@@ -202,9 +201,8 @@ def _layer(config, params, body, x, positions, page_table, kv_lens, valid,
     rp.update({k: params[f"e_{k}_{body}"] for k in EXPERTS})
     y, load = expert_block(c, rp, u, valid, moe_impl)
     if counted:
-        stats = jnp.concatenate([
-            count_step(stats[:5], c.num_experts_per_tok, load, valid),
-            stats[5:]])
+        stats = count_step(stats, c.num_experts_per_tok, load, valid,
+                           c.router_width)
     return x + y, kept, stats
 
 

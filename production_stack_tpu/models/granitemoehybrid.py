@@ -34,7 +34,7 @@ why) and ``v_cache[i]`` the convolution tails ``[slots, K-1, channels
 sequence owns (slot 0 is the trash slot of padded rows). A row whose
 block starts at position 0 starts from a zero state whatever its slot
 holds. ``k_cache`` carries one entry more than there are layers, the
-five counters of the expert layers' decode steps (``count_step``).
+six counters of the expert layers' decode steps (``count_step``).
 With ``kv_tail`` (a deferred-write decode burst) the attention layers
 append to tails and leave their planes unwritten; with ``conv_tail``
 the Mamba layers take their rows' convolution tails dense from the
@@ -249,7 +249,8 @@ def sparse_block(config: ModelConfig, lp, x, valid, moe_impl="xla"):
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
         config.expert_parallel_rank * config.num_experts,
-        valid=valid.reshape(b * t), impl=moe_impl)
+        valid=valid.reshape(b * t), impl=moe_impl,
+        router_width=config.router_width)
     y = y + swiglu(flat, lp["shared_gate_up"], lp["shared_down"])
     return y.reshape(b, t, h), load
 
@@ -314,7 +315,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         y, load = sparse_block(config, common, m_in, valid, impl)
         if t == 1:
             stats = count_step(stats, config.num_experts_per_tok, load,
-                               valid)
+                               valid, config.router_width)
         x = _joined(x, y, res)
 
     x = rms_norm(x, params["final_norm"], eps)

@@ -27,7 +27,7 @@ alone, ``models/registry.py``) and ``v_cache[i]`` the convolution tails
 row's sequence owns (slot 0 is the trash slot of padded rows). A row
 whose block starts at position 0 starts from a zero tail whatever its
 slot holds. ``k_cache`` carries one entry more than there are layers,
-the five counters of the expert layers' decode steps (``count_step``;
+the six counters of the expert layers' decode steps (``count_step``;
 ``layer_steps`` counts expert layers). With ``kv_tail`` (a
 deferred-write decode burst) the attention layers append to tails and
 leave their planes unwritten; with ``conv_tail`` the conv layers take
@@ -189,7 +189,8 @@ def sparse_block(config: ModelConfig, lp, x, valid, moe_impl="xla"):
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
         config.expert_parallel_rank * config.num_experts,
-        valid=valid.reshape(b * t), impl=moe_impl)
+        valid=valid.reshape(b * t), impl=moe_impl,
+        router_width=config.router_width)
     return y.reshape(b, t, h), load
 
 
@@ -252,7 +253,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         y, load = sparse_block(config, lp, m_in, valid, impl)
         if t == 1:
             stats = count_step(stats, config.num_experts_per_tok, load,
-                               valid)
+                               valid, config.router_width)
         x = x + y
 
     x = rms_norm(x, params["final_norm"], eps)

@@ -31,8 +31,8 @@ No shared expert. Norms are plain (``x / rms(x) * w``).
 
 Same contract as ``models.lfm2_moe.forward`` with per-entry caches:
 ``k_cache`` is one latent plane a sublayer (entry ``2 * layer`` is
-``A1``'s, ``2 * layer + 1`` ``A2``'s) and after them the six counters of
-the expert branches' decode steps (``count_step``'s five and the
+``A1``'s, ``2 * layer + 1`` ``A2``'s) and after them the seven counters
+of the expert branches' decode steps (``count_step``'s six and the
 choices that fell on zero experts); every ``v_cache`` entry is ``None``
 and stays so. With ``kv_tail`` (a deferred-write decode burst) a
 sublayer appends its latent to its tail and leaves its plane unwritten.
@@ -226,7 +226,8 @@ def moe_branch(config: ModelConfig, lp, x, valid, moe_impl="xla"):
         c.routed_scaling_factor)
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
-        c.expert_parallel_rank * c.num_experts, valid=real, impl=moe_impl)
+        c.expert_parallel_rank * c.num_experts, valid=real, impl=moe_impl,
+        router_width=c.router_width)
     kept, zero = identity_weight(weights, ids,
                                  c.router_width - c.zero_expert_num)
     y = y + (kept[:, None] * flat.astype(jnp.float32)).astype(y.dtype)
@@ -276,9 +277,8 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         rp.update({k: params[f"e_{k}_{layer}"] for k in EXPERTS})
         m, load, zero = moe_branch(c, rp, u, valid, impl)
         if t == 1:
-            stats = jnp.concatenate([
-                count_step(stats[:5], c.num_experts_per_tok, load, valid),
-                stats[5:] + zero])
+            stats = count_step(stats, c.num_experts_per_tok, load, valid,
+                               c.router_width).at[-1].add(zero)
         h2 = h1 + dense_ffn(lp, u)
         lp, h3 = attend(2 * layer + 1, h2)
         x = h3 + dense_ffn(lp, rms_norm(h3, lp["ffn_norm"], eps)) + m

@@ -20,7 +20,7 @@ the trash slot of padded rows, as page 0 is the trash page). A row
 whose block starts at position 0 starts from a zero state whatever its
 slot holds, so a slot needs no clearing between sequences or before a
 recompute. ``k_cache`` carries one entry more than there are layers:
-``k_cache[L]``, five float32 counters of the expert layer's decode
+``k_cache[L]``, six float32 counters of the expert layer's decode
 steps that the runner reads and zeroes (the family's ``counters``,
 ``models/registry.py``; ``ops/moe.py`` ``count_step`` fills them in that
 order). With
@@ -249,7 +249,8 @@ def sparse_block(config: ModelConfig, lp, x, valid, moe_impl="xla"):
     y, load = held_experts(
         flat, weights, ids, lp["w_gate_up"], lp["w_down"],
         config.expert_parallel_rank * config.num_experts,
-        valid=valid.reshape(b * t), impl=moe_impl)
+        valid=valid.reshape(b * t), impl=moe_impl,
+        router_width=config.router_width)
     share = jax.nn.sigmoid(
         (flat.astype(jnp.float32)
          @ lp["shared_gate"].astype(jnp.float32))[:, None])
@@ -326,7 +327,7 @@ def forward(params: Params, config: ModelConfig, tokens: jnp.ndarray,
         y, load = sparse_block(config, common, m_in, valid, impl)
         if t == 1:
             stats = count_step(stats, config.num_experts_per_tok, load,
-                               valid)
+                               valid, config.router_width)
         x = x + y
 
     x = rms_norm(x, params["final_norm"], config.rms_norm_eps)

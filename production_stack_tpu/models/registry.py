@@ -191,7 +191,7 @@ def _glm4_moe_lite_pages(c) -> PageCache:
 
 
 _EXPERT_COUNTERS = ("layer_steps", "choices", "held_choices", "max_load",
-                    "experts_hit")
+                    "experts_hit", "room_overflows")
 
 _LLAMA = Family("llama", deferred_kv=True)
 

@@ -16,19 +16,30 @@ elsewhere would add is left out, and the partial sum is what goes on
 complete it is simply absent). With every expert held the sum is
 whole.
 
-Work follows the choices, not the experts: the (token, choice) pairs
-that fall on held experts are sorted by expert and each expert's rows
-go through its three matrices as one group of a grouped matrix
-product, so FLOPs are tokens x held choices x one expert, and an
-expert nobody chose is never read. On a TPU the grouped product is the
-Pallas ``megablox`` kernel that ships with JAX (group sizes reach it
-through scalar prefetch; it visits only tiles that hold rows) under
-tiles that follow each product's shape (``expert_tiles``: a k tile that
-divides k, a few grid steps a visit); elsewhere it is
-``jax.lax.ragged_dot``.
+Work follows the HELD choices, inside the kernel and around it: the
+(token, choice) pairs are sorted by held expert (the sort and the count
+an expert are over all N x k keys: they are what finds the held pairs),
+each expert's rows go through its three matrices as one group of a
+grouped matrix product, so FLOPs are tokens x held choices x one
+expert, and an expert nobody chose is never read. Where only part of
+the router's experts is held, every pass around the two products (the
+gather of ``x``, both float32 outputs and their masks, the activation,
+the weighting, the sum back to tokens) is over ``room`` rows and not
+N x k: ``expert_room`` finds that many whole tiles from the call's
+shapes (the held pairs to expect, times ``_ROOM_MARGIN``), the sorted
+order's held rows go through in chunks of ``room`` (one chunk unless a
+step's routing leans on the held block: ``count_step`` counts those
+steps), and with every expert held all rows go through as one. On a TPU
+the grouped product is the Pallas ``megablox`` kernel that ships with
+JAX (group sizes reach it through scalar prefetch; it visits only tiles
+that hold rows) under tiles that follow each product's shape
+(``expert_tiles``: a k tile that divides k, a few grid steps a visit);
+elsewhere it is ``jax.lax.ragged_dot``.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -197,10 +208,40 @@ def _grouped_dot(lhs, rhs, group_sizes, impl: str):
     return jnp.where(grouped, out[:m], 0.0)
 
 
+# Rows a call makes room for, over the held pairs it expects
+# (``expert_room``). A step whose held choices pass its room is exact
+# and pays one more chunk, so the constant buys speed against the share
+# of such steps, which ``count_step`` counts. Measured on a v5e (PERF.md
+# section 6, PR 54): at 1.5 the bursts' rooms are 128 rows (LongCat,
+# K-EXAONE), 384 (LFM2) and 512 (Granite, Qwen3-Next), and the cell
+# nearest its room, LFM2 (280 held pairs a layer-step of 384), passed
+# it in 0.64% of a window's 94 336 layer-steps, LongCat in none of
+# 13 696; a second chunk costs a call a quarter to a half more
+# (0.34 -> 0.50 ms at three chunks), so 0.2% of the calls' time, where
+# room for twice the pairs costs every call 0.3 to 1.5% in a burst and
+# 4 to 9% in a prefill step (0.996 -> 1.083 ms on LFM2's).
+_ROOM_MARGIN = 1.5
+
+
+def expert_room(n: int, top_k: int, held: int, router_width: int):
+    """Rows of the sorted (token, choice) order that ``held_experts``
+    sends through the held experts at a time, from the call's shapes
+    alone: the held pairs to expect of ``n`` tokens x ``top_k``
+    choices over a router ``router_width`` wide of which ``held``
+    experts are here, times ``_ROOM_MARGIN``, in whole tiles of
+    ``_TILE_ROWS``. None where that reaches every pair (all experts
+    held, or too few rows for a tile to be less): every row then goes
+    through as one."""
+    pairs = n * top_k
+    expected = pairs * held / router_width
+    room = -(-math.ceil(_ROOM_MARGIN * expected) // _TILE_ROWS) * _TILE_ROWS
+    return None if room >= pairs else room
+
+
 def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
                  w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
                  first_expert: int, valid: jnp.ndarray = None,
-                 impl: str = "xla", room: "int | None" = None):
+                 impl: str = "xla", router_width: "int | None" = None):
     """The held experts' part of the routed sum.
 
     Args:
@@ -214,12 +255,12 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
                  kernel, on a TPU, under ``expert_tiles(H, 2F)`` and
                  ``expert_tiles(F, H)``: both follow the operands'
                  shapes and dtype, nothing else) or "pallas-interpret"
-      room:      static, under N * k: where few of the published
-                 experts are held, the rows the held choices are
-                 expected to fit in. A call whose held choices do fit
-                 (seen at run time) gathers, multiplies and sums those
-                 rows alone; one whose do not takes all N * k, as a
-                 call without ``room`` does. Exact either way
+      router_width: outputs the router chose among (static). With it
+                 the rows that go around and through the products are
+                 ``expert_room``'s, a chunk at a time; without it, or
+                 where the room would be every row, all N * k go
+                 through as one. Exact either way: only the order of a
+                 float32 sum differs
 
     Returns (y [N, H] in x's dtype, load [E] int32: real tokens that
     chose each held expert).
@@ -238,54 +279,82 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, ids: jnp.ndarray,
         order = jnp.argsort(key, stable=True)
         load = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
 
-        def through(order):
-            """The sorted rows ``order`` through their experts,
-            weighted: [len(order), H] in x's dtype, zero past the
-            groups' total."""
-            hidden = _grouped_dot(x[order // top_k], w_gate_up, load, impl)
+        def through(rows, sizes):
+            """The sorted rows ``rows`` through their experts (``sizes``
+            of them each), weighted: [len(rows), H] in x's dtype, zero
+            past the groups' total."""
+            hidden = _grouped_dot(x[rows // top_k], w_gate_up, sizes, impl)
             act = (jax.nn.silu(hidden[:, :f])
                    * hidden[:, f:]).astype(x.dtype)
-            out = _grouped_dot(act, w_down, load, impl)   # f32
-            return (out * weights.reshape(-1)[order][:, None]).astype(
+            out = _grouped_dot(act, w_down, sizes, impl)   # f32
+            return (out * weights.reshape(-1)[rows][:, None]).astype(
                 x.dtype)
 
-        def every_row():
-            out = through(order)
+        room = (None if router_width is None
+                else expert_room(n, top_k, e, router_width))
+        if room is None:
+            out = through(order, load)
             # Back to (token, choice) order, then the sum over choices.
             inverse = jnp.zeros_like(order).at[order].set(
                 jnp.arange(order.shape[0], dtype=order.dtype))
             return jnp.sum(out[inverse].reshape(n, top_k, -1), axis=1,
-                           dtype=jnp.float32).astype(x.dtype)
+                           dtype=jnp.float32).astype(x.dtype), load
 
-        def held_rows():
-            # Each token's sum as one product with the 0/1 matrix of
-            # which row is whose: exact in float32, and no scatter.
-            first = order[:room]
-            whose = (first // top_k)[None, :] == jnp.arange(n)[:, None]
-            return jnp.dot(whose.astype(x.dtype), through(first),
-                           preferred_element_type=jnp.float32
-                           ).astype(x.dtype)
+        ends = jnp.cumsum(load)
+        starts = ends - load
+        # Whole chunks: the rows added lie past every held pair.
+        chunked = jnp.pad(order, (0, -order.shape[0] % room))
 
-        if room is None or room >= n * top_k:
-            return every_row(), load
-        return jax.lax.cond(jnp.sum(load) <= room, held_rows,
-                            every_row), load
+        def chunk(i, total):
+            """Adds to the tokens' float32 sums what rows [i * room,
+            (i + 1) * room) of the order give: each expert's part of
+            them is cut from ``load``'s running sum."""
+            lo = i * room
+            rows = jax.lax.dynamic_slice(chunked, (lo,), (room,))
+            sizes = jnp.maximum(jnp.minimum(ends, lo + room)
+                                - jnp.maximum(starts, lo), 0)
+            out = through(rows, sizes)
+            # Back to tokens by one product with the 0/1 matrix of
+            # which row is whose: exact in float32, no scatter, and at
+            # every expert cell's burst and prefill shape faster on a
+            # v5e than gathering each (token, choice)'s row by its
+            # place in the order (0.34 against 0.39 ms a call in
+            # LFM2's burst, 1.00 against 1.09 and 2.6 against 6.0 in
+            # LFM2's and LongCat's prefill steps; the gather is the
+            # faster only past some 2048 rows of room a choice, four
+            # times LFM2's step: PERF.md section 6, PR 54).
+            whose = (rows // top_k)[None, :] == jnp.arange(n)[:, None]
+            return total + jnp.dot(whose.astype(x.dtype), out,
+                                   preferred_element_type=jnp.float32)
+
+        y = jax.lax.fori_loop(0, -(-ends[-1] // room), chunk,
+                              jnp.zeros(x.shape, jnp.float32))
+        return y.astype(x.dtype), load
 
 
 def count_step(stats: jnp.ndarray, top_k: int, load: jnp.ndarray,
-               valid: jnp.ndarray) -> jnp.ndarray:
-    """Add one decode step of one expert layer to a family's five
-    float32 counters (``models/registry.py``: layer_steps, choices,
-    held_choices, max_load, experts_hit). ``load [E]`` is
-    ``held_experts``'; ``valid`` marks the real rows."""
+               valid: jnp.ndarray,
+               router_width: "int | None" = None) -> jnp.ndarray:
+    """Add one decode step of one expert layer to the first six of a
+    family's float32 counters (``models/registry.py``: layer_steps,
+    choices, held_choices, max_load, experts_hit, room_overflows); what
+    the family keeps after them stays. ``load [E]`` is ``held_experts``';
+    ``valid`` marks the real rows; ``router_width`` is what that call
+    was given: the step counts under ``room_overflows`` if its held
+    choices took more than one chunk of that call's room."""
     rows = jnp.sum(valid).astype(jnp.float32)
-    return stats + jnp.stack([
+    room = (None if router_width is None else expert_room(
+        valid.size, top_k, load.shape[0], router_width))
+    step = jnp.stack([
         jnp.float32(1.0),
         rows * top_k,
         jnp.sum(load).astype(jnp.float32),
         jnp.max(load).astype(jnp.float32),
         jnp.sum(load > 0).astype(jnp.float32),
+        (jnp.float32(0.0) if room is None
+         else (jnp.sum(load) > room).astype(jnp.float32)),
     ])
+    return stats.at[:step.shape[0]].add(step)
 
 
 def swiglu(x, w_gate_up, w_down):

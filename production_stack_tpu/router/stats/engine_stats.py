@@ -158,6 +158,7 @@ _ROUTER_UNSCRAPED = frozenset({
     "vllm:engine_moe_tokens_per_expert_mean",
     "vllm:engine_moe_held_choice_share",
     "vllm:engine_moe_zero_choice_share",
+    "vllm:moe_room_overflow_steps_total",
     # The interpreter's two threads (docs/observability.md, "Is the
     # front the wall?"): an operator's rate, not a routing signal.
     "vllm:engine_front_cpu_seconds_total",

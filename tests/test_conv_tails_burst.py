@@ -334,3 +334,31 @@ def test_version_states_the_expert_products_tiles(family, routed):
         assert tiles["down"] == list(expert_tiles(width, hidden, itemsize))
         # Tiny widths: each product is one tile.
         assert tiles["steps_per_visit"] == 2
+
+
+@pytest.mark.parametrize("family,routed", [
+    ("qwen3_next", True), ("lfm2_moe", True), ("jamba", False),
+    ("llama", False)])
+def test_version_states_the_expert_room(family, routed):
+    """Beside the tiles, the rows that go around and through the
+    products a chunk, for the burst and for each shape a prefill step
+    is compiled at (``ops/moe.py`` ``expert_room``); ``null`` at these
+    tiny shapes, where a tile of 128 is every row."""
+    from production_stack_tpu.engine.model_runner import prefill_shapes
+    from production_stack_tpu.ops.moe import expert_room
+    body = version_of(family)
+    assert ("expert_room" in body) == routed
+    if routed:
+        config = HYBRIDS[family].engine_config()
+        model, scheduler = config.model, config.scheduler
+        shapes = prefill_shapes(scheduler.prefill_batch_size,
+                                scheduler.prefill_chunk_size)
+        assert body["expert_room"] == {
+            "burst": expert_room(
+                scheduler.max_num_seqs, model.num_experts_per_tok,
+                model.num_experts, model.router_width),
+            "prefill": {f"{rows}x{tokens}": expert_room(
+                rows * tokens, model.num_experts_per_tok,
+                model.num_experts, model.router_width)
+                for rows, tokens in shapes}}
+        assert len(shapes) >= 2

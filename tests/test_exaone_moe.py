@@ -188,8 +188,8 @@ def test_prefill_then_decode_agree_with_one_full_forward(prompt, chunk,
     # window's keys in sight, or the row's while it was shorter.
     steps = LENGTH - prompt
     stats = [float(v) for v in k_cache[4]]
-    assert stats[0] == 3 * steps and stats[6] == 3 * steps
-    assert stats[5] == 3 * sum(min(p + 1, 16)
+    assert stats[0] == 3 * steps and stats[7] == 3 * steps
+    assert stats[6] == 3 * sum(min(p + 1, 16)
                                for p in range(prompt, LENGTH))
 
 
@@ -512,36 +512,47 @@ def test_padded_and_stopped_rows_leave_their_slot_bit_identical(impl):
             assert list(np.nonzero(moved)[0]) == [21 % 16]
     # One live row: three expert layers of three choices, three
     # windowed layers of a window's keys.
-    assert [float(v) for v in k_new[4]] == [3.0, 9.0, 9.0, 3.0, 9.0,
+    assert [float(v) for v in k_new[4]] == [3.0, 9.0, 9.0, 3.0, 9.0, 0.0,
                                             48.0, 3.0]
 
 
-@pytest.mark.parametrize("room,same", [(64, False), (24, True),
-                                       (256, True)])
-def test_held_choices_that_fit_their_room_go_through_alone(room, same):
-    """64 tokens x 4 choices over 16 experts of which 2 are held: 32
-    held choices on average. With room for them the sum is taken over
-    ``room`` rows and agrees with the sum over all 256 to float32's
-    rounding; with too little room (24), or room for every row, the
-    call is the call without ``room``, bit for bit."""
+@pytest.mark.parametrize("width,lean,chunks", [(32, 0.0, 1), (32, 4.0, 2),
+                                               (2, 0.0, None)])
+def test_held_choices_that_fit_their_room_go_through_alone(width, lean,
+                                                           chunks):
+    """128 tokens x 4 choices over 32 experts of which 2 are held: 32
+    held choices to expect and a room of 128 (``ops/moe.py``
+    ``expert_room``, from the shapes). Where they fit, the sum is taken
+    over the room's rows and agrees with the sum over all 512 to
+    float32's rounding; where the router leans on the held block they
+    take a second chunk of the room, and the sum is as exact; with a
+    router no wider than the held block there is no room, and the call
+    is the call without the router's width, bit for bit."""
     keys = jax.random.split(jax.random.PRNGKey(3), 5)
-    n, h, f, e = 64, 32, 16, 2
+    n, h, f, e = 128, 32, 16, 2
+    first = 4 if width > e else 0
     x = jax.random.normal(keys[0], (n, h), jnp.float32)
     w_gate_up = 0.2 * jax.random.normal(keys[1], (e, h, 2 * f), jnp.float32)
     w_down = 0.2 * jax.random.normal(keys[2], (e, f, h), jnp.float32)
-    ids = jax.random.randint(keys[3], (n, 4), 0, 16)
+    scores = jax.random.gumbel(keys[3], (n, max(width, 4))).at[
+        :, first:first + e].add(lean)
+    ids = jax.lax.top_k(scores, 4)[1]
     weights = jax.random.uniform(keys[4], (n, 4), jnp.float32)
-    valid = jnp.arange(n) < 60
+    valid = jnp.arange(n) < 120
     want, want_load = moe.held_experts(x, weights, ids, w_gate_up, w_down,
-                                       4, valid)
-    got, load = jax.jit(lambda *a: moe.held_experts(*a, 4, valid,
-                                                    room=room))(
+                                       first, valid)
+    got, load = jax.jit(lambda *a: moe.held_experts(
+        *a, first, valid, router_width=width))(
         x, weights, ids, w_gate_up, w_down)
-    assert 24 < int(want_load.sum()) <= 64
+    room = moe.expert_room(n, 4, e, width)
+    assert room == (128 if chunks else None)
+    if chunks:
+        assert -(-int(want_load.sum()) // room) == chunks
     assert load.tolist() == want_load.tolist()
     assert np.abs(np.asarray(want)).max() > 0.1
     assert np.abs(np.asarray(got - want)).max() < FLOAT32
-    assert np.array_equal(np.asarray(got), np.asarray(want)) == same
+    if not chunks:
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_the_pallas_paths_in_interpret_mode_equal_the_xla_paths():

@@ -235,7 +235,7 @@ def test_the_family_declares_its_rings_and_the_engine_names_no_model():
     # trash); the full layer's are the pages.
     ring, pages = ((2, 4, 16, 16), "bfloat16"), ((2, 8, 16, 16), "bfloat16")
     assert [(a.shape, str(a.dtype)) for a in k_cache] == [
-        ring, ring, pages, ring, ((7,), "float32")]
+        ring, ring, pages, ring, ((8,), "float32")]
     assert [(a.shape, str(a.dtype)) for a in v_cache] == [
         ring, ring, pages, ring]
     for module in (model_runner, scheduler, engine_module):

@@ -131,7 +131,7 @@ def test_greedy_output_with_drafts_equals_the_output_without(
     # Four latent planes (three layers and the module's) and the
     # counters; no second plane anywhere.
     assert [e.shape for e in engine.runner.k_cache] == [
-        (1, 64, 32, 16)] * 4 + [(7,)]
+        (1, 64, 32, 16)] * 4 + [(8,)]
     assert engine.runner.v_cache == (None,) * 4
 
 

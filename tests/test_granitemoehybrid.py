@@ -394,7 +394,8 @@ def test_padded_and_stopped_rows_leave_their_slot_bit_identical(impl):
             assert np.array_equal(after[1], before[1])    # nobody's
             assert not np.array_equal(after[3], before[3])
     # One live row, three choices, four layers of experts.
-    assert [float(v) for v in k_new[4]] == [4.0, 12.0, 12.0, 4.0, 12.0]
+    assert [float(v) for v in k_new[4]] == [4.0, 12.0, 12.0, 4.0, 12.0,
+                                            0.0]
 
 
 def test_the_pallas_paths_in_interpret_mode_equal_the_xla_paths():

@@ -200,7 +200,7 @@ def test_the_family_declares_its_state_and_the_engine_names_no_model():
     assert [(a.shape, str(a.dtype)) for a in k_cache] == [
         ((4, 16, 128), "float32"), ((4, 16, 128), "float32"),
         ((2, 8, 16, 16), "bfloat16"), ((4, 16, 128), "float32"),
-        ((5,), "float32")]
+        ((6,), "float32")]
     assert [(a.shape, str(a.dtype)) for a in v_cache] == [
         ((4, 3, 160), "bfloat16"), ((4, 3, 160), "bfloat16"),
         ((2, 8, 16, 16), "bfloat16"), ((4, 3, 160), "bfloat16")]

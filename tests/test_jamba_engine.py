@@ -272,7 +272,7 @@ def test_both_hybrids_declare_themselves_in_the_registry():
         assert set(fam.refusals) == {"tensor parallelism",
                                      "weight quantization"}
     assert registry.family("jamba").counters == ()
-    assert len(registry.family("qwen3_next").counters) == 5
+    assert len(registry.family("qwen3_next").counters) == 6
     assert registry.family("llama").recurrent_layers is None
     # The runner names no model module.
     import inspect

@@ -287,7 +287,7 @@ def test_a_family_that_declares_the_tail_alone_owns_no_other_pool():
     ``k_cache``; the families that declare two keep their pools as
     they were, shape for shape and dtype for dtype."""
     fam = registry.family("lfm2_moe")
-    assert fam.conv_tail and fam.deferred_kv and len(fam.counters) == 5
+    assert fam.conv_tail and fam.deferred_kv and len(fam.counters) == 6
     assert fam.counters == registry.family("qwen3_next").counters
     assert set(fam.refusals) == {"tensor parallelism",
                                  "weight quantization"}
@@ -295,7 +295,7 @@ def test_a_family_that_declares_the_tail_alone_owns_no_other_pool():
     assert registry.state_pools(config) == (None, ((2, 64), "model"))
     k_cache, v_cache = registry.init_hybrid_cache(config, 8, 16, 3)
     assert [None if a is None else a.shape for a in k_cache] == [
-        None, (2, 8, 16, 16), None, None, (2, 8, 16, 16), (5,)]
+        None, (2, 8, 16, 16), None, None, (2, 8, 16, 16), (6,)]
     assert [a.shape for a in v_cache] == [
         (4, 2, 64), (2, 8, 16, 16), (4, 2, 64), (4, 2, 64),
         (2, 8, 16, 16)]
@@ -321,7 +321,7 @@ def test_a_family_that_declares_the_tail_alone_owns_no_other_pool():
         (4, 4, 16, 16), "float32")
     assert (v_cache[0].shape, str(v_cache[0].dtype)) == (
         (4, 3, 128), "bfloat16")
-    assert k_cache[2].shape == (2, 8, 32, 16) and k_cache[-1].shape == (5,)
+    assert k_cache[2].shape == (2, 8, 32, 16) and k_cache[-1].shape == (6,)
     assert hybrid.recurrent_state_shapes() == ((4, 16, 16), (3, 128))
     # A family may not declare three.
     three = dataclasses.replace(fam, state=lambda c: (((1,), "model"),) * 3)

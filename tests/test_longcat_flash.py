@@ -125,7 +125,7 @@ def test_prefill_then_decode_agree_with_one_full_forward(prompt, chunk):
     assert np.abs(got - want).max() < FLOAT32
     # Two latent planes a layer of one head of 24 + 8 rows, the six
     # counters after them, and no second plane anywhere.
-    assert [e.shape for e in k_cache] == [(1, 32, 32, 16)] * 4 + [(6,)]
+    assert [e.shape for e in k_cache] == [(1, 32, 32, 16)] * 4 + [(7,)]
     assert v_cache == (None,) * 4
     # The row's pages hold its 56 latents in every sublayer's plane,
     # each sublayer its own; the other pages hold nothing.

@@ -118,7 +118,7 @@ def test_engine_prefill_chunks_and_bursts_agree_with_the_reference(form):
     assert 0 < stats["engine_moe_zero_choice_share"] < 1
     # Four latent planes and the counters; no second plane anywhere.
     assert [e.shape for e in engine.runner.k_cache] == [
-        (1, 64, 32, 16)] * 4 + [(6,)]
+        (1, 64, 32, 16)] * 4 + [(7,)]
     assert engine.runner.v_cache == (None,) * 4
 
 
@@ -246,7 +246,7 @@ def test_at_the_cells_sizes_a_page_is_1179648_bytes():
         config, cache.num_pages, cache.page_size, 0))
     assert [e.shape for e in k_cache[:-1]] == [
         (1, flags["num-pages"], 576, 128)] * 8
-    assert k_cache[-1].shape == (6,) and v_cache == (None,) * 8
+    assert k_cache[-1].shape == (7,) and v_cache == (None,) * 8
     nbytes = sum(int(np.prod(e.shape)) * e.dtype.itemsize
                  for e in k_cache[:-1])
     assert nbytes == flags["num-pages"] * 1179648
@@ -358,7 +358,7 @@ def test_the_family_declares_its_pages_and_the_others_are_what_they_were():
     assert registry.page_cache(config) == registry.PageCache(
         entries=4, heads=1, width=32, planes=1)
     k_cache, v_cache = registry.init_hybrid_cache(config, 8, 16, 0)
-    assert [a.shape for a in k_cache] == [(1, 8, 32, 16)] * 4 + [(6,)]
+    assert [a.shape for a in k_cache] == [(1, 8, 32, 16)] * 4 + [(7,)]
     assert v_cache == (None,) * 4
     # The hybrids' caches, shape for shape.
     import test_lfm2_moe_engine
@@ -367,7 +367,7 @@ def test_the_family_declares_its_pages_and_the_others_are_what_they_were():
     assert lfm2.cache_entry_is_state == lfm2.layer_is_linear
     k_cache, v_cache = registry.init_hybrid_cache(lfm2, 8, 16, 3)
     assert [None if a is None else a.shape for a in k_cache] == [
-        None, (2, 8, 16, 16), None, None, (2, 8, 16, 16), (5,)]
+        None, (2, 8, 16, 16), None, None, (2, 8, 16, 16), (6,)]
     assert [a.shape for a in v_cache] == [
         (4, 2, 64), (2, 8, 16, 16), (4, 2, 64), (4, 2, 64),
         (2, 8, 16, 16)]
