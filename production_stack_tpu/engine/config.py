@@ -49,7 +49,7 @@ class ModelConfig:
     name: str = "tiny-llama"
     # llama | opt | gpt2 | mistral | qwen2 | mixtral | qwen3_next | jamba
     # | lfm2_moe | longcat_flash | glm4_moe_lite | granitemoehybrid
-    # | exaone_moe (models/registry.py FAMILIES)
+    # | exaone_moe | sdar_moe (models/registry.py FAMILIES)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -189,6 +189,22 @@ class ModelConfig:
     # Norms on each sublayer's output; the first num_dense_layers
     # feed-forwards dense, the rest the expert block of glm4_moe_lite.
     sliding_window: int = 0
+    # SDAR-MoE block-diffusion decoders (architecture == "sdar_moe",
+    # models/sdar_moe.py): the Qwen3-MoE layer (per-head q/k norms,
+    # softmax top-k of num_experts held experts) under sight by block:
+    # positions come in blocks of diffusion_block_length (a power of
+    # two), a place not known yet enters as mask_token_id's embedding,
+    # and a block is generated by up to diffusion_steps denoising
+    # passes that commit its places by diffusion_remasking
+    # (ops/sampling.py REMASKING_STRATEGIES; the dynamic rule's
+    # diffusion_confidence_threshold) and one store pass. The three
+    # last are a request's defaults (denoising_steps,
+    # remasking_strategy, confidence_threshold).
+    diffusion_block_length: int = 0
+    mask_token_id: int = 0
+    diffusion_steps: int = 0
+    diffusion_remasking: str = "low_confidence_dynamic"
+    diffusion_confidence_threshold: float = 0.9
     # Weight-only quantization: none | int8 (engine/quantization.py).
     quantization: str = "none"
     # Decode attention implementation:
@@ -285,6 +301,14 @@ class ModelConfig:
                 and self.num_nextn_predict_layers >= 1)
 
     @property
+    def block_length(self) -> int:
+        """Positions a block of a family that generates by diffusion
+        over blocks (models/registry.py ``Family.block``); 0 for a
+        family that generates left to right."""
+        block = self.family.block
+        return block(self) if block is not None else 0
+
+    @property
     def router_width(self) -> int:
         """Outputs the router chooses among: the published count of
         routed experts and, after them, the zero-compute ones."""
@@ -310,6 +334,89 @@ class ModelConfig:
                 self.jax_dtype if dtype == "model" else dtype).itemsize
             for shape, dtype in self.family.state(self))
         return per_layer * self.layer_is_linear.count(True)
+
+    @classmethod
+    def _from_sdar_moe(cls, hf: dict, name: str) -> "ModelConfig":
+        """``from_hf_config`` for ``SDARMoeForCausalLM`` / ``sdar_moe``:
+        the published Qwen3-MoE keys, and this engine's own for what
+        the family's ``generate.py`` takes as arguments (defaults
+        those of that script)."""
+        from production_stack_tpu.ops.sampling import REMASKING_STRATEGIES
+        ep, rank = _expert_parallel_share(hf)
+        block = int(hf.get("diffusion_block_length", 4))
+        steps = int(hf.get("diffusion_steps", 4))
+        remasking = hf.get("diffusion_remasking", "low_confidence_dynamic")
+        mask_id = int(hf.get("mask_token_id", 151669))
+        refused = [why for bad, why in (
+            (bool(hf.get("mlp_only_layers")),
+             f"mlp_only_layers {hf.get('mlp_only_layers')}: every layer "
+             "is served as an expert layer"),
+            (hf.get("decoder_sparse_step", 1) != 1,
+             f"decoder_sparse_step {hf.get('decoder_sparse_step')}: "
+             "every layer is served as an expert layer"),
+            (hf.get("rope_scaling") is not None,
+             f"rope_scaling {hf.get('rope_scaling')!r}: the rotary "
+             "embedding is served unscaled"),
+            (bool(hf.get("use_sliding_window", False)),
+             "use_sliding_window true: a query sees the whole row up "
+             "to the end of its block"),
+            (bool(hf.get("attention_bias", False)),
+             "attention_bias true: the attention projections are "
+             "served without a bias"),
+            (hf.get("hidden_act", "silu") != "silu",
+             f"hidden_act {hf.get('hidden_act')!r}: the experts are "
+             "SwiGLU"),
+            (block < 1 or block & (block - 1) != 0,
+             f"diffusion_block_length {block}: a block's end is found "
+             "as position | (length - 1), so its length is a power of "
+             "two"),
+            (not 1 <= steps <= max(block, 1),
+             f"diffusion_steps {steps} for blocks of {block}: a "
+             "denoising pass commits at least one place, so a block "
+             "takes 1 to its length of them"),
+            (remasking not in REMASKING_STRATEGIES,
+             f"diffusion_remasking {remasking!r}: the rules served are "
+             f"{', '.join(REMASKING_STRATEGIES)}"),
+            (not 0 <= mask_id < hf["vocab_size"],
+             f"mask_token_id {mask_id} is no row of an embedding of "
+             f"{hf['vocab_size']} rows"),
+        ) if bad]
+        if refused:
+            raise ValueError(
+                "SDAR-MoE config this engine does not serve: "
+                + "; ".join(refused))
+        return cls(
+            name=name or hf.get("_name_or_path", "sdar-moe"),
+            architecture="sdar_moe",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf.get("intermediate_size", 0),
+            num_hidden_layers=hf["num_hidden_layers"],
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf["num_key_value_heads"],
+            head_dim=hf.get("head_dim"),
+            max_position_embeddings=hf.get(
+                "max_position_embeddings", 32768),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            rope_theta=hf.get("rope_theta", 1e6),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            # The count this engine holds; the router's width is this
+            # times expert_parallel_size.
+            num_experts=hf["num_experts"],
+            expert_parallel_size=ep,
+            expert_parallel_rank=rank,
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            norm_topk_prob=hf.get("norm_topk_prob", True),
+            diffusion_block_length=block,
+            mask_token_id=mask_id,
+            diffusion_steps=steps,
+            diffusion_remasking=remasking,
+            diffusion_confidence_threshold=float(hf.get(
+                "diffusion_confidence_threshold", 0.9)),
+            activation="silu",
+            dtype="bfloat16",
+        )
 
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "") -> "ModelConfig":
@@ -388,6 +495,8 @@ class ModelConfig:
                 activation="silu",
                 dtype="bfloat16",
             )
+        if "sdarmoe" in arch.replace("_", ""):
+            return cls._from_sdar_moe(hf, name)
         if "exaonemoe" in arch.replace("_", ""):
             ep, rank = _expert_parallel_share(hf)
             layers = hf["num_hidden_layers"]
@@ -1060,6 +1169,15 @@ class SchedulerConfig:
     # configuration keeps one (num_nextn_predict_layers >= 1); off, the
     # module is not made and has no pages. Needs deferred_kv_writes.
     draft_module: bool = False
+    # Generation by diffusion over blocks (docs/block_diffusion.md):
+    # the positions a block (0: the model generates left to right) and
+    # the denoising passes a block is planned at, both the model's
+    # (EngineConfig takes them from it: no flag sets them). A prefill
+    # covers a prompt's whole blocks and yields no token; a burst of
+    # decode_steps forward passes is decode_steps // (block_steps + 1)
+    # blocks a row.
+    block_length: int = 0
+    block_steps: int = 0
     # Overlapped async execution pipeline (docs/async_pipeline.md):
     # plan and dispatch decode step N+1 — feeding step N's sampled
     # tokens forward as a device array — before step N's results are
@@ -1414,6 +1532,21 @@ class EngineConfig:
             # The module is not loaded: no weights, no cache entry.
             self.model = dataclasses.replace(
                 self.model, num_nextn_predict_layers=0)
+        if self.model.block_length:
+            refused = _block_diffusion_refusals(self)
+            if refused:
+                raise ValueError(
+                    f"{self.model.architecture} generates by diffusion "
+                    "over blocks of "
+                    f"{self.model.block_length} positions; refused: "
+                    + "; ".join(f"{feature} ({why})"
+                                for feature, why in refused))
+            if self.cache.cache_layout == "auto":
+                self.cache = dataclasses.replace(
+                    self.cache, cache_layout="per_layer")
+            self.scheduler = dataclasses.replace(
+                self.scheduler, block_length=self.model.block_length,
+                block_steps=self.model.diffusion_steps)
         if self.model.has_latent_cache:
             refused = _latent_cache_refusals(self)
             if refused:
@@ -1522,6 +1655,67 @@ def _recurrent_state_refusals(config: "EngineConfig"):
         (config.cache.cache_layout == "stacked",
          "cache_layout='stacked'",
          "pages and state pools are per-layer buffers"),
+    )
+    return [(feature, why) for on, feature, why in checks if on]
+
+
+def _block_diffusion_refusals(config: "EngineConfig"):
+    """(feature, why) for every configured feature that takes a step
+    to commit one token a row left to right, or has no path for a
+    block's passes."""
+    s, p, m = config.scheduler, config.parallel, config.model
+    own = m.family.refusals
+    block, passes = m.block_length, m.diffusion_steps + 1
+    checks = (
+        (s.speculative_k > 0, "speculative decoding by prompt lookup",
+         "a draft continues a row left to right and is verified "
+         "causally; a block's places are committed in any order"),
+        (s.draft_module, "a draft module",
+         "its proposal is the next token after the last committed one, "
+         "which a block has none of"),
+        (s.unified_step, "the unified ragged step",
+         "its decode rows are one token under a causal mask, and a "
+         "block's rows see their block in both directions"),
+        (s.async_scheduling, "async scheduling",
+         "its plan-ahead step assumes one committed token a row a "
+         "dispatch"),
+        (not s.deferred_kv_writes or s.decode_steps < passes,
+         f"decode_steps {s.decode_steps} with deferred_kv_writes "
+         f"{s.deferred_kv_writes}",
+         f"a block is {m.diffusion_steps} denoising passes and a store "
+         "pass over the burst's tails, so a burst is at least "
+         f"{passes} forward passes (--decode-steps) with deferred K/V "
+         "writes"),
+        (p.tensor_parallel_size > 1, "tensor parallelism",
+         own["tensor parallelism"]),
+        (p.pipeline_parallel_size > 1, "pipeline-parallel serving",
+         "the staged forward has no pass over a block"),
+        (p.context_parallel_size > 1, "context-parallel prefill",
+         "the ring prefill's mask is causal"),
+        (config.engine_role != "both", "disaggregated prefill/decode",
+         "the handoff follows the prefill's first token, and this "
+         "prefill yields none"),
+        (config.offload.enable, "KV offload",
+         "a restored row resumes after its last token, not at a "
+         "block's edge"),
+        (config.checkpoint_interval_tokens > 0,
+         "mid-stream checkpoint descriptors",
+         "a resume restores pages up to a token, not to a block's "
+         "edge"),
+        (config.lora.enable, "LoRA", "the model has no LoRA targets"),
+        (config.cache.resolved_kv_dtype() == "int8", "int8 KV pages",
+         "the burst's tails flush to plain planes in place"),
+        (m.quantization != "none", "weight quantization",
+         own["weight quantization"]),
+        (config.cache.cache_layout == "stacked",
+         "cache_layout='stacked'",
+         "the family's counters ride the per-layer cache tuples"),
+        (any(n % block for n in (config.cache.page_size,
+                                 s.prefill_chunk_size, s.max_model_len)),
+         f"page_size {config.cache.page_size}, prefill_chunk_size "
+         f"{s.prefill_chunk_size} or max_model_len {s.max_model_len}",
+         f"pages, chunks and the longest row end at a block's edge: "
+         f"each is a whole number of blocks of {block}"),
     )
     return [(feature, why) for on, feature, why in checks if on]
 
@@ -1662,6 +1856,11 @@ INTERNAL_FIELDS = {
     "model.attention_multiplier",
     "model.residual_multiplier",
     "model.logits_scaling",
+    "model.diffusion_block_length",
+    "model.mask_token_id",
+    "model.diffusion_steps",
+    "model.diffusion_remasking",
+    "model.diffusion_confidence_threshold",
     # Per-shape kernel overrides resolved by the model runner's
     # compile probe, not operator-set (--attention-impl is the knob).
     "model.attention_impl_decode",
@@ -1672,6 +1871,9 @@ INTERNAL_FIELDS = {
     "parallel.data_parallel_size",
     # Derived from the model and the scheduler's widths.
     "cache.num_state_slots",
+    # The model's own (EngineConfig.__post_init__ copies them).
+    "scheduler.block_length",
+    "scheduler.block_steps",
 }
 
 # Mutually-exclusive feature combos: (field_a, field_b, token). The
@@ -1866,6 +2068,36 @@ def tiny_exaone_moe_config(expert_parallel_size: int = 1,
         routed_scaling_factor=2.5,
         dtype="float32",
     )
+
+
+def tiny_sdar_moe_config(expert_parallel_size: int = 1,
+                         expert_parallel_rank: int = 0,
+                         **overrides) -> ModelConfig:
+    """A small SDAR-MoE for tests: blocks of four, every layer an
+    expert layer, float32."""
+    return ModelConfig(**{**dict(
+        name="tiny-sdar-moe",
+        architecture="sdar_moe",
+        vocab_size=512,
+        hidden_size=64,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=16,
+        max_position_embeddings=512,
+        rms_norm_eps=1e-6,
+        rope_theta=1e6,
+        num_experts=8 // expert_parallel_size,
+        expert_parallel_size=expert_parallel_size,
+        expert_parallel_rank=expert_parallel_rank,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        diffusion_block_length=4,
+        mask_token_id=511,
+        diffusion_steps=2,
+        diffusion_remasking="sequential",
+        dtype="float32",
+    ), **overrides})
 
 
 def tiny_longcat_flash_config(expert_parallel_size: int = 1,

@@ -346,6 +346,7 @@ class LLMEngine:
                     "need an outlines-style vocabulary DFA product — "
                     "not yet supported)")
             fsm_state = 0
+        self.check_sampling(sampling)
         lora_id = 0
         if lora_name is not None:
             if self.runner.lora_registry is None:
@@ -391,6 +392,69 @@ class LLMEngine:
                 if seq.cold_start_probe:
                     self._tracer.event(seq.seq_id, "awaiting_kv_park")
         return seq.seq_id
+
+    def check_sampling(self, sampling: SamplingParams) -> None:
+        """Raises ValueError for what this model's generation has no
+        rule for (the server answers 400). A block-diffusion family's
+        request: its three fields made
+        whole from the model configuration's defaults, and what a
+        block's passes have no rule for refused in words
+        (docs/block_diffusion.md). Any other family refuses the three
+        fields."""
+        from production_stack_tpu.ops.sampling import REMASKING_STRATEGIES
+        model = self.config.model
+        block = model.block_length
+        asked = [name for name in ("denoising_steps", "remasking_strategy",
+                                   "confidence_threshold")
+                 if getattr(sampling, name) is not None]
+        if not block:
+            if asked:
+                raise ValueError(
+                    f"{', '.join(asked)}: {model.architecture} generates "
+                    "left to right, a token a step; these are a "
+                    "block-diffusion model's")
+            return
+        refused = [why for bad, why in (
+            (sampling.guided is not None,
+             "a guided grammar: its automaton walks left to right, and "
+             "a block's places are committed in any order"),
+            (sampling.needs_penalties,
+             "presence, frequency or repetition penalties: they count "
+             "the tokens before a place, and a block's places are drawn "
+             "together"),
+            (bool(sampling.logit_bias),
+             "logit_bias: the block's sampler reads the logits as the "
+             "head wrote them"),
+            (sampling.min_tokens > 0,
+             "min_tokens: a stop token is suppressed by how many tokens "
+             "precede it, which a block's places do not know when they "
+             "are drawn"),
+            (sampling.seed is not None,
+             "seed: a seeded draw is keyed by a token's index in the "
+             "answer, and a place is drawn once a denoising pass until "
+             "it is committed"),
+        ) if bad]
+        if refused:
+            raise ValueError(
+                f"{model.architecture} generates by diffusion over "
+                f"blocks; refused: " + "; ".join(refused))
+        steps = sampling.denoising_steps
+        if steps is None:
+            steps = model.diffusion_steps
+        if not 1 <= steps <= block:
+            raise ValueError(
+                f"denoising_steps {steps}: a pass commits at least one "
+                f"place of a block of {block}, so 1 to {block}")
+        strategy = sampling.remasking_strategy or model.diffusion_remasking
+        if strategy not in REMASKING_STRATEGIES:
+            raise ValueError(
+                f"remasking_strategy {strategy!r}: one of "
+                f"{', '.join(REMASKING_STRATEGIES)}")
+        sampling.denoising_steps = steps
+        sampling.remasking_strategy = strategy
+        if sampling.confidence_threshold is None:
+            sampling.confidence_threshold = (
+                model.diffusion_confidence_threshold)
 
     def _cold_start_target(self, seq: Sequence):
         """First full usable prompt page neither in HBM nor hashed
@@ -1002,6 +1066,8 @@ class LLMEngine:
                     # then there is nothing to decode and nothing
                     # worth shipping).
                     self._ship_handoff(chunk.seq)
+                if token is None:
+                    continue  # a prefill that yields no token
                 self._owed.append(self._delta(
                     chunk.seq, token,
                     lp_rows[i] if lp_rows else None))
@@ -1089,6 +1155,11 @@ class LLMEngine:
                                "accepted": int(moe["accepted"])}
                 self.metrics.on_spec_step(module_note["drafts"],
                                           module_note["accepted"])
+            if moe and "denoise_passes" in moe:
+                # A block-diffusion burst: the passes that ran (they
+                # replace the planned window), the blocks and what the
+                # passes committed, before any host-side truncation.
+                module_note = self.metrics.on_block_burst(moe)
             if self._tracer is not None:
                 self._step_note = {
                     "kind": "spec" if spec_drafts is not None

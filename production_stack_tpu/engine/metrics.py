@@ -99,6 +99,16 @@ class EngineMetrics:
         # off) so the router scraper sees a stable metric surface.
         self.spec_draft_tokens_total = 0
         self.spec_accepted_tokens_total = 0
+        # Block-diffusion decoding (docs/block_diffusion.md): forward
+        # passes that denoised a block, passes that stored one, blocks
+        # worked (rows x blocks) and tokens the passes committed, over
+        # all bursts. Tokens are not forward passes here: both are
+        # counted. Always rendered (0 for a family that generates left
+        # to right).
+        self.diffusion_denoise_passes_total = 0
+        self.diffusion_store_passes_total = 0
+        self.diffusion_blocks_total = 0
+        self.diffusion_committed_tokens_total = 0
         # Overlapped async pipeline (docs/async_pipeline.md): per-step
         # host vs device-wait seconds, the device-idle gap the
         # pipeline hides, and how many steps were dispatched ahead of
@@ -185,6 +195,20 @@ class EngineMetrics:
         with self._lock:
             self.spec_draft_tokens_total += drafted
             self.spec_accepted_tokens_total += accepted
+
+    def on_block_burst(self, stats: dict) -> dict:
+        """One block-diffusion burst's own counts (the last four of
+        its family's counters), added to the totals and returned for
+        the step record: ``window`` is the forward passes that ran."""
+        note = {name: int(stats[name]) for name in (
+            "denoise_passes", "store_passes", "blocks", "committed")}
+        with self._lock:
+            self.diffusion_denoise_passes_total += note["denoise_passes"]
+            self.diffusion_store_passes_total += note["store_passes"]
+            self.diffusion_blocks_total += note["blocks"]
+            self.diffusion_committed_tokens_total += note["committed"]
+        return {**note,
+                "window": note["denoise_passes"] + note["store_passes"]}
 
     def on_ragged_step(self, prefill_rows: int, decode_rows: int,
                        pad_rows: int) -> None:
@@ -326,6 +350,19 @@ class EngineMetrics:
                  "counter"),
                 ("vllm:spec_decode_num_accepted_tokens_total "
                  f"{self.spec_accepted_tokens_total}"),
+                "# TYPE vllm:diffusion_denoise_passes_total counter",
+                ("vllm:diffusion_denoise_passes_total "
+                 f"{self.diffusion_denoise_passes_total}"),
+                "# TYPE vllm:diffusion_store_passes_total counter",
+                ("vllm:diffusion_store_passes_total "
+                 f"{self.diffusion_store_passes_total}"),
+                "# TYPE vllm:diffusion_blocks_total counter",
+                ("vllm:diffusion_blocks_total "
+                 f"{self.diffusion_blocks_total}"),
+                ("# TYPE vllm:diffusion_committed_tokens_total "
+                 "counter"),
+                ("vllm:diffusion_committed_tokens_total "
+                 f"{self.diffusion_committed_tokens_total}"),
                 "# TYPE vllm:moe_room_overflow_steps_total counter",
                 ("vllm:moe_room_overflow_steps_total "
                  f"{self.moe_room_overflow_steps_total}"),

@@ -27,11 +27,16 @@ from production_stack_tpu.engine.perf_observatory import (
     rounded_split,
     take_load_split,
 )
-from production_stack_tpu.engine.scheduler import DecodePlan, PrefillPlan
+from production_stack_tpu.engine.scheduler import (
+    DecodePlan,
+    PrefillPlan,
+    burst_blocks,
+)
 from production_stack_tpu.engine.tracing import StartupTimeline
 from production_stack_tpu.engine.sequence import (
     STOP_SET_WIDTH,
     Sequence,
+    block_start,
     decode_budget,
     draftless,
 )
@@ -54,11 +59,13 @@ from production_stack_tpu.ops.quant_kv import (
 )
 from production_stack_tpu.ops.window_attention import write_to_ring
 from production_stack_tpu.ops.sampling import (
+    REMASKING_STRATEGIES,
     apply_penalties,
     draw_proposal,
     sample_tokens,
     spec_verify,
     token_logprobs,
+    unmask_block,
     verify_proposal,
 )
 from production_stack_tpu.parallel.mesh import (
@@ -86,6 +93,14 @@ PALLAS_RAGGED_IN_AUTO = False
 # silently returns fewer alternatives than requested (the server also
 # rejects top_logprobs > 20 with a 400).
 TOP_LOGPROBS_WIDTH = 20
+
+# A block-diffusion burst's per-row inputs beside the sampling knobs
+# (dispatch_burst): the given places of the row's first block, its
+# denoising passes a block, its rule (an index into
+# ops/sampling.py REMASKING_STRATEGIES) and the dynamic rule's
+# threshold.
+BLOCK_ROW_INPUTS = ("block_given", "block_steps", "block_strategy",
+                    "block_threshold")
 
 # Model families served by the deferred-KV-write burst: those that
 # declare that their forward takes kv_tail (models/registry.py: the
@@ -552,6 +567,16 @@ class ModelRunner:
         if self._drafts:
             self._draft = get_draft(model_config)
 
+        # A family that generates by diffusion over blocks
+        # (models/registry.py Family.block): the positions a block, 0
+        # for a family that generates left to right. Its prefill
+        # program samples nothing and its burst is the block one.
+        self._block = model_config.block_length
+        self._prefill_mode = "none" if self._block else "last"
+        self.burst_blocks = (burst_blocks(config.scheduler.decode_steps,
+                                          model_config.diffusion_steps)
+                             if self._block else 0)
+
         with self.startup.within("boot.weights") as span:
             self.params = self._place_params(params, model_config, mesh)
             # Until the arrays are there: the cache's planes below
@@ -719,7 +744,8 @@ class ModelRunner:
         # mixed-progress batches). One dispatch + one device_get per K
         # tokens.
         self._decode_burst_jit = InstrumentedJit("decode_burst", jax.jit(
-            (self._decode_burst_draft_impl if self._drafts
+            (self._decode_burst_block_impl if self._block
+             else self._decode_burst_draft_impl if self._drafts
              else self._decode_burst_deferred_impl if self._deferred
              else self._decode_burst_impl),
             static_argnames=("num_steps", "want_logprobs"),
@@ -1154,9 +1180,19 @@ class ModelRunner:
         b = config.scheduler.max_num_seqs
         pb = config.scheduler.prefill_batch_size
         rows_i32 = jax.ShapeDtypeStruct((b,), np.int32)
-        tail = (jax.ShapeDtypeStruct(
-            (b, config.scheduler.decode_steps, nkv, d), dtype)
-            if config.scheduler.deferred_kv_writes else None)
+        tail_slots = config.scheduler.decode_steps
+        block = model_config.block_length
+        if block:
+            # A block's queries ride the group axis of the decode
+            # kernel (models/llama.py block_attention) and the tails
+            # hold the burst's blocks; a chunk's sight is by block.
+            nh = nh * block
+            tail_slots = block * burst_blocks(
+                tail_slots, model_config.diffusion_steps)
+            paged_prefill_attention = functools.partial(
+                paged_prefill_attention, block=block)
+        tail = (jax.ShapeDtypeStruct((b, tail_slots, nkv, d), dtype)
+                if config.scheduler.deferred_kv_writes else None)
         probes = {
             "decode": [(
                 paged_decode_attention,
@@ -1176,8 +1212,9 @@ class ModelRunner:
             # start.
             "prefill": [(
                 paged_prefill_attention,
-                (jax.ShapeDtypeStruct((pb, t, nh, d), dtype), cache,
-                 cache,
+                (jax.ShapeDtypeStruct(
+                    (pb, t, model_config.num_attention_heads, d), dtype),
+                 cache, cache,
                  jax.ShapeDtypeStruct((pb, max_pages), np.int32),
                  jax.ShapeDtypeStruct((pb, t), np.int32),
                  jax.ShapeDtypeStruct((pb,), np.int32), layer0),
@@ -1303,6 +1340,15 @@ class ModelRunner:
         # A prefill step of a family that drafts also fills the draft
         # module's cache entry (below, once the token is sampled).
         fills_draft = self._drafts and sample_index_mode == "last"
+        if sample_index_mode == "none":
+            # A block-diffusion family's prefill chunk: whole blocks
+            # to their pages, no head and no token.
+            _, k_cache, v_cache = self._forward(
+                params, self.config.model, tokens, positions,
+                page_table, kv_lens, valid, k_cache, v_cache,
+                head=False)
+            return (jnp.zeros((tokens.shape[0],), jnp.int32), k_cache,
+                    v_cache)
         logits, *hidden, k_cache, v_cache = self._forward(
             params, self.config.model, tokens, positions, page_table,
             kv_lens, valid, k_cache, v_cache,
@@ -1931,6 +1977,157 @@ class ModelRunner:
             lambda x: x.reshape((2 * num_steps,) + x.shape[2:]), out)
         return (out,) + flush(kt, vt, emitted)
 
+    def _decode_burst_block_impl(self, params, k_cache, v_cache,
+                                 tokens, positions, page_table,
+                                 kv_lens, active, budgets,
+                                 stop_tokens, temperature, top_p,
+                                 top_k, rng, lora, lora_ids,
+                                 penalties, seeding, bias,
+                                 suppress, fsm, num_steps: int,
+                                 want_logprobs: bool = False,
+                                 state_slots=None, block_rows=None):
+        """The burst of a family that generates by diffusion over
+        blocks (docs/block_diffusion.md), on ``_burst_tails``: a scan
+        over ``burst_blocks(num_steps, diffusion_steps)`` blocks of
+        ``B`` positions a row, the rows in lockstep by block as the
+        published batched loop has them.
+
+        A row's block starts at ``positions[:, 0] + j * B`` (the
+        pages hold exactly the tokens before the burst's first block:
+        ``kv_lens0``) and owns tail slots ``j * B .. j * B + B - 1``.
+        It begins as ``tokens [rows, B]``' given places (the first
+        block: the prompt's remainder, ``block_rows[0]`` of them) and
+        masked places after them. A denoising pass runs every row's B
+        places against pages, finished blocks and the block itself
+        (masked places as the mask's embedding), writes the block's
+        K/V to its slots PROVISIONALLY (a later pass overwrites them
+        in place, as a rejected draft's slot is overwritten in the
+        draft burst) and commits some masked places (``unmask_block``:
+        the row's own passes a block, rule and threshold,
+        ``block_rows[1:]``); passes go on while a live row has a
+        masked place, at most B. Then the block's new tokens go out in
+        position order, under the row's budget and stop set (a stop
+        token or the budget inside a block drops the places after it
+        and ends the row), and one STORE pass, without the head or the
+        sampler, writes the final K/V of the rows still alive to the
+        same slots: what a prefill of prompt + answer would write. A
+        row that ended inside a block stores nothing of it, and the
+        flush writes each row's stored blocks alone.
+
+        ``num_steps`` (--decode-steps) is the burst's planned forward
+        passes; the passes that ran are counted on the device with the
+        blocks worked and the places committed, in the family's
+        counters (``denoise_passes``, ``store_passes``, ``blocks``,
+        ``committed``). Returns tokens ``[blocks * B, rows]`` (-1
+        where nothing went out), with ``want_logprobs`` the raw
+        log-probabilities at each token's committing pass beside
+        them."""
+        del kv_lens, penalties, seeding, bias, suppress, fsm  # refused
+        m = self.config.model
+        bl = m.block_length
+        blocks = burst_blocks(num_steps, m.diffusion_steps)
+        b = active.shape[0]
+        given, steps, strategy, threshold = block_rows
+        i_denoise = m.family.counters.index("denoise_passes")
+        kv_lens0 = positions[:, 0]  # pages hold this many tokens
+        (k_kinds, v_kinds, k_carry0, v_carry0, served,
+         flush) = self._burst_tails(k_cache, v_cache, page_table,
+                                    kv_lens0, blocks * bl, state_slots)
+        place = jnp.arange(bl)
+        width = TOP_LOGPROBS_WIDTH
+
+        def counted(kt, denoise=0, store=0, worked=0, committed=0):
+            add = jnp.stack([jnp.asarray(x, jnp.float32) for x in (
+                denoise, store, worked, committed)])
+            return kt[:-1] + (
+                kt[-1].at[i_denoise:i_denoise + 4].add(add),)
+
+        def run(ids, masked, pos, live, kt, vt, **how):
+            return self._forward(
+                params, m, ids, pos, page_table, kv_lens0,
+                jnp.broadcast_to(live[:, None], ids.shape),
+                served(k_cache, kt, k_kinds),
+                served(v_cache, vt, v_kinds), kv_tail=(kt, vt),
+                masked=masked, **how)
+
+        def block_body(carry, xs):
+            ids, new, act, emitted, stored, kt, vt = carry
+            j, key = xs
+            pos = kv_lens0[:, None] + j * bl + place[None, :]
+
+            def denoise(state):
+                s, ids, masked, lps, kt, vt = state
+                logits, kt, vt = run(ids, masked, pos, act, kt, vt,
+                                     position_major=True)
+                quota = bl // steps + (s < bl % steps)
+                x0, commit, _ = unmask_block(
+                    logits, masked, quota, strategy, threshold,
+                    temperature, top_p, top_k,
+                    jax.random.fold_in(key, s))
+                if want_logprobs:
+                    # The raw distribution of the committing pass.
+                    at = [token_logprobs(logits[i], x0[:, i], width)
+                          for i in range(bl)]
+                    lps = tuple(
+                        jnp.where(
+                            commit.reshape(commit.shape
+                                           + (1,) * (old.ndim - 2)),
+                            jnp.stack(new_, axis=1), old)
+                        for old, new_ in zip(lps, zip(*at)))
+                kt = counted(kt, denoise=1, committed=jnp.sum(commit))
+                return (s + 1, jnp.where(commit, x0, ids),
+                        masked & ~commit, lps, kt, vt)
+
+            lps0 = ((jnp.zeros((b, bl), jnp.float32),
+                     jnp.zeros((b, bl, width), jnp.int32),
+                     jnp.zeros((b, bl, width), jnp.float32))
+                    if want_logprobs else ())
+            _, ids, _, lps, kt, vt = jax.lax.while_loop(
+                lambda state: (state[0] < bl) & jnp.any(state[2]),
+                denoise,
+                (jnp.int32(0), ids, new & act[:, None], lps0, kt, vt))
+
+            # The block's new tokens in position order: a place goes
+            # out while the row lives, and a stop token or the budget
+            # met ends the row there.
+            alive, out = act, []
+            for i in range(bl):
+                emit = alive & new[:, i]
+                emitted = emitted + emit
+                out.append(jnp.where(emit, ids[:, i], -1))
+                hit_stop = jnp.any(
+                    ids[:, i, None] == stop_tokens, axis=-1)
+                alive = (alive & ~(emit & hit_stop)
+                         & (emitted < budgets))
+            out = jnp.stack(out)
+            if want_logprobs:
+                out = (out,) + tuple(jnp.swapaxes(x, 0, 1) for x in lps)
+
+            def store(kt, vt):
+                _, kt, vt = run(ids, None, pos, alive, kt, vt,
+                                head=False)
+                return counted(kt, store=1), vt
+
+            kt = counted(kt, worked=jnp.sum(act))
+            kt, vt = jax.lax.cond(jnp.any(alive), store,
+                                  lambda kt, vt: (kt, vt), kt, vt)
+            stored = stored + alive.astype(stored.dtype) * bl
+            return ((jnp.zeros_like(ids), jnp.ones_like(new), alive,
+                     emitted, stored, kt, vt), out)
+
+        zeros = jnp.zeros(active.shape, jnp.int32)
+        carry = (tokens, place[None, :] >= given[:, None], active, zeros,
+                 zeros, k_carry0, v_carry0)
+        carry, out = jax.lax.scan(
+            block_body, carry,
+            (jnp.arange(blocks), jax.random.split(rng, blocks)))
+        stored, kt, vt = carry[4], carry[5], carry[6]
+        # [blocks, B, rows, ...] -> [blocks * B, rows, ...]: a row's
+        # tokens in position order.
+        out = jax.tree_util.tree_map(
+            lambda x: x.reshape((blocks * bl,) + x.shape[2:]), out)
+        return (out,) + flush(kt, vt, stored)
+
     def _burst_row_logits(self, penalties, bias, suppress, guided: bool):
         """``_burst_sample_step``'s way from a row's raw logits to the
         ones it is sampled from, for a burst body that samples by
@@ -2171,6 +2368,10 @@ class ModelRunner:
                     num_steps=t, want_logprobs=want_lp, **state,
                     **({"draft_rows": _as_device(payload["draft_rows"])}
                        if "draft_rows" in payload else {}),
+                    **({"block_rows": tuple(
+                        _as_device(payload[name])
+                        for name in BLOCK_ROW_INPUTS)}
+                       if BLOCK_ROW_INPUTS[0] in payload else {}),
                 )
             # [K, B], or [2K, B] where the burst drafts (+ logprob
             # arrays when requested)
@@ -2189,7 +2390,8 @@ class ModelRunner:
             _as_device(payload["rng"]),
             self._lora_stack, lora_ids, penalties, seeding, bias,
             suppress, fsm,
-            sample_index_mode=("last" if kind == 1 else "first"),
+            sample_index_mode=(self._prefill_mode if kind == 1
+                               else "first"),
             want_logprobs=want_lp, **state,
             **({"next_tokens": _as_device(payload["next_tokens"])}
                if "next_tokens" in payload else {}),
@@ -2222,7 +2424,8 @@ class ModelRunner:
             self._lora_stack,
             None if lora_ids is None else _as_device(lora_ids),
             None, None, None, None, None,
-            sample_index_mode="last", want_logprobs=False, **state)
+            sample_index_mode=self._prefill_mode, want_logprobs=False,
+            **state)
         obs = self.observatory
         lowering = take_load_split(since)
         lowered_s = time.perf_counter() - since
@@ -2644,8 +2847,8 @@ class ModelRunner:
         # Only rows whose LAST chunk is in this dispatch keep their
         # sampled token; mid-prompt chunks skip the [B, vocab] penalty
         # transfer and the penalized program entirely.
-        sampling_rows = [c.seq if c.is_last_chunk else None
-                         for c in chunks]
+        sampling_rows = [c.seq if c.is_last_chunk and not self._block
+                         else None for c in chunks]
         payload.update(self._penalty_payload(sampling_rows, b))
         payload.update(self._seed_payload(sampling_rows, b))
         payload.update(self._bias_payload(sampling_rows, b))
@@ -2686,8 +2889,13 @@ class ModelRunner:
                     lps.append(None)
             return out, (lps if want_lp else None)
 
-        sampling = any(c.is_last_chunk for c in chunks)
-        return StepHandle(self, sampled if sampling else None, parse)
+        sampling = any(row is not None for row in sampling_rows)
+        if not sampling:
+            # Mid-prompt chunks alone, or a family whose prefill yields
+            # no token: nothing to read.
+            return StepHandle(
+                self, None, lambda host: ([None] * len(chunks), None))
+        return StepHandle(self, sampled, parse)
 
     # ---- decode -----------------------------------------------------------
 
@@ -2851,6 +3059,8 @@ class ModelRunner:
         row requested logprobs."""
         if plan.drafts is not None:
             return self.dispatch_spec(plan)
+        if self._block:
+            return self.dispatch_burst(plan)
         if max(1, plan.window) == 1 and self.bridge is None:
             # Single-host single-step decode rides the async
             # pipeline's dispatch path (staged inputs, one fused
@@ -2874,12 +3084,17 @@ class ModelRunner:
         if self.tracer is not None:
             self.tracer.phase("build")
 
-        tokens = np.zeros((b, 1), np.int32)
+        block = self._block
+        tokens = np.zeros((b, block or 1), np.int32)
         positions = np.zeros((b, 1), np.int32)
         valid = np.zeros((b, 1), bool)
         kv_lens = np.zeros((b,), np.int32)
         budgets = np.zeros((b,), np.int32)
         stop_tokens = np.full((b, stop_w), -1, np.int32)
+        # A block-diffusion row (BLOCK_ROW_INPUTS): a pad row takes one
+        # pass a block.
+        block_rows = (np.zeros((b,), np.int32), np.ones((b,), np.int32),
+                      np.zeros((b,), np.int32), np.ones((b,), np.float32))
         # Pad rows stay temperature 0 so an all-greedy batch keeps the
         # sampler's sort-free fast path (ops/sampling.py).
         temperature = np.zeros((b,), np.float32)
@@ -2887,13 +3102,26 @@ class ModelRunner:
         top_k = np.zeros((b,), np.int32)
 
         for i, seq in enumerate(seqs):
-            last_token = (seq.output_token_ids[-1]
-                          if seq.output_token_ids
-                          else seq.prompt_token_ids[-1])
-            tokens[i, 0] = last_token
-            positions[i, 0] = seq.total_len - 1
+            if block:
+                # The row's next block: the tokens past its last whole
+                # block are the block's given places.
+                start = block_start(seq, block)
+                rest = seq.all_token_ids[start:]
+                tokens[i, :len(rest)] = rest
+                positions[i, 0] = kv_lens[i] = start
+                sp = seq.sampling
+                for column, value in zip(block_rows, (
+                        len(rest), sp.denoising_steps,
+                        REMASKING_STRATEGIES.index(sp.remasking_strategy),
+                        sp.confidence_threshold)):
+                    column[i] = value
+            else:
+                tokens[i, 0] = (seq.output_token_ids[-1]
+                                if seq.output_token_ids
+                                else seq.prompt_token_ids[-1])
+                positions[i, 0] = seq.total_len - 1
+                kv_lens[i] = seq.total_len
             valid[i, 0] = True
-            kv_lens[i] = seq.total_len
             budgets[i] = decode_budget(
                 seq, self.config.scheduler.max_model_len)
             if not seq.sampling.ignore_eos:
@@ -2921,6 +3149,8 @@ class ModelRunner:
             payload["active"] = valid[:, 0].copy()
             payload["budgets"] = budgets
             payload["stop_tokens"] = stop_tokens
+        if block:
+            payload.update(zip(BLOCK_ROW_INPUTS, block_rows))
         if self._drafts:
             # Rows the draft module proposes for: those whose logits
             # are sampled as the model gives them (the exclusion set

@@ -34,6 +34,7 @@ from production_stack_tpu.engine.sequence import (
     FinishReason,
     Sequence,
     SequenceState,
+    block_start,
     decode_budget,
     draftless,
 )
@@ -45,6 +46,14 @@ logger = init_logger(__name__)
 # warning is rate-limited to one line per interval (with a
 # suppressed-count) so logging can't become the bottleneck.
 _PREEMPT_LOG_INTERVAL_S = 5.0
+
+
+def burst_blocks(window: int, block_steps: int) -> int:
+    """Blocks a row works in a block-diffusion burst of ``window``
+    forward passes planned at ``block_steps`` denoising passes and a
+    store pass a block. Single source of truth for the scheduler's
+    page reservation and the runner's compiled burst."""
+    return max(1, window // (block_steps + 1))
 
 
 @dataclass
@@ -504,8 +513,22 @@ class Scheduler:
     def _window_tokens(self, window: int) -> int:
         """The most tokens a row commits in a burst of ``window``
         iterations, which is what its pages must hold: an iteration
-        that verifies the draft module's proposal commits two."""
+        that verifies the draft module's proposal commits two; a
+        block-diffusion burst's iterations are forward passes, a block
+        of ``block_length`` tokens for every ``block_steps`` denoising
+        passes and a store pass."""
+        block = self.config.block_length
+        if block:
+            return block * burst_blocks(window, self.config.block_steps)
         return window * (2 if self.config.draft_module else 1)
+
+    def _prefill_target(self, seq: Sequence) -> int:
+        """The prompt tokens a prefill computes: all of them, or the
+        whole blocks of a block-diffusion family's prompt (the
+        remainder enters the first block as given places)."""
+        block = self.config.block_length
+        n = seq.num_prompt_tokens
+        return n - n % block if block else n
 
     def _seq_budget(self, seq: Sequence) -> int:
         return decode_budget(seq, self.config.max_model_len)
@@ -565,7 +588,7 @@ class Scheduler:
             if rows >= self.config.prefill_batch_size:
                 return True
             if (seq.num_computed_tokens + self.config.prefill_chunk_size
-                    >= seq.num_prompt_tokens):
+                    >= self._prefill_target(seq)):
                 admitting += 1
         return False
 
@@ -674,11 +697,18 @@ class Scheduler:
                     )
                     return None
             start = seq.num_computed_tokens
-            end = min(start + self.config.prefill_chunk_size,
-                      seq.num_prompt_tokens)
+            target = self._prefill_target(seq)
+            if start >= target:
+                # A block-diffusion prompt with no whole block left to
+                # compute (shorter than a block, or all of its blocks
+                # found in the prefix cache): it runs from here.
+                self._admit(seq)
+                admitting += 1
+                continue
+            end = min(start + self.config.prefill_chunk_size, target)
             if max_tokens is not None:
                 end = min(end, start + (max_tokens - tokens_planned))
-            is_last = end == seq.num_prompt_tokens
+            is_last = end == target
             if seq.first_scheduled_time is None:
                 seq.first_scheduled_time = time.time()
             chunks.append(PrefillChunk(
@@ -835,7 +865,11 @@ class Scheduler:
             len(seq.pages),
             seq.num_computed_tokens // self.page_size,
         )
-        if chunk.is_last_chunk:
+        if chunk.is_last_chunk and self.config.block_length:
+            # The prefill of a block-diffusion family yields no token:
+            # the row's first come out of its first block.
+            self._admit(seq)
+        elif chunk.is_last_chunk:
             assert sampled_token is not None
             try:
                 self.waiting.remove(seq)
@@ -848,6 +882,16 @@ class Scheduler:
                                   token=int(sampled_token))
             self.running.append(seq)
             self._append_token(seq, sampled_token)
+
+    def _admit(self, seq: Sequence) -> None:
+        """A waiting row whose prefill is done (or had nothing to do)
+        joins ``running`` with no token of its own yet."""
+        try:
+            self.waiting.remove(seq)
+        except ValueError:
+            return  # raced with an abort that already dequeued it
+        seq.transition(SequenceState.RUNNING)
+        self.running.append(seq)
 
     def finish_handoff(self, seq: Sequence) -> None:
         """Disagg prefill handoff complete (the engine already shipped
@@ -893,6 +937,12 @@ class Scheduler:
         row), a stop set wider than the device's (the burst ran past
         what the host ends at), logprobs (an entry a token)."""
         sp = seq.sampling
+        if tokens and seq.first_token_time is None:
+            # A block-diffusion row: its prefill yielded no token.
+            seq.first_token_time = time.time()
+            if self.tracer is not None:
+                self.tracer.event(seq.seq_id, "first_token",
+                                  token=int(tokens[0]))
         if (seq.fsm_state is not None or sp.logprobs
                 or sp.min_tokens > seq.num_generated
                 or (not sp.ignore_eos

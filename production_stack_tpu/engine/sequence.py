@@ -59,6 +59,14 @@ class SamplingParams:
     # the byte-level automaton (engine/guided.py); the device masks
     # inadmissible tokens inside the sampling step. None = free text.
     guided: Optional[str] = None
+    # Block-diffusion families only (docs/block_diffusion.md; the
+    # names of the published generate.py): the denoising passes a
+    # block (1 to the block's length), which masked places a pass
+    # commits (ops/sampling.py REMASKING_STRATEGIES) and the dynamic
+    # rule's threshold. None = the model configuration's.
+    denoising_steps: Optional[int] = None
+    remasking_strategy: Optional[str] = None
+    confidence_threshold: Optional[float] = None
 
     @property
     def greedy(self) -> bool:
@@ -98,7 +106,9 @@ SEQUENCE_TRANSITIONS = (
      "disagg handoff / crash resume arrives parked until its shipped "
      "KV is reachable in an offload tier"),
     ("waiting", "running",
-     "last prefill chunk executed and the first token sampled"),
+     "last prefill chunk executed and the first token sampled (a "
+     "block-diffusion family: the prompt's whole blocks prefilled, or "
+     "none to prefill, and no token yet)"),
     ("waiting", "awaiting_kv",
      "cold-start probe: park a fresh request to ask the shared KV "
      "tier for its prefix before computing"),
@@ -273,6 +283,14 @@ def decode_budget(seq: "Sequence", max_model_len: int) -> int:
         seq.sampling.max_tokens - seq.num_generated,
         max_model_len - seq.total_len,
     )
+
+
+def block_start(seq: "Sequence", block: int) -> int:
+    """Where ``seq``'s next block begins: its tokens before that are
+    whole blocks, in the pages once prefilled or stored; the tokens
+    from there on (the prompt's remainder, before the first block; none
+    after it) enter the block as given places."""
+    return seq.total_len - seq.total_len % block
 
 
 def draftless(seq: "Sequence") -> bool:

@@ -471,6 +471,18 @@ def _sampling_from_body(body: dict, max_model_len: int,
         logit_bias=logit_bias,
         min_tokens=int(body.get("min_tokens") or 0),
         guided=_guided_from_body(body),
+        # A block-diffusion model's (docs/block_diffusion.md; the
+        # names of the published generate.py). The engine makes them
+        # whole from the model's defaults, or refuses them for a model
+        # that generates left to right (LLMEngine.check_sampling).
+        denoising_steps=(None if body.get("denoising_steps") is None
+                         else int(body["denoising_steps"])),
+        remasking_strategy=(
+            None if body.get("remasking_strategy") is None
+            else str(body["remasking_strategy"])),
+        confidence_threshold=(
+            None if body.get("confidence_threshold") is None
+            else float(body["confidence_threshold"])),
     )
     _validate_sampling(params)
     return params
@@ -855,6 +867,7 @@ class EngineServer:
                 body, self.engine.config.scheduler.max_model_len,
                 vocab_size=self.engine.config.model.vocab_size,
             )
+            self.engine.check_sampling(sampling)
         except (ValueError, TypeError) as e:
             return web.json_response(
                 {"error": {"message": str(e),
@@ -1395,6 +1408,7 @@ class EngineServer:
                 body, self.engine.config.scheduler.max_model_len,
                 vocab_size=self.engine.config.model.vocab_size,
             )
+            self.engine.check_sampling(sampling)
         except (ValueError, TypeError) as e:
             return web.json_response(
                 {"error": {"message": str(e),
@@ -2351,7 +2365,9 @@ class EngineServer:
                 jnp.dtype(model.jax_dtype).itemsize),
             "expert_room": {
                 "burst": room(runner.decode_width,
-                              2 if config.scheduler.draft_module else 1),
+                              model.block_length
+                              or (2 if config.scheduler.draft_module
+                                  else 1)),
                 "prefill": {f"{rows}x{tokens}": room(rows, tokens)
                             for rows, tokens in prefill_shapes(
                                 runner.prefill_width,
@@ -2400,6 +2416,19 @@ class EngineServer:
             **window,
             "family": config.model.architecture,
             **kv,
+            # A family that generates by diffusion over blocks: the
+            # block, the defaults a request may replace, and what a
+            # burst is planned at (docs/block_diffusion.md).
+            **({"block_diffusion": {
+                "block_length": model.block_length,
+                "mask_token_id": model.mask_token_id,
+                "denoising_steps": model.diffusion_steps,
+                "remasking_strategy": model.diffusion_remasking,
+                "confidence_threshold":
+                    model.diffusion_confidence_threshold,
+                "burst_passes": config.scheduler.decode_steps,
+                "burst_blocks": runner.burst_blocks}}
+               if model.block_length else {}),
             # The start by span, from the process's first instant to
             # the listener, on the unix clock (engine/tracing.py
             # STARTUP_SPANS; docs/observability.md, "Why is a start
@@ -2821,7 +2850,8 @@ def _resolve_unified_step(args, model_config=None) -> bool:
     if args.unified_step == "off":
         return False
     if model_config is not None and (model_config.has_recurrent_state
-                                     or model_config.has_latent_cache):
+                                     or model_config.has_latent_cache
+                                     or model_config.block_length):
         # The ragged rows have no path for a recurrent state or for a
         # latent plane (engine/config.py refuses an explicit 'on').
         return False

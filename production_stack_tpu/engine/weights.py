@@ -291,6 +291,12 @@ def load_weights(model_dir: str, config: ModelConfig,
             "stacks (gate | up fused, the held experts' block, the "
             "prediction layer left out) is not written yet: serve the "
             "architecture with --random-weights")
+    if config.architecture == "sdar_moe":
+        raise NotImplementedError(
+            "reading an SDAR-MoE checkpoint into this engine's stacks "
+            "(gate | up fused, the held experts' block one array a "
+            "layer) is not written yet: serve the architecture with "
+            "--random-weights")
     if config.architecture not in ("llama", "mistral", "qwen2"):
         raise NotImplementedError(
             f"no reader for a {config.architecture!r} checkpoint, and "

@@ -20,7 +20,10 @@ import jax.numpy as jnp
 
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.ops.attention import (
+    fold_block_queries,
     paged_attention,
+    unfold_block_queries,
+    write_block_to_tail,
     write_run_to_pages,
     write_to_pages,
     write_to_tail,
@@ -32,7 +35,8 @@ Params = Dict[str, jnp.ndarray]
 
 
 def dispatch_attention(config: ModelConfig, q, k_cache, v_cache,
-                       page_table, positions, kv_lens, layer=None):
+                       page_table, positions, kv_lens, layer=None,
+                       block: int = 0):
     """Pick the attention implementation for this step shape.
 
     Under the pallas impl both shapes use page-walking kernels: decode
@@ -52,8 +56,13 @@ def dispatch_attention(config: ModelConfig, q, k_cache, v_cache,
     aliased, layer form only) — callers must use the returned caches
     for subsequent layers so the buffer chain stays linear and XLA's
     copy-insertion never duplicates the cache around the custom call.
+
+    ``block`` (a power of two; a block-diffusion family's chunk): sight
+    is by block and not causal, a query at ``t`` sees the keys up to
+    ``t | (block - 1)``, all of them in the pages; the chunk forms
+    serve it whatever the step's length.
     """
-    if q.shape[1] == 1:
+    if q.shape[1] == 1 and not block:
         impl = config.attention_impl_decode or config.attention_impl
         if impl.startswith("pallas"):
             from production_stack_tpu.ops.paged_attention_pallas import (
@@ -71,7 +80,7 @@ def dispatch_attention(config: ModelConfig, q, k_cache, v_cache,
             return out[:, None], k_cache, v_cache
     else:
         impl = config.attention_impl_prefill or config.attention_impl
-        if impl.startswith("pallas_ragged"):
+        if impl.startswith("pallas_ragged") and not block:
             # Fused unified-step kernel: rebuild the row descriptors
             # from the planner's layout invariant (docs/unified_step.md
             # — every row kind satisfies positions[:, 0] == kv_lens - 1
@@ -97,7 +106,7 @@ def dispatch_attention(config: ModelConfig, q, k_cache, v_cache,
             )
             res = paged_prefill_attention(
                 q, k_cache, v_cache, page_table, positions, kv_lens,
-                layer=layer,
+                layer=layer, block=block,
                 interpret=impl == "pallas-interpret",
             )
             if layer is not None:
@@ -106,13 +115,15 @@ def dispatch_attention(config: ModelConfig, q, k_cache, v_cache,
                 out = res
             return out, k_cache, v_cache
     return paged_attention(
-        q, k_cache, v_cache, page_table, positions, kv_lens,
+        q, k_cache, v_cache, page_table,
+        positions | (block - 1) if block else positions, kv_lens,
         layer=layer,
     ), k_cache, v_cache
 
 
 def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
-                     page_table, positions, kv_lens, valid, layer: int):
+                     page_table, positions, kv_lens, valid, layer: int,
+                     block: int = 0):
     """Write one layer's K/V into the paged cache and attend.
 
     The single place both cache layouts are handled
@@ -133,7 +144,8 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
 
     Returns ``(attn, k_cache, v_cache)``; callers must thread the
     returned caches so the buffer chain stays linear (see
-    dispatch_attention).
+    dispatch_attention). ``block``: sight by block
+    (dispatch_attention).
     """
     if isinstance(k_cache, (list, tuple)):
         kc, vc = k_cache[layer], v_cache[layer]
@@ -151,7 +163,7 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
                 vc = write_to_pages(vc, v, page_table, positions, valid)
         attn, kc, vc = dispatch_attention(
             config, q, kc, vc, page_table, positions, kv_lens,
-            layer=None)
+            layer=None, block=block)
         k_cache = (tuple(k_cache[:layer]) + (kc,)
                    + tuple(k_cache[layer + 1:]))
         v_cache = (tuple(v_cache[:layer]) + (vc,)
@@ -162,7 +174,52 @@ def cached_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
     v_cache = write_to_pages(v_cache, v, page_table, positions, valid,
                              layer=layer)
     return dispatch_attention(config, q, k_cache, v_cache, page_table,
-                              positions, kv_lens, layer=layer)
+                              positions, kv_lens, layer=layer,
+                              block=block)
+
+
+def block_attention(config: ModelConfig, q, k, v, k_cache, v_cache,
+                    page_table, positions, kv_lens, valid, layer: int,
+                    kv_tail):
+    """One attention layer of one pass of a block-diffusion burst: the
+    T positions of each row's block (``positions [B, T]``, a whole
+    block; the rows go block by block in lockstep) write their K/V to
+    the tail slots the block owns, over whatever an earlier pass left
+    there, and attend the row's pages, the tail's finished blocks and
+    the block itself, in both directions.
+
+    All T queries of a block see exactly the same keys, so they are
+    ``T x group`` query rows of each KV head: folded into the group
+    axis, the decode forms serve them as one token a row (the Pallas
+    paged decode kernel under its impl, else the XLA form), the tail's
+    positional mask fed the block's LAST position. The planes are read
+    and never written; ``kv_lens`` is the frozen pre-burst count.
+
+    Returns ``(attn [B, T, q_heads, d], k_tail, v_tail)``."""
+    t = q.shape[1]
+    nkv = k.shape[2]
+    slot = (positions[0, 0] - kv_lens[0]).astype(jnp.int32)
+    act = valid[:, 0]
+    kt = write_block_to_tail(kv_tail[0][layer], k, slot, act)
+    vt = write_block_to_tail(kv_tail[1][layer], v, slot, act)
+    kc, vc = k_cache[layer], v_cache[layer]
+    last = positions[:, -1]
+    folded = fold_block_queries(q, nkv)
+    impl = config.attention_impl_decode or config.attention_impl
+    with jax.named_scope("block_attention"):
+        if impl.startswith("pallas"):
+            from production_stack_tpu.ops.paged_attention_pallas import (
+                paged_decode_attention,
+            )
+            out = paged_decode_attention(
+                folded, kc, vc, page_table, kv_lens, k_tail=kt,
+                v_tail=vt, q_positions=last,
+                interpret=impl == "pallas-interpret")
+        else:
+            out = paged_attention(
+                folded[:, None], kc, vc, page_table, last[:, None],
+                kv_lens, k_tail=kt, v_tail=vt)[:, 0]
+    return unfold_block_queries(out, t, nkv), kt, vt
 
 
 def deferred_attention(config: ModelConfig, q, k, v, k_cache, v_cache,

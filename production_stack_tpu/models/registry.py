@@ -61,6 +61,20 @@ so:
   deferred burst drafts with it (``engine/model_runner.py``); the
   counters then end in ``drafts`` and ``accepted``.
 
+A family that generates by diffusion over blocks says so:
+
+- ``block(config)``: the block's length ``B`` in positions (a power of
+  two). With it the engine knows the rest: a query sees its own block
+  in both directions, so the forward takes whole blocks (``masked``
+  beside the ids for the places not known yet, ``head=False`` for a
+  pass that samples nothing); a prefill covers the prompt's whole
+  blocks and yields NO token; a burst works a block a row at a time,
+  denoising passes that commit some of its places and then one pass
+  of its own that writes the block's final K/V (the store pass); the
+  counters end in ``denoise_passes``, ``store_passes``, ``blocks`` and
+  ``committed`` (engine/model_runner.py ``_decode_burst_block_impl``,
+  docs/block_diffusion.md).
+
 This module imports no model and nothing of the engine at load, so
 ``engine/config.py`` can ask it.
 """
@@ -91,6 +105,7 @@ class Family:
     refusals: Dict[str, str] = dataclasses.field(default_factory=dict)
     page_cache: Optional[Callable] = None
     draft_module: bool = False
+    block: Optional[Callable] = None
 
 
 def _qwen3_next_layers(c) -> tuple:
@@ -190,6 +205,15 @@ def _glm4_moe_lite_pages(c) -> PageCache:
         heads=1, width=c.kv_lora_rank + c.qk_rope_head_dim, planes=1)
 
 
+def _sdar_moe_pages(c) -> PageCache:
+    """K and V of every layer, as a family that declares nothing has
+    them; declared so that the cache is built here, with the family's
+    counters after the layers."""
+    return PageCache(entries=c.num_hidden_layers,
+                     heads=c.num_key_value_heads, width=c.head_dim,
+                     planes=2)
+
+
 _EXPERT_COUNTERS = ("layer_steps", "choices", "held_choices", "max_load",
                     "experts_hit", "room_overflows")
 
@@ -280,6 +304,18 @@ FAMILIES: Dict[str, Family] = {
                                   "layer has no sharding rules",
             "weight quantization": "the low-rank projections and the "
                                    "experts have no quantized form",
+        }),
+    "sdar_moe": Family(
+        "sdar_moe", deferred_kv=True,
+        page_cache=_sdar_moe_pages,
+        counters=_EXPERT_COUNTERS + ("denoise_passes", "store_passes",
+                                     "blocks", "committed"),
+        block=lambda c: c.diffusion_block_length,
+        refusals={
+            "tensor parallelism": "the expert layer has no sharding "
+                                  "rules",
+            "weight quantization": "the experts have no quantized "
+                                   "form",
         }),
 }
 

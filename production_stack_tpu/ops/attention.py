@@ -291,6 +291,52 @@ def write_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
     return jnp.where(hit[..., None, None], new_kv, tail)
 
 
+def write_block_to_tail(tail: jnp.ndarray, new_kv: jnp.ndarray,
+                        slot: jnp.ndarray, active: jnp.ndarray
+                        ) -> jnp.ndarray:
+    """A block's K or V into its tail slots (block-diffusion burst).
+
+    The rows of such a burst go block by block in lockstep, so every
+    row's block lies at the same slots ``slot .. slot + T - 1``
+    (``slot`` a scalar): one dynamic slice of the tail is replaced,
+    for the active rows. A denoising pass writes a block provisionally
+    and a later pass, at last the store pass, overwrites it in place.
+
+    Args:
+      tail:   [B, S, kv_heads, head_dim]
+      new_kv: [B, T, kv_heads, head_dim] this pass's K or V
+      slot:   scalar int32, the block's first tail slot
+      active: [B] bool
+    """
+    t = new_kv.shape[1]
+    old = jax.lax.dynamic_slice_in_dim(tail, slot, t, axis=1)
+    new = jnp.where(active[:, None, None, None], new_kv, old)
+    return jax.lax.dynamic_update_slice_in_dim(tail, new, slot, axis=1)
+
+
+def fold_block_queries(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
+    """``[B, T, q_heads, d]`` to ``[B, kv * T * group, d]``: the T
+    queries of a block that all see the same keys, as ``T * group``
+    query heads of each KV head (kv-major, so that either decode form's
+    ``reshape(kv, group', d)`` finds them)."""
+    b, t, num_q_heads, d = q.shape
+    group = num_q_heads // num_kv_heads
+    return (q.reshape(b, t, num_kv_heads, group, d)
+            .transpose(0, 2, 1, 3, 4)
+            .reshape(b, num_kv_heads * t * group, d))
+
+
+def unfold_block_queries(out: jnp.ndarray, t: int,
+                         num_kv_heads: int) -> jnp.ndarray:
+    """``fold_block_queries``' inverse on the attention's output:
+    ``[B, kv * T * group, d]`` to ``[B, T, q_heads, d]``."""
+    b, folded, d = out.shape
+    group = folded // (num_kv_heads * t)
+    return (out.reshape(b, num_kv_heads, t, group, d)
+            .transpose(0, 2, 1, 3, 4)
+            .reshape(b, t, num_kv_heads * group, d))
+
+
 def tail_softmax_state(qg: jnp.ndarray, k_tail: jnp.ndarray,
                        v_tail: jnp.ndarray, q_positions: jnp.ndarray,
                        kv_lens: jnp.ndarray):

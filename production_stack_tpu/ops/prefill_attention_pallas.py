@@ -83,9 +83,10 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
                     chunk: int, head_dim: int, head_dim_pad: int,
                     rows_pad: int, max_pages: int,
                     has_layer: bool, quantized: bool,
-                    window: "int | None"):
+                    window: "int | None", block: int = 0):
     # first_key_ref is None but under ``window``: the first position
-    # of the row's table that holds a key at all.
+    # of the row's table that holds a key at all. ``block``: a query
+    # sees the keys up to the end of its block of that many positions.
     # ks_hbm/vs_hbm carry the per-slot f32 dequant scales of an int8
     # cache (ops/quant_kv.py), pre-reshaped by the wrapper to
     # [.., pages, 1, page_size]; None for a full-precision cache.
@@ -133,7 +134,9 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
         # Causal over the chunk's own tokens plus everything cached
         # before it — exactly the ragged mixed-length contract: each
         # row masks independently off its scalar-prefetched start.
-        in_sight = (token_pos <= q_pos) & (token_pos < kv_len)
+        # A block (a power of two) ends at ``q_pos | (block - 1)``.
+        sight = q_pos | (block - 1) if block else q_pos
+        in_sight = (token_pos <= sight) & (token_pos < kv_len)
         if window is not None:
             in_sight = (in_sight & (token_pos > q_pos - window)
                         & (token_pos >= first_key_ref[b]))
@@ -154,7 +157,8 @@ def _prefill_kernel(page_table_ref, kv_lens_ref, q_start_ref,
     o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("window", "block", "interpret"))
 def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                             v_cache_layer: jnp.ndarray,
                             page_table: jnp.ndarray,
@@ -163,6 +167,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                             layer: "jnp.ndarray | int | None" = None,
                             window: "int | None" = None,
                             first_key: "jnp.ndarray | None" = None,
+                            block: int = 0,
                             interpret: bool = False) -> jnp.ndarray:
     """Chunked-prefill attention against a sequence's cached pages.
 
@@ -184,6 +189,12 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
                    first positions hold no key: a step's own plane
                    over a ring, ops/window_attention.py). Without it
                    nothing of the kernel or its operands changes
+      block:       static, a power of two; with it sight is by block
+                   and not causal: a query at ``t`` sees every key up
+                   to the end of its block, ``key <= t | (block - 1)``
+                   (block-diffusion prefill, models/sdar_moe.py; the
+                   block's keys are in the pages, ``kv_lens`` covers
+                   them). 0: nothing of the kernel changes
       interpret:   run in interpreter mode (CPU testing)
 
     Returns [B, T, num_q_heads, head_dim] for the 4D per-layer cache
@@ -227,7 +238,10 @@ def paged_prefill_attention(q: jnp.ndarray, k_cache_layer: jnp.ndarray,
         chunk=t, head_dim=head_dim, head_dim_pad=d_pad,
         rows_pad=rows_pad, max_pages=max_pages,
         has_layer=has_layer, quantized=quantized, window=window,
+        block=block,
     )
+    if block & (block - 1):
+        raise ValueError(f"block {block} is not a power of two")
     if (window is None) != (first_key is None):
         raise ValueError(
             "a window and the first key that exists go together "

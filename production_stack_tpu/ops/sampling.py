@@ -523,3 +523,97 @@ def spec_verify(logits: jnp.ndarray, drafts: jnp.ndarray,
     accept, final = jax.lax.cond(
         jnp.any(stochastic), with_stochastic, greedy_only)
     return _emitted(drafts, _accepted_length(accept), final)
+
+
+# Which masked places of a block a denoising pass commits (the
+# published ``remasking_strategy`` names of the block-diffusion
+# families' ``generate.py``), as the integer a row carries.
+REMASKING_STRATEGIES = ("sequential", "low_confidence_static",
+                        "low_confidence_dynamic")
+
+
+def unmask_block(logits, masked: jnp.ndarray, quota: jnp.ndarray,
+                 strategy: jnp.ndarray, threshold: jnp.ndarray,
+                 temperature: jnp.ndarray, top_p: jnp.ndarray,
+                 top_k: jnp.ndarray, key: jax.Array):
+    """One denoising pass's draws and choice for a block of ``T``
+    places a row (block-diffusion decoding, docs/block_diffusion.md).
+
+    At every place a token ``x0`` is drawn from the place's own
+    distribution ``softmax(mask(logits / T))`` (``sample_tokens``'
+    temperature, top-k and top-p; Gumbel-max in one pass over the
+    plane; the argmax for a greedy row) with its confidence ``p(x0)``
+    under that same distribution (a greedy row's under the raw
+    softmax). Of the places still masked, ``quota`` are then committed
+    by the row's rule:
+
+    - 0 ``sequential``: the first masked place and the places after it,
+      ``quota`` of them;
+    - 1 ``low_confidence_static``: the ``quota`` masked places of
+      highest confidence (ties: the leftmost first);
+    - 2 ``low_confidence_dynamic``: every masked place whose confidence
+      is over ``threshold`` if they are at least ``quota``, else as
+      static.
+
+    Only masked places are ever committed (a quota larger than what is
+    left commits what is left).
+
+    Args:
+      logits:    [T, B, vocab] float32, position-major: each place a
+                 dense plane (the draft burst's layout, PERF.md PR 44)
+      masked:    [B, T] bool, places not yet committed
+      quota:     [B] int32, places to commit this pass
+      strategy:  [B] int32, index into ``REMASKING_STRATEGIES``
+      threshold: [B] float32 (the dynamic rule's)
+      temperature, top_p, top_k: [B], as ``sample_tokens``
+      key:       PRNG key of the pass
+
+    Returns ``(x0 [B, T] int32, commit [B, T] bool, confidence [B, T]
+    float32)``.
+    """
+    t = len(logits) if isinstance(logits, (tuple, list)) else logits.shape[0]
+    b = masked.shape[0]
+    stochastic = temperature > 0
+    scale = _inverse_temperature(temperature)
+    keys = jax.random.split(key, t)
+    rows = jnp.arange(b)
+
+    def draw(j, mask):
+        scaled = mask(logits[j] * scale[:, None])
+        noise = jax.random.gumbel(keys[j], scaled.shape, scaled.dtype)
+        x = jnp.argmax(
+            scaled + noise * stochastic.astype(scaled.dtype)[:, None],
+            axis=-1)
+        m = jnp.max(scaled, axis=-1)
+        lse = jnp.log(jnp.sum(jnp.exp(scaled - m[:, None]), axis=-1))
+        return x.astype(jnp.int32), jnp.exp(scaled[rows, x] - m - lse)
+
+    with jax.named_scope("unmask_block"):
+        # A place at a time, each under its own choice of form: the
+        # vocabulary is sorted only where some row has a top-k or a
+        # top-p, and the sort's buffers are one plane's, not T planes'.
+        needs_mask = _needs_mask(top_p, top_k)
+        drawn = [jax.lax.cond(
+            needs_mask,
+            lambda j=j: draw(j, lambda x: _mask_top_k_top_p(x, top_p,
+                                                             top_k)),
+            lambda j=j: draw(j, lambda x: x)) for j in range(t)]
+        x0 = jnp.stack([x for x, _ in drawn], axis=1)
+        conf = jnp.stack([c for _, c in drawn], axis=1)
+        place = jnp.arange(t)[None, :]
+        n = quota[:, None]
+        first = jnp.argmax(masked, axis=1)[:, None]
+        sequential = (place >= first) & (place < first + n)
+        # A place's rank by confidence among the masked ones.
+        c = jnp.where(masked, conf, -jnp.inf)
+        ahead = ((c[:, None, :] > c[:, :, None])
+                 | ((c[:, None, :] == c[:, :, None])
+                    & (place[:, None, :] < place[:, :, None])))
+        static = jnp.sum(ahead, axis=2) < n
+        high = c > threshold[:, None]
+        dynamic = jnp.where(
+            jnp.sum(high, axis=1, keepdims=True) >= n, high, static)
+        kind = strategy[:, None]
+        commit = jnp.where(kind == 0, sequential,
+                           jnp.where(kind == 1, static, dynamic))
+        return x0, commit & masked, conf

@@ -97,6 +97,7 @@ _LONG_MODULES = {
     "test_qwen3_next_deferred": 257,
     "test_conv_tails_burst": 245,
     "test_pallas_lowering": 205,
+    "test_sdar_moe_engine": 196,
     "test_granitemoehybrid": 150,
     "test_exaone_moe": 140,
     "test_exaone_moe_engine": 132,
@@ -111,6 +112,7 @@ _LONG_MODULES = {
     "test_longcat_flash_engine": 130,
     "test_prefill_width": 113,
     "test_chipbench_longcat_family": 106,
+    "test_chipbench_sdar_family": 102,
     "test_chipbench_granitemoehybrid_family": 105,
     "test_granitemoehybrid_engine": 100,
     "test_qwen3_next_engine": 105,
