@@ -5,8 +5,10 @@ to touch?
 
 Lowers, for the TPU and from the checkout given, the deferred decode
 burst and the prefill step of a tiny bfloat16 model of each family the
-chip benchmark has an older cell for (Pallas attention, 4 rows, pages
-of 128), and prints one line a program: its name, the length of its
+chip benchmark has a cell for that generates left to right (Pallas
+attention, 4 rows, pages of 128; ``glm4_moe_lite``'s burst is the
+drafting one, since PR 57, when ``exaone_moe`` came too), and prints
+one line a program: its name, the length of its
 StableHLO text and a hash of that text with the Mosaic kernels'
 serialized bodies left out (they embed the source files' paths, so two
 checkouts never agree on them; compare the kernel files themselves).
@@ -42,8 +44,11 @@ def main() -> None:
               "jamba": cfg.tiny_jamba_config(),
               "lfm2_moe": cfg.tiny_lfm2_moe_config(),
               "longcat_flash": cfg.tiny_longcat_flash_config(),
-              "granitemoehybrid": cfg.tiny_granitemoehybrid_config()}
+              "granitemoehybrid": cfg.tiny_granitemoehybrid_config(),
+              "glm4_moe_lite": cfg.tiny_glm4_moe_lite_config(),
+              "exaone_moe": cfg.tiny_exaone_moe_config()}
     models["qwen2"].attention_bias = True
+    models["exaone_moe"].sliding_window = 128     # whole pages
     for name, model in models.items():
         model.attention_impl, model.dtype = "pallas", "bfloat16"
         runner = ModelRunner(cfg.EngineConfig(
@@ -52,27 +57,32 @@ def main() -> None:
             scheduler=cfg.SchedulerConfig(
                 max_num_seqs=ROWS, max_model_len=256,
                 prefill_chunk_size=CHUNK, decode_steps=STEPS,
-                deferred_kv_writes=True)))
+                deferred_kv_writes=True,
+                draft_module=name == "glm4_moe_lite")))
         i32 = lambda *dims: jnp.zeros(dims, jnp.int32)  # noqa: E731
         sampling = (jnp.zeros((ROWS,), jnp.float32),
                     jnp.ones((ROWS,), jnp.float32), i32(ROWS),
                     jax.random.PRNGKey(0)) + (None,) * 7
         state = ({"state_slots": i32(ROWS)} if model.has_recurrent_state
                  else {})
-        burst = jax.jit(runner._decode_burst_deferred_impl,
+        drafts = name == "glm4_moe_lite"
+        burst = jax.jit(runner._decode_burst_draft_impl if drafts
+                        else runner._decode_burst_deferred_impl,
                         static_argnames=("num_steps",)).trace(
             runner.params, runner.k_cache, runner.v_cache, i32(ROWS, 1),
             i32(ROWS, 1), i32(ROWS, runner.max_pages_per_seq), i32(ROWS),
             jnp.zeros((ROWS,), bool), i32(ROWS),
             jnp.full((ROWS, 16), -1, jnp.int32), *sampling,
-            num_steps=STEPS, **state)
+            num_steps=STEPS, **state,
+            **({"draft_rows": jnp.ones((ROWS,), bool)} if drafts else {}))
         step = jax.jit(runner._step_impl, static_argnames=(
             "sample_index_mode", "want_logprobs")).trace(
             runner.params, runner.k_cache, runner.v_cache,
             i32(ROWS, CHUNK), i32(ROWS, CHUNK),
             i32(ROWS, runner.max_pages_per_seq), i32(ROWS),
             jnp.zeros((ROWS, CHUNK), bool), i32(ROWS), *sampling,
-            sample_index_mode="last", **state)
+            sample_index_mode="last", **state,
+            **({"next_tokens": i32(ROWS)} if drafts else {}))
         for program, traced in (("burst", burst), ("step", step)):
             text = traced.lower(lowering_platforms=("tpu",)).as_text()
             print(name, program, len(text), text_hash(text), flush=True)

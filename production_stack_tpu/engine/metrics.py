@@ -102,13 +102,15 @@ class EngineMetrics:
         # Block-diffusion decoding (docs/block_diffusion.md): forward
         # passes that denoised a block, passes that stored one, blocks
         # worked (rows x blocks) and tokens the passes committed, over
-        # all bursts. Tokens are not forward passes here: both are
-        # counted. Always rendered (0 for a family that generates left
-        # to right).
+        # all bursts, and the denoising passes that sorted the
+        # vocabulary (some row had a top-k or a top-p). Tokens are not
+        # forward passes here: both are counted. Always rendered (0 for
+        # a family that generates left to right).
         self.diffusion_denoise_passes_total = 0
         self.diffusion_store_passes_total = 0
         self.diffusion_blocks_total = 0
         self.diffusion_committed_tokens_total = 0
+        self.diffusion_sorted_passes_total = 0
         # Overlapped async pipeline (docs/async_pipeline.md): per-step
         # host vs device-wait seconds, the device-idle gap the
         # pipeline hides, and how many steps were dispatched ahead of
@@ -197,16 +199,18 @@ class EngineMetrics:
             self.spec_accepted_tokens_total += accepted
 
     def on_block_burst(self, stats: dict) -> dict:
-        """One block-diffusion burst's own counts (the last four of
+        """One block-diffusion burst's own counts (the last five of
         its family's counters), added to the totals and returned for
         the step record: ``window`` is the forward passes that ran."""
         note = {name: int(stats[name]) for name in (
-            "denoise_passes", "store_passes", "blocks", "committed")}
+            "denoise_passes", "store_passes", "blocks", "committed",
+            "sorted_passes")}
         with self._lock:
             self.diffusion_denoise_passes_total += note["denoise_passes"]
             self.diffusion_store_passes_total += note["store_passes"]
             self.diffusion_blocks_total += note["blocks"]
             self.diffusion_committed_tokens_total += note["committed"]
+            self.diffusion_sorted_passes_total += note["sorted_passes"]
         return {**note,
                 "window": note["denoise_passes"] + note["store_passes"]}
 
@@ -363,6 +367,9 @@ class EngineMetrics:
                  "counter"),
                 ("vllm:diffusion_committed_tokens_total "
                  f"{self.diffusion_committed_tokens_total}"),
+                "# TYPE vllm:diffusion_sorted_passes_total counter",
+                ("vllm:diffusion_sorted_passes_total "
+                 f"{self.diffusion_sorted_passes_total}"),
                 "# TYPE vllm:moe_room_overflow_steps_total counter",
                 ("vllm:moe_room_overflow_steps_total "
                  f"{self.moe_room_overflow_steps_total}"),

@@ -60,6 +60,7 @@ from production_stack_tpu.ops.quant_kv import (
 from production_stack_tpu.ops.window_attention import write_to_ring
 from production_stack_tpu.ops.sampling import (
     REMASKING_STRATEGIES,
+    _needs_mask,
     apply_penalties,
     draw_proposal,
     sample_tokens,
@@ -2018,7 +2019,9 @@ class ModelRunner:
         passes; the passes that ran are counted on the device with the
         blocks worked and the places committed, in the family's
         counters (``denoise_passes``, ``store_passes``, ``blocks``,
-        ``committed``). Returns tokens ``[blocks * B, rows]`` (-1
+        ``committed``; ``sorted_passes``: the denoising passes that
+        sorted the vocabulary, a row of the batch carrying a top-k or
+        a top-p). Returns tokens ``[blocks * B, rows]`` (-1
         where nothing went out), with ``want_logprobs`` the raw
         log-probabilities at each token's committing pass beside
         them."""
@@ -2035,12 +2038,13 @@ class ModelRunner:
                                     kv_lens0, blocks * bl, state_slots)
         place = jnp.arange(bl)
         width = TOP_LOGPROBS_WIDTH
+        sorts = _needs_mask(top_p, top_k)  # unmask_block's own choice
 
         def counted(kt, denoise=0, store=0, worked=0, committed=0):
             add = jnp.stack([jnp.asarray(x, jnp.float32) for x in (
-                denoise, store, worked, committed)])
+                denoise, store, worked, committed, sorts * denoise)])
             return kt[:-1] + (
-                kt[-1].at[i_denoise:i_denoise + 4].add(add),)
+                kt[-1].at[i_denoise:i_denoise + len(add)].add(add),)
 
         def run(ids, masked, pos, live, kt, vt, **how):
             return self._forward(

@@ -71,9 +71,9 @@ A family that generates by diffusion over blocks says so:
   blocks and yields NO token; a burst works a block a row at a time,
   denoising passes that commit some of its places and then one pass
   of its own that writes the block's final K/V (the store pass); the
-  counters end in ``denoise_passes``, ``store_passes``, ``blocks`` and
-  ``committed`` (engine/model_runner.py ``_decode_burst_block_impl``,
-  docs/block_diffusion.md).
+  counters end in ``denoise_passes``, ``store_passes``, ``blocks``,
+  ``committed`` and ``sorted_passes`` (engine/model_runner.py
+  ``_decode_burst_block_impl``, docs/block_diffusion.md).
 
 This module imports no model and nothing of the engine at load, so
 ``engine/config.py`` can ask it.
@@ -309,7 +309,8 @@ FAMILIES: Dict[str, Family] = {
         "sdar_moe", deferred_kv=True,
         page_cache=_sdar_moe_pages,
         counters=_EXPERT_COUNTERS + ("denoise_passes", "store_passes",
-                                     "blocks", "committed"),
+                                     "blocks", "committed",
+                                     "sorted_passes"),
         block=lambda c: c.diffusion_block_length,
         refusals={
             "tensor parallelism": "the expert layer has no sharding "

@@ -5,6 +5,8 @@ decode batch mixes sampling configs.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -532,6 +534,90 @@ REMASKING_STRATEGIES = ("sequential", "low_confidence_static",
                         "low_confidence_dynamic")
 
 
+# Ids a block of ``draw_by_blocks``: one tile of the minor axis, so a
+# block's sum is a reduction inside a tile and the chosen block of a
+# row is one aligned slice of the plane.
+DRAW_BLOCK = 128
+
+
+def draw_by_blocks(planes, j: int, scale: jnp.ndarray, u: jnp.ndarray,
+                   stochastic: jnp.ndarray):
+    """A token a row from ``softmax(planes[j] * scale)`` by inversion:
+    the first id whose running sum of ``exp(planes[j] * scale - m)``
+    passes ``u * Z`` (``m`` the row's maximum, ``Z`` the whole sum), so
+    one uniform variate a row where Gumbel-max wants one a logit. A row
+    that is not ``stochastic`` gets its first maximum. Float32
+    throughout, every id weighed.
+
+    Two passes over the plane: its argmax (whose value is ``m``, and
+    a greedy row's token), then the sums of ``exp(. - m)`` over blocks
+    of ``DRAW_BLOCK`` ids (the vocabulary padded with weight 0 to whole
+    blocks), so that no pass scans the vocabulary serially and no
+    float32 running sum is long. The rest is small: the running sum
+    over a row's block sums picks its block, that block alone is
+    gathered from the plane (from ``[S, B, vocab]`` as it stands, as
+    ``_element`` does), and the running sum inside it picks the id. An
+    id of weight 0 (``NEG_INF`` under ``_mask_top_k_top_p``, the
+    padding) is never drawn whatever ``u``: the comparisons are strict
+    and block and id are held to the last of positive weight, so
+    ``u * Z`` rounding to or past a last partial sum picks no masked id.
+
+    Args:
+      planes:     ``[S, B, vocab]`` float32 logits or a sequence of
+                  ``[B, vocab]`` planes (masked entries ``NEG_INF``)
+      j:          the plane drawn from
+      scale:      [B] float32 > 0, the row's inverse temperature
+      u:          [B] float32 in [0, 1)
+      stochastic: [B] bool
+
+    Returns ``(x [B] int32, p [B] float32)``: the ids and their
+    probabilities ``exp(planes[j][x] * scale - m) / Z``.
+    """
+    b, vocab = planes[j].shape
+    w = DRAW_BLOCK
+    nb = -(-vocab // w)
+    # Pass 1. The scale is positive, so the scaled plane's argmax is the
+    # raw one's and the pass reads the plane as it stands.
+    top = jnp.argmax(planes[j], axis=-1).astype(jnp.int32)
+    m = _element(planes, j, top) * scale
+    if isinstance(planes, (tuple, list)) or nb * w != vocab:
+        planes, j = jnp.pad(planes[j], ((0, 0), (0, nb * w - vocab)),
+                            constant_values=NEG_INF)[None], 0
+    # Pass 2. Rows by eights beside the blocks: on a tiled device this
+    # view is the planes' own memory (tiles of 8 rows x 128 ids), where
+    # [B, blocks, 128] would be a copy of the plane in another layout.
+    # The barrier keeps it ONE pass: without it ``Z`` is fused into a
+    # reduction over the plane of its own.
+    sub = math.gcd(b, 8)
+    tiles = planes.reshape(planes.shape[0], b // sub, sub, nb, w)
+    by_tile = (b // sub, sub, 1, 1)
+    block_sum = jax.lax.optimization_barrier(jnp.sum(jnp.exp(
+        tiles[j] * scale.reshape(by_tile) - m.reshape(by_tile)),
+        axis=3)).reshape(b, nb)
+
+    def first_past(weights, target):
+        """Per row the first index whose running sum of ``weights``
+        passes ``target`` (>= 0), held to the last positive weight, and
+        the running sum before it."""
+        run = jnp.cumsum(weights, axis=-1)
+        index = jnp.arange(weights.shape[-1])[None, :]
+        last = jnp.max(jnp.where(weights > 0, index, 0), axis=-1)
+        at = jnp.minimum(jnp.sum(run <= target[:, None], axis=-1), last)
+        before = jnp.take_along_axis(run - weights, at[:, None], axis=1)
+        return at.astype(jnp.int32), before[:, 0]
+
+    z = jnp.sum(block_sum, axis=-1)
+    target = u * z
+    k, before = first_past(block_sum, target)
+    rows = jnp.arange(b)
+    weights = jnp.exp(tiles[j, rows // sub, rows % sub, k] * scale[:, None]
+                      - m[:, None])                             # [B, w]
+    i, _ = first_past(weights, jnp.maximum(target - before, 0.0))
+    weight = jnp.take_along_axis(weights, i[:, None], axis=1)[:, 0]
+    return (jnp.where(stochastic, k * w + i, top),
+            jnp.where(stochastic, weight, 1.0) / z)
+
+
 def unmask_block(logits, masked: jnp.ndarray, quota: jnp.ndarray,
                  strategy: jnp.ndarray, threshold: jnp.ndarray,
                  temperature: jnp.ndarray, top_p: jnp.ndarray,
@@ -541,11 +627,11 @@ def unmask_block(logits, masked: jnp.ndarray, quota: jnp.ndarray,
 
     At every place a token ``x0`` is drawn from the place's own
     distribution ``softmax(mask(logits / T))`` (``sample_tokens``'
-    temperature, top-k and top-p; Gumbel-max in one pass over the
-    plane; the argmax for a greedy row) with its confidence ``p(x0)``
-    under that same distribution (a greedy row's under the raw
-    softmax). Of the places still masked, ``quota`` are then committed
-    by the row's rule:
+    temperature, top-k and top-p; ``draw_by_blocks``: one uniform
+    variate a row and two passes over the plane; the argmax for a
+    greedy row) with its confidence ``p(x0)`` under that same
+    distribution (a greedy row's under the raw softmax). Of the places
+    still masked, ``quota`` are then committed by the row's rule:
 
     - 0 ``sequential``: the first masked place and the places after it,
       ``quota`` of them;
@@ -576,17 +662,16 @@ def unmask_block(logits, masked: jnp.ndarray, quota: jnp.ndarray,
     stochastic = temperature > 0
     scale = _inverse_temperature(temperature)
     keys = jax.random.split(key, t)
-    rows = jnp.arange(b)
 
-    def draw(j, mask):
-        scaled = mask(logits[j] * scale[:, None])
-        noise = jax.random.gumbel(keys[j], scaled.shape, scaled.dtype)
-        x = jnp.argmax(
-            scaled + noise * stochastic.astype(scaled.dtype)[:, None],
-            axis=-1)
-        m = jnp.max(scaled, axis=-1)
-        lse = jnp.log(jnp.sum(jnp.exp(scaled - m[:, None]), axis=-1))
-        return x.astype(jnp.int32), jnp.exp(scaled[rows, x] - m - lse)
+    def draw(j, masked_form: bool):
+        u = jax.random.uniform(keys[j], (b,), jnp.float32)
+        if masked_form:
+            # The mask's NEG_INF entries weigh nothing in the draw.
+            return draw_by_blocks(
+                (_mask_top_k_top_p(logits[j] * scale[:, None], top_p,
+                                   top_k),),
+                0, jnp.ones_like(scale), u, stochastic)
+        return draw_by_blocks(logits, j, scale, u, stochastic)
 
     with jax.named_scope("unmask_block"):
         # A place at a time, each under its own choice of form: the
@@ -594,10 +679,8 @@ def unmask_block(logits, masked: jnp.ndarray, quota: jnp.ndarray,
         # top-p, and the sort's buffers are one plane's, not T planes'.
         needs_mask = _needs_mask(top_p, top_k)
         drawn = [jax.lax.cond(
-            needs_mask,
-            lambda j=j: draw(j, lambda x: _mask_top_k_top_p(x, top_p,
-                                                             top_k)),
-            lambda j=j: draw(j, lambda x: x)) for j in range(t)]
+            needs_mask, lambda j=j: draw(j, True),
+            lambda j=j: draw(j, False)) for j in range(t)]
         x0 = jnp.stack([x for x, _ in drawn], axis=1)
         conf = jnp.stack([c for _, c in drawn], axis=1)
         place = jnp.arange(t)[None, :]

@@ -161,11 +161,13 @@ _ROUTER_UNSCRAPED = frozenset({
     "vllm:moe_room_overflow_steps_total",
     # A block-diffusion engine's forward passes by kind, blocks and
     # committed tokens (docs/block_diffusion.md): an operator's rates
-    # (tokens a pass, the store passes' share); nothing routes on them.
+    # (tokens a pass, the store passes' share, the passes that sorted
+    # the vocabulary); nothing routes on them.
     "vllm:diffusion_denoise_passes_total",
     "vllm:diffusion_store_passes_total",
     "vllm:diffusion_blocks_total",
     "vllm:diffusion_committed_tokens_total",
+    "vllm:diffusion_sorted_passes_total",
     # The interpreter's two threads (docs/observability.md, "Is the
     # front the wall?"): an operator's rate, not a routing signal.
     "vllm:engine_front_cpu_seconds_total",

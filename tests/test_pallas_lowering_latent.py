@@ -354,3 +354,40 @@ def test_that_census_sees_the_position_minor_form(one_chip):
     assert any("T(2,128)" in f for f in minor), census["pair_arrays"]
     assert {f.split(" ")[1] for f in census["pair_arrays"]} & {
         "reshape", "transpose", "copy", "pad", "gather"}
+
+
+def _unmask_census(form, rows, vocab, one_chip):
+    from benchmarks.unmask_iteration import (
+        FORMS,
+        pass_shapes,
+        plane_census,
+    )
+    text = jax.jit(FORMS[form]).lower(
+        *pass_shapes(rows, 4, vocab, one_chip)).compile().as_text()
+    return [found for name, found in plane_census(text, rows,
+                                                  vocab).items()
+            if "sorts" not in name]
+
+
+def test_the_block_samplers_plain_draw_reads_a_plane_twice(one_chip):
+    """``unmask_block`` at the SDAR cell's 256 rows, four places and
+    vocabulary of 151936, compiled for the described chip: outside the
+    branches that sort the vocabulary each place's branch READS its
+    plane in two reductions (the argmax, the blocks' sums), gathers
+    from it twice (the maximum a row, a block a row) and WRITES none:
+    no copy of a plane in another layout for the blocks' sake, no slice
+    of ``[T, B, V]`` written out for the gather's, no reduction over
+    the plane for the whole sum's (PERF.md section 6, PR 57). The
+    control: PR 56's form (kept in ``benchmarks/unmask_iteration.py``)
+    writes a plane (the scaled logits) and reads it three times more,
+    which this census sees."""
+    rows, vocab = 256, 151936
+    branches = _unmask_census("two_pass", rows, vocab, one_chip)
+    assert len(branches) == 4, branches
+    for found in branches:
+        assert not found["writes"], found
+        assert (len(found["reads"]), len(found["gathers"])) == (2, 2), found
+    parent = _unmask_census("parent", rows, 2048, one_chip)
+    assert len(parent) == 4, parent
+    assert all(found["writes"] and len(found["reads"]) >= 3
+               for found in parent), parent

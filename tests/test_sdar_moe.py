@@ -252,8 +252,8 @@ def test_the_published_config_is_read_with_the_scripts_defaults():
         "sdar_moe", 2, 128, 1)
     fam = registry.family("sdar_moe")
     assert fam.deferred_kv and fam.block(config) == 4
-    assert fam.counters[-4:] == ("denoise_passes", "store_passes",
-                                 "blocks", "committed")
+    assert fam.counters[-5:] == ("denoise_passes", "store_passes",
+                                 "blocks", "committed", "sorted_passes")
     assert registry.page_cache(config) == registry.PageCache(
         entries=48, heads=4, width=128, planes=2)
     assert [name for name, f in registry.FAMILIES.items()
@@ -501,3 +501,194 @@ def test_unmask_block_draws_from_the_rows_own_distribution():
     assert counts[:-4].sum() == 0           # top-k 4
     share = counts[-4:] / counts.sum()
     assert np.abs(share - kept).max() < 0.02
+
+
+# ---- the draw by blocks (ops/sampling.py draw_by_blocks) --------------------
+
+
+# Jitted, as the burst runs it (one program, not an executable an op).
+_draw = jax.jit(sampling.draw_by_blocks, static_argnums=1)
+
+
+def _inversion(plane, scale, u):
+    """Float64 ``searchsorted(cumsum(p), u)`` a row, with the running
+    sum itself and ``p``: what ``draw_by_blocks`` is held to."""
+    x = plane.astype(np.float64) * scale.astype(np.float64)[:, None]
+    p = np.exp(x - x.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    run = np.cumsum(p, -1)
+    want = np.array([np.searchsorted(run[r], u[r], side="right")
+                     for r in range(len(u))])
+    return want, run, p
+
+
+def _held_to_inversion(plane, scale, u, slack=5e-6):
+    """Draw with ``u`` given and check every row against the float64
+    inversion: the id drawn has positive weight and its stretch of the
+    running sum holds ``u`` (to ``slack``, float32 sums against float64
+    ones: a ``u`` further than that from every edge must give the very
+    id), and the probability returned is the id's."""
+    rows = len(u)
+    x, conf = _draw(
+        (jnp.asarray(plane),), 0, jnp.asarray(scale), jnp.asarray(u),
+        jnp.ones((rows,), bool))
+    x, conf = np.asarray(x), np.asarray(conf)
+    want, run, p = _inversion(plane, scale, u)
+    at = np.arange(rows)
+    assert ((0 <= x) & (x < plane.shape[1])).all()
+    assert (p[at, x] > 0).all()
+    below = np.where(x > 0, run[at, np.maximum(x - 1, 0)], 0.0)
+    assert (below - slack <= u).all() and (u <= run[at, x] + slack).all()
+    clear = np.abs(run - u[:, None]).min(-1) > slack
+    assert np.array_equal(x[clear], want[clear])
+    assert np.allclose(conf, p[at, x], rtol=1e-4, atol=1e-9)
+    return x
+
+
+def _us(rows, rng, edges=()):
+    """``u`` a row: 0, the largest float32 under 1, the given edges of
+    the running sum, the rest uniform."""
+    u = rng.random(rows).astype(np.float32)
+    fixed = [0.0, np.nextafter(np.float32(1.0), np.float32(0.0))]
+    fixed += list(edges)
+    u[:len(fixed)] = fixed
+    return u
+
+
+DRAW_CASES = ("vocab-16", "vocab-1000", "vocab-151936", "neg-inf-first",
+              "neg-inf-last", "neg-inf-block", "dominant", "greedy-ties",
+              "position-major", "frequency")
+
+
+@pytest.mark.parametrize("case", DRAW_CASES)
+def test_draw_by_blocks(case):
+    rng = np.random.default_rng(DRAW_CASES.index(case))
+    block = sampling.DRAW_BLOCK
+    if case.startswith("vocab-"):
+        # One block, no whole number of blocks, the published width:
+        # against the float64 inversion at 0, just under 1, on the
+        # edges of the first blocks (a uniform plane, where the running
+        # sum at an edge is a float32) and anywhere.
+        vocab = int(case.split("-")[1])
+        rows = 8 if vocab > 10000 else 32
+        scale = rng.uniform(0.5, 2.0, rows).astype(np.float32)
+        plane = (2.0 * rng.standard_normal((rows, vocab))).astype(np.float32)
+        _, run, _ = _inversion(plane, scale, np.zeros(rows))
+        edge = min(block, vocab // 2) - 1
+        _held_to_inversion(plane, scale, _us(rows, rng, [
+            np.float32(run[2, edge]), np.float32(run[3, 2 * edge + 1])]))
+        flat = np.zeros((rows, vocab), np.float32)
+        ones = np.ones((rows,), np.float32)
+        x = _held_to_inversion(flat, ones, _us(rows, rng, [
+            np.float32(min(block, vocab // 2) / vocab)]), slack=1e-9)
+        assert x[0] == 0 and x[1] == vocab - 1
+        assert x[2] == min(block, vocab // 2)
+    elif case.startswith("neg-inf"):
+        # Masked ids (the sorted form's NEG_INF) weigh nothing and are
+        # never drawn, whatever u: the first ids, the last (with the
+        # padding behind them), a whole block in the middle.
+        rows, vocab = 64, 300
+        plane = rng.standard_normal((rows, vocab)).astype(np.float32)
+        gone = {"neg-inf-first": slice(0, 5),
+                "neg-inf-last": slice(vocab - 50, vocab),
+                "neg-inf-block": slice(block, 2 * block)}[case]
+        plane[:, gone] = sampling.NEG_INF
+        ones = np.ones((rows,), np.float32)
+        _, run, _ = _inversion(plane, ones, np.zeros(rows))
+        kept = np.ones(vocab, bool)
+        kept[gone] = False
+        for edges in ([], [np.float32(run[2, gone.start])],
+                      [np.float32(run[2, -1]), np.float32(run[3, 0])]):
+            x = _held_to_inversion(plane, ones, _us(rows, rng, edges))
+            assert kept[x].all()
+        first, last = np.flatnonzero(kept)[[0, -1]]
+        assert x[0] == first and x[1] == last
+    elif case == "dominant":
+        # One id holds all but 1e-15 of the mass, in the last block: u
+        # of 0 alone falls before it (on the first id, whose weight is
+        # small and not 0).
+        rows, vocab = 16, 700
+        plane = rng.standard_normal((rows, vocab)).astype(np.float32)
+        plane[:, 650] = 40.0
+        x = _held_to_inversion(plane, np.ones((rows,), np.float32),
+                               _us(rows, rng))
+        assert x[0] == 0 and (x[1:] == 650).all()
+    elif case == "greedy-ties":
+        # A row that is not stochastic: jnp.argmax, the first of equal
+        # maxima (inside one block and across blocks), whatever u, with
+        # its probability under softmax(plane * scale).
+        rows, vocab = 12, 700
+        plane = rng.standard_normal((rows, vocab)).astype(np.float32)
+        for row, ids in enumerate(((3, 9), (130, 600), (127, 128),
+                                   (0, 699), (699,))):
+            plane[row, list(ids)] = 7.0
+        scale = rng.uniform(0.5, 2.0, rows).astype(np.float32)
+        x, conf = _draw(
+            (jnp.asarray(plane),), 0, jnp.asarray(scale),
+            jnp.asarray(_us(rows, rng)), jnp.zeros((rows,), bool))
+        assert np.array_equal(np.asarray(x),
+                              np.asarray(jnp.argmax(plane, axis=-1)))
+        assert list(np.asarray(x)[:5]) == [3, 130, 127, 0, 699]
+        _, _, p = _inversion(plane, scale, np.zeros(rows))
+        assert np.allclose(np.asarray(conf), p.max(-1), rtol=1e-5)
+    elif case == "position-major":
+        # A plane of [S, B, vocab] drawn from as it stands gives what
+        # the plane alone gives, rows a multiple of eight or not.
+        for rows in (16, 6):
+            planes = rng.standard_normal((3, rows, 256)).astype(np.float32)
+            scale = rng.uniform(0.5, 2.0, rows).astype(np.float32)
+            u = _us(rows, rng)
+            mixed = jnp.asarray(np.arange(rows) % 2 == 0)
+            for j in range(3):
+                whole = _draw(jnp.asarray(planes), j, jnp.asarray(scale),
+                              jnp.asarray(u), mixed)
+                alone = _draw((jnp.asarray(planes[j]),), 0,
+                              jnp.asarray(scale), jnp.asarray(u), mixed)
+                assert np.array_equal(whole[0], alone[0])
+                assert np.array_equal(whole[1], alone[1])
+    else:
+        # The stochastic draw through unmask_block at a vocabulary of
+        # three blocks: 40960 draws follow softmax(logits / T).
+        rows, vocab, temperature = 256, 300, 0.7
+        base = (1.5 * rng.standard_normal(vocab)).astype(np.float32)
+        logits = jnp.asarray(np.tile(base, (BLOCK, rows, 1)))
+        ones = jnp.ones((rows,), jnp.float32)
+        counts = np.zeros(vocab)
+        p = np.exp(base.astype(np.float64) / temperature)
+        p /= p.sum()
+        for seed in range(40):
+            x0, _, conf = jax.jit(sampling.unmask_block)(
+                logits, jnp.ones((rows, BLOCK), bool),
+                jnp.full((rows,), 4, jnp.int32),
+                jnp.zeros((rows,), jnp.int32), ones, temperature * ones,
+                ones, jnp.zeros((rows,), jnp.int32),
+                jax.random.PRNGKey(100 + seed))
+            counts += np.bincount(np.asarray(x0).ravel(), minlength=vocab)
+            assert np.allclose(np.asarray(conf), p[np.asarray(x0)],
+                               rtol=1e-4)
+        share = counts / counts.sum()
+        # Three standard deviations of the likeliest id's share.
+        assert np.abs(share - p).max() < 3 * np.sqrt(
+            p.max() / counts.sum()) + 1e-4
+        assert counts.sum() == 40 * rows * BLOCK
+
+
+def test_the_samplers_micro_benchmark_runs_both_forms(monkeypatch, capsys):
+    """``benchmarks/unmask_iteration.py`` at its rehearsal's size: both
+    forms run their passes in one program each, commit what the rule
+    says, and draw from the softmax (the times are the CPU's and are
+    not looked at)."""
+    import json
+
+    from benchmarks import unmask_iteration
+    monkeypatch.setattr("sys.argv", [
+        "unmask_iteration.py", "--rows", "8", "--vocab", "2048",
+        "--passes", "2", "--repeats", "1"])
+    unmask_iteration.main()
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert (line["rows"], line["vocab"], line["passes"]) == (8, 2048, 2)
+    for form in ("parent", "two_pass"):
+        assert line[form]["committed_share"] == 0.5
+        assert 0 < line[form]["mean_confidence"] < 1
+        assert line[form]["frequency_gap"] < 0.01
+        assert len(line[form]["ms_per_pass"]) == 1

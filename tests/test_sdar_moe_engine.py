@@ -281,6 +281,34 @@ def test_the_step_record_and_the_counters_count_passes_and_tokens():
     assert bursts[0]["moe_layer_steps"] == 5 * 2   # passes x layers
 
 
+@pytest.mark.parametrize("top_k", (0, 3))
+def test_sorted_passes_counts_the_passes_that_sorted_the_vocabulary(top_k):
+    """A burst one of whose rows carries a top-k sorts the vocabulary in
+    every denoising pass and says so; a burst without sorts in none."""
+    from production_stack_tpu.engine.tracing import EngineTracer
+    engine = LLMEngine(engine_config())
+    engine.tracer = EngineTracer(ring_size=256)
+    seqs = []
+    for n, (length, answers, k) in enumerate(((16, 8, 0), (17, 7, top_k))):
+        sid = engine.add_request(
+            prompt_of(length, seed=100 + n), SamplingParams(
+                temperature=1.0, top_k=k, max_tokens=answers,
+                ignore_eos=True))
+        seqs.append(engine.sequences[sid])
+    while engine.has_work():
+        engine.step()
+    bursts = [s for s in engine.tracer.recent_steps(limit=256)
+              if s.get("kind") == "decode"]
+    assert len(bursts) == 1 and bursts[0]["denoise_passes"] == 4
+    sorted_passes = 4 if top_k else 0
+    assert bursts[0]["sorted_passes"] == sorted_passes
+    assert bursts[0]["window"] == 5           # the passes, as before
+    assert engine.metrics.diffusion_sorted_passes_total == sorted_passes
+    assert (f"vllm:diffusion_sorted_passes_total {sorted_passes}"
+            in engine.metrics.render())
+    assert sum(len(seq.output_token_ids) for seq in seqs) == 15
+
+
 def test_a_burst_reserves_pages_by_blocks():
     engine = LLMEngine(engine_config(decode_steps=30))
     assert engine.runner.burst_blocks == 10
@@ -373,6 +401,7 @@ def test_the_server_streams_a_blocks_tokens_in_order_with_usage():
                 "burst_blocks": 2}
             metrics = await (await client.get("/metrics")).text()
             assert "vllm:diffusion_store_passes_total" in metrics
+            assert "vllm:diffusion_sorted_passes_total 0" in metrics
         finally:
             await client.close()
 
